@@ -654,8 +654,10 @@ max-op-n = 10000
 #                          # under <data-dir>/flightrec, 0 = off
 # warm start (docs/warmup.md)
 # compile-cache-dir = ""   # persistent XLA compile cache; "" =
-#                          # <data-dir>/.compile-cache, "off" disables
-# compile-cache-mb = 256   # cache size bound, LRU-pruned; 0 = unbounded
+#                          # <checkout>/.compile-cache, "off" disables;
+#                          # JAX_COMPILATION_CACHE_DIR overrides either
+# compile-cache-mb = 256   # bound on a cache the program placed,
+#                          # LRU-pruned; 0 = unbounded
 # warmup-top-n = 32        # corpus signatures replayed before READY,
 #                          # 0 = no warmup replay
 # warmup-budget-s = 30     # wall-clock budget for the warmup replay

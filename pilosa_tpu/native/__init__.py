@@ -11,6 +11,8 @@ the slow path rather than failing.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -21,13 +23,18 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 
 
 def _build_and_load(name: str):
-    """Compile native/<name>.c to _<name>.so (if stale) and dlopen it.
-    Returns None on any failure — callers must treat the native path as
-    an optimization, never a requirement."""
+    """dlopen the build of native/<name>.c, compiling it first unless an
+    artefact of exactly this source exists: the .so carries the source's
+    content hash in its name (_<name>.<sha>.so), so a copied tree whose
+    mtimes lie, or an artefact left by another version of the source, is
+    never taken for current.  Returns None when there is no toolchain or
+    the build fails — callers must treat the native path as an
+    optimization, never a requirement."""
     src = os.path.join(_DIR, f"{name}.c")
-    so = os.path.join(_DIR, f"_{name}.so")
-    if os.path.exists(so) and \
-            os.path.getmtime(so) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        sha = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_DIR, f"_{name}.{sha}.so")
+    if os.path.exists(so):
         try:
             return ctypes.CDLL(so)
         except OSError:
@@ -36,18 +43,30 @@ def _build_and_load(name: str):
         # build to a temp file + atomic rename: concurrent importers
         # (test workers, multi-server benches) must not dlopen a
         # half-written .so
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
+        fd, tmp = tempfile.mkstemp(suffix=".so.tmp", dir=_DIR)
         os.close(fd)
-        subprocess.run(
-            ["cc", "-O3", "-shared", "-fPIC", "-o", tmp, src],
-            check=True, capture_output=True, timeout=60)
-        os.replace(tmp, so)
-        return ctypes.CDLL(so)
-    # lint: allow(swallowed-exception) — the native extension is an
-    # optional accelerator: no cc / no toolchain falls back to the pure-
-    # python path, and callers treat None as exactly that
-    except Exception:
+        try:
+            subprocess.run(
+                ["cc", "-O3", "-shared", "-fPIC", "-o", tmp, src],
+                check=True, capture_output=True, timeout=60)
+            os.replace(tmp, so)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        lib = ctypes.CDLL(so)
+    # the native extension is an optional accelerator: no cc / a failed
+    # build falls back to the pure-python path, and callers treat None
+    # as exactly that
+    except (OSError, subprocess.SubprocessError):
         return None
+    # artefacts of other versions of the source
+    for old in glob.glob(os.path.join(_DIR, f"_{name}*.so")):
+        if old != so:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return lib
 
 
 _fp_lib = _build_and_load("fingerprint")
@@ -58,6 +77,12 @@ if _fp_lib is not None:
         ctypes.POINTER(ctypes.c_int64), ctypes.c_long,
     ]
     _fp_lib.fingerprint_scan.restype = ctypes.c_long
+
+
+def fingerprint_live() -> bool:
+    """Whether the C fingerprint scanner built and loaded (the
+    prepared-statement front end takes the Python scanner otherwise)."""
+    return _fp_lib is not None
 
 
 def fingerprint_native(query: str):

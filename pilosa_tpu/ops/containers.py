@@ -146,7 +146,6 @@ def pack_words(idx: np.ndarray, val: np.ndarray) -> Packed:
     counts = np.empty(C, dtype=np.int32)
     offsets = np.empty(C, dtype=np.int32)
     parts: list[np.ndarray] = []
-    off = 0
     a_max = r_max = 0
     for i in range(C):
         a, n = int(start[i]), int(cnt[i])
@@ -185,10 +184,15 @@ def pack_words(idx: np.ndarray, val: np.ndarray) -> Packed:
                 pl = dense
                 counts[i] = CONTAINER_WORDS
         types[i] = ctype
-        offsets[i] = off
         parts.append(pl)
-        off += pl.size
-    payload = np.concatenate(parts) if parts \
+    # payload order: bitmap containers first, so each one starts on a
+    # CONTAINER_WORDS boundary and the TPU kernel (ops/kernels.py)
+    # copies it with one tile-aligned VMEM load; array and run entries
+    # are read word by word and need no alignment
+    order = np.argsort(types != TYPE_BITMAP, kind="stable")
+    sizes = np.array([parts[i].size for i in order], dtype=np.int64)
+    offsets[order] = np.cumsum(sizes) - sizes
+    payload = np.concatenate([parts[i] for i in order]) if parts \
         else np.zeros(0, dtype=np.uint32)
     return Packed(keys, types, counts, offsets, payload, a_max, r_max)
 
@@ -353,7 +357,8 @@ def upload_decode(p: Packed, rows: int, target=None,
 
     arrs = [jax.device_put(a, target) for a in pad_packed(p)]
     a_b, r_b = pow2_bucket(p.a_max), pow2_bucket(p.r_max)
-    backend = kernels.resolve()
+    backend = kernels.backend_for(rows, pow2_bucket(p.payload.size),
+                                  a_b, r_b)
     fn = _decode_jit(rows, words, a_b, r_b, backend)
     reg = devobs.COMPILES
     reg.begin_call()

@@ -5,7 +5,7 @@ The compressed-residency layer (ops/containers.py) decodes packed
 array/bitmap/run container streams to dense tiles with pure-jnp
 gather/scatter — XLA schedules that decode through HBM-resident
 temporaries bounded only by ``decode-workspace-mb``.  This module is the
-hand-scheduled alternative: Pallas kernels that walk the same PR 7
+hand-scheduled alternative: Pallas kernels that walk the same
 key/type/count/offset/payload tables CONTAINER-TILE-BY-TILE, so each
 2048-word dense tile is materialised in a VMEM block, consumed, and
 overwritten by the next grid step instead of round-tripping through HBM.
@@ -13,41 +13,52 @@ Two kernels ship:
 
 * ``decode_block`` — drop-in for ``containers.decode_block`` (same
   signature, same answer): grid over the fragment's ``rows x 16`` output
-  container tiles, each step decoding one container form (bitmap:
-  dynamic-slice copy; array: one-hot scatter of (slot, value) entries;
-  run: per-word range masks OR-reduced) into its (16, 128) VMEM block.
-* ``fused_row_counts`` — the headline fusion: decode + optional AND with
-  a dense filter segment + per-row popcount accumulation in ONE kernel,
-  so the decoded words never exist outside the tile at all (the
-  TopN/Rows ``row_counts`` hot path, parallel/mesh_exec.py).
+  container tiles, each step decoding one container into its (16, 128)
+  VMEM block.
+* ``fused_row_counts`` — decode + optional AND with a dense filter
+  segment + per-row popcount accumulation in ONE kernel, so the decoded
+  words never exist outside the tile at all (the TopN/Rows
+  ``row_counts`` hot path, parallel/mesh_exec.py).
+
+How a tile is decoded is shaped by what Mosaic lowers on a TPU (no
+word-granular dynamic slice, no vector gather/scatter, no scalar reads
+from VMEM):
+
+* the per-tile type/count/offset tables are gathered by XLA outside the
+  kernel and ride in as SCALAR-PREFETCH operands (SMEM), indexed by the
+  grid position;
+* a bitmap container is one tile-aligned ``pl.ds`` load from the
+  payload held in VMEM as ``[P/128, 128]`` — ``containers.pack_words``
+  lays bitmap containers first in the payload so every one starts on a
+  2048-word boundary;
+* array and run containers are scalar loops with a dynamic trip count
+  (the container's own entry count, not its pow2 bucket) reading their
+  entries from a second, scalar-prefetched copy of the payload in SMEM
+  and selecting / OR-ing into the tile against a word-index iota.
+
+Under ``vmap`` (every call site maps over the stacked shard axis) a
+pallas_call with batched scalar-prefetch operands becomes one
+``fori_loop`` over the fragments — jax's own batching rule.
 
 Backend selection rides the ``container-kernels`` knob
 (``CONTAINER_KERNELS``, set process-wide from the server config like
-``DECODE_WORKSPACE_BYTES``): ``auto`` resolves to the Pallas kernels on
-TPU and the jnp decode elsewhere, ``pallas`` forces the kernels
-(executing through the Pallas INTERPRETER off-TPU, so the whole path is
-differentially testable in CPU tier-1), and ``jnp`` is the kill switch
-restoring the PR 7 path exactly.  The resolved backend is part of every
-compressed ``Fragment.device_sig()`` (the kernel-backend axis), so a
-flip changes the group signatures, rebuilds stacks, and recompiles
-executables instead of silently replaying a jnp-compiled program.
-
-TPU-lowering caveat: the kernel bodies use word-granularity dynamic
-slices and gathers that the Pallas interpreter (and a TPU with relaxed
-layout constraints) accepts but that may need 128-lane alignment work
-before they lower on every real-TPU toolchain; the interpret-mode
-differential pins the SEMANTICS now so the r10 on-TPU round only has to
-tune the schedule.  Buckets whose per-tile working set (whole payload +
-form intermediates) exceeds ``VMEM_TILE_BUDGET_BYTES`` fall back to the
-jnp decode — the VMEM budget rule — statically per signature, so the
-choice is trace-stable.
+``DECODE_WORKSPACE_BYTES``): ``auto`` selects the Pallas kernels on a
+TPU for every decode bucket whose footprint ``fits`` the chip's VMEM and
+SMEM, and the jnp decode elsewhere; ``pallas`` forces the kernels for
+every bucket (compiled on a TPU — an over-budget bucket is then the
+compiler's error, not a quiet fallback — and executed through the
+Pallas INTERPRETER off-TPU, so the whole path is differentially
+testable in CPU tier-1); ``jnp`` is the kill switch restoring the jnp
+path exactly.  The selected backend is part of every compressed
+``Fragment.device_sig()`` (the kernel-backend axis), so the choice is
+static per signature, identical on every trace of one executable, and a
+knob flip rebuilds stacks and recompiles instead of replaying a
+jnp-compiled program.
 """
 
 from __future__ import annotations
 
 import functools
-
-import numpy as np
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS, WORD_BITS
 from .containers import TYPE_ARRAY, TYPE_BITMAP, TYPE_RUN
@@ -62,13 +73,19 @@ TILE_ROWS = CONTAINER_WORDS // 128    # 16
 TILE_LANES = 128
 TILES_PER_SHARD_ROW = SHARD_WORDS // CONTAINER_WORDS  # 16
 
-# The VMEM budget rule: a decode bucket only takes the Pallas path when
-# its per-tile working set — the whole (pow2-bucketed) payload the
-# kernel keeps VMEM-resident plus the array/run form intermediates and
-# the tile itself — fits under this.  Over-budget buckets fall back to
-# the jnp decode; the decision depends only on signature fields, so it
-# is identical on every trace of one executable.
-VMEM_TILE_BUDGET_BYTES = 12 << 20
+# What one kernel launch may hold on chip, under the limits the v5e
+# compiler enforces (libtpu 0.0.34, compiled for "TPU v5 lite"): 16 MiB
+# of scoped VMEM by default and 1 MiB of SMEM.  VMEM holds the whole
+# payload block — allocated once, its block index never changes: a
+# 16 MiB payload compiles, a 32 MiB one fails "Scoped allocation with
+# size 32.00M and limit 16.00M" — plus the double-buffered output and
+# filter tiles.  SMEM holds the three per-tile tables and, when the
+# bucket has array or run containers, the payload's scalar copy: a
+# 2^17-word payload compiles, 2^18 fails "Ran out of memory in memory
+# space smem. Used 1.01M of 1.00M".  Both budgets leave the compiler
+# its own headroom.
+VMEM_BUDGET_BYTES = 12 << 20
+SMEM_BUDGET_BYTES = 768 << 10
 
 
 @functools.lru_cache(maxsize=1)
@@ -79,45 +96,52 @@ def _platform() -> str:
     return jax.default_backend()
 
 
-@functools.lru_cache(maxsize=1)
-def _pallas_available() -> bool:
-    """Whether the installed jax ships jax.experimental.pallas — gated
-    so a trimmed install degrades to the jnp backend instead of an
-    ImportError on the query path."""
-    try:
-        from jax.experimental import pallas  # noqa: F401
-    except ImportError:
-        return False
-    return True
-
-
 def resolve(mode: str | None = None) -> str:
-    """Resolved backend ("pallas" | "jnp") for the given knob value
-    (default: the process-wide ``CONTAINER_KERNELS``)."""
+    """Backend ("pallas" | "jnp") the knob value (default: the
+    process-wide ``CONTAINER_KERNELS``) selects for buckets that fit the
+    chip — what the server reports as ``kernel_backend``.  Per-bucket
+    selection is ``backend_for``."""
     m = CONTAINER_KERNELS if mode is None else mode
-    if m == "jnp":
-        return "jnp"
-    if m == "pallas":
-        return "pallas" if _pallas_available() else "jnp"
+    if m in ("jnp", "pallas"):
+        return m
     # auto: kernels where they pay (TPU), jnp elsewhere — CPU tier-1
     # exercises the kernels only when a test/bench forces "pallas"
-    return "pallas" if (_platform() == "tpu" and _pallas_available()) \
-        else "jnp"
+    return "pallas" if _platform() == "tpu" else "jnp"
 
 
 def interpret_mode() -> bool:
     """Off-TPU the kernels run through the Pallas interpreter — same
     kernel logic, XLA:CPU execution — so tier-1 can differentially test
-    the exact code path the TPU compiles."""
+    the exact code path the TPU compiles.  On a TPU they always
+    compile."""
     return _platform() != "tpu"
 
 
-def sig_tag() -> str:
-    """The kernel-backend axis of compressed ``Fragment.device_sig()``
-    tuples (storage/fragment.py): the RESOLVED backend, so an
-    auto->pallas TPU process and an auto->jnp CPU process produce
-    distinct signatures and a knob flip rebuilds stacks/executables."""
-    return resolve()
+def fits(rows: int, payload_bucket: int, a_bucket: int,
+         r_bucket: int) -> bool:
+    """Whether one fragment's decode bucket fits the chip: payload block
+    and tiles in VMEM, tables and the payload's scalar copy in SMEM."""
+    p_bytes = max(payload_bucket, CONTAINER_WORDS) * 4
+    vmem = p_bytes + 4 * CONTAINER_WORDS * 4
+    smem = 3 * rows * TILES_PER_SHARD_ROW * 4
+    if a_bucket or r_bucket:
+        smem += p_bytes
+    return vmem <= VMEM_BUDGET_BYTES and smem <= SMEM_BUDGET_BYTES
+
+
+def backend_for(rows: int, payload_bucket: int, a_bucket: int,
+                r_bucket: int) -> str:
+    """The kernel-backend axis of a compressed ``Fragment.device_sig()``
+    (storage/fragment.py): which decode the executables built for this
+    bucket compile in.  ``auto`` leaves buckets that do not ``fits`` to
+    jnp — statically, by signature; a forced ``pallas`` is never
+    replaced."""
+    if resolve() == "jnp":
+        return "jnp"
+    if CONTAINER_KERNELS == "auto" and not fits(
+            rows, payload_bucket, a_bucket, r_bucket):
+        return "jnp"
+    return "pallas"
 
 
 def sig_backend(sig) -> str:
@@ -127,81 +151,103 @@ def sig_backend(sig) -> str:
     return sig[6] if len(sig) > 6 else "jnp"
 
 
-def fits_vmem(payload_bucket: int, a_bucket: int, r_bucket: int) -> bool:
-    """The VMEM budget rule (module docstring): whether a decode
-    bucket's per-tile working set fits ``VMEM_TILE_BUDGET_BYTES``."""
-    est = (max(payload_bucket, CONTAINER_WORDS)
-           + a_bucket * CONTAINER_WORDS      # one-hot scatter compare
-           + r_bucket * CONTAINER_WORDS      # per-run range masks
-           + CONTAINER_WORDS) * 4
-    return est <= VMEM_TILE_BUDGET_BYTES
-
-
-def _tile_slots(keys, tiles: int):
-    """int32[tiles] inverse container map: output tile t's index into
-    the container tables, -1 where no container covers the tile.  Keys
-    are unique and padding rows carry key -1, so one drop-mode scatter
-    (outside the kernel) builds it."""
+def _tile_tables(keys, types, counts, offsets, tiles: int):
+    """int32[tiles] type/count/offset of the container covering each
+    output tile (type -1, count 0 where none does).  Keys are unique
+    and padding rows carry key -1, so one drop-mode scatter inverts the
+    container map; XLA runs this outside the kernel."""
     import jax.numpy as jnp
     C = keys.shape[0]
     idx = jnp.where(keys >= 0, keys, tiles).astype(jnp.int32)
-    return jnp.full((tiles,), -1, dtype=jnp.int32).at[idx].set(
+    slot = jnp.full((tiles,), -1, dtype=jnp.int32).at[idx].set(
         jnp.arange(C, dtype=jnp.int32), mode="drop")
+    live = slot >= 0
+    ci = jnp.where(live, slot, 0)
+    return (jnp.where(live, types[ci], -1),
+            jnp.where(live, counts[ci], 0),
+            jnp.where(live, offsets[ci], 0))
 
 
-def _pad_payload(payload):
-    """Payload padded to at least one container tile so the kernel's
-    static-size bitmap dynamic-slice never exceeds the buffer."""
+def _kernel_operands(keys, types, counts, offsets, payload, tiles: int,
+                     a_bucket: int, r_bucket: int):
+    """(scalar-prefetch operands, VMEM payload) of one launch: the three
+    per-tile tables, the payload's SMEM copy when the bucket has array
+    or run containers, and the payload as ``[P/128, 128]`` padded to at
+    least one container tile so the bitmap load never leaves it."""
     import jax.numpy as jnp
-    P = payload.shape[0]
-    if P >= CONTAINER_WORDS:
-        return payload
-    return jnp.zeros(CONTAINER_WORDS, dtype=jnp.uint32).at[:P].set(payload)
+    if payload.shape[0] < CONTAINER_WORDS:
+        payload = jnp.zeros(CONTAINER_WORDS, dtype=jnp.uint32).at[
+            :payload.shape[0]].set(payload)
+    scalars = list(_tile_tables(keys, types, counts, offsets, tiles))
+    if a_bucket or r_bucket:
+        scalars.append(payload)
+    return scalars, payload.reshape(-1, TILE_LANES)
 
 
-def _container_tile(pv, typ, cnt, off, a_bucket: int, r_bucket: int):
-    """One container's dense (TILE_ROWS, TILE_LANES) word tile, decoded
-    from the VMEM-resident payload ``pv`` — the per-grid-step body both
-    kernels share.  Mirrors containers.decode_block's per-container
-    math exactly (bitmap copy / array one-hot scatter / run range
-    masks); a_bucket/r_bucket of 0 compile that form out."""
+def _container_tile(typ, cnt, off, pay_s, pay_v, a_bucket: int,
+                    r_bucket: int):
+    """One container's dense (TILE_ROWS, TILE_LANES) word tile — the
+    per-grid-step body both kernels share.  ``typ``/``cnt``/``off`` are
+    SMEM scalars, ``pay_s`` the payload's SMEM ref (None when the bucket
+    has no array or run containers), ``pay_v`` its VMEM ref.  Mirrors
+    containers.decode_block's per-container math (bitmap copy / array
+    (slot, value) entries / run range masks); a_bucket/r_bucket of 0
+    compile that form out.  Offsets are clamped into the payload: a
+    stack staged while a write raced its signature may carry tables that
+    point past the clamped payload (mesh_exec._place_packed_block), and
+    the jnp decode fills such reads with zeros rather than faulting."""
     import jax
     import jax.numpy as jnp
-    cw = CONTAINER_WORDS
-    # bitmap: contiguous copy.  dynamic_slice clamps the start, so a
-    # non-bitmap off near the buffer end reads garbage that the where()
-    # discards — never out of bounds.
-    bm = jax.lax.dynamic_slice(pv, (off,), (cw,))
-    tile = jnp.where(typ == TYPE_BITMAP, bm, jnp.uint32(0))
-    j = jnp.arange(cw, dtype=jnp.int32)
+    from jax.experimental import pallas as pl
+    P = pay_v.shape[0] * TILE_LANES
+    is_bm = typ == TYPE_BITMAP
+    row0 = jnp.where(
+        is_bm, jnp.minimum(off, P - CONTAINER_WORDS) // TILE_LANES, 0)
+    bm = pay_v[pl.ds(pl.multiple_of(row0, TILE_ROWS), TILE_ROWS), :]
+    tile = jnp.where(is_bm, bm, jnp.uint32(0))
+    shape = (TILE_ROWS, TILE_LANES)
+    word = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * TILE_LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+    def entry(i):
+        return pay_s[jnp.minimum(i, P - 1)]
+
     if a_bucket:
-        e = jnp.arange(a_bucket, dtype=jnp.int32)
-        live = (e < cnt) & (typ == TYPE_ARRAY)
-        slots = jnp.where(live, pv.at[off + e].get(
-            mode="fill", fill_value=0).astype(jnp.int32), -1)
-        vals = pv.at[off + cnt + e].get(mode="fill", fill_value=0)
-        hit = slots[:, None] == j[None, :]               # [a_bucket, cw]
-        tile = tile | jax.lax.reduce(
-            jnp.where(hit, vals[:, None], jnp.uint32(0)), np.uint32(0),
-            jax.lax.bitwise_or, dimensions=(0,))
+        def a_body(e, tile):
+            slot = entry(off + e).astype(jnp.int32)
+            return jnp.where(word == slot, entry(off + cnt + e), tile)
+
+        tile = jax.lax.fori_loop(
+            0, jnp.where(typ == TYPE_ARRAY, cnt, 0), a_body, tile)
     if r_bucket:
-        r = jnp.arange(r_bucket, dtype=jnp.int32)
-        live = (r < cnt) & (typ == TYPE_RUN)
-        rs = jnp.where(live, pv.at[off + 2 * r].get(
-            mode="fill", fill_value=0).astype(jnp.int32), 0)
-        re_ = jnp.where(live, pv.at[off + 2 * r + 1].get(
-            mode="fill", fill_value=0).astype(jnp.int32), 0)
-        base = j * WORD_BITS
-        lo = jnp.clip(rs[:, None] - base[None, :], 0, WORD_BITS)
-        hi = jnp.clip(re_[:, None] - base[None, :], 0, WORD_BITS)
+        base = word * WORD_BITS
         full = jnp.uint32(0xFFFFFFFF)
-        mhi = jnp.where(hi == 0, jnp.uint32(0),
-                        full >> (WORD_BITS - hi).astype(jnp.uint32))
-        mlo = jnp.where(lo == 0, jnp.uint32(0),
-                        full >> (WORD_BITS - lo).astype(jnp.uint32))
-        tile = tile | jax.lax.reduce(mhi & ~mlo, np.uint32(0),
-                                     jax.lax.bitwise_or, dimensions=(0,))
-    return tile.reshape(TILE_ROWS, TILE_LANES)
+
+        def below(n):
+            # the n low bits set, n in [0, WORD_BITS]
+            return jnp.where(
+                n == 0, jnp.uint32(0),
+                full >> (WORD_BITS - n).astype(jnp.uint32))
+
+        def r_body(r, tile):
+            rs = entry(off + 2 * r).astype(jnp.int32)
+            re_ = entry(off + 2 * r + 1).astype(jnp.int32)
+            lo = jnp.clip(rs - base, 0, WORD_BITS)
+            hi = jnp.clip(re_ - base, 0, WORD_BITS)
+            return tile | (below(hi) & ~below(lo))
+
+        tile = jax.lax.fori_loop(
+            0, jnp.where(typ == TYPE_RUN, cnt, 0), r_body, tile)
+    return tile
+
+
+def _step_tile(refs, ns: int, t, a_bucket: int, r_bucket: int):
+    """The tile of grid position ``t`` from a kernel's refs: the
+    ``ns`` scalar-prefetch refs (three tables, then the payload's SMEM
+    copy when ns == 4) followed by the VMEM payload."""
+    return _container_tile(
+        refs[0][t], refs[1][t], refs[2][t], refs[3] if ns == 4 else None,
+        refs[ns], a_bucket, r_bucket)
 
 
 def decode_block(keys, types, counts, offsets, payload, *, rows: int,
@@ -209,55 +255,37 @@ def decode_block(keys, types, counts, offsets, payload, *, rows: int,
                  r_bucket: int = 0):
     """Pallas drop-in for ``containers.decode_block``: decode one
     fragment's packed stream to dense ``uint32[rows, words]``, one
-    container tile per grid step.  Same arguments, same answer; buckets
-    over the VMEM budget rule (and degenerate shapes) fall back to the
-    jnp decode."""
+    container tile per grid step.  Same arguments, same answer."""
     import jax
     import jax.numpy as jnp
-
-    from . import containers
-
-    C = keys.shape[0]
-    if (C == 0 or rows == 0 or words % CONTAINER_WORDS
-            or not fits_vmem(payload.shape[0], a_bucket, r_bucket)):
-        return containers.decode_block(
-            keys, types, counts, offsets, payload, rows=rows, words=words,
-            a_bucket=a_bucket, r_bucket=r_bucket)
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    if keys.shape[0] == 0 or rows == 0:
+        return jnp.zeros((rows, words), dtype=jnp.uint32)
     tiles = rows * (words // CONTAINER_WORDS)
-    slot = _tile_slots(keys, tiles)
-    pay = _pad_payload(payload)
+    scalars, pay_v = _kernel_operands(
+        keys, types, counts, offsets, payload, tiles, a_bucket, r_bucket)
+    ns = len(scalars)
 
-    def kernel(slot_ref, types_ref, counts_ref, offsets_ref, pay_ref,
-               out_ref):
-        t = pl.program_id(0)
-        c = slot_ref[...][t]
-        live = c >= 0
-        ci = jnp.where(live, c, 0)
-        typ = jnp.where(live, types_ref[...][ci], -1)
-        cnt = jnp.where(live, counts_ref[...][ci], 0)
-        off = jnp.where(live, offsets_ref[...][ci], 0)
-        out_ref[...] = _container_tile(pay_ref[...], typ, cnt, off,
-                                       a_bucket, r_bucket)
+    def kernel(*refs):
+        refs[-1][...] = _step_tile(refs, ns, pl.program_id(0), a_bucket,
+                                   r_bucket)
 
-    full = [slot, types, counts, offsets, pay]
     out = pl.pallas_call(
         kernel,
-        grid=(tiles,),
-        in_specs=[pl.BlockSpec(a.shape, _full_block) for a in full],
-        out_specs=pl.BlockSpec((TILE_ROWS, TILE_LANES), lambda t: (t, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=ns, grid=(tiles,),
+            # whole payload every step: fetched once per fragment
+            in_specs=[pl.BlockSpec(pay_v.shape, lambda t, *_: (0, 0))],
+            out_specs=pl.BlockSpec((TILE_ROWS, TILE_LANES),
+                                   lambda t, *_: (t, 0))),
         out_shape=jax.ShapeDtypeStruct((tiles * TILE_ROWS, TILE_LANES),
                                        jnp.uint32),
         interpret=interpret_mode(),
-    )(*full)
+        name="container_decode",
+    )(*scalars, pay_v)
     return out.reshape(rows, words)
-
-
-def _full_block(t):
-    # whole-array input block every grid step (tables + payload stay
-    # VMEM-resident across the container tiles of one fragment)
-    return (0,)
 
 
 def fused_row_counts(keys, types, counts, offsets, payload, filt=None, *,
@@ -267,68 +295,56 @@ def fused_row_counts(keys, types, counts, offsets, payload, filt=None, *,
     kernel launch: int32[rows] set-bit counts of a packed fragment,
     optionally masked by a dense ``uint32[words]`` segment.  The decoded
     words exist only as the grid step's VMEM tile — no dense
-    ``[rows, words]`` temporary at all (the jnp path's decode output).
-    Falls back to decode+popcount via jnp under the same conditions as
-    ``decode_block``."""
+    ``[rows, words]`` temporary at all (the jnp path's decode output)."""
     import jax
     import jax.numpy as jnp
-
-    from . import containers
-
-    C = keys.shape[0]
-    if (C == 0 or rows == 0 or words % CONTAINER_WORDS
-            or not fits_vmem(payload.shape[0], a_bucket, r_bucket)):
-        frag = containers.decode_block(
-            keys, types, counts, offsets, payload, rows=rows, words=words,
-            a_bucket=a_bucket, r_bucket=r_bucket)
-        if filt is not None:
-            frag = frag & filt[None, :]
-        return jnp.sum(jax.lax.population_count(frag).astype(jnp.int32),
-                       axis=-1)
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    if keys.shape[0] == 0 or rows == 0:
+        return jnp.zeros((rows,), dtype=jnp.int32)
     tpr = words // CONTAINER_WORDS
-    tiles = rows * tpr
-    slot = _tile_slots(keys, tiles)
-    pay = _pad_payload(payload)
+    scalars, pay_v = _kernel_operands(
+        keys, types, counts, offsets, payload, rows * tpr, a_bucket,
+        r_bucket)
+    ns = len(scalars)
+    half = TILE_ROWS // 2
 
-    def kernel(slot_ref, types_ref, counts_ref, offsets_ref, pay_ref,
-               *rest):
-        filt_out = rest
-        t = pl.program_id(0)
-        c = slot_ref[...][t]
-        live = c >= 0
-        ci = jnp.where(live, c, 0)
-        typ = jnp.where(live, types_ref[...][ci], -1)
-        cnt = jnp.where(live, counts_ref[...][ci], 0)
-        off = jnp.where(live, offsets_ref[...][ci], 0)
-        tile = _container_tile(pay_ref[...], typ, cnt, off,
-                               a_bucket, r_bucket)
-        if len(filt_out) == 2:
-            tile = tile & filt_out[0][...]
-        out_ref = filt_out[-1]
-        # out block (1, 1) revisited by the row's tpr consecutive steps:
-        # zero on the first, accumulate the tile popcount on each
-        @pl.when(t % tpr == 0)
+    def kernel(*refs):
+        out_ref = refs[-1]
+        k = pl.program_id(1)
+        tile = _step_tile(refs, ns, pl.program_id(0) * tpr + k, a_bucket,
+                          r_bucket)
+        if filt is not None:
+            tile = tile & refs[ns + 1][...]
+        pc = jax.lax.population_count(tile).astype(jnp.int32)
+
+        # the row's (8, 128) accumulator block is revisited by its tpr
+        # consecutive steps: zero on the first, add this tile's per-lane
+        # popcounts (its two sublane halves folded) on each.  The
+        # cross-lane sum waits for XLA outside the kernel.
+        @pl.when(k == 0)
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
-        out_ref[...] += jnp.sum(
-            jax.lax.population_count(tile).astype(jnp.int32))[None, None]
+        out_ref[...] += pc[:half] + pc[half:]
 
-    full = [slot, types, counts, offsets, pay]
-    in_specs = [pl.BlockSpec(a.shape, _full_block) for a in full]
+    operands = [pay_v]
+    in_specs = [pl.BlockSpec(pay_v.shape, lambda r, k, *_: (0, 0))]
     if filt is not None:
         # the filter segment's matching container tile rides in a
         # (16, 128) block indexed by the step's position within the row
-        full.append(filt.reshape(tpr * TILE_ROWS, TILE_LANES))
+        operands.append(filt.reshape(tpr * TILE_ROWS, TILE_LANES))
         in_specs.append(pl.BlockSpec((TILE_ROWS, TILE_LANES),
-                                     lambda t, _tpr=tpr: (t % _tpr, 0)))
+                                     lambda r, k, *_: (k, 0)))
     out = pl.pallas_call(
         kernel,
-        grid=(tiles,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda t, _tpr=tpr: (t // _tpr, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=ns, grid=(rows, tpr), in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, half, TILE_LANES),
+                                   lambda r, k, *_: (r, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((rows, half, TILE_LANES),
+                                       jnp.int32),
         interpret=interpret_mode(),
-    )(*full)
-    return out[:, 0]
+        name="container_row_counts",
+    )(*scalars, *operands)
+    return out.sum(axis=(1, 2))

@@ -61,8 +61,8 @@ from ..utils import devobs as _devobs
 from ..utils import profile as qprof
 from ..utils.deadline import check_current
 from ..utils.faults import FAULTS
-from .mesh_exec import _DISPATCH_LOCK, _SM_CHECK_KW, _flatten_present, \
-    _shard_map, _sig_rows, _unpack_frags, SHARD_AXIS
+from .mesh_exec import _DISPATCH_LOCK, _flatten_present, _sig_rows, \
+    _unpack_frags, SHARD_AXIS
 
 
 class WholeQueryUnsupported(Exception):
@@ -595,9 +595,8 @@ class WholeQueryRunner:
         check = not any(
             n > 1 and _kernels.sig_backend(s) == "pallas"
             for layout_g, _ in groups_static for _, n, s in layout_g)
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(jax.shard_map(
             traced, mesh=self.mesh.mesh,
             in_specs=(P(),) + (P(SHARD_AXIS),) * n_flat_all,
-            out_specs=tuple(out_specs),
-            **{_SM_CHECK_KW: check}))
+            out_specs=tuple(out_specs), check_vma=check))
         return _InstrumentedWhole(fn, key, out_index)

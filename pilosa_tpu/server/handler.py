@@ -238,7 +238,8 @@ def build_debug_vars(api: API, server=None) -> dict:
     # time-series summary; full detail at /debug/compiles,
     # /debug/launches, /debug/timeseries
     from ..utils import devobs
-    out["device"] = {"compiles": devobs.COMPILES.totals(),
+    out["device"] = {**devobs.device_info(),
+                     "compiles": devobs.COMPILES.totals(),
                      "launches": devobs.LEDGER.aggregates()}
     # warm start (docs/warmup.md): phase, replay progress, and the
     # compile-seconds-saved headline for the deploy dashboard

@@ -77,9 +77,10 @@ class Config:
     # cut so one launch never decodes more dense tile bytes than this.
     decode_workspace_mb: int = 1024
     # Container decode backend (ops/kernels.py): "auto" picks the fused
-    # Pallas kernels on TPU and the jnp decode elsewhere; "pallas"
-    # forces the kernels (interpreted off-TPU — the differential-test
-    # mode); "jnp" is the kill switch restoring the pure-XLA decode.
+    # Pallas kernels on TPU, per decode bucket that fits the chip, and
+    # the jnp decode elsewhere; "pallas" forces the kernels (compiled on
+    # TPU, interpreted off-TPU — the differential-test mode); "jnp" is
+    # the kill switch restoring the pure-XLA decode.
     container_kernels: str = "auto"
     # -- streaming ingest (docs/ingest.md) ---------------------------------
     # Group-commit window: milliseconds the committer lets submissions
@@ -308,11 +309,15 @@ class Config:
     # -- warm start (docs/warmup.md) ---------------------------------------
     # Directory for jax's persistent XLA compilation cache, so a
     # restarted process reuses executables instead of recompiling.
-    # "" = <data-dir>/.compile-cache; "off" disables the on-disk cache
-    # (the signature corpus + warmup replay still run).
+    # JAX_COMPILATION_CACHE_DIR in the environment wins over this
+    # option and is never pruned; "" = <checkout>/.compile-cache (one
+    # fixed path: the directory is part of the cache key); "off"
+    # disables the program's own cache (the signature corpus + warmup
+    # replay still run).
     compile_cache_dir: str = ""
-    # Size bound (MB) for the compile-cache directory, LRU-pruned by
-    # file mtime at startup and clean shutdown.  0 = unbounded.
+    # Size bound (MB) for a compile-cache directory the program placed,
+    # LRU-pruned by file mtime at startup and clean shutdown.
+    # 0 = unbounded.
     compile_cache_mb: int = 256
     # Corpus signatures the AOT warmup replayer replays at startup (the
     # top-N by traffic) before this node reports READY.  0 disables the
@@ -770,27 +775,23 @@ class Server:
             if slo.enabled:
                 self.slo = slo
         # Warm-start subsystem (docs/warmup.md): persistent XLA compile
-        # cache under the data dir, durable signature corpus, and the
+        # cache (the environment's directory, else one fixed path —
+        # never under the data dir), durable signature corpus, and the
         # AOT warmup coordinator that replays the corpus before READY.
         # The compile cache is configured HERE (before any executable
         # compiles) so even the first queries of a fresh process land
         # their compilations on disk for the next restart.
         from .. import warmup as _warmup
-        self._compile_cache_dir = _warmup.resolve_dir(
-            self.config.compile_cache_dir, data_dir)
-        cache_on = False
-        if self._compile_cache_dir is not None:
-            cache_on = _warmup.configure(self._compile_cache_dir)
-            if cache_on:
-                _warmup.prune(self._compile_cache_dir,
-                              self.config.compile_cache_mb)
+        self._compile_cache_dir = _warmup.configure(
+            self.config.compile_cache_dir)
+        self._prune_compile_cache()
         self.warmup = _warmup.WarmupCoordinator(
             self.api.executor,
             os.path.join(data_dir, "signatures.log"),
             top_n=self.config.warmup_top_n,
             budget_s=self.config.warmup_budget_s,
             logger=self.logger, stats=self.stats)
-        self.warmup.cache_enabled = cache_on
+        self.warmup.cache_enabled = self._compile_cache_dir is not None
         self.api.warmup = self.warmup
         # the executor feeds the corpus recorder on its success paths
         # (the logger-injection pattern)
@@ -828,6 +829,15 @@ class Server:
         self._threads: list[threading.Thread] = []
         self._closing = threading.Event()
 
+    def _prune_compile_cache(self):
+        """LRU-prune the compile cache to ``compile-cache-mb`` — only a
+        directory the program placed; one the environment named is its
+        owner's to bound."""
+        from .. import warmup as _warmup
+        if self._compile_cache_dir is not None and _warmup.owned():
+            _warmup.prune(self._compile_cache_dir,
+                          self.config.compile_cache_mb)
+
     @staticmethod
     def _parse_bind(bind: str) -> tuple[str, int]:
         bind = bind.removeprefix("https://").removeprefix("http://")
@@ -862,8 +872,12 @@ class Server:
         t = threading.Thread(target=self.httpd.serve_forever, daemon=True)
         t.start()
         self._threads.append(t)
+        from ..utils import devobs
+        dev = devobs.device_info()
         self.logger.info(
-            f"pilosa-tpu listening on http://{self.config.bind}")
+            f"pilosa-tpu listening on http://{self.config.bind}; "
+            f"device platform={dev['platform']} "
+            f"kind={dev['deviceKind']!r} count={dev['deviceCount']}")
         if self.cluster is not None and self.config.anti_entropy_interval > 0:
             t = threading.Thread(target=self._monitor_anti_entropy,
                                  daemon=True)
@@ -1281,10 +1295,7 @@ class Server:
         # final corpus flush while the compile registry still holds this
         # run's entries, and LRU-prune the compile cache to its bound
         self.warmup.close()
-        if self._compile_cache_dir is not None:
-            from .. import warmup as _warmup
-            _warmup.prune(self._compile_cache_dir,
-                          self.config.compile_cache_mb)
+        self._prune_compile_cache()
         # release this server's on-disk event log handle (the journal
         # itself is process-wide and keeps its ring)
         from ..utils.events import EVENTS

@@ -1201,27 +1201,28 @@ class Fragment:
         carry ('z', rows, C, P, A, R, backend) with pow2-bucketed
         container, payload, array-entry and run counts so one compiled
         decode executable serves every fragment in a bucket.  The
-        trailing element is the RESOLVED container-kernels backend
-        (ops/kernels.py): the decode code compiled into the executable
-        is part of its shape, so a knob flip mints new signatures —
-        new plans, new stacks, fresh compiles — instead of replaying a
-        jnp-compiled program through the pallas path (the PR 7 retrace
-        class)."""
+        trailing element is the container-kernels backend selected for
+        this bucket (ops/kernels.py backend_for): the decode code
+        compiled into the executable is part of its shape, so a knob
+        flip mints new signatures — new plans, new stacks, fresh
+        compiles — instead of replaying a jnp-compiled program through
+        the pallas path (the PR 7 retrace class)."""
         if self.device_form() == "dense":
             return (self.n_rows, SHARD_WORDS)
         from ..ops import kernels
         from ..ops.containers import pow2_bucket
-        backend = kernels.sig_tag()
+        knob = kernels.CONTAINER_KERNELS
         with self._lock:
             s = self._psig
-            if s is not None and s[0] == (self.device_gen, backend):
+            if s is not None and s[0] == (self.device_gen, knob):
                 return s[1]
         p = self.packed_host()
-        sig = ("z", self.n_rows, pow2_bucket(p.keys.size),
-               pow2_bucket(p.payload.size), pow2_bucket(p.a_max),
-               pow2_bucket(p.r_max), backend)
+        rows, pb = self.n_rows, pow2_bucket(p.payload.size)
+        ab, rb = pow2_bucket(p.a_max), pow2_bucket(p.r_max)
+        sig = ("z", rows, pow2_bucket(p.keys.size), pb, ab, rb,
+               kernels.backend_for(rows, pb, ab, rb))
         with self._lock:
-            self._psig = ((self.device_gen, backend), sig)
+            self._psig = ((self.device_gen, knob), sig)
         return sig
 
     def packed_stats(self) -> dict | None:

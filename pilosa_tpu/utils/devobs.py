@@ -48,6 +48,18 @@ from .stats import BucketHistogram
 def _wall_stamp() -> float: return time.time()  # display-only wall clock
 
 
+def device_info() -> dict:
+    """The device jax runs this process on, as jax reports it —
+    {platform, deviceKind, deviceCount}: what the server logs at
+    start-up and /debug/vars' ``device`` section shows, so a process
+    that fell back to the CPU says so."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "deviceKind": devs[0].device_kind,
+            "deviceCount": len(devs)}
+
+
 def fingerprint(args) -> str:
     """Compact argument-shape fingerprint of one executable call —
     ``8x4:int32|16x12x32768:uint32|...`` — the thing a retrace DIFFS:

@@ -5,21 +5,21 @@ A restart today pays the full trace+compile bill per program signature;
 the reference engine just reopens mmap'd fragments.  This package earns
 the same property for an XLA-lowered engine in three layers:
 
-* ``compile_cache`` — jax's on-disk persistent compilation cache wired
-  under data-dir, size-bounded with LRU pruning;
+* ``compile_cache`` — jax's on-disk persistent compilation cache on one
+  fixed directory (or the environment's), size-bounded with LRU pruning;
 * ``corpus`` — a CRC-framed durable log of what this process compiles
   (signature, shape fingerprint, params schema/template, traffic);
 * ``replayer`` — the boot-time coordinator that replays the top-N
   corpus queries through the real compile paths before READY.
 """
 
-from .compile_cache import cache_stats, configure, prune, resolve_dir
+from .compile_cache import cache_stats, configure, owned, prune
 from .corpus import CorpusRecorder, SignatureCorpus, top_n
 from .replayer import (PHASE_COLD, PHASE_READY, PHASE_WARMING,
                        WarmupCoordinator)
 
 __all__ = [
-    "cache_stats", "configure", "prune", "resolve_dir",
+    "cache_stats", "configure", "owned", "prune",
     "CorpusRecorder", "SignatureCorpus", "top_n",
     "PHASE_COLD", "PHASE_READY", "PHASE_WARMING", "WarmupCoordinator",
 ]

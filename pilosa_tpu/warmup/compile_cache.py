@@ -2,71 +2,85 @@
 cache").
 
 jax ships an on-disk compilation cache (keyed by a hash of the lowered
-HLO + compile options + backend version); pointing it under data-dir
-means a restarted process REUSES yesterday's executables instead of
-re-lowering and re-compiling them.  The warmup replayer
-(warmup/replayer.py) drives the top-N corpus queries through the real
-compile paths at startup, so every hit lands here at disk speed instead
-of XLA-compile speed — that's the whole warm-start story: the corpus
-remembers WHAT to compile, this cache remembers the COMPILED BYTES.
+HLO + compile options + backend version + the cache directory itself);
+a restarted process that finds the same directory REUSES yesterday's
+executables instead of re-lowering and re-compiling them.  The warmup
+replayer (warmup/replayer.py) drives the top-N corpus queries through
+the real compile paths at startup, so every hit lands here at disk speed
+instead of XLA-compile speed — that's the whole warm-start story: the
+corpus remembers WHAT to compile, this cache remembers the COMPILED
+BYTES.
+
+Where the cache lives, in order of precedence:
+
+1. ``JAX_COMPILATION_CACHE_DIR`` in the environment: jax reads it
+   itself.  The program sets no directory in code and never prunes it —
+   whoever placed it owns it.
+2. ``compile-cache-dir`` set to a path: that path (``off`` disables the
+   program's own cache).
+3. otherwise ``DEFAULT_DIR``, one fixed path inside the checkout.  The
+   directory is part of every cache key, so a cache that moved with the
+   data dir (a fresh ``mkdtemp`` per run) never hit.
+
+jax initialises its cache once per process, so the first ``Server``
+decides and every later one in the process reports the same directory.
 
 This module is deliberately thin glue:
 
-* ``configure(dir)`` flips the three jax config knobs (cache dir, and
-  both min-compile-time/min-entry-size floors to zero — the defaults
-  skip sub-second compiles, which on CPU smoke runs is everything).
-  Gated in try/except: an older jax without the knobs, or no jax at
-  all, degrades to no persistent cache, never a failed boot.
+* ``configure(dir)`` puts the process on its cache directory (only
+  where none is in effect yet) and drops both
+  min-compile-time/min-entry-size floors to zero — the defaults skip
+  sub-second compiles, which on CPU smoke runs is everything.
 * ``prune(dir, max_mb)`` LRU-prunes the cache directory to the
   ``compile-cache-mb`` bound by file mtime (jax touches entries on
   read), oldest first.  Runs at startup (before the cache is hot) and
-  on clean shutdown.
-
-The cache directory defaults to ``<data-dir>/.compile-cache`` (knob
-``compile-cache-dir``); ``off`` disables the whole subsystem.
+  on clean shutdown, on directories ``owned`` by the program only.
 """
 
 from __future__ import annotations
 
 import os
 
-# Hidden: the holder scans data-dir subdirectories as indexes and
-# skips dot-dirs, so the cache must not look like an index.
-DEFAULT_SUBDIR = ".compile-cache"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+# <checkout>/.compile-cache (listed in .gitignore)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".compile-cache")
 
 
-def resolve_dir(cache_dir: str, data_dir: str | None) -> str | None:
-    """The effective cache directory for the config knobs: explicit
-    path wins, "" means <data-dir>/.compile-cache, "off" (or "" with no
-    data dir) disables."""
+def owned() -> bool:
+    """Whether the cache directory is the program's own to prune — not
+    one the environment placed."""
+    return not os.environ.get(ENV_VAR)
+
+
+def configure(cache_dir: str) -> str | None:
+    """Put this process's persistent compilation cache on its directory
+    and return the one in effect (None: disabled).  A directory already
+    in effect — from the environment, or from an earlier ``Server`` in
+    this process — is left alone; otherwise ``cache_dir`` ("" =
+    ``DEFAULT_DIR``, "off" = none) is created and set.  An unwritable
+    directory disables the cache (``cacheEnabled=false`` on the warmup
+    status surface): a warm start is an optimization, never a boot
+    requirement."""
+    import jax
+    # default floors skip fast/small compiles; the corpus replays
+    # exactly the programs we want cached, so cache everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    current = jax.config.jax_compilation_cache_dir
+    if current:
+        return current
     if cache_dir == "off":
         return None
-    if cache_dir:
-        return cache_dir
-    if data_dir:
-        return os.path.join(data_dir, DEFAULT_SUBDIR)
-    return None
-
-
-def configure(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``;
-    returns False (disabled) when jax is missing or too old — a warm
-    start is an optimization, never a boot requirement."""
+    target = cache_dir or DEFAULT_DIR
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # default floors skip fast/small compiles; the corpus replays
-        # exactly the programs we want cached, so cache everything
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        return True
-    # lint: allow(swallowed-exception) — no jax / old jax / unwritable
-    # dir all mean "no persistent cache", a pure perf downgrade the
-    # warmup status surface reports as cacheEnabled=false
-    except Exception:
-        return False
+        os.makedirs(target, exist_ok=True)
+    except OSError:
+        return None
+    jax.config.update("jax_compilation_cache_dir", target)
+    return target
 
 
 def cache_stats(cache_dir: str) -> dict:

@@ -1,13 +1,16 @@
 """Fused Pallas container kernels (ops/kernels.py): per-container-form
 kernel goldens against the ``unpack_packed`` host oracle, the fused
-decode+op+popcount kernel, backend resolution (the ``container-kernels``
-knob and its kill switch), the device_sig kernel-backend axis (a flip
-must rebuild stacks, not retrace — the PR 7 retrace class), and the
-3-LEG DIFFERENTIAL: a mixed-forms corpus executed dense-resident,
-compressed-jnp, and compressed-pallas-interpret must return
-byte-identical results with zero retrace alarms.  Everything runs
-through the Pallas INTERPRETER on the CPU tier-1 platform — the same
-kernel logic a TPU compiles."""
+decode+op+popcount kernel, backend selection (the ``container-kernels``
+knob, its kill switch, and the static per-bucket budget rule), the
+device_sig kernel-backend axis (a flip must rebuild stacks, not retrace
+— the PR 7 retrace class), and the 3-LEG DIFFERENTIAL: a mixed-forms
+corpus executed dense-resident, compressed-jnp, and
+compressed-pallas-interpret must return byte-identical results with
+zero retrace alarms.  Answers come from the Pallas INTERPRETER on the
+CPU tier-1 platform — the same kernel logic a TPU compiles — and
+``test_kernels_lower_for_tpu`` lowers every kernel ``auto`` can select
+for the TPU with ``interpret=False``, so a kernel that stops lowering
+fails here and not on the next chip run."""
 
 import numpy as np
 import pytest
@@ -161,33 +164,135 @@ def test_fused_row_counts_golden(rng):
                                   _popcounts(dense & filt[None, :]))
 
 
-def test_vmem_budget_rule_falls_back(rng, monkeypatch):
-    """A bucket whose working set exceeds the VMEM budget rule must
-    take the jnp fallback — and still be exact (the rule is a schedule
-    choice, never a correctness choice)."""
-    monkeypatch.setattr(kernels, "VMEM_TILE_BUDGET_BYTES", 1024)
-    assert not kernels.fits_vmem(1 << 20, 0, 0)
-    flat = np.sort(rng.choice(SHARD_WORDS, 64, replace=False)) \
-        .astype(np.int64)
-    vals = rng.integers(1, 1 << 32, 64, dtype=np.uint64) \
-        .astype(np.uint32)
-    _kernel_golden(flat, vals, rows=1)
+def test_budget_rule_selects_statically(force_backend, monkeypatch):
+    """``auto`` on a TPU selects the kernels per decode bucket, from the
+    signature alone: a bucket over the VMEM or SMEM budget is a jnp
+    signature from the start (never a lowering error answered by jnp),
+    and a forced ``pallas`` is never replaced."""
+    monkeypatch.setattr(kernels, "_platform", lambda: "tpu")
+    force_backend("auto")
+    small = (8, 16384, 512, 64)
+    assert kernels.fits(*small)
+    assert kernels.backend_for(*small) == "pallas"
+    # array entries need the payload's scalar copy in SMEM: 2^18 words
+    # is the 1 MiB the v5e compiler refuses; a bitmap-only bucket of the
+    # same payload needs none
+    assert not kernels.fits(8, 1 << 18, 8, 0)
+    assert kernels.backend_for(8, 1 << 18, 8, 0) == "jnp"
+    assert kernels.backend_for(8, 1 << 18, 0, 0) == "pallas"
+    # the payload block itself against the scoped VMEM
+    assert kernels.backend_for(64, 1 << 22, 0, 0) == "jnp"
+    force_backend("pallas")
+    assert kernels.backend_for(8, 1 << 18, 8, 0) == "pallas"
+    force_backend("jnp")
+    assert kernels.backend_for(*small) == "jnp"
 
 
 # -- backend resolution and the device_sig backend axis ---------------------
 
-def test_resolve_backends(force_backend):
+def test_resolve_backends(force_backend, monkeypatch):
     """Knob semantics: jnp is the kill switch, pallas forces the
-    kernels, auto picks by platform (jnp on the CPU tier-1 box)."""
+    kernels, auto picks by platform (jnp on the CPU tier-1 box).  On a
+    TPU nothing interprets and nothing forced turns into jnp."""
     import jax
+    assert jax.default_backend() == "cpu"
     force_backend("jnp")
     assert kernels.resolve() == "jnp"
     force_backend("pallas")
     assert kernels.resolve() == "pallas"
     force_backend("auto")
-    want = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    assert kernels.resolve() == want
-    assert kernels.interpret_mode() == (jax.default_backend() != "tpu")
+    assert kernels.resolve() == "jnp"
+    assert kernels.interpret_mode()
+    monkeypatch.setattr(kernels, "_platform", lambda: "tpu")
+    assert kernels.resolve() == "pallas"
+    assert not kernels.interpret_mode()
+    force_backend("pallas")
+    assert kernels.resolve() == "pallas"
+
+
+def _smoke_buckets():
+    """(rows, C, P, A, R) decode buckets of chip_smoke.py's sparse
+    corpus — the signatures its compressed leg hands the kernels on the
+    chip — from real fragments of a few generated shards."""
+    import chip_smoke
+    budget = DeviceBudget(limit_bytes=64 << 20)
+    out = set()
+    for shard in range(3):
+        words = chip_smoke.shard_words(7, shard, sparse=True)
+        for lo, hi in ((0, chip_smoke.SEG_ROWS),
+                       (chip_smoke.SEG_ROWS, chip_smoke.ROWS)):
+            f = Fragment(None, "i", "f", "standard", shard, budget=budget)
+            for r in range(lo, hi):
+                f.set_row(r - lo, words[r])
+            sig = f.device_sig()
+            assert sig[0] == "z", sig
+            out.add(sig[1:6])
+    return sorted(out)
+
+
+def _aot_tpu():
+    """scripts/aot_tpu.py as a module: the one definition of "every
+    kernel over a decode bucket" that the lowering test here and the
+    deviceless compile there share."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "aot_tpu.py")
+    spec = importlib.util.spec_from_file_location("aot_tpu", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_kernels_lower_for_tpu(monkeypatch):
+    """Every kernel ``auto`` can select on a TPU lowers for the TPU
+    with ``interpret=False`` — the Pallas->Mosaic lowering, run from
+    the CPU by cross-platform lowering — over the smoke corpus's
+    buckets plus a run-bearing and a bitmap-only one, under the
+    call sites' vmap over the stacked shard axis.  (What only the TPU
+    compiler itself can refuse — VMEM and SMEM limits, layouts — is
+    what ``fits`` bounds, test_kernels_compile_for_v5e compiles and
+    chip_smoke.py runs.)"""
+    import jax
+    monkeypatch.setattr(kernels, "_platform", lambda: "tpu")
+    aot = _aot_tpu()
+    generated = _smoke_buckets()
+    assert set(generated) <= set(aot.SMOKE_BUCKETS), generated
+    buckets = list(aot.SMOKE_BUCKETS) + [(8, 128, 16384, 64, 64),
+                                         (4, 64, 1 << 17, 0, 0)]
+    lowered = 0
+    for bucket in buckets:
+        rows, _, P, A, R = bucket
+        assert kernels.backend_for(rows, P, A, R) == "pallas", bucket
+        for fn, avals in aot.kernel_cases(bucket).values():
+            text = jax.jit(fn).trace(*avals).lower(
+                lowering_platforms=("tpu",)).as_text()
+            assert "tpu_custom_call" in text
+            lowered += 1
+    assert lowered == 3 * len(buckets)
+
+
+def test_kernels_compile_for_v5e():
+    """The other half of test_kernels_lower_for_tpu: the TPU compiler
+    itself, Mosaic included, against a deviceless v5e topology
+    (scripts/aot_tpu.py) — VMEM, SMEM and layouts, which lowering
+    alone cannot see.  In a subprocess: libtpu initialises there, not
+    in the test process.  Skipped where libtpu offers no topology."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "scripts", "aot_tpu.py")],
+        capture_output=True, text=True, timeout=600, cwd=root)
+    if out.returncode == 3:
+        pytest.skip(f"no deviceless TPU topology here: {out.stdout[-300:]}")
+    report = json.loads(out.stdout.strip().splitlines()[-1])
+    bad = [k for k in report["kernels"] if not k["compiled"]]
+    assert out.returncode == 0 and not bad, bad or out.stderr[-2000:]
+    assert all(k["auto_selects"] == "pallas" for k in report["kernels"])
+    assert len(report["kernels"]) == 3 * len(_aot_tpu().SMOKE_BUCKETS)
 
 
 def test_device_sig_backend_axis(force_backend):

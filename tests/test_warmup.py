@@ -1,7 +1,9 @@
 """Warm-start subsystem (pilosa_tpu/warmup/, docs/warmup.md): the
 CRC-framed signature corpus's crash safety (every-length truncation,
 every-byte corruption — load never raises, never returns garbage),
-recorder fold/seed/flush/compaction, the compile-cache LRU prune, the
+recorder fold/seed/flush/compaction, the compile-cache LRU prune and
+where the cache lives (the environment's directory left alone, else one
+fixed path shared by every Server and every process), the
 coordinator's degrade-to-cold guarantees (corrupt/empty/stale corpus,
 replay errors, expired budget all still reach READY), and a real
 Server warm restart: prepared templates rebuilt, zero retraces during
@@ -9,12 +11,15 @@ replay, EXPLAIN flipping plan compile cold -> warm."""
 
 import json
 import os
+import subprocess
+import sys
 import time
 
 import pytest
 
-from pilosa_tpu.warmup import (CorpusRecorder, SignatureCorpus, prune,
-                               resolve_dir, top_n, WarmupCoordinator)
+from pilosa_tpu.warmup import (CorpusRecorder, SignatureCorpus,
+                               compile_cache, prune, top_n,
+                               WarmupCoordinator)
 from pilosa_tpu.warmup.corpus import (CORPUS_MAGIC, SCHEMA_VERSION,
                                       _frame)
 from pilosa_tpu.warmup.replayer import PHASE_READY, PHASE_WARMING
@@ -202,12 +207,82 @@ def test_recorder_compacts_when_log_outgrows_bound(tmp_path):
 # -- compile cache -----------------------------------------------------------
 
 
-def test_resolve_dir_semantics(tmp_path):
-    d = str(tmp_path)
-    assert resolve_dir("off", d) is None
-    assert resolve_dir("", d) == os.path.join(d, ".compile-cache")
-    assert resolve_dir("/explicit/path", d) == "/explicit/path"
-    assert resolve_dir("", None) is None
+# One process: argv[1] Servers in a row, each on its own mkdtemp data
+# dir, each compiling the same query.  Prints which cache directory each
+# Server reports, what jax itself is configured with, and every
+# directory prune was called on.
+_CACHE_WORKER = r'''
+import json, sys, tempfile
+import jax
+import pilosa_tpu.warmup as warmup
+from pilosa_tpu.server.server import Config, Server
+pruned = []
+real_prune = warmup.prune
+warmup.prune = lambda d, mb: (pruned.append(d), real_prune(d, mb))[1]
+dirs = []
+for _ in range(int(sys.argv[1])):
+    s = Server(Config(data_dir=tempfile.mkdtemp(prefix="ptpu-cc-"),
+                      bind="localhost:0", timeseries_interval=0,
+                      metric_poll_interval=0, anti_entropy_interval=0,
+                      compile_cache_mb=int(sys.argv[2])))
+    s.open()
+    s.api.create_index("ci")
+    s.api.create_field("ci", "f")
+    s.api.query("ci", "Set(1, f=1) Set(2, f=1)")
+    assert s.api.query("ci", "Count(Row(f=1))") == [2]
+    dirs.append(s._compile_cache_dir)
+    s.close()
+print(json.dumps({"dirs": dirs, "pruned": pruned,
+                  "jax_dir": jax.config.jax_compilation_cache_dir}))
+'''
+
+
+def _cache_worker(n_servers, cache_mb, env_dir=None):
+    env = {k: v for k, v in os.environ.items()
+           if k != compile_cache.ENV_VAR}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env[compile_cache.ENV_VAR] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_WORKER, str(n_servers),
+         str(cache_mb)],
+        capture_output=True, text=True, env=env, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_environment_cache_dir_is_left_alone(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the program uses that
+    directory, sets none in code and never prunes it: a file far over
+    compile-cache-mb survives a Server's start-up and shutdown."""
+    cc = tmp_path / "env-cache"
+    cc.mkdir()
+    old = cc / "somebody-elses-entry"
+    old.write_bytes(b"x" * (2 << 20))
+    os.utime(old, (100.0, 100.0))
+    out = _cache_worker(1, 1, env_dir=str(cc))
+    assert out["dirs"] == [str(cc)] and out["jax_dir"] == str(cc)
+    assert out["pruned"] == []
+    assert old.exists()
+    assert len(list(cc.iterdir())) > 1, "nothing was cached there"
+
+
+def test_fixed_cache_dir_shared_by_servers_and_processes():
+    """Without the variable, Servers on different mkdtemp data dirs all
+    land on the one fixed directory in the checkout — the directory is
+    part of jax's cache key — and a second process compiling the same
+    query adds no file to it."""
+    fixed = compile_cache.DEFAULT_DIR
+    assert os.path.basename(fixed) == ".compile-cache"
+    out = _cache_worker(2, 256)
+    assert out["dirs"] == [fixed, fixed] and out["jax_dir"] == fixed
+    assert out["pruned"] and set(out["pruned"]) == {fixed}
+    files = set(os.listdir(fixed))
+    assert files
+    again = _cache_worker(1, 256)
+    assert again["dirs"] == [fixed]
+    assert set(os.listdir(fixed)) == files
 
 
 def test_prune_removes_oldest_first(tmp_path):
