@@ -18,6 +18,10 @@ from ..astlint import Finding, project_rule
 CALL = re.compile(
     r'[a-z_]*stats\.(?:count|gauge|timing|timer|histogram)\(\s*(f?)"([^"]+)"',
     re.S)
+# layer spans name their timing in their first argument
+# (utils/tracing.py); the ones given no stats client feed no series
+SPAN = re.compile(
+    r'\blayer_span\(\s*"([^"]+)",\s*[\w.]*stats\b', re.S)
 HELPER = re.compile(r"\b_count\(")  # dotted-name prefix helpers
 NAME = re.compile(r'"([a-z0-9_]+(?:\.[a-z0-9_{}.]+)+)"')
 CATALOG = re.compile(r"<!-- metrics-catalog:begin -->(.*?)"
@@ -36,6 +40,9 @@ def check(modules, root):
             if is_f:
                 name = re.sub(r"\{[^}]*\}", "*", name)
             code.setdefault(name,
+                            (rel, mod.source.count("\n", 0, m.start()) + 1))
+        for m in SPAN.finditer(mod.source):
+            code.setdefault(m.group(1),
                             (rel, mod.source.count("\n", 0, m.start()) + 1))
         for m in HELPER.finditer(mod.source):
             # every dotted literal near the helper call (covers

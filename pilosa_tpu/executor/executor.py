@@ -538,6 +538,7 @@ class Executor:
                         check_current, qprof) -> list[Any]:
         from ..utils import degraded
         from ..utils import tenant as qtenant
+        from ..utils.tracing import layer_span
         stats = self.stats
         # warm-start corpus (warmup/corpus.py) records by query TEXT —
         # the only replayable identity across restarts
@@ -612,20 +613,25 @@ class Executor:
                 stats.count("query.prepared.miss")
                 if out is not None:
                     query = out  # parsed (tagged) AST — don't parse twice
+        # query.plan off the prepared path: parse and translate (the
+        # lowering sits with its launch, inside query.dispatch)
+        with layer_span("query.plan", stats):
             if isinstance(query, str):
                 with stats.timer("query.parse"), qprof.stage("parse"):
                     query = parse(query)
-        idx = self.holder.index(index_name)
-        if idx is None:
-            raise ExecutionError(f"index not found: {index_name}")
-        if translate:
-            # always runs: validates stray string keys even when no store
-            # is enabled (executor.go:2658 "string 'col' value not
-            # allowed...")
-            with stats.timer("query.translate"), qprof.stage("translate"):
-                query = self.translator.translate_query(index_name, query)
-        if shards is None:
-            shards = sorted(idx.available_shards())
+            idx = self.holder.index(index_name)
+            if idx is None:
+                raise ExecutionError(f"index not found: {index_name}")
+            if translate:
+                # always runs: validates stray string keys even when no
+                # store is enabled (executor.go:2658 "string 'col' value
+                # not allowed...")
+                with stats.timer("query.translate"), \
+                        qprof.stage("translate"):
+                    query = self.translator.translate_query(index_name,
+                                                            query)
+            if shards is None:
+                shards = sorted(idx.available_shards())
         # Batched grouping reorders dispatch, which is only sound when no
         # call mutates state a later call could read — mixed write/read
         # queries run strictly sequentially like the reference.
@@ -676,7 +682,7 @@ class Executor:
                     DEFAULT_BUDGET.upload_bytes - up0
                 dnode.tags["evictions"] = DEFAULT_BUDGET.evictions - ev0
         check_current("result fetch")
-        with stats.timer("query.fetch"), qprof.stage("fetch"):
+        with layer_span("query.fetch", stats), qprof.stage("fetch"):
             results = _resolve_pendings(results)
         if translate and self.translator.needs_translation(index_name):
             results = self.translator.translate_results(

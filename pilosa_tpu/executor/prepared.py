@@ -190,7 +190,13 @@ class PreparedEntry:
         v = values[self.g_lit]
         return bool(np.all((v >= self.g_lo) & (v <= self.g_hi)))
 
-    def run(self, ex, index: str, values: np.ndarray, shards):
+    def bind(self, values: np.ndarray) -> list:
+        """The call groups with this request's literals in their params:
+        the last of its planning."""
+        return [(g.kind, g.slotted, g.build_params(values),
+                 g.call_idxs, g.extra) for g in self.groups]
+
+    def run(self, ex, index: str, groups: list, shards):
         """Dispatch all groups, then resolve with one device fetch.
         Returns the results list, in call order.  With whole-query on
         (docs/whole-query.md) the WHOLE template replays as one pjit
@@ -199,6 +205,7 @@ class PreparedEntry:
         Either way concurrent requests replaying the same template fuse
         into one device launch — the serving hot path the dynamic
         batching exists for."""
+        from ..utils.tracing import layer_span
         from .executor import _resolve_pendings, _run_batched_groups
 
         holder = ex.holder
@@ -206,21 +213,21 @@ class PreparedEntry:
             idx = holder.index(index)
             shards = sorted(idx.available_shards())
         results: list = [None] * self.n_calls
-        groups = [(g.kind, g.slotted, g.build_params(values),
-                   g.call_idxs, g.extra) for g in self.groups]
         if ex.wholequery is not None and ex.whole_query:
             from ..parallel.wholequery import WholeQueryUnsupported
             try:
                 ex._wq_run_batched(index, shards, groups, results)
                 ex.wq_requests += 1
                 ex.stats.count("wholequery.requests")
-                return _resolve_pendings(results)
+                with layer_span("query.fetch", ex.stats):
+                    return _resolve_pendings(results)
             except WholeQueryUnsupported as e:
                 ex._note_wq_fallback(index, e)
                 results = [None] * self.n_calls
         _run_batched_groups(ex.batcher, holder, index, shards, groups,
                             results)
-        return _resolve_pendings(results)
+        with layer_span("query.fetch", ex.stats):
+            return _resolve_pendings(results)
 
 
 _UNCACHEABLE = "uncacheable"
@@ -246,6 +253,20 @@ class PreparedCache:
         (True, results) on a hit; (False, parsed_query_or_None) on a miss
         — the parsed AST (literal-tagged, tags invisible to the classic
         path) is handed back so the caller never parses twice."""
+        from ..utils.tracing import layer_span
+        # query.plan: the host's work before the launch — fingerprint,
+        # lookup and guards (or the build, on a miss) and the params
+        with layer_span("query.plan", self.executor.stats):
+            entry, vals, parsed = self._lookup(index, query)
+            if entry is None:
+                return False, parsed
+            groups = entry.bind(vals)
+        return True, entry.run(self.executor, index, groups, shards)
+
+    def _lookup(self, index: str, query: str):
+        """(entry, literal values, None) for a template that replays,
+        built now if it was not known; (None, None, parsed query or
+        None) for one that takes the classic path."""
         template, values = _fingerprint_fast(query)
         key = (index, template)
         with self._lock:
@@ -262,22 +283,23 @@ class PreparedCache:
                 # a literal beyond int64 can't ride the params machinery;
                 # the classic path (arbitrary-precision ints) owns it
                 self.misses += 1
-                return False, None
+                return None, None, None
 
         if entry is _UNCACHEABLE:
             self.misses += 1
-            return False, None
+            return None, None, None
         if isinstance(entry, PreparedEntry):
             if entry.epoch == schema_epoch() and entry.guards_ok(vals):
                 self.hits += 1
-                return True, entry.run(self.executor, index, vals, shards)
+                return entry, vals, None
             if entry.epoch != schema_epoch():
                 with self._lock:
                     self._entries.pop(key, None)
             else:
                 self.guard_misses += 1
-                return False, None  # entry stays; these values take another
-                #                     branch -> classic path
+                # entry stays; these values take another branch ->
+                # classic path
+                return None, None, None
 
         # build: tagged parse + prepare; on ineligibility remember that
         self.misses += 1
@@ -291,8 +313,8 @@ class PreparedCache:
             while len(self._entries) > self.max_entries:
                 self._entries.popitem(last=False)
         if entry is not None:
-            return True, entry.run(self.executor, index, vals, shards)
-        return False, q
+            return entry, vals, None
+        return None, None, q
 
     # -- preparation -------------------------------------------------------
 

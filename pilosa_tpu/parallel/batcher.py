@@ -58,7 +58,7 @@ from ..utils.deadline import DeadlineExceeded, activate, current
 from ..utils.faults import FAULTS
 from ..utils.locks import make_condition
 from ..utils.stats import BucketHistogram, NopStatsClient, ReservoirTimer
-from ..utils.tracing import GLOBAL_TRACER
+from ..utils.tracing import GLOBAL_TRACER, layer_span
 from .mesh_exec import _DISPATCH_LOCK
 
 _EMPTY_PARAMS = np.zeros(0, dtype=np.int32)
@@ -102,6 +102,26 @@ class _Ticket:
         self.background = background
 
 
+class _RoundTimings:
+    """The dispatcher thread's layer-span seconds, handed to the stats
+    client once a round: one acquisition of the stats lock, which every
+    request thread contends for, in place of one a span and one a
+    ticket.  Stands where a span expects its stats client."""
+
+    __slots__ = ("stats", "pending")
+
+    def __init__(self, stats):
+        self.stats = stats
+        self.pending: list[tuple[str, float]] = []
+
+    def timing(self, name: str, seconds: float):
+        self.pending.append((name, seconds))
+
+    def flush(self):
+        pending, self.pending = self.pending, []
+        self.stats.timings(pending)
+
+
 class DispatchBatcher:
     """Front door for every mesh reducer dispatch (docs/batching.md).
 
@@ -120,6 +140,7 @@ class DispatchBatcher:
         self.max_batch = max(int(max_batch), 1)
         self.window_s = max(float(window_us), 0.0) / 1e6
         self.stats = stats if stats is not None else NopStatsClient()
+        self._round_stats = _RoundTimings(self.stats)
         self._cond = make_condition("batcher", rlock=True)
         self._queue: list[_Ticket] = []
         self._thread: threading.Thread | None = None
@@ -131,7 +152,6 @@ class DispatchBatcher:
         self.single_launches = 0
         self.stream_fallbacks = 0
         self.expired_drops = 0
-        self.temp_splits = 0  # fusion packs split by the temp workspace
         self.batch_size_hist = BucketHistogram([1, 2, 4, 8, 16, 32, 64])
         self.window_wait = ReservoirTimer(512)
 
@@ -405,15 +425,28 @@ class DispatchBatcher:
     # -- dispatcher --------------------------------------------------------
 
     def _loop(self):
+        # Every instant of this thread lies in one of three layer spans
+        # (docs/observability.md "Layer spans"): dispatch.idle (no
+        # ticket: the cause of a device gap is upstream), dispatch.window
+        # (the coalescing hold) and dispatch.round (busy).  Each span
+        # closes after the condition is released, so that no submitter
+        # waits for this thread's bookkeeping, and a round's timings go
+        # to the stats client together once it has ended.
         self._tid = threading.get_ident()
+        rstats = self._round_stats
         while True:
-            with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
+            # only this thread takes tickets off the queue, so one seen
+            # without the lock stays
+            if not self._queue:
+                with layer_span("dispatch.idle", rstats), self._cond:
+                    while not self._queue and not self._closed:
+                        self._cond.wait()
                 if not self._queue:
+                    rstats.flush()
                     return  # closed and drained
-                # adaptive window: launch when full OR the oldest ticket
-                # has waited its window (new arrivals re-check the gate)
+            # adaptive window: launch when full OR the oldest ticket
+            # has waited its window (new arrivals re-check the gate)
+            with layer_span("dispatch.window", rstats), self._cond:
                 limit = self._queue[0].enq + self.window_s
                 while not self._closed and \
                         len(self._queue) < self.max_batch:
@@ -422,25 +455,21 @@ class DispatchBatcher:
                         break
                     self._cond.wait(limit - now)
                 batch, self._queue = self._queue, []
-            try:
-                self._dispatch(batch)
-            except BaseException as e:  # the loop must survive anything
-                err = e if isinstance(e, Exception) else RuntimeError(
-                    f"dispatcher aborted: {e!r}")
-                for t in batch:
-                    if not t.future.done():
-                        t.future.set_exception(err)
+            with layer_span("dispatch.round", rstats, tickets=len(batch)):
+                try:
+                    self._dispatch(batch)
+                # the loop must survive anything
+                except BaseException as e:
+                    err = e if isinstance(e, Exception) else RuntimeError(
+                        f"dispatcher aborted: {e!r}")
+                    for t in batch:
+                        if not t.future.done():
+                            t.future.set_exception(err)
+            rstats.flush()
 
     def _dispatch(self, batch):
-        now = time.monotonic()
         groups: dict[tuple, list[_Ticket]] = {}
         for t in batch:
-            self.window_wait.observe(now - t.enq)
-            if t.prof is not None:
-                # queue + coalesce wait, attributed under the stage the
-                # query was in when it submitted (its dispatch node)
-                t.prof.event("batcher.queue", now - t.enq,
-                             node=t.prof_node, kind=t.kind)
             if t.background:
                 self.stats.count("dispatch.background")
             ctx = t.ctx
@@ -476,7 +505,6 @@ class DispatchBatcher:
                     # fusing this ticket would exceed the batch-temp
                     # workspace ([B, rows, W] temps scale with the
                     # fused row count): split the pack, visibly
-                    self.temp_splits += 1
                     self.stats.count("dispatch.fused_temp_split")
                 if pack and (len(pack) >= self.max_batch
                              or rows + n > FUSED_ROWS_MAX
@@ -494,7 +522,30 @@ class DispatchBatcher:
             if not t.future.done():
                 t.future.set_exception(exc)
 
-    def _launch(self, kind, tickets):
+    def _note_wait(self, tickets) -> float:
+        """Each ticket's wait for the batcher, taken once where its
+        launch begins: ``dispatch.ticket_wait`` (a timing only — it
+        crosses threads), the ``window_wait`` reservoir, and the
+        profile's ``batcher.queue`` event under the stage the query was
+        in when it submitted.  Returns the longest, the launch ledger's
+        ``queueS``."""
+        now = time.monotonic()
+        longest = 0.0
+        for t in tickets:
+            wait = max(now - t.enq, 0.0)
+            longest = max(longest, wait)
+            self._round_stats.timing("dispatch.ticket_wait", wait)
+            self.window_wait.observe(wait)
+            if t.prof is not None:
+                t.prof.event("batcher.queue", wait, node=t.prof_node,
+                             kind=t.kind)
+        return longest
+
+    def _launch(self, kind, tickets, queue_s: float | None = None):
+        """``queue_s`` is given where the tickets' wait was already taken
+        (a fused launch that falls back to one launch a ticket)."""
+        if queue_s is None:
+            queue_s = self._note_wait(tickets)
         self.batch_size_hist.observe(len(tickets))
         if len(tickets) == 1:
             t = tickets[0]
@@ -506,8 +557,7 @@ class DispatchBatcher:
                 # the launch-ledger context carries the queued wait into
                 # the device launches this ticket drives
                 ltok = devobs.set_launch_ctx(
-                    queue_s=max(time.monotonic() - t.enq, 0.0),
-                    tickets=1, rows=t.params.shape[0])
+                    queue_s=queue_s, tickets=1, rows=t.params.shape[0])
                 try:
                     with activate(t.ctx), GLOBAL_TRACER.attach(t.trace), \
                             qprof.activate(t.prof):
@@ -529,7 +579,7 @@ class DispatchBatcher:
             self.stats.count("dispatch.launch.single")
             t.future.set_result(result)
             return
-        self._launch_fused(kind, tickets)
+        self._launch_fused(kind, tickets, queue_s)
 
     def _direct(self, t):
         """Un-fused launch: scalar tickets take the existing un-vmapped
@@ -599,7 +649,7 @@ class DispatchBatcher:
                      "paddedRows": padded_rows},
                     collect=t.trace.collect)
 
-    def _launch_fused_whole(self, tickets):
+    def _launch_fused_whole(self, tickets, queue_s):
         """Fuse same-shape whole-query programs: concatenate each
         node's params matrix along the batch axis and launch the shared
         compiled program ONCE; per-ticket results are batch-axis slices
@@ -632,8 +682,6 @@ class DispatchBatcher:
                 for m in node_mats)
             # no FAULTS.hit here: runner.run gates the launch (one
             # mesh.slice hit per launch, matching the direct path)
-            queue_s = max(time.monotonic()
-                          - min(t.enq for t in tickets), 0.0)
             ltok = devobs.set_launch_ctx(queue_s=queue_s,
                                          tickets=len(tickets), rows=B)
             try:
@@ -643,7 +691,9 @@ class DispatchBatcher:
                 devobs.reset_launch_ctx(ltok)
             self._note_fused(tickets, time.perf_counter() - t_launch0,
                              batch_rows=B, padded_rows=pad_total)
-            with _DISPATCH_LOCK:
+            with _DISPATCH_LOCK, layer_span(
+                    "dispatch.scatter", self._round_stats,
+                    tickets=len(tickets)):
                 for ti, t in enumerate(tickets):
                     t.future.set_result(out.slice_batch(
                         program,
@@ -662,9 +712,9 @@ class DispatchBatcher:
         self.stats.count("dispatch.launch.fused")
         self.stats.count("dispatch.fused_queries", len(tickets))
 
-    def _launch_fused(self, kind, tickets):
+    def _launch_fused(self, kind, tickets, queue_s):
         if kind == "wholequery":
-            return self._launch_fused_whole(tickets)
+            return self._launch_fused_whole(tickets, queue_s)
         p0 = tickets[0].payload
         mesh = self.mesh
         t_launch0 = time.perf_counter()
@@ -672,14 +722,15 @@ class DispatchBatcher:
             # PR1 composition: an over-budget working set streams in shard
             # slices — the fused single-slice path would stage it whole,
             # so stream each ticket through its direct path instead
-            sched = mesh.shard_schedule(
-                p0["holder"], p0["index"],
-                self._group_key_lists(kind, p0), p0["shards"])
+            with layer_span("dispatch.place", devobs.LEDGER):
+                sched = mesh.shard_schedule(
+                    p0["holder"], p0["index"],
+                    self._group_key_lists(kind, p0), p0["shards"])
             if len(sched.slices) > 1:
                 self.stream_fallbacks += 1
                 self.stats.count("dispatch.launch.stream_fallback")
                 for t in tickets:
-                    self._launch(kind, [t])
+                    self._launch(kind, [t], queue_s)
                 return
             mats = [t.params for t in tickets]
             mat = np.concatenate(mats) if len(mats) > 1 else mats[0]
@@ -694,8 +745,6 @@ class DispatchBatcher:
             # launch ledger context: the queued wait and the ACTUAL fused
             # row count ride into the device launch so padding waste is
             # measured, not inferred (docs/observability.md)
-            queue_s = max(time.monotonic()
-                          - min(t.enq for t in tickets), 0.0)
             ltok = devobs.set_launch_ctx(queue_s=queue_s,
                                          tickets=len(tickets), rows=B)
             try:
@@ -726,7 +775,9 @@ class DispatchBatcher:
             # Outputs are replicated (psum, P() specs), so slicing is a
             # local per-device gather — but hold the collective-launch
             # lock anyway to keep one global program-enqueue order.
-            with _DISPATCH_LOCK:
+            with _DISPATCH_LOCK, layer_span(
+                    "dispatch.scatter", self._round_stats,
+                    tickets=len(tickets)):
                 lo = 0
                 for t in tickets:
                     n = t.params.shape[0]
@@ -752,11 +803,13 @@ class DispatchBatcher:
         self._note_fused(tickets, time.perf_counter() - t_launch0,
                          batch_rows=mat.shape[0] - padded_rows,
                          padded_rows=padded_rows)
-        lo = 0
-        for t in tickets:  # segments tickets are always scalar (B=1)
-            t.future.set_result(
-                {shard: arr[lo] for shard, arr in by_shard.items()})
-            lo += t.params.shape[0]
+        with layer_span("dispatch.scatter", self._round_stats,
+                        tickets=len(tickets)):
+            lo = 0
+            for t in tickets:  # segments tickets are always scalar (B=1)
+                t.future.set_result(
+                    {shard: arr[lo] for shard, arr in by_shard.items()})
+                lo += t.params.shape[0]
         self.fused_launches += 1
         self.stats.count("dispatch.launch.fused")
         self.stats.count("dispatch.fused_queries", len(tickets))
@@ -773,7 +826,6 @@ class DispatchBatcher:
             "singleLaunches": self.single_launches,
             "streamFallbacks": self.stream_fallbacks,
             "expiredDrops": self.expired_drops,
-            "tempSplits": self.temp_splits,
             "batchSize": self.batch_size_hist.snapshot(),
             "windowWaitS": self.window_wait.snapshot(),
         }

@@ -77,7 +77,7 @@ from ..utils import profile as qprof
 from ..utils.deadline import check_current
 from ..utils.faults import FAULTS
 from ..utils.locks import make_lock, make_rlock
-from ..utils.tracing import GLOBAL_TRACER
+from ..utils.tracing import GLOBAL_TRACER, layer_span
 
 SHARD_AXIS = "shards"
 
@@ -224,15 +224,6 @@ class _InstrumentedExec:
             s[1] * (SHARD_WORDS // CONTAINER_WORDS) for s in pallas)
 
     def __call__(self, *args, _launch_meta=None):
-        reg = _devobs.COMPILES
-        reg.begin_call()
-        t0 = _time.perf_counter()
-        out = self.fn(*args)
-        dt = _time.perf_counter() - t0
-        compiled = reg.traced()
-        if compiled:  # fingerprinting is only paid on compiles
-            reg.note_call(self.sig, self.kind, dt,
-                          _devobs.fingerprint(args), detail=self.detail)
         # call-site meta: actual shard count, or (shards, actual batch
         # rows) where the call site pads its own batch axis outside the
         # batcher (group_countsB's pow-2 combo padding)
@@ -248,14 +239,29 @@ class _InstrumentedExec:
         ctx = _devobs.launch_ctx() or {}
         rows = ctx.get("rows")
         if rows is None:
-            rows = meta_rows
+            rows = meta_rows if meta_rows is not None else b_pad
+        tickets = ctx.get("tickets", 1)
+        reg = _devobs.COMPILES
+        reg.begin_call()
+        # dispatch.enqueue: host time to hand this program to the
+        # runtime; its seconds reach /debug/vars through the ledger
+        # (dispatchSecondsTotal)
+        with layer_span("dispatch.enqueue", kind=self.kind, sig=self.sig,
+                        rows=rows, rows_padded=b_pad,
+                        tickets=tickets) as span:
+            t0 = _time.perf_counter()
+            out = self.fn(*args)
+            dt = _time.perf_counter() - t0
+            compiled = reg.traced()
+            span.tag(compiled=compiled)
+        if compiled:  # fingerprinting is only paid on compiles
+            reg.note_call(self.sig, self.kind, dt,
+                          _devobs.fingerprint(args), detail=self.detail)
         _devobs.LEDGER.record(
             sig=self.sig, kind=self.kind, shards=shards,
             shards_padded=shards_pad,
-            batch_rows=rows if rows is not None else b_pad,
-            batch_rows_padded=b_pad,
-            queue_s=ctx.get("queue_s", 0.0),
-            tickets=ctx.get("tickets", 1),
+            batch_rows=rows, batch_rows_padded=b_pad,
+            queue_s=ctx.get("queue_s", 0.0), tickets=tickets,
             dispatch_s=dt, compiled=compiled,
             decode_bytes=self.decode_per_shard * shards,
             slice_pos=_devobs.current_slice(),
@@ -268,8 +274,7 @@ class _InstrumentedExec:
             # so an explain record cross-checks the ledger by sig
             prof.event("device.launch", dt, kind=self.kind, sig=self.sig,
                        shards=shards, shardsPadded=shards_pad,
-                       batchRows=rows if rows is not None else b_pad,
-                       batchRowsPadded=b_pad,
+                       batchRows=rows, batchRowsPadded=b_pad,
                        decodeBytes=self.decode_per_shard * shards,
                        compiled=compiled)
         return out
@@ -292,6 +297,7 @@ class MeshExecutor:
         # executor's plan keys (and thus compile-registry signatures)
         # from any earlier executor's — see _plan_key
         self._exec_seq = next(_EXEC_SEQ)
+        _devobs.COMPILES.listen()  # eager compiles count from here on
         self.n_devices = self.mesh.devices.size
         # A mesh spanning >1 jax process (multihost mode 2,
         # parallel/multihost.py): shard-axis-sharded OUTPUTS are not
@@ -371,6 +377,11 @@ class MeshExecutor:
                 _devobs.COMPILES.mark_traced()
                 return _fn(*a)
 
+            # the program's name in a profiler trace and in XLA's dumps
+            # (jit_ptpu_<kind>): a function of the kind alone, so the
+            # persistent compile cache keys do not move with a digest,
+            # a shape or this executor's sequence number
+            traced_body.__name__ = f"ptpu_{key[0]}"
             fn = _InstrumentedExec(
                 jax.jit(jax.shard_map(
                     traced_body, mesh=self.mesh,
@@ -443,6 +454,10 @@ class MeshExecutor:
     # -- shard grouping ----------------------------------------------------
 
     def _placed_groups(self, keys, holder, index, shards):
+        with layer_span("dispatch.place", _devobs.LEDGER):
+            return self._place_groups(keys, holder, index, shards)
+
+    def _place_groups(self, keys, holder, index, shards):
         """Group shards by input-shape signature over fragment keys
         [(field, view), ...] and stack+place each group's fragments over
         the mesh axis.  Returns [(shard_list, placed_per_key, shapes)];
@@ -685,6 +700,7 @@ class MeshExecutor:
                 contrib = jnp.where(ok, v_ & ~cur, jnp.uint32(0))
                 return block.at[loc, r_, w_].add(contrib)
 
+            block_fn.__name__ = "ptpu_overlay"
             fn = jax.jit(jax.shard_map(
                 block_fn, mesh=self.mesh,
                 in_specs=(P(SHARD_AXIS), P(), P(), P(), P()),

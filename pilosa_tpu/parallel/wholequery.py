@@ -61,6 +61,7 @@ from ..utils import devobs as _devobs
 from ..utils import profile as qprof
 from ..utils.deadline import check_current
 from ..utils.faults import FAULTS
+from ..utils.tracing import layer_span
 from .mesh_exec import _DISPATCH_LOCK, _flatten_present, _sig_rows, \
     _unpack_frags, SHARD_AXIS
 
@@ -111,6 +112,21 @@ def program_keys(program, mesh) -> list[tuple[str, str]]:
             if k not in out:
                 out.append(k)
     return out
+
+
+PROGRAM_NAME_NODES = 6   # node kinds spelled out in a program's name
+
+
+def program_name(program) -> str:
+    """``ptpu_wq_<node kinds joined>``: the whole-query program's name
+    in a profiler trace (``jit_ptpu_wq_count``).  A function of the node
+    kinds alone — never of the digest, the shapes or the executor — so
+    the persistent compile cache sees one key per program."""
+    kinds = [n.kind for n in program]
+    name = "ptpu_wq_" + "_".join(kinds[:PROGRAM_NAME_NODES])
+    if len(kinds) > PROGRAM_NAME_NODES:
+        name += f"_n{len(kinds)}"
+    return name
 
 
 def pad_pow2_rows(mat: np.ndarray, repeat: bool = True) -> np.ndarray:
@@ -190,29 +206,35 @@ class _InstrumentedWhole:
         self.out_index = out_index
 
     def __call__(self, mats, *flat, _launch_meta=None):
-        reg = _devobs.COMPILES
-        reg.begin_call()
-        t0 = _time.perf_counter()
-        out = self.fn(mats, *flat)
-        dt = _time.perf_counter() - t0
-        compiled = reg.traced()
-        if compiled:  # fingerprinting is only paid on compiles
-            leaves = jax.tree_util.tree_leaves(mats)
-            reg.note_call(self.sig, "wholequery", dt,
-                          _devobs.fingerprint(list(leaves) + list(flat)),
-                          detail=self.detail)
         m = _launch_meta or {}
         ctx = _devobs.launch_ctx() or {}
         rows = ctx.get("rows")
         if rows is None:
             rows = m.get("rows", 1)
+        rows_padded = m.get("rows_padded", 1)
+        tickets = ctx.get("tickets", 1)
+        reg = _devobs.COMPILES
+        reg.begin_call()
+        # dispatch.enqueue, as in mesh_exec._InstrumentedExec
+        with layer_span("dispatch.enqueue", kind="wholequery",
+                        sig=self.sig, rows=rows, rows_padded=rows_padded,
+                        tickets=tickets) as span:
+            t0 = _time.perf_counter()
+            out = self.fn(mats, *flat)
+            dt = _time.perf_counter() - t0
+            compiled = reg.traced()
+            span.tag(compiled=compiled)
+        if compiled:  # fingerprinting is only paid on compiles
+            leaves = jax.tree_util.tree_leaves(mats)
+            reg.note_call(self.sig, "wholequery", dt,
+                          _devobs.fingerprint(list(leaves) + list(flat)),
+                          detail=self.detail)
         _devobs.LEDGER.record(
             sig=self.sig, kind="wholequery",
             shards=m.get("shards", 0),
             shards_padded=m.get("shards_padded", 0),
-            batch_rows=rows, batch_rows_padded=m.get("rows_padded", 1),
-            queue_s=ctx.get("queue_s", 0.0),
-            tickets=ctx.get("tickets", 1),
+            batch_rows=rows, batch_rows_padded=rows_padded,
+            queue_s=ctx.get("queue_s", 0.0), tickets=tickets,
             dispatch_s=dt, compiled=compiled,
             decode_bytes=m.get("decode_bytes", 0),
             slice_pos=_devobs.current_slice(),
@@ -225,8 +247,7 @@ class _InstrumentedWhole:
             prof.event("device.launch", dt, kind="wholequery",
                        sig=self.sig, shards=m.get("shards", 0),
                        shardsPadded=m.get("shards_padded", 0),
-                       batchRows=rows,
-                       batchRowsPadded=m.get("rows_padded", 1),
+                       batchRows=rows, batchRowsPadded=rows_padded,
                        decodeBytes=m.get("decode_bytes", 0),
                        compiled=compiled)
         return out
@@ -447,7 +468,8 @@ class WholeQueryRunner:
             "kernel_tiles": kernel_tiles,
         }
         sharding = NamedSharding(mesh.mesh, P())
-        mats_dev = jax.device_put(pad_mats, sharding)
+        with layer_span("dispatch.place", _devobs.LEDGER):
+            mats_dev = jax.device_put(pad_mats, sharding)
         with _DISPATCH_LOCK:
             flat_out = fn(mats_dev, *flat_all, _launch_meta=launch_meta)
         parts = [[flat_out[j] for j in idxs] for idxs in fn.out_index]
@@ -586,6 +608,8 @@ class WholeQueryRunner:
             # runs ONLY while jax traces: an exact compile detector
             _devobs.COMPILES.mark_traced()
             return body(mats, *flat)
+
+        traced.__name__ = program_name(program)
 
         n_flat_all = sum(n for _, n in groups_static)
         from ..ops import kernels as _kernels

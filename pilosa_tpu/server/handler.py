@@ -1075,6 +1075,7 @@ class _HandlerClass(BaseHTTPRequestHandler):
         want_profile = False
         want_explain = False
         trace_out = None
+        fn_s = 0.0  # seconds inside the route's fn: http.query.self's rest
         t_req0 = time.perf_counter()
         try:
             if fn is None:
@@ -1173,10 +1174,15 @@ class _HandlerClass(BaseHTTPRequestHandler):
                         # traces from the bounded span ring
                         root_sampled = sampled if tid is not None \
                             else (False if background else None)
+                        # a query's root is http.query on the profiler's
+                        # clock, like its timing (docs/observability.md
+                        # "Layer spans")
                         with GLOBAL_TRACER.span(
                                 f"{method} {parsed.path}", trace_id=tid,
                                 parent_id=parent_id, sampled=root_sampled,
-                                collect=collect) as span, \
+                                collect=collect,
+                                annotation="http.query"
+                                if gate == "query" else None) as span, \
                                 qprof.activate(prof), \
                                 qexplain.activate(erec):
                             self._trace_span = span
@@ -1186,7 +1192,11 @@ class _HandlerClass(BaseHTTPRequestHandler):
                                 # searchable root-span tags:
                                 # /debug/traces?index=... filters on them
                                 span.set_tag("index", args["index"])
-                            out = fn(self, args)
+                            t_fn0 = time.perf_counter()
+                            try:
+                                out = fn(self, args)
+                            finally:
+                                fn_s = time.perf_counter() - t_fn0
                 finally:
                     if admitted:
                         adm.release()
@@ -1264,10 +1274,10 @@ class _HandlerClass(BaseHTTPRequestHandler):
             self._send(500, {"error": f"internal error: {e}"})
         finally:
             self._observe(gate, args, time.perf_counter() - t_req0,
-                          status, background, prof, erec, trace_out)
+                          status, background, prof, erec, trace_out, fn_s)
 
     def _observe(self, gate, args, dur_s, status, background, prof,
-                 erec, trace_id):
+                 erec, trace_id, fn_s=0.0):
         """Post-request accounting (docs/observability.md): latency
         histograms (with the trace id attached as the landing bucket's
         exemplar) + the slow-query log.  Background traffic (probes,
@@ -1289,6 +1299,11 @@ class _HandlerClass(BaseHTTPRequestHandler):
             self.stats.timing("http.request", dur_s, exemplar=exemplar)
             if gate == "query":
                 self.stats.timing("http.query", dur_s, exemplar=exemplar)
+                # the front end's own share: admission, the tenant /
+                # deadline / profile / explain contexts, JSON and the
+                # socket write — http.query less the time inside the
+                # route's fn
+                self.stats.timing("http.query.self", dur_s - fn_s)
                 if status >= 500:
                     # availability SLO numerator (utils/slo.py): 5xx
                     # query responses, sheds and deadline aborts

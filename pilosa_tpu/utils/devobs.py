@@ -95,6 +95,10 @@ class CompileRegistry:
     exact even with concurrent launches."""
 
     MAX_ENTRIES = 512  # bounds /debug/compiles (LRU on compile recency)
+    # jax's own event for every executable it builds or loads from its
+    # cache, eager ones included (the jit_dynamic_slice of a new padded
+    # batch size never passes through an instrumented boundary)
+    BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
     def __init__(self):
         self._lock = make_lock("compile-registry")
@@ -103,9 +107,29 @@ class CompileRegistry:
         self.compiles_total = 0
         self.retraces_total = 0
         self.compile_seconds_total = 0.0
+        self.backend_compiles_total = 0
+        self._listening = False
         # Server injects its Logger so retraces land in the server log;
         # None (engine/bench standalone) keeps the registry silent.
         self.logger = None
+
+    def listen(self):
+        """Count jax's backend-compile events from now on (idempotent;
+        the process-wide registry starts listening with the first
+        MeshExecutor).  ``backendCompiles - compiles`` is what the
+        instrumented boundaries missed."""
+        with self._lock:
+            if self._listening:
+                return
+            self._listening = True
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_jax_event)
+
+    def _on_jax_event(self, event: str, duration: float, **kw):
+        if event == self.BACKEND_COMPILE_EVENT:
+            with self._lock:
+                self.backend_compiles_total += 1
 
     # -- trace detection (thread-local; tracing is synchronous) ------------
 
@@ -201,6 +225,7 @@ class CompileRegistry:
                     "retraces": self.retraces_total,
                     "compileSecondsTotal": round(
                         self.compile_seconds_total, 4),
+                    "backendCompiles": self.backend_compiles_total,
                     "executables": len(self._entries)}
 
     def snapshot(self) -> dict:
@@ -276,6 +301,12 @@ class LaunchLedger:
         # traffic instead of an XLA temp watermark
         self.kernel_launches_total = 0
         self.kernel_tiles_total = 0
+        # host seconds of the dispatch path's layers, summed over every
+        # launch (docs/observability.md "Layer spans"): waiting for the
+        # batcher, stacking and placing, handing programs to the runtime
+        self.queue_seconds_total = 0.0
+        self.place_seconds_total = 0.0
+        self.dispatch_seconds_total = 0.0
         # exported as pilosa_tpu_device_* histogram families at /metrics
         # (own exposition like the batcher's, outside the stats client)
         self.launch_hist = BucketHistogram(
@@ -329,9 +360,22 @@ class LaunchLedger:
                                          decode_bytes)
             self.kernel_launches_total += kernel_launches
             self.kernel_tiles_total += kernel_tiles
+            self.queue_seconds_total += queue_s
+            self.dispatch_seconds_total += dispatch_s
         self.launch_hist.observe(dispatch_s)
         if queue_s > 0:
             self.queue_hist.observe(queue_s)
+
+    def timing(self, name: str, seconds: float):
+        """The ledger as the sink of the ``dispatch.place`` layer spans
+        (``layer_span("dispatch.place", LEDGER)``), and of no other:
+        stacking and placing a launch's inputs happens in modules that
+        hold no stats client."""
+        if name != "dispatch.place":
+            raise ValueError(f"the launch ledger keeps no seconds of "
+                             f"{name!r}")
+        with self._lock:
+            self.place_seconds_total += seconds
 
     def reset_decode_peak(self):
         """Restart the decode-workspace high-watermark (bench-leg
@@ -358,6 +402,9 @@ class LaunchLedger:
                 "decodeBytesTotal": self.decode_bytes_total,
                 "kernelLaunches": self.kernel_launches_total,
                 "kernelTiles": self.kernel_tiles_total,
+                "queueSecondsTotal": self.queue_seconds_total,
+                "placeSecondsTotal": self.place_seconds_total,
+                "dispatchSecondsTotal": self.dispatch_seconds_total,
                 "size": self.size,
             }
 

@@ -137,6 +137,14 @@ class StatsClient:
         with self._lock:
             self._timings[self._key(name)].observe(value_s, exemplar)
 
+    def timings(self, pairs: list[tuple[str, float]]):
+        """Several timing observations under one acquisition of the lock
+        (the dispatch batcher hands over a round's layer spans at
+        once)."""
+        with self._lock:
+            for name, value_s in pairs:
+                self._timings[self._key(name)].observe(value_s)
+
     def histogram(self, name: str, value: float, rate: float = 1.0):
         self.timing(name, value, rate)
 
@@ -411,6 +419,11 @@ class StatsdClient(StatsClient):
         super().timing(name, value_s, rate, exemplar)
         self._send(f"{name}:{value_s * 1e3:.3f}|ms")
 
+    def timings(self, pairs: list[tuple[str, float]]):
+        super().timings(pairs)
+        for name, value_s in pairs:
+            self._send(f"{name}:{value_s * 1e3:.3f}|ms")
+
     def histogram(self, name: str, value: float, rate: float = 1.0):
         # record in-process via the BASE timing (bucketed, feeds
         # /metrics + percentile) but wire as a statsd histogram, not ms
@@ -450,6 +463,9 @@ class NopStatsClient(StatsClient):
         pass
 
     def timing(self, *a, **k):
+        pass
+
+    def timings(self, *a, **k):
         pass
 
     def histogram(self, *a, **k):

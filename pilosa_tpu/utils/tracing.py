@@ -14,7 +14,13 @@ a plain threading.local would silently drop it at every pool hop.
 Remote nodes piggyback their span summaries on /internal/query responses
 (``adopt()`` folds them into the coordinator's ring buffer), so
 ``GET /debug/traces?trace=<id>`` on the coordinator renders the whole
-cluster tree."""
+cluster tree.
+
+Layer spans (``layer_span``, docs/observability.md "Layer spans") are the
+second, cheaper primitive: one name that lands both on the profiler's
+clock (a ``jax.profiler.TraceAnnotation`` on the calling thread's host
+line of the same trace that holds the device ops) and in ``/debug/vars``
+``timings[name]``.  They never touch the span ring."""
 
 from __future__ import annotations
 
@@ -64,6 +70,60 @@ def parse_trace_header(value: str | None):
 
 _CTX: contextvars.ContextVar[TraceContext | None] = \
     contextvars.ContextVar("pilosa_tpu_trace_ctx", default=None)
+
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, **tags):
+    """A ``jax.profiler.TraceAnnotation``, or None while no profiler is
+    recording: with tracing off a span's annotation costs one call.
+    Imported where the first span opens: a process that never opens one
+    (a client, the CLI's offline commands) does not pay for importing
+    jax."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    if not _TraceAnnotation.is_enabled():
+        return None
+    return _TraceAnnotation(name, **tags)
+
+
+class layer_span:
+    """One layer boundary of the served path, measured once for two
+    sinks: a profiler annotation ``name`` (with ``tags`` — values the
+    site already holds) and ``stats.timing(name, seconds)`` from a
+    perf_counter pair.  ``stats`` is a stats client, or whatever takes
+    its place where the module holds none (the launch ledger for
+    ``dispatch.place``, the batcher's once-a-round buffer); ``None``
+    leaves the annotation alone (``dispatch.enqueue``, whose seconds the
+    site hands to the ledger with the launch's record)."""
+
+    __slots__ = ("name", "stats", "_ann", "_t0")
+
+    def __init__(self, name: str, stats=None, **tags):
+        self.name = name
+        self.stats = stats
+        self._ann = _annotation(name, **tags)
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def tag(self, **tags):
+        """Tags known only once the work ran (did this call compile)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**tags)
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.stats is not None:
+            self.stats.timing(self.name, dt)
 
 
 class Span:
@@ -205,7 +265,11 @@ class Tracer:
     @contextmanager
     def span(self, name: str, trace_id: str | None = None,
              parent_id: str | None = None, sampled: bool | None = None,
-             collect: list | None = None):
+             collect: list | None = None, annotation: str | None = None):
+        """``annotation``: the name of the span's profiler annotation
+        where it differs from the ring's (the request root is
+        ``http.query`` there, like its timing); every span's annotation
+        carries ``trace=<trace id>``, sampled or not."""
         cur = _CTX.get()
         tid = trace_id or (cur.trace_id if cur is not None else None)
         if parent_id is None and trace_id is None and cur is not None:
@@ -224,10 +288,15 @@ class Tracer:
             tid = uuid.uuid4().hex[:16]
         s = Span(self, name, tid, parent_id, sampled=sampled,
                  collect=collect)
+        ann = _annotation(annotation or name, trace=tid)
         token = _CTX.set(TraceContext(tid, s.span_id, sampled, collect))
+        if ann is not None:
+            ann.__enter__()
         try:
             yield s
         finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
             s.finish()
             _CTX.reset(token)
 

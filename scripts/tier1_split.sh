@@ -37,6 +37,7 @@ tests/test_explain.py
 tests/test_fuzz.py
 tests/test_ingest.py
 tests/test_kernels.py
+tests/test_layer_spans.py
 tests/test_native.py
 tests/test_observability.py
 tests/test_pql.py
