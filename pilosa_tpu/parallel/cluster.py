@@ -3647,7 +3647,9 @@ class Cluster:
                     for shard in list(v.fragments):
                         if self.node_id not in self.shard_owner_nodes(
                                 index_name, shard):
-                            frag = v.fragments.pop(shard)
+                            frag = v.remove_fragment(shard)
+                            if frag is None:
+                                continue
                             try:
                                 frag.close()
                             # lint: allow(swallowed-exception) — the

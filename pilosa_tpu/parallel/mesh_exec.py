@@ -329,6 +329,12 @@ class MeshExecutor:
         from ..storage.membudget import DEFAULT_BUDGET
         self._stack_cache: OrderedDict = OrderedDict()
         self.stack_cache_max = 64
+        # /debug/vars stackCache.fastHits / .walks: lookups validated by
+        # the device epoch alone, and every other lookup (a walk over
+        # the fragments).  Plain ints, bumped without a lock on the
+        # dispatcher thread and read racily by the handler.
+        self.stack_fast_hits = 0
+        self.stack_walks = 0
         self._budget = DEFAULT_BUDGET
         # single-worker background uploader for streamed shard slices
         # (created on first over-budget query; one worker serializes
@@ -471,14 +477,33 @@ class MeshExecutor:
         evict whole stacks (r3 advisor).  A budget-eviction callback may
         pop entries concurrently from outside ``self._lock`` (it must not
         lock: two executors evicting each other's entries would deadlock),
-        so every cache op here tolerates a vanished key."""
-        frags, token, epochs = self._stack_token(keys, holder, index, shards)
+        so every cache op here tolerates a vanished key.
+
+        An entry is ``(token, out, epochs, epoch)``.  ``epoch`` is the
+        device epoch (holder.device_epoch, fragment.py _DEVICE_EPOCH)
+        read BEFORE the walk that validated the entry; while the epoch
+        read at the top of a later call equals it, no input of the
+        token can have moved and the entry is served without looking at
+        a fragment.  The order is the safety argument: a write bumps the
+        epoch after it changed the fragment and before it is
+        acknowledged, so a reader that sees the old epoch may serve the
+        old stack (the write is not acknowledged yet), and a reader that
+        walks stores an epoch no newer than the state it saw.  Reading
+        the epoch after the walk would be wrong: a write landing between
+        the two would be stamped as seen."""
+        epoch = holder.device_epoch(index, keys)
         ckey = (index, tuple(keys), tuple(shards))
         skey = ("stack", id(self), ckey)
         with self._sc_lock:
             cached = self._stack_cache.get(ckey)
-            if cached is not None and cached[0] == token:
+            if cached is not None:
                 self._stack_cache.move_to_end(ckey)
+        if cached is not None and cached[3] == epoch:
+            self.stack_fast_hits += 1
+            self._budget.touch(skey)
+            return cached[1]
+        self.stack_walks += 1
+        frags, token, epochs = self._stack_token(keys, holder, index, shards)
         if cached is not None and cached[0] == token:
             if cached[2] != epochs:
                 # ingest delta overlay (docs/ingest.md): the stack is
@@ -492,7 +517,13 @@ class MeshExecutor:
                     cached = None
                 else:
                     self._refresh_overlays(ckey, token, frags, shards,
-                                           keys, epochs)
+                                           keys, epochs, epoch)
+            else:
+                # still current: stamp the entry with the epoch read at
+                # the top so the next lookup takes the fast check
+                with self._sc_lock:
+                    if self._stack_cache.get(ckey) is cached:
+                        self._stack_cache[ckey] = cached[:3] + (epoch,)
             if cached is not None:
                 self._budget.touch(skey)
                 return cached[1]
@@ -579,7 +610,7 @@ class MeshExecutor:
                         del s._stack_cache[ck]
 
         with self._sc_lock:
-            self._stack_cache[ckey] = (token, out, epochs)
+            self._stack_cache[ckey] = (token, out, epochs, epoch)
             trimmed = []
             while len(self._stack_cache) > self.stack_cache_max:
                 trimmed.append(self._stack_cache.popitem(last=False)[0])
@@ -617,18 +648,22 @@ class MeshExecutor:
     def _is_resident(self, keys, holder, index, shards) -> bool:
         """Whether this (keys, shards) stack is cached AND current — the
         residency signal the streaming scheduler orders slices by."""
-        _, token, _epochs = self._stack_token(keys, holder, index, shards)
         with self._sc_lock:
             cached = self._stack_cache.get(
                 (index, tuple(keys), tuple(shards)))
+        if cached is None:
+            return False
+        if cached[3] == holder.device_epoch(index, keys):
+            return True
+        _, token, _epochs = self._stack_token(keys, holder, index, shards)
         # an epoch lag still counts as resident: the overlay scatter is
         # a few KB of device work, not a re-stage
-        return cached is not None and cached[0] == token
+        return cached[0] == token
 
     # -- ingest delta overlay (docs/ingest.md) -----------------------------
 
     def _refresh_overlays(self, ckey, token, frags, shards, keys,
-                          new_epochs):
+                          new_epochs, epoch):
         """OR journaled ingest flushes into the resident stacked blocks
         of a token-valid cache entry.  Per dense group/key: gather every
         member fragment's unseen journal chunks, dedupe host-side, and
@@ -637,7 +672,8 @@ class MeshExecutor:
         ('z') entries never appear here (their fragments fold instead
         of journaling).  Serialized under the executor lock; a racing
         duplicate application is harmless (OR of already-present bits
-        contributes nothing)."""
+        contributes nothing).  ``epoch`` is the device epoch the caller
+        read before it walked ``new_epochs`` off the fragments."""
         from ..ingest.delta import merge_chunks
         nk = len(keys)
         row_of = {s: i for i, s in enumerate(shards)}
@@ -672,7 +708,8 @@ class MeshExecutor:
             with self._sc_lock:
                 cur2 = self._stack_cache.get(ckey)
                 if cur2 is not None and cur2[0] == token:
-                    self._stack_cache[ckey] = (token, out, new_epochs)
+                    self._stack_cache[ckey] = (token, out, new_epochs,
+                                               epoch)
 
     def _overlay_stack(self, stacked, member, flat_idx, vals):
         """One scatter-OR launch: ``stacked`` is the mesh-sharded

@@ -52,7 +52,7 @@ import time as _time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS
 from ..executor.plan import eval_plan, plan_inputs
@@ -467,11 +467,11 @@ class WholeQueryRunner:
             "kernel_launches": kernel_launches,
             "kernel_tiles": kernel_tiles,
         }
-        sharding = NamedSharding(mesh.mesh, P())
-        with layer_span("dispatch.place", _devobs.LEDGER):
-            mats_dev = jax.device_put(pad_mats, sharding)
+        # the params ride with the launch: jit places the host matrices
+        # replicated (the program's in_specs say P()) as part of the
+        # call, with no transfer or sync of their own
         with _DISPATCH_LOCK:
-            flat_out = fn(mats_dev, *flat_all, _launch_meta=launch_meta)
+            flat_out = fn(pad_mats, *flat_all, _launch_meta=launch_meta)
         parts = [[flat_out[j] for j in idxs] for idxs in fn.out_index]
         # tracing is synchronous on this thread (CompileRegistry's
         # thread-local protocol), so the flag read here is exactly

@@ -156,6 +156,8 @@ def build_debug_vars(api: API, server=None) -> dict:
         out["stackCache"] = {
             "entries": len(ex.mesh_exec._stack_cache),
             "executables": len(ex.mesh_exec._cache),
+            "fastHits": ex.mesh_exec.stack_fast_hits,
+            "walks": ex.mesh_exec.stack_walks,
         }
     # cross-query dynamic batching (docs/batching.md): fused/single
     # launch counters, the batch-size histogram, and the queue-wait

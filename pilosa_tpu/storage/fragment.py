@@ -52,7 +52,7 @@ from ..core import (
     SHARD_WIDTH,
     SHARD_WORDS,
 )
-from ..ops import bitset, bsi
+from ..ops import bitset, bsi, kernels as _kernels
 from ..utils import events
 from ..utils.durable import checksum, durable_replace, fsync_dir, fsync_file
 from ..utils.faults import FAULTS
@@ -107,6 +107,36 @@ QUARANTINE_ON_CORRUPTION = True
 # Process-wide, set from the server config like WAL_CRC above.
 COMPRESSED_RESIDENT = True
 COMPRESS_MAX_DENSITY = 0.5
+
+# Device epoch (docs/ingest.md "Device epoch").  A mesh stack's cache
+# token (mesh_exec._stack_token) is read off every member fragment:
+# device_gen, the device signature, ingest_epoch.  The epoch is the O(1)
+# summary of "none of those can have moved": each View owns one cell
+# holding a stamp drawn from this process-wide counter, shares it with
+# its fragments, and whoever moves a token input re-stamps the cell
+# AFTER the change and BEFORE the write is acknowledged.  Stamps never
+# repeat, so a deleted and re-created view can not alias an old one.
+# The bump sites: _mark_device_dirty (every non-ingest write, row
+# growth, repair), _enter_quarantine, _fold_journal_locked, the overlay
+# branch of ingest_apply, and a fragment entering or leaving a view
+# (view.py).  A spurious bump costs one walk; a missed one is a stale
+# answer, so whoever adds an input to _stack_token adds its bump and a
+# case to tests/test_stack_epoch.py.
+_DEVICE_EPOCH = itertools.count(1)
+
+
+def next_device_epoch() -> int:
+    return next(_DEVICE_EPOCH)
+
+
+def device_knobs() -> tuple:
+    """What device_form() / device_sig() read that no fragment owns.
+    Server config, bench legs and tests assign these module attributes
+    directly, so the stack cache's fast check compares their values
+    instead of trusting anyone to bump an epoch."""
+    return (DEFAULT_BUDGET.limit_bytes, COMPRESSED_RESIDENT,
+            COMPRESS_MAX_DENSITY, _kernels.CONTAINER_KERNELS)
+
 
 # Storage-event counters (surfaced at /debug/vars and /metrics via
 # Server.update_storage_gauges): process-wide, like the knobs above.
@@ -179,8 +209,12 @@ class Fragment:
 
     def __init__(self, path: str | None, index: str, field: str, view: str,
                  shard: int, max_op_n: int = DEFAULT_FRAGMENT_MAX_OP_N,
-                 row_id_cap: int | None = None, budget=None):
+                 row_id_cap: int | None = None, budget=None,
+                 epoch_cell: list | None = None):
         self.path = path  # None = purely in-memory (tests)
+        # the owning View's device-epoch cell (see _DEVICE_EPOCH above);
+        # a bare fragment stamps a cell nobody reads
+        self._epoch_cell = epoch_cell if epoch_cell is not None else [0]
         self.index = index
         self.field = field
         self.view = view
@@ -480,6 +514,7 @@ class Fragment:
         self.gen = next(self._GEN)  # derived caches must not serve stale
         self.device_gen = self.gen
         self._clear_journal()
+        self._bump_device_epoch()
         self._stage = None
         if self._wal_file is not None:
             try:
@@ -657,6 +692,13 @@ class Fragment:
         # which already holds every journaled bit
         self.device_gen = self.gen
         self._clear_journal()
+        self._bump_device_epoch()
+
+    def _bump_device_epoch(self):
+        """Re-stamp the view's device epoch: called after a token input
+        of the mesh stack cache moved (device_gen, _cap_rows,
+        ingest_epoch) and before the write returns."""
+        self._epoch_cell[0] = next_device_epoch()
 
     def _clear_journal(self):
         if self._journal:
@@ -673,6 +715,7 @@ class Fragment:
         self._device_dirty = True
         self.device_gen = self.gen
         self._clear_journal()
+        self._bump_device_epoch()
 
     def _note_rank(self, rows):
         """Incremental rank-cache maintenance after a successful mutation
@@ -1276,6 +1319,7 @@ class Fragment:
                 self.gen = next(self._GEN)
                 self.ingest_epoch += 1
                 self._journal.append((self.ingest_epoch, nidx, nval))
+                self._bump_device_epoch()
                 self._journal_bytes += int(nidx.nbytes + nval.nbytes)
                 INGEST_DELTA_BUDGET.register(
                     ("delta", id(self)), self._journal_bytes,

@@ -5,7 +5,7 @@ from __future__ import annotations
 import os
 import shutil
 
-from .fragment import Fragment
+from .fragment import Fragment, device_knobs
 from .index import Index
 from .field import Field, FieldOptions
 from ..utils.locks import make_rlock
@@ -109,6 +109,23 @@ class Holder:
             return None
         v = f.view(view)
         return None if v is None else v.fragment(shard)
+
+    def device_epoch(self, index: str, keys) -> tuple:
+        """The device epoch of the (field, view) ``keys`` of one index:
+        the process-wide device knobs plus each view's stamp, -1 where
+        the index, field or view does not exist.  Looked up through the
+        live dicts on every call, so deleting or re-creating an index,
+        field or view changes the answer without a bump of its own
+        (a new View draws a stamp no earlier one had).  Equal epochs
+        read before and after mean no input of a mesh stack's cache
+        token moved in between (fragment.py _DEVICE_EPOCH)."""
+        idx = self.indexes.get(index)
+        out = [device_knobs()]
+        for field, view in keys:
+            f = None if idx is None else idx.fields.get(field)
+            v = None if f is None else f.views.get(view)
+            out.append(-1 if v is None else v.device_epoch)
+        return tuple(out)
 
     def iter_fragments(self, index: str | None = None):
         """Yield (index, field, view, shard, fragment) over local data

@@ -9,7 +9,7 @@ from __future__ import annotations
 import os
 
 from ..core import VIEW_STANDARD
-from .fragment import Fragment
+from .fragment import Fragment, next_device_epoch
 from ..utils.locks import make_rlock
 
 
@@ -34,7 +34,15 @@ class View:
         self.cache_type = cache_type
         self.cache_size = cache_size
         self.fragments: dict[int, Fragment] = {}
+        # device epoch (fragment.py _DEVICE_EPOCH): one stamp for the
+        # whole view, shared with every fragment it creates and
+        # re-stamped here when a fragment enters or leaves
+        self._epoch_cell = [next_device_epoch()]
         self._lock = make_rlock("view")
+
+    @property
+    def device_epoch(self) -> int:
+        return self._epoch_cell[0]
 
     def fragment(self, shard: int) -> Fragment | None:
         return self.fragments.get(shard)
@@ -51,7 +59,8 @@ class View:
                 if self.max_op_n is not None:
                     kwargs["max_op_n"] = self.max_op_n
                 frag = Fragment(frag_path, self.index, self.field, self.name,
-                                shard, row_id_cap=self.row_id_cap, **kwargs)
+                                shard, row_id_cap=self.row_id_cap,
+                                epoch_cell=self._epoch_cell, **kwargs)
                 # Only the STANDARD view caches: TopN candidate pruning
                 # reads exclusively from it (cache/rank.topn_from_rank),
                 # so rank maintenance on time/BSI views would be pure
@@ -62,6 +71,16 @@ class View:
                     frag.rank_cache = RankCache(self.cache_type,
                                                 self.cache_size)
                 self.fragments[shard] = frag
+                self._epoch_cell[0] = next_device_epoch()
+            return frag
+
+    def remove_fragment(self, shard: int) -> Fragment | None:
+        """Drop a shard's fragment from the view (the caller closes
+        it); cached mesh stacks that held it fail their epoch check."""
+        with self._lock:
+            frag = self.fragments.pop(shard, None)
+            if frag is not None:
+                self._epoch_cell[0] = next_device_epoch()
             return frag
 
     def available_shards(self) -> set[int]:
