@@ -9,6 +9,7 @@ only scalars/count-vectors back from the device.
 
 from __future__ import annotations
 
+import functools
 from datetime import datetime
 from typing import Any
 
@@ -88,56 +89,108 @@ class _PendingGroup:
                                if hp else [0] * nB))
 
 
-# A batched executable materializes roughly one [B, SHARD_WORDS] u32
-# gather temp per params slot per stacked shard (measured: an 8-slot
-# Intersect batch at B=16384 on one shard exhausts a 16 GB HBM with
-# 8 x 2 GB gather temps).  Batches are therefore dispatched in chunks
-# sized so those temps stay under BATCH_TEMP_BYTES, and every chunk is
-# padded up to a power of two (repeating its last row — always in-range)
-# so arbitrary client batch sizes reuse a bounded set of compiled
-# executables instead of compiling one per distinct B.  BATCH_CHUNK_MAX
-# was sized when one compile cost 20-40 s on a remote device that no
-# longer exists; it awaits re-derivation on the chip (ROADMAP.md S9).
+# What a launch costs in program temporaries, and what it may cost.
 #
-# Filtered row_counts/TopN batches ADDITIONALLY materialize one
-# [B, rows, W] masked temp per stacked shard (rows = the fragment row
-# count, usually >> P): sizing by P alone let bench configs 3-8 OOM
-# small-RAM hosts on both dispatch paths (BENCH_r07's skipped legs).
-# Callers pass that axis as ``row_weight`` so the chunking budget sees
-# the real per-B-row footprint.  The budget itself is the
-# ``batch-temp-mb`` knob (the decode-workspace pattern; process-wide,
-# most recent Server wins).
+# A batched executable materializes device temporaries beside its
+# stacked inputs, per stacked shard of the device.  The per-stage
+# batched launches state theirs in [SHARD_WORDS] u32 rows (128 KiB) a
+# batch row (``node_temp_rows``, the one function ``_batch_chunks`` and
+# the cross-query batcher's per-stage tickets share):
+#
+# * count / segments: one gather temp per params slot (measured: an
+#   8-slot Intersect batch at B=16384 on one shard exhausts a 16 GB HBM
+#   with 8 x 2 GB gather temps);
+# * filtered row_counts / TopN: one [B, rows, W] masked temp, rows = the
+#   fragment row count (BENCH_r07's small-RAM OOM gap);
+# * filtered bsi_sum: the summed field's bit rows under the filter, so
+#   its depth + 2 rows.
+#
+# A whole-query program asks the compiler instead: its launch reads
+# ``memory_analysis().temp_size_in_bytes`` of the executable it is about
+# to run, once per compiled shape, and walks the device's shards in
+# blocks where that figure would pass the bound
+# (parallel/wholequery.py ``run``).  ``batch_temp_bound`` is what a
+# launch may cost: what the device has left — its ``bytes_limit`` less
+# what the device budget counts resident, less BATCH_TEMP_MARGIN — with
+# the ``batch-temp-mb`` knob (BATCH_TEMP_BYTES; process-wide, most
+# recent Server wins) as a ceiling only.  Per-stage batches are
+# dispatched in chunks whose temporaries stay under the bound, every
+# chunk padded up to a power of two (repeating its last row — always
+# in-range) so arbitrary client batch sizes reuse a bounded set of
+# compiled executables.  BATCH_CHUNK_MAX was sized when one compile cost
+# 20-40 s on a remote device that no longer exists; it awaits
+# re-derivation on the chip (ROADMAP.md S9).
 BATCH_TEMP_BYTES = 4 << 30
-BATCH_CHUNK_MIN, BATCH_CHUNK_MAX = 8, 32768
+BATCH_CHUNK_MAX = 32768
+# Device bytes the bound leaves free beside resident blocks and one
+# launch's temporaries: outputs, params, the temporaries of launches
+# still in flight at B = 1 (0.4-0.5 GB each at 176 stacked shards) and
+# the allocator's own slack.
+BATCH_TEMP_MARGIN = 1 << 30
+ROW_BYTES = SHARD_WORDS * 4
 
 
-def batch_chunk_size(P: int, n_shards: int, row_weight: int = 0) -> int:
-    """Pow-2 batch-axis chunk size under the batch-temp workspace —
-    THE sizing formula, shared by _batch_chunks, the whole-query chunk
-    guard, and the cross-query batcher's fusion cap."""
-    weight = max(1, P, row_weight) * n_shards * SHARD_WORDS * 4
-    chunk = max(BATCH_CHUNK_MIN,
-                min(BATCH_CHUNK_MAX, BATCH_TEMP_BYTES // weight))
+def node_temp_rows(kind: str, plan, P: int, primary_rows: int = 0) -> int:
+    """[SHARD_WORDS] u32 rows one batch row of a per-stage batched
+    launch keeps per stacked shard.  ``kind`` is the batched call-group
+    name or its node kind (sum = bsi_sum, topn = row_counts); ``plan``
+    the slotted filter (None: a B-independent broadcast pass, 0), ``P``
+    its params slots, ``primary_rows`` the rows of the (field, view)
+    reduced."""
+    if kind in ("count", "segments"):
+        return max(1, P)
+    if plan is None:
+        return 0
+    return max(1, P, primary_rows)
+
+
+@functools.cache
+def device_bytes_limit() -> int | None:
+    """The smallest ``bytes_limit`` over the local devices, where the
+    backend reports one (the CPU does not).  Read once."""
+    import jax
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in jax.local_devices()]
+    return min((b for b in limits if b), default=None)
+
+
+def batch_temp_bound() -> int:
+    """What one launch's temporaries may cost now: what the device has
+    left, ``batch-temp-mb`` as a ceiling."""
+    limit = device_bytes_limit()
+    if limit is None:
+        return BATCH_TEMP_BYTES
+    from ..storage.membudget import DEFAULT_BUDGET
+    free = limit - DEFAULT_BUDGET.resident_bytes - BATCH_TEMP_MARGIN
+    return max(0, min(BATCH_TEMP_BYTES, free))
+
+
+def batch_chunk_size(rows: int, n_shards: int) -> int:
+    """Pow-2 batch-axis chunk size whose temporaries, at ``rows``
+    (``node_temp_rows``) a batch row over ``n_shards`` stacked shards a
+    device, fit the bound; one row where not even one fits (a launch
+    that cannot shrink further still runs)."""
+    chunk = batch_temp_bound() // (max(1, rows) * n_shards * ROW_BYTES)
+    chunk = max(1, min(BATCH_CHUNK_MAX, chunk))
     return 1 << (chunk.bit_length() - 1)
 
 
-def _batch_chunks(params_mat: np.ndarray, n_shards: int,
-                  row_weight: int = 0):
+def _batch_chunks(params_mat: np.ndarray, n_shards: int, rows: int = 0):
     """Yield (lo, n, padded_params) covering params_mat[lo:lo+n]; padded
     rows beyond n are duplicates whose results the caller ignores.
-    ``n_shards`` is the per-device stacked-shard count — gather temps
-    live per device, so the budget divides by the mesh size, not the
+    ``n_shards`` is the per-device stacked-shard count — temporaries
+    live per device, so the bound divides by the mesh size, not the
     total shard count.  ``n_shards <= 0`` marks a filter-less group whose
     device pass is a B-independent broadcast: it dispatches as ONE chunk
     regardless of B (splitting would repeat the full fragment pass per
     chunk — r5 advisor, the old path still cut at BATCH_CHUNK_MAX).
-    ``row_weight``: the rows axis of a [B, rows, W] masked temp
-    (filtered row_counts/TopN), 0 for gather-temp-only kinds."""
+    ``rows``: the group's ``node_temp_rows``; one gather temp a params
+    slot where none is given."""
     B, P = params_mat.shape
     if n_shards <= 0:
-        chunk = max(BATCH_CHUNK_MIN, B)
+        chunk = max(1, B)
     else:
-        chunk = batch_chunk_size(P, n_shards, row_weight)
+        chunk = batch_chunk_size(max(1, P, rows), n_shards)
     for lo in range(0, B, chunk):
         sub = params_mat[lo: lo + chunk]
         n = sub.shape[0]
@@ -203,14 +256,13 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
         # filter broadcast one pass — single chunk (see _batch_chunks)
         return per_dev if (kind == "count" or slotted is not None) else 0
 
-    def _row_weight(kind, slotted, extra):
-        # filtered row_counts launches materialize a [B, rows, W]
-        # masked temp per stacked shard: the rows axis must size the
-        # chunk budget (BENCH_r07's small-RAM OOM gap)
-        if kind != "topn" or slotted is None:
-            return 0
-        from ..parallel.mesh_exec import field_rows
-        return field_rows(holder, index, extra["field"], extra["view"])
+    def _temp_rows(kind, slotted, params_mat, extra):
+        rows = 0
+        if kind != "count" and slotted is not None:
+            from ..parallel.mesh_exec import field_rows
+            rows = field_rows(holder, index, extra["field"],
+                              extra.get("view", VIEW_STANDARD))
+        return node_temp_rows(kind, slotted, params_mat.shape[1], rows)
 
     # chunk layouts computed ONCE; on the multi-slice direct path the
     # padded params also go to device once (slice-major iteration would
@@ -222,13 +274,15 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
         [(lo, n_c, sub if fuse else jnp.asarray(sub))
          for lo, n_c, sub in
          _batch_chunks(params_mat, _n_split(kind, slotted),
-                       _row_weight(kind, slotted, extra))]
+                       _temp_rows(kind, slotted, params_mat, extra))]
         for kind, slotted, params_mat, _ci, extra in groups]
-    # the batch axis split to honor the workspace: visible, not silent
-    # (docs/observability.md — `query.batch_temp_splits`)
+    # the batch axis split to honor the bound: visible, not silent
+    # (docs/observability.md — `query.batch_temp_splits`, and
+    # `batchTemp.splits` with the batcher's and the block walks')
     n_splits = sum(len(ch) - 1 for ch in group_chunks if len(ch) > 1)
     if n_splits:
         batcher.stats.count("query.batch_temp_splits", n_splits)
+        mesh.temp_splits += n_splits
 
     parts_acc: dict[tuple[int, int], list] = {}
     for shard_slice in sched:
@@ -842,24 +896,15 @@ class Executor:
 
     def _wq_dispatch(self, index: str, shards, program, mats):
         """One program launch through the dispatch batcher (concurrent
-        same-shape requests fuse along the params batch axis)."""
+        same-shape requests fuse along the params batch axis).  The
+        launch holds the compiler's figure for the program's
+        temporaries against the batch-temp bound and walks the device's
+        shards in blocks where the whole would not fit; only a batch
+        whose temporaries do not fit over ONE stacked shard raises
+        ``batch-chunks`` and stays on the legacy chunked path, which
+        cuts the batch axis."""
         return self.batcher.whole_query(self.wholequery, program, mats,
                                         self.holder, index, shards)
-
-    @staticmethod
-    def _wq_chunk_guard(mat: np.ndarray, n_split: int,
-                        row_weight: int = 0):
-        """A params batch needing more than one dispatch chunk (device
-        temp budget) stays on the legacy chunked path.  Pure arithmetic
-        — the same batch_chunk_size sizing as _batch_chunks (including
-        the [B, rows, W] row_weight axis for filtered row_counts),
-        without materializing a padded chunk just to count them."""
-        from ..parallel.wholequery import WholeQueryUnsupported
-        B, P = mat.shape
-        if n_split <= 0:
-            return  # broadcast pass: always one chunk
-        if B > batch_chunk_size(P, n_split, row_weight):
-            raise WholeQueryUnsupported("batch-chunks", f"B={B}")
 
     def _wq_run_batched(self, index: str, shards, groups, results):
         """Whole-query dispatch of standard batched call groups —
@@ -873,18 +918,8 @@ class Executor:
         groups = list(groups)
         if not groups:
             return
-        per_dev = self.mesh_exec.stacked_per_device(max(len(shards), 1))
         nodes, mats = [], []
         for kind, slotted, params_mat, call_idxs, extra in groups:
-            n_split = per_dev if (kind == "count" or slotted is not None) \
-                else 0
-            row_weight = 0
-            if kind == "topn" and slotted is not None:
-                from ..parallel.mesh_exec import field_rows
-                row_weight = field_rows(self.holder, index,
-                                        extra["field"],
-                                        extra.get("view", _STD))
-            self._wq_chunk_guard(params_mat, n_split, row_weight)
             if kind == "count":
                 nodes.append(ReduceNode("count", slotted))
             elif kind == "sum":
@@ -957,7 +992,6 @@ class Executor:
         if not units:
             return results
 
-        per_dev = self.mesh_exec.stacked_per_device(max(len(shards), 1))
         nodes, mats, unit_nodes = [], [], []
         for u in units:
             kind, ds = u["kind"], u["descs"]
@@ -965,24 +999,15 @@ class Executor:
             d0 = ds[0]
             if kind in ("count", "segments"):
                 mat = np.stack([d["params"] for d in ds])
-                self._wq_chunk_guard(mat, per_dev)
                 nodes.append(ReduceNode(kind, d0["slotted"]))
                 mats.append(mat)
             elif kind == "sum":
                 mat = np.stack([d["params"] for d in ds])
-                self._wq_chunk_guard(
-                    mat, per_dev if d0["slotted"] is not None else 0)
                 nodes.append(ReduceNode("bsi_sum", d0["slotted"],
                                         (d0["field"], d0["view"])))
                 mats.append(mat)
             elif kind == "topn":
                 mat = np.stack([d["params"] for d in ds])
-                from ..parallel.mesh_exec import field_rows
-                self._wq_chunk_guard(
-                    mat, per_dev if d0["slotted"] is not None else 0,
-                    row_weight=field_rows(self.holder, index,
-                                          d0["field"], VIEW_STANDARD)
-                    if d0["slotted"] is not None else 0)
                 nodes.append(ReduceNode("row_counts", d0["slotted"],
                                         (d0["field"], VIEW_STANDARD)))
                 mats.append(mat)

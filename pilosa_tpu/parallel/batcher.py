@@ -85,8 +85,9 @@ class _Ticket:
         self.payload = payload
         # device-temp bytes one fused B-row of this ticket costs (the
         # [B, rows, W] masked temp of filtered row_counts; 0 = only the
-        # FUSED_ROWS_MAX row cap applies).  The fusion packer bounds
-        # SUM(rows x weight) by the batch-temp workspace — fusing k
+        # FUSED_ROWS_MAX row cap applies; a whole-query ticket's is the
+        # runner's to say, at dispatch).  The fusion packer holds
+        # SUM(rows x weight) to the batch-temp bound — fusing k
         # over-sized tickets multiplied the temp k-fold and OOM'd
         # small-RAM hosts (the BENCH_r07 sizing gap).
         self.temp_weight = temp_weight
@@ -265,13 +266,15 @@ class DispatchBatcher:
     def _rowcount_weight(self, field, view, slotted, holder, index,
                          shards) -> int:
         """Per-fused-B-row device-temp bytes of a filtered row_counts
-        launch ([rows, W] masked temp per stacked shard per device) —
-        the fusion packer's batch-temp workspace unit.  0 for the
+        launch ([rows, W] masked temp per stacked shard per device, by
+        executor.node_temp_rows) — the fusion packer's unit.  0 for the
         filter-less broadcast pass (B-independent)."""
         if slotted is None:
             return 0
         from .mesh_exec import field_rows
-        rows = field_rows(holder, index, field, view)
+        from ..executor.executor import node_temp_rows
+        rows = node_temp_rows("row_counts", slotted, 0,
+                              field_rows(holder, index, field, view))
         per_dev = self.mesh.stacked_per_device(max(len(shards), 1))
         return rows * per_dev * SHARD_WORDS * 4
 
@@ -339,7 +342,11 @@ class DispatchBatcher:
         axis — the batched parameter axis rides the SAME compiled
         program, so the fused-launch economics of the reducer tickets
         carry over to whole requests.  Programs with non-batchable
-        nodes (bsi_minmax, group_counts) launch un-fused."""
+        nodes (bsi_minmax, group_counts) launch un-fused.  Fusing
+        programs multiplies their batch-dependent temporaries: the
+        packer asks the runner what a batch row of the program cost by
+        the compiler's figure for its last launch (``row_temp_bytes``),
+        over the fewest shards the launch can take at once."""
         if not self._use_ticket():
             return runner.run(program, mats, holder, index, shards)
         key = ("wholequery", repr(program), index, tuple(shards),
@@ -349,24 +356,11 @@ class DispatchBatcher:
             key = key + ("nofuse", next(self._wq_nofuse))
         rows = sum(m[0].shape[0] if isinstance(m, tuple) else m.shape[0]
                    for m in mats)
-        # batch-temp weight: every FILTERED row_counts node of the
-        # program adds a [B, rows, W] masked temp per stacked shard —
-        # fusing programs multiplies them, so the packer must see it
-        from .mesh_exec import field_rows
-        weight = 0
-        for node in program:
-            if node.kind == "row_counts" and node.plan is not None:
-                f_name, v_name = node.primary
-                weight += (field_rows(holder, index, f_name, v_name)
-                           * self.mesh.stacked_per_device(
-                               max(len(shards), 1))
-                           * SHARD_WORDS * 4)
         out = self._submit(
             "wholequery", key,
             np.zeros((max(rows, 1), 0), dtype=np.int32), False,
             {"runner": runner, "program": program, "mats": mats,
-             "holder": holder, "index": index, "shards": list(shards)},
-            temp_weight=weight)
+             "holder": holder, "index": index, "shards": list(shards)})
         if out is None:  # closed mid-flight: direct
             return runner.run(program, mats, holder, index, shards)
         return out
@@ -487,25 +481,32 @@ class DispatchBatcher:
                 self.stats.count("dispatch.expired_drop")
                 continue
             groups.setdefault(t.key, []).append(t)
-        from ..executor import executor as _exec_mod
+        from ..executor.executor import batch_temp_bound
+        bound = batch_temp_bound()
         for key, tickets in groups.items():
             # foreground first, then pack under the ticket, fused-row,
-            # and batch-temp-workspace caps; an over-cap ticket launches
-            # alone (un-fused)
+            # and batch-temp caps; an over-cap ticket launches alone
+            # (un-fused)
             tickets.sort(key=lambda t: t.background)
             pack: list[_Ticket] = []
             rows = 0
             temp = 0
+            weight = None
+            if key[0] == "wholequery" and len(tickets) > 1:
+                weight = tickets[0].payload["runner"].row_temp_bytes(
+                    key[1], key[2])
             for t in tickets:
                 n = t.params.shape[0]
-                cost = n * t.temp_weight
-                over_temp = pack and t.temp_weight > 0 and \
-                    temp + cost > _exec_mod.BATCH_TEMP_BYTES
+                w = t.temp_weight if weight is None else weight
+                cost = n * w
+                over_temp = pack and w > 0 and temp + cost > bound
                 if over_temp:
-                    # fusing this ticket would exceed the batch-temp
-                    # workspace ([B, rows, W] temps scale with the
-                    # fused row count): split the pack, visibly
+                    # fusing this ticket would take the pack's
+                    # temporaries past the batch-temp bound (they
+                    # scale with the fused row count): split the
+                    # pack, visibly
                     self.stats.count("dispatch.fused_temp_split")
+                    self.mesh.temp_splits += 1
                 if pack and (len(pack) >= self.max_batch
                              or rows + n > FUSED_ROWS_MAX
                              or over_temp):

@@ -60,6 +60,7 @@ working set is resident.
 
 from __future__ import annotations
 
+import functools
 import itertools as _itertools
 import time as _time
 from concurrent import futures
@@ -247,8 +248,9 @@ class _InstrumentedExec:
         # runtime; its seconds reach /debug/vars through the ledger
         # (dispatchSecondsTotal)
         with layer_span("dispatch.enqueue", kind=self.kind, sig=self.sig,
-                        rows=rows, rows_padded=b_pad,
-                        tickets=tickets) as span:
+                        rows=rows, rows_padded=b_pad, tickets=tickets,
+                        shards=shards, shards_padded=shards_pad,
+                        temp_bytes=ctx.get("temp_bytes", 0)) as span:
             t0 = _time.perf_counter()
             out = self.fn(*args)
             dt = _time.perf_counter() - t0
@@ -286,6 +288,30 @@ def default_mesh(devices=None) -> Mesh:
 
 
 _EXEC_SEQ = _itertools.count()
+_BLOCK_SEQ = _itertools.count()
+
+
+class _Block:
+    """One (field, view)'s stacked block over one shard list — the unit
+    of stacked residency: resident once, registered with the device
+    budget once (``skey``, unique to this block, so a late unregister
+    can never hit its successor), shared by every stack-cache entry
+    whose program reads it.  ``bkey`` is (index, (field, view), shard
+    list), ``token`` its signature and fragments' device generations,
+    ``arrays`` the placed block (the five packed tables of a compressed
+    one), ``epochs`` the ingest epochs it reflects; the last two move
+    together, under the executor's stack-cache lock."""
+
+    __slots__ = ("bkey", "skey", "token", "arrays", "epochs", "nbytes",
+                 "compressed")
+
+    def __init__(self, exec_id, bkey, token, arrays, epochs):
+        self.bkey, self.token = bkey, token
+        self.skey = ("block", exec_id, next(_BLOCK_SEQ))
+        self.arrays, self.epochs = arrays, epochs
+        self.nbytes = sum(a.nbytes for a in arrays) \
+            if isinstance(arrays, tuple) else arrays.nbytes
+        self.compressed = self.nbytes if token[0][0] == "z" else 0
 
 
 class MeshExecutor:
@@ -329,12 +355,27 @@ class MeshExecutor:
         from ..storage.membudget import DEFAULT_BUDGET
         self._stack_cache: OrderedDict = OrderedDict()
         self.stack_cache_max = 64
+        # (index, (field, view), shards) -> _Block: a (field, view)'s
+        # rows are resident ONCE however many programs stack them (Q1.1,
+        # Q1.2 and Q1.3 all read lo_extdisc; the load check's and the
+        # mix's key lists differ).  The block is what the device budget
+        # registers, touches, pins and evicts; a stack-cache entry only
+        # refers to its blocks.  Exactly the blocks some entry holds are
+        # in here (``_store_entry`` and the eviction callback keep it
+        # so), read and written under ``_sc_lock``.
+        import weakref
+        self._blocks: dict = {}
         # /debug/vars stackCache.fastHits / .walks: lookups validated by
         # the device epoch alone, and every other lookup (a walk over
         # the fragments).  Plain ints, bumped without a lock on the
         # dispatcher thread and read racily by the handler.
         self.stack_fast_hits = 0
         self.stack_walks = 0
+        # /debug/vars batchTemp.splits: packs the batcher cut short,
+        # batches chunked and launches that walked their shards in
+        # blocks, each for the batch-temp bound's sake.  A plain int
+        # like the two above.
+        self.temp_splits = 0
         self._budget = DEFAULT_BUDGET
         # single-worker background uploader for streamed shard slices
         # (created on first over-budget query; one worker serializes
@@ -346,10 +387,9 @@ class MeshExecutor:
         # executor lock could deadlock two executors evicting each other's
         # entries.
         self._sc_lock = make_lock("stack-cache")
-        import weakref
         self._finalizer = weakref.finalize(
-            self, MeshExecutor._cleanup_budget, self._budget, id(self),
-            self._stack_cache)
+            self, MeshExecutor._cleanup_budget, self._budget,
+            self._stack_cache, self._blocks)
         # Concurrent request threads share this executor (the server
         # overlaps in-flight query batches to hide the dispatch round
         # trip); the lock covers the python-side cache bookkeeping only —
@@ -472,14 +512,16 @@ class MeshExecutor:
 
         Results are cached against the fragments' data-generation stamps
         (fragment.gen) so repeat queries reuse the resident stacked blocks
-        without touching (or pinning) the per-fragment mirrors at all; the
-        stacked bytes register with the DeviceBudget so HBM pressure can
-        evict whole stacks (r3 advisor).  A budget-eviction callback may
-        pop entries concurrently from outside ``self._lock`` (it must not
-        lock: two executors evicting each other's entries would deadlock),
-        so every cache op here tolerates a vanished key.
+        without touching (or pinning) the per-fragment mirrors at all; each
+        block's bytes register with the DeviceBudget so HBM pressure can
+        evict it, and with it every entry that reads it (r3 advisor).  A
+        budget-eviction callback may pop entries concurrently from outside
+        ``self._lock`` (it must not lock: two executors evicting each
+        other's entries would deadlock), so every cache op here tolerates
+        a vanished key.
 
-        An entry is ``(token, out, epochs, epoch)``.  ``epoch`` is the
+        An entry is ``(token, out, epochs, epoch, blocks)``; ``blocks``
+        are the ``_Block``s whose arrays ``out`` places.  ``epoch`` is the
         device epoch (holder.device_epoch, fragment.py _DEVICE_EPOCH)
         read BEFORE the walk that validated the entry; while the epoch
         read at the top of a later call equals it, no input of the
@@ -493,14 +535,13 @@ class MeshExecutor:
         the two would be stamped as seen."""
         epoch = holder.device_epoch(index, keys)
         ckey = (index, tuple(keys), tuple(shards))
-        skey = ("stack", id(self), ckey)
         with self._sc_lock:
             cached = self._stack_cache.get(ckey)
             if cached is not None:
                 self._stack_cache.move_to_end(ckey)
         if cached is not None and cached[3] == epoch:
             self.stack_fast_hits += 1
-            self._budget.touch(skey)
+            self._budget.touch(*[b.skey for b in cached[4]])
             return cached[1]
         self.stack_walks += 1
         frags, token, epochs = self._stack_token(keys, holder, index, shards)
@@ -523,9 +564,10 @@ class MeshExecutor:
                 # the top so the next lookup takes the fast check
                 with self._sc_lock:
                     if self._stack_cache.get(ckey) is cached:
-                        self._stack_cache[ckey] = cached[:3] + (epoch,)
+                        self._stack_cache[ckey] = \
+                            cached[:3] + (epoch,) + cached[4:]
             if cached is not None:
-                self._budget.touch(skey)
+                self._budget.touch(*[b.skey for b in cached[4]])
                 return cached[1]
 
         groups: dict[tuple, list[tuple[int, list]]] = {}
@@ -533,9 +575,11 @@ class MeshExecutor:
             sig = tuple(None if fr is None
                         else self._frag_sig(fr) for fr in row)
             groups.setdefault(sig, []).append((shard, row))
+        nk = len(keys)
+        row_of = {s: i for i, s in enumerate(shards)}
+        held = list(epochs)     # the ingest epochs the placed blocks hold
+        blocks, fresh = [], []
         out = []
-        nbytes = 0
-        comp_bytes = 0
         for sig, members in groups.items():
             shard_list = [m[0] for m in members]
             placed = []
@@ -544,81 +588,151 @@ class MeshExecutor:
                     placed.append(None)
                     continue
                 frs = [m[1][i] for m in members]
-                if shape[0] == "z":
-                    # compressed staging: the resident form IS the
-                    # packed stream; bytes registered below are the
-                    # compressed footprint
-                    pk = self._place_packed_block(frs, shape)
-                    pb = sum(a.nbytes for a in pk)
-                    nbytes += pb
-                    comp_bytes += pb
-                    placed.append(pk)
-                    continue
-                # Two staging paths.  Warm (mirrors already resident, one
-                # device): stack on device — no host transfer at all.
-                # Cold: build the dense [S, rows, W] block on host and
-                # ship it as ONE sharded transfer instead of one upload
-                # per fragment.  On a mesh of several devices the warm
-                # path would first build the whole stack on the default
-                # device, where every mirror lives, and then move all
-                # but one device's share off it; the host block goes to
-                # each device directly.  The 4/5 residency threshold
-                # below was chosen against a remote device whose
-                # per-transfer cost no longer applies; it awaits
-                # re-derivation on the chip (ROADMAP.md S9).
-                resident = sum(
-                    1 for fr in frs
-                    if not fr._device_dirty
-                    and fr._mirrors.get(self.stage_device) is not None)
-                if self.multiprocess:
-                    # per-process staging: each process supplies only its
-                    # addressable shards (device_put would assert the
-                    # whole host block equal across processes)
-                    p = self._place_host_block(frs, shape)
-                elif self.n_devices == 1 and \
-                        5 * resident >= 4 * len(frs):
-                    arrs = [fr.device(self.stage_device) for fr in frs]
-                    if all(a.shape == shape for a in arrs):
-                        p = self._pad_and_place(arrs, shape, len(frs))
-                    else:
-                        # a concurrent write grew a fragment's capacity
-                        # after the shape signature was read — the host
-                        # path slices to the signature's shape
-                        p = self._place_host_block(frs, shape)
-                else:
-                    p = self._place_host_block(frs, shape)
-                nbytes += p.nbytes
-                placed.append(p)
+                bkey = (index, keys[i], tuple(shard_list))
+                btoken = (shape,) + tuple(fr.device_gen for fr in frs)
+                at = tuple(epochs[row_of[shard] * nk + i]
+                           for shard in shard_list)
+                with self._sc_lock:
+                    blk = self._blocks.get(bkey)
+                    arrays, b_at = (blk.arrays, blk.epochs) \
+                        if blk is not None else (None, None)
+                if blk is None or blk.token != btoken or \
+                        (self.multiprocess and b_at != at):
+                    # (a multi-process mesh overlays nothing: a block
+                    # behind its fragments' flushes is stacked anew)
+                    blk = _Block(id(self), bkey, btoken,
+                                 self._place_block(frs, shape), at)
+                    fresh.append(blk)
+                    arrays, b_at = blk.arrays, at
+                # another program may have stacked these rows: they are
+                # shared, and the ingest epochs they were stacked at
+                for shard, ep in zip(shard_list, b_at):
+                    held[row_of[shard] * nk + i] = ep
+                blocks.append(blk)
+                placed.append(arrays)
             out.append((shard_list, placed, sig))
-
-        import weakref
-        wself = weakref.ref(self)  # entries must not pin the executor
-
-        def _evict(ck=ckey, tok=token):
-            # Guard on the registration's token VALUE, under the leaf
-            # lock: a deferred callback that lost a race with a rebuild
-            # after a data change must not drop the fresh entry (its token
-            # differs — gens are unique per mutation).  Value equality, not
-            # identity: a concurrent double-miss stores one thread's tuple
-            # while the budget holds the other's, and both describe the
-            # same data.
-            s = wself()
-            if s is not None:
-                with s._sc_lock:
-                    cur = s._stack_cache.get(ck)
-                    if cur is not None and cur[0] == tok:
-                        del s._stack_cache[ck]
-
-        with self._sc_lock:
-            self._stack_cache[ckey] = (token, out, epochs, epoch)
-            trimmed = []
-            while len(self._stack_cache) > self.stack_cache_max:
-                trimmed.append(self._stack_cache.popitem(last=False)[0])
-        self._budget.register(skey, nbytes, _evict,
-                              compressed_bytes=comp_bytes)
-        for old_key in trimmed:
-            self._budget.unregister(("stack", id(self), old_key))
+        held = tuple(held)
+        self._store_entry(ckey, (token, out, held, epoch, tuple(blocks)),
+                          fresh)
+        if held != epochs:
+            # a shared block was stacked before flushes this entry's
+            # fragments have journaled since: overlay them now
+            self._refresh_overlays(ckey, token, frags, shards, keys,
+                                   epochs, epoch)
         return out
+
+    def _store_entry(self, ckey, entry, fresh):
+        """Store a stack-cache entry and the blocks it placed, and give
+        the device budget exactly the blocks that are held.  A fresh
+        block takes its key's place in ``_blocks``; entries that still
+        read the block it displaced are stale (a fragment of it has
+        moved on) and go, as does the least recently used entry past
+        ``stack_cache_max``; blocks no entry holds any more are
+        unregistered, fresh ones registered — outside the leaf lock,
+        since registering may evict (and call back into
+        ``_evict_block``).  An entry that shares a block which left
+        ``_blocks`` while it was being built (evicted, displaced) is
+        not stored: its launch runs on what it placed and the next
+        lookup builds anew."""
+        with self._sc_lock:
+            if any(self._blocks.get(b.bkey) is not b
+                   for b in entry[4] if b not in fresh):
+                return
+            gone = []
+            for blk in fresh:
+                old = self._blocks.get(blk.bkey)
+                if old is not None:
+                    gone.append(old)
+                    self._drop_readers_locked(old)
+                self._blocks[blk.bkey] = blk
+            self._stack_cache[ckey] = entry
+            while len(self._stack_cache) > self.stack_cache_max:
+                self._stack_cache.popitem(last=False)
+            gone += self._sweep_blocks_locked()
+        for blk in gone:
+            self._budget.unregister(blk.skey)
+        import weakref
+        wself = weakref.ref(self)  # the budget must not pin the executor
+        for blk in fresh:
+            self._budget.register(
+                blk.skey, blk.nbytes, functools.partial(
+                    MeshExecutor._evict_block, wself, blk.bkey, blk.skey),
+                compressed_bytes=blk.compressed)
+            with self._sc_lock:
+                held = self._blocks.get(blk.bkey) is blk
+            if not held:    # swept or displaced before it was registered
+                self._budget.unregister(blk.skey)
+
+    @staticmethod
+    def _evict_block(wself, bkey, skey):
+        """The budget evicted a block: it goes, with every entry that
+        reads it and every block those entries alone held.  Guarded on
+        the block's own budget key, under the leaf lock: a deferred
+        callback that lost a race with a rebuild after a data change
+        must not drop the fresh block."""
+        s = wself()
+        if s is None:
+            return
+        with s._sc_lock:
+            cur = s._blocks.get(bkey)
+            if cur is None or cur.skey != skey:
+                return
+            del s._blocks[bkey]
+            s._drop_readers_locked(cur)
+            unheld = s._sweep_blocks_locked()
+        for blk in unheld:
+            s._budget.unregister(blk.skey)
+
+    def _drop_readers_locked(self, blk):
+        """Drop every stack-cache entry that reads ``blk``."""
+        for ck in [ck for ck, e in self._stack_cache.items()
+                   if blk in e[4]]:
+            del self._stack_cache[ck]
+
+    def _sweep_blocks_locked(self) -> list:
+        """Take out of ``_blocks`` those no entry holds; returns them
+        for the caller to unregister outside the lock."""
+        held = {id(b) for e in self._stack_cache.values() for b in e[4]}
+        dead = [b for b in self._blocks.values() if id(b) not in held]
+        for blk in dead:
+            del self._blocks[blk.bkey]
+        return dead
+
+    def _place_block(self, frs, shape):
+        """Stack one (field, view)'s fragments ``frs`` of one signature
+        and place the block over the mesh axis."""
+        if shape[0] == "z":
+            # compressed staging: the resident form IS the packed
+            # stream; the bytes registered are the compressed footprint
+            return self._place_packed_block(frs, shape)
+        # Two staging paths.  Warm (mirrors already resident, one
+        # device): stack on device — no host transfer at all.  Cold:
+        # build the dense [S, rows, W] block on host and ship it as ONE
+        # sharded transfer instead of one upload per fragment.  On a
+        # mesh of several devices the warm path would first build the
+        # whole stack on the default device, where every mirror lives,
+        # and then move all but one device's share off it; the host
+        # block goes to each device directly.  The 4/5 residency
+        # threshold below was chosen against a remote device whose
+        # per-transfer cost no longer applies; it awaits re-derivation
+        # on the chip (ROADMAP.md S9).
+        resident = sum(
+            1 for fr in frs
+            if not fr._device_dirty
+            and fr._mirrors.get(self.stage_device) is not None)
+        if self.multiprocess:
+            # per-process staging: each process supplies only its
+            # addressable shards (device_put would assert the whole
+            # host block equal across processes)
+            return self._place_host_block(frs, shape)
+        if self.n_devices == 1 and 5 * resident >= 4 * len(frs):
+            arrs = [fr.device(self.stage_device) for fr in frs]
+            if all(a.shape == shape for a in arrs):
+                return self._pad_and_place(arrs, shape, len(frs))
+            # a concurrent write grew a fragment's capacity after the
+            # shape signature was read — the host path slices to the
+            # signature's shape
+        return self._place_host_block(frs, shape)
 
     def _stack_token(self, keys, holder, index, shards):
         """(per-shard fragment rows, device-generation token, ingest
@@ -644,6 +758,13 @@ class MeshExecutor:
             0 if fr is None else fr.ingest_epoch
             for row in frags for fr in row)
         return frags, token, epochs
+
+    def stack_block_bytes(self) -> int:
+        """Bytes of the stacked blocks held now (/debug/vars
+        stackCache.blockBytes): each block once, as it is resident and
+        registered once, however many entries read it."""
+        with self._sc_lock:
+            return sum(b.nbytes for b in self._blocks.values())
 
     def _is_resident(self, keys, holder, index, shards) -> bool:
         """Whether this (keys, shards) stack is cached AND current — the
@@ -702,14 +823,30 @@ class MeshExecutor:
                             vals.append(dv)
                     if not members:
                         continue
+                    at = tuple(new_epochs[row_of[shard] * nk + ki]
+                               for shard in shard_list)
+                    with self._sc_lock:
+                        blk = self._blocks.get(
+                            (ckey[0], keys[ki], tuple(shard_list)))
+                        arrays, b_at = (blk.arrays, blk.epochs) \
+                            if blk is not None else (None, None)
+                    if blk is not None and arrays is not placed[ki] \
+                            and b_at == at:
+                        # another entry overlaid the shared block
+                        # already: take its array
+                        placed[ki] = arrays
+                        continue
                     placed[ki] = self._overlay_stack(
                         placed[ki], np.concatenate(members),
                         np.concatenate(idxs), np.concatenate(vals))
+                    if blk is not None and blk in cur[4]:
+                        with self._sc_lock:
+                            blk.arrays, blk.epochs = placed[ki], at
             with self._sc_lock:
                 cur2 = self._stack_cache.get(ckey)
                 if cur2 is not None and cur2[0] == token:
-                    self._stack_cache[ckey] = (token, out, new_epochs,
-                                               epoch)
+                    self._stack_cache[ckey] = \
+                        (token, out, new_epochs, epoch) + cur2[4:]
 
     def _overlay_stack(self, stacked, member, flat_idx, vals):
         """One scatter-OR launch: ``stacked`` is the mesh-sharded
@@ -747,12 +884,13 @@ class MeshExecutor:
             return fn(stacked, m, r, w, v)
 
     @staticmethod
-    def _cleanup_budget(budget, exec_id, stack_cache):
+    def _cleanup_budget(budget, stack_cache, blocks):
         """Drop this executor's budget accounting (runs on close() or GC —
         without it, accounting-only budgets would grow phantom resident
         bytes for every discarded executor)."""
-        for ck in list(stack_cache):
-            budget.unregister(("stack", exec_id, ck))
+        for blk in list(blocks.values()):
+            budget.unregister(blk.skey)
+        blocks.clear()
         stack_cache.clear()
 
     def close(self):
@@ -774,15 +912,22 @@ class MeshExecutor:
             return self._uploader
 
     def _bucket(self, n: int) -> int:
-        """Stacked shard counts round UP to n_devices * 2^k: executables
-        are keyed by shape, and a one-shard difference between two shard
-        sets (resize, Options(shards=...), working-set rotation) must not
-        pay a multi-second XLA recompile.  Padding shards are zero blocks
-        — they contribute nothing to counts/reductions."""
-        b = self.n_devices
-        while b < n:
-            b *= 2
-        return b
+        """Stacked shard counts round UP to a bucket: executables are
+        keyed by shape, and a one-shard difference between two shard
+        sets (resize, Options(shards=...), working-set rotation) must
+        not pay a multi-second XLA recompile.  A device's share of the
+        shards goes up to a power of two as far as 8, and beyond to a
+        multiple of an eighth of the power of two below it, at least 8
+        (33 -> 40, 58 -> 64, 172 -> 176, 256 -> 256, 954 -> 960): the
+        steps keep growing with the count, and the padding stays under
+        an eighth of it where doubling cost up to half.  Padding shards
+        are zero blocks — they contribute nothing to counts/reductions,
+        but every kernel reads them and every temporary holds them."""
+        per_dev = -(-max(n, 1) // self.n_devices)
+        if per_dev <= 8:
+            return self.n_devices * (1 << (per_dev - 1).bit_length())
+        step = max(8, (1 << ((per_dev - 1).bit_length() - 1)) // 8)
+        return self.n_devices * (-(-per_dev // step) * step)
 
     def stacked_per_device(self, n_shards: int) -> int:
         """Per-device rows of a stacked dispatch after _bucket padding —
@@ -942,11 +1087,9 @@ class MeshExecutor:
         drains all work against staged data before rotating the budget,
         and iteration prefetches slice k+1 while slice k dispatches."""
         shards = list(shards)
-        # bytes are estimated per key LIST occurrence, not the union:
-        # each list stages its own stacked block, so a key shared by two
-        # lists occupies device memory twice — union-sizing would let
-        # the pinned current+prefetched pair exceed the budget
-        all_keys: list = [k for kl in key_lists for k in kl]
+        # bytes are estimated over the union of the lists' keys: a
+        # (field, view) that two lists stack is one resident block
+        all_keys = list(dict.fromkeys(k for kl in key_lists for k in kl))
         limit = self._budget.limit_bytes
         slices = [shards]
         if limit and not self.multiprocess and \
@@ -990,10 +1133,14 @@ class MeshExecutor:
                         [sl for sl, r in zip(slices, res) if not r]
         return _ShardSchedule(self, holder, index, key_lists, slices)
 
-    def _pin_stack(self, keys, index, shard_slice) -> tuple | None:
-        skey = ("stack", id(self),
+    def _pin_stack(self, keys, index, shard_slice) -> list:
+        """Pin the blocks of this (keys, shard slice) stack; returns
+        the budget keys pinned, for the caller to unpin."""
+        with self._sc_lock:
+            cached = self._stack_cache.get(
                 (index, tuple(keys), tuple(shard_slice)))
-        return skey if self._budget.pin(skey) else None
+        return [b.skey for b in (cached[4] if cached else ())
+                if self._budget.pin(b.skey)]
 
     def _stream_groups(self, keys, holder, index, shards):
         """``_placed_groups`` over the streaming schedule: the default
@@ -1668,9 +1815,8 @@ class _ShardSchedule:
             for kl in self.key_lists:
                 self.mexec._placed_groups(kl, self.holder, self.index,
                                           shard_slice)
-                skey = self.mexec._pin_stack(kl, self.index, shard_slice)
-                if skey is not None:
-                    pinned.append(skey)
+                pinned += self.mexec._pin_stack(kl, self.index,
+                                                shard_slice)
         except BaseException:
             for k in pinned:
                 self.mexec._budget.unpin(k)

@@ -26,7 +26,14 @@ the device-local block, and reduces IN PROGRAM — local sums +
 the legacy path assembled host-side.  (Manual partitioning on purpose:
 auto-partitioned jit replicates the vmapped row-gathers — a 4096-wide
 Count batch allocated a 279 GB gather temp — while shard_map pins the
-per-device shapes the ``BATCH_TEMP_BYTES`` chunk budget assumes.)  One
+per-device shapes the batch-temp bound is held against.)  Where the
+compiler's own figure for a program's temporaries
+(``memory_analysis().temp_size_in_bytes`` of the executable about to
+run, read once per compiled shape) would pass the bound over all of a
+device's stacked shards, the body walks them in blocks (``lax.map``
+over a shard block, the per-shard parts laid end to end as the whole
+pass lays them), so the temporaries are one block's however many shards
+are stacked.  One
 launch per request — the launch ledger (utils/devobs.py) records it as
 kind ``wholequery``.
 
@@ -35,8 +42,8 @@ the executor reroutes to the legacy per-stage dispatch, counting
 ``wholequery.fallback`` (docs/whole-query.md has the fallback matrix):
 multi-process meshes (per-process staging must stay deterministic),
 over-budget working sets (the streaming slice planner owns those),
-params batches beyond one dispatch chunk, and GroupBy grids beyond one
-combo chunk.
+params batches whose temporaries do not fit the bound over one stacked
+shard, and GroupBy grids beyond one combo chunk.
 
 Batching (docs/batching.md): concurrent requests whose programs share a
 shape fuse in the dispatch batcher by concatenating each node's params
@@ -149,6 +156,11 @@ def _mat_rows(mat) -> int:
     return mat[0].shape[0] if isinstance(mat, tuple) else mat.shape[0]
 
 
+def _divisor_at_most(n: int, m: int) -> int:
+    """The largest divisor of ``n`` that is at most ``m`` (m >= 1)."""
+    return next(d for d in range(min(n, m), 0, -1) if n % d == 0)
+
+
 class WholeOut:
     """One whole-query launch's unfetched device outputs.
 
@@ -197,13 +209,43 @@ class _InstrumentedWhole:
     detection), and every invocation lands in the launch ledger with
     the call site's actual-vs-padded shard and batch rows."""
 
-    __slots__ = ("fn", "sig", "detail", "out_index")
+    __slots__ = ("fn", "sig", "detail", "out_index", "_temps")
 
     def __init__(self, fn, key, out_index):
         self.fn = fn
         self.sig = _devobs.sig_of(key)
         self.detail = repr(key[1])[:120]
         self.out_index = out_index
+        # device-local stacked shards -> the compiler's temp bytes
+        self._temps: dict = {}
+
+    def temp_bytes(self, local, mats, flat) -> tuple[int, bool]:
+        """The compiler's own figure for this program's temporaries on
+        a device, at ``local`` stacked shards a group:
+        ``memory_analysis().temp_size_in_bytes`` of the executable a
+        call with these arguments runs.  jax keeps one trace, lowering
+        and executable for ``lower().compile()`` and for the call, so
+        the figure costs the compile the first launch of the shape
+        would have paid and that launch then compiles nothing.  Returns
+        (bytes, whether this call traced); a traced one is folded into
+        the compile registry here."""
+        temp = self._temps.get(local)
+        if temp is not None:
+            return temp, False
+        reg = _devobs.COMPILES
+        reg.begin_call()
+        t0 = _time.perf_counter()
+        analysis = self.fn.lower(mats, *flat).compile().memory_analysis()
+        traced = reg.traced()
+        if traced:
+            leaves = jax.tree_util.tree_leaves(mats)
+            reg.note_call(self.sig, "wholequery",
+                          _time.perf_counter() - t0,
+                          _devobs.fingerprint(list(leaves) + list(flat)),
+                          detail=self.detail)
+        temp = self._temps[local] = int(
+            getattr(analysis, "temp_size_in_bytes", 0) or 0)
+        return temp, traced
 
     def __call__(self, mats, *flat, _launch_meta=None):
         m = _launch_meta or {}
@@ -218,13 +260,18 @@ class _InstrumentedWhole:
         # dispatch.enqueue, as in mesh_exec._InstrumentedExec
         with layer_span("dispatch.enqueue", kind="wholequery",
                         sig=self.sig, rows=rows, rows_padded=rows_padded,
-                        tickets=tickets) as span:
+                        tickets=tickets, shards=m.get("shards", 0),
+                        shards_padded=m.get("shards_padded", 0),
+                        temp_bytes=m.get("temp_bytes", 0)) as span:
             t0 = _time.perf_counter()
             out = self.fn(mats, *flat)
             dt = _time.perf_counter() - t0
-            compiled = reg.traced()
+            traced = reg.traced()
+            # the launch's program was built when its temporaries were
+            # read (``temp_bytes``), just before this call
+            compiled = traced or m.get("compiled", False)
             span.tag(compiled=compiled)
-        if compiled:  # fingerprinting is only paid on compiles
+        if traced:  # fingerprinting is only paid on compiles
             leaves = jax.tree_util.tree_leaves(mats)
             reg.note_call(self.sig, "wholequery", dt,
                           _devobs.fingerprint(list(leaves) + list(flat)),
@@ -323,6 +370,23 @@ def _node_shard(node, mat, frags):
     return jax.vmap(one_combo)(rids)                       # [C, rows]
 
 
+def _over_shards(per_shard, arrs, block: int | None):
+    """``per_shard`` over the leading (device-local shard) axis of
+    ``arrs``: one vmapped pass over whatever that axis is where
+    ``block`` is None (a bucket change re-traces it), else a ``lax.map``
+    over blocks of ``block`` shards, a divisor of the axis, each a
+    vmapped pass, their outputs laid end to end — the same
+    [S_local, ...] parts with the temporaries of one block."""
+    s_local = arrs[0].shape[0]
+    if block is None or block == s_local:
+        return jax.vmap(per_shard)(*arrs)
+    blocked = tuple(a.reshape((s_local // block, block) + a.shape[1:])
+                    for a in arrs)
+    outs = jax.lax.map(lambda blk: jax.vmap(per_shard)(*blk), blocked)
+    return jax.tree_util.tree_map(
+        lambda o: o.reshape((s_local,) + o.shape[2:]), outs)
+
+
 class WholeQueryRunner:
     """Compiles + launches whole-query programs over a MeshExecutor's
     mesh, reusing its stacked-input staging (stack cache, device
@@ -331,8 +395,23 @@ class WholeQueryRunner:
 
     def __init__(self, mesh):
         self.mesh = mesh
+        # (program repr, index) -> what a batch row of the program's
+        # last launch cost, for the batcher's packer (``row_temp_bytes``)
+        self._row_temp: dict = {}
 
     # -- shape probes ------------------------------------------------------
+
+    ROW_TEMP_MAX = 1024     # programs the packer's figures are kept for
+
+    def row_temp_bytes(self, program_repr: str, index: str) -> int:
+        """Temp bytes one batch row of the program cost at its last
+        launch, by the compiler's figure, had the device's shards been
+        walked one at a time (the fewest a launch can take at once): the
+        figure over the padded batch rows and the shards taken at once.
+        0 before its first launch (a first pack fuses unweighed: its
+        launch still sizes its blocks, or sends every ticket to the
+        chunked path)."""
+        return self._row_temp.get((program_repr, index), 0)
 
     def program_keys(self, program):
         return program_keys(program, self.mesh)
@@ -436,13 +515,18 @@ class WholeQueryRunner:
                tuple(jax.tree_util.tree_map(lambda a: a.shape,
                                             pad_mats)),
                mesh._exec_seq)
-        with mesh._lock:
-            fn = mesh._cache.get(key)
-            if fn is None:
-                fn = self._compile(key, program, live, sched, pad_mats)
-                mesh._cache[key] = fn
-
         flat_all = [a for g in live for a in g[2]]
+        local = tuple(b // mesh.n_devices for b in buckets)
+        fn, blocks, temp_bytes, fresh = self._fit(
+            key, local, program, live, sched, pad_mats, flat_all)
+        rows_padded = sum(_mat_rows(m) for m in pad_mats)
+        if len(self._row_temp) >= self.ROW_TEMP_MAX:
+            self._row_temp.clear()      # the packer fuses unweighed once
+        self._row_temp[key[1], index] = temp_bytes // (
+            sum(blocks or local) * max(rows_padded, 1))
+        if blocks is not None:
+            mesh.temp_splits += 1
+
         from ..ops import kernels as _kernels
         decode_bytes = sum(
             bucket * sum(s[1] * SHARD_WORDS * 4
@@ -462,10 +546,12 @@ class WholeQueryRunner:
             "shards": sum(len(g[0]) for g in live),
             "shards_padded": sum(buckets),
             "rows": sum(actual_b),
-            "rows_padded": sum(_mat_rows(m) for m in pad_mats),
+            "rows_padded": rows_padded,
             "decode_bytes": decode_bytes,
             "kernel_launches": kernel_launches,
             "kernel_tiles": kernel_tiles,
+            "temp_bytes": temp_bytes,
+            "compiled": fresh,
         }
         # the params ride with the launch: jit places the host matrices
         # replicated (the program's in_specs say P()) as part of the
@@ -477,7 +563,50 @@ class WholeQueryRunner:
         # thread-local protocol), so the flag read here is exactly
         # whether THIS launch compiled — even when run() executes on the
         # batcher's dispatcher thread for a fused launch
-        return WholeOut(parts, meta, fn.sig, _devobs.COMPILES.traced())
+        return WholeOut(parts, meta, fn.sig,
+                        fresh or _devobs.COMPILES.traced())
+
+    def _fit(self, key, local, program, live, sched, pad_mats, flat):
+        """The executable this launch runs: the program over each
+        group's whole share of the device's shards where the compiler's
+        figure for its temporaries (``_InstrumentedWhole.temp_bytes``)
+        fits the batch-temp bound, else over blocks of at most half the
+        largest group's shards, a quarter, ... down to one (each group
+        its largest divisor under the rung: every block has one shape),
+        the first rung the figure fits at.  The ladder does not move
+        with the bound, so no more than log2(shards) programs exist per
+        shape; the whole figure only says where to start on it.  A
+        blocked program's key carries ``local`` and its blocks (it
+        cannot be re-traced at another bucket); a whole one's is what
+        it always was.  Returns (fn, blocks or None, temp bytes, whether
+        a program was built); raises ``batch-chunks`` where not even
+        one shard at a time fits (the chunked path cuts the batch
+        axis)."""
+        from ..executor.executor import batch_temp_bound
+        mesh, bound = self.mesh, batch_temp_bound()
+        blocks, fresh, top = None, False, max(local)
+        while True:
+            ckey = key if blocks is None else \
+                key + (("blocks", local, blocks),)
+            with mesh._lock:
+                fn = mesh._cache.get(ckey)
+                if fn is None:
+                    fn = mesh._cache[ckey] = self._compile(
+                        ckey, program, live, sched, pad_mats, blocks)
+            temp, traced = fn.temp_bytes(local, pad_mats, flat)
+            fresh |= traced
+            if temp <= bound:
+                return fn, blocks, temp, fresh
+            rung = top // 2 if blocks is None else max(blocks) // 2
+            if blocks is None:
+                # temporaries grow about with the shards taken at once
+                while rung > 1 and temp * rung > bound * top:
+                    rung //= 2
+            if rung < 1:
+                raise WholeQueryUnsupported(
+                    "batch-chunks",
+                    f"B={max(_mat_rows(m) for m in pad_mats)}")
+            blocks = tuple(_divisor_at_most(s, rung) for s in local)
 
     def _node_meta(self, program, actual_b, live, sched, empty_shards):
         meta = []
@@ -493,11 +622,14 @@ class WholeQueryRunner:
 
     # -- compilation -------------------------------------------------------
 
-    def _compile(self, key, program, live, sched, pad_mats):
+    def _compile(self, key, program, live, sched, pad_mats, blocks):
         """Build + jit the program body.  Everything consulted inside
         the traced body is frozen static structure (program nodes,
-        layouts, participation schedule, combine shapes) — the body
-        takes only (mats, *stacked arrays)."""
+        layouts, participation schedule, combine shapes, shard blocks)
+        — the body takes only (mats, *stacked arrays).  ``blocks`` is
+        None where every group's per-shard pass takes the device's whole
+        share of it, else ``blocks[gi]`` is the divisor of that share
+        group gi is walked in."""
         groups_static = tuple((g[3], len(g[2])) for g in live)
         sig_maps = tuple(g[1] for g in live)
 
@@ -541,7 +673,8 @@ class WholeQueryRunner:
                         _node_shard(program[ni], mats[ni], frags)
                         for ni in _nis)
 
-                outs_g = jax.vmap(per_shard)(*arrs)
+                outs_g = _over_shards(
+                    per_shard, arrs, blocks[gi] if blocks else None)
                 for slot, ni in enumerate(node_ids):
                     per_group_raw[gi][ni] = outs_g[slot]
 

@@ -158,6 +158,15 @@ def build_debug_vars(api: API, server=None) -> dict:
             "executables": len(ex.mesh_exec._cache),
             "fastHits": ex.mesh_exec.stack_fast_hits,
             "walks": ex.mesh_exec.stack_walks,
+            "blockBytes": ex.mesh_exec.stack_block_bytes(),
+        }
+        # the batch-temp bound (docs/batching.md): what one launch's
+        # temporaries may cost now, and how often it cut a
+        # pack, chunked a batch or sent a launch to shard blocks
+        from ..executor.executor import batch_temp_bound
+        out["batchTemp"] = {
+            "boundBytes": batch_temp_bound(),
+            "splits": ex.mesh_exec.temp_splits,
         }
     # cross-query dynamic batching (docs/batching.md): fused/single
     # launch counters, the batch-size histogram, and the queue-wait

@@ -299,12 +299,12 @@ class Config:
     # <data-dir>/flightrec, LRU-pruned by file mtime (the compile-cache
     # discipline).  0 disables the recorder (alerts still fire).
     flight_recorder_mb: int = 64
-    # Per-launch batch-temp workspace ceiling (MB) for fused/batched
-    # [B, rows, W] device temps (row_counts/TopN batches): the batch
-    # axis chunks when a launch would exceed it (counted
-    # query.batch_temp_splits), and the cross-query batcher stops
-    # fusing past it.  The decode-workspace-mb pattern, on the batch
-    # axis.
+    # Ceiling (MB) on the batch-temp bound: one launch's
+    # program temporaries may cost what the device has left, and never
+    # more than this (executor.batch_temp_bound; docs/batching.md).
+    # Under the bound a whole-query program walks its shards in
+    # blocks, a batch axis chunks (query.batch_temp_splits) and the
+    # cross-query batcher cuts a pack (dispatch.fused_temp_split).
     batch_temp_mb: int = 4096
     # -- warm start (docs/warmup.md) ---------------------------------------
     # Directory for jax's persistent XLA compilation cache, so a
@@ -594,9 +594,8 @@ class Server:
         # change rebuilds stacks/executables rather than retracing
         from ..ops import kernels as _kernels
         _kernels.CONTAINER_KERNELS = str(self.config.container_kernels)
-        # batch-temp workspace (docs/observability.md satellite of the
-        # decode-workspace pattern): bounds fused/batched [B, rows, W]
-        # device temps; process-wide, most recent Server wins
+        # the batch-temp bound's ceiling (docs/batching.md);
+        # process-wide, most recent Server wins
         from ..executor import executor as _executor_mod
         _executor_mod.BATCH_TEMP_BYTES = \
             max(self.config.batch_temp_mb, 1) << 20

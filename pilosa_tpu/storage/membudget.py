@@ -248,10 +248,11 @@ class DeviceBudget:
             to_evict = self._evict_lru_locked(0)
         self._run_evictions(to_evict)
 
-    def touch(self, key: tuple):
+    def touch(self, *keys: tuple):
         with self._lock:
-            if key in self._entries:
-                self._entries.move_to_end(key)
+            for key in keys:
+                if key in self._entries:
+                    self._entries.move_to_end(key)
 
     def pin(self, key: tuple) -> bool:
         """Mark ``key`` in use by an in-flight plan or prefetch: eviction

@@ -293,6 +293,10 @@ class LaunchLedger:
         self.launches_total = 0
         self.rows_actual_total = 0
         self.rows_padded_total = 0
+        # stacked shards every launch ran over, and how many of them
+        # were the bucket's zero padding (MeshExecutor._bucket)
+        self.shards_stacked_total = 0
+        self.shards_padded_total = 0
         self.decode_peak_bytes = 0   # high-watermark of per-launch decode
         self.decode_bytes_total = 0
         # Pallas container-kernel accounting (ops/kernels.py): launches
@@ -355,6 +359,8 @@ class LaunchLedger:
             self.launches_total += 1
             self.rows_actual_total += actual
             self.rows_padded_total += padded
+            self.shards_stacked_total += max(shards_padded, shards, 0)
+            self.shards_padded_total += max(shards_padded - shards, 0)
             self.decode_bytes_total += decode_bytes
             self.decode_peak_bytes = max(self.decode_peak_bytes,
                                          decode_bytes)
@@ -398,6 +404,8 @@ class LaunchLedger:
                 "rowsPadded": self.rows_padded_total,
                 "paddingWasteRatio": round(
                     self.rows_padded_total / total, 4) if total else 0.0,
+                "shardsStacked": self.shards_stacked_total,
+                "shardsPadded": self.shards_padded_total,
                 "decodePeakBytes": self.decode_peak_bytes,
                 "decodeBytesTotal": self.decode_bytes_total,
                 "kernelLaunches": self.kernel_launches_total,

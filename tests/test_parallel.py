@@ -150,20 +150,23 @@ def test_stacks_register_with_device_budget(loaded):
     sc = me.mesh_exec._stack_cache
     assert len(sc) == 1
     ckey = next(iter(sc))
-    key = ("stack", id(me.mesh_exec), ckey)
+    # the unit registered is the (field, view)'s block the entry reads
+    (blk,) = sc[ckey][4]
+    key = blk.skey
     assert key in DEFAULT_BUDGET._entries
     nbytes = DEFAULT_BUDGET._entries[key][0]
-    assert nbytes > 0
-    # budget eviction drops the stack-cache entry
+    assert nbytes == blk.nbytes > 0
+    assert me.mesh_exec.stack_block_bytes() == nbytes
+    # budget eviction drops the block and the stack-cache entry over it
     DEFAULT_BUDGET._entries[key][1]()
-    assert ckey not in sc
+    assert ckey not in sc and not me.mesh_exec._blocks
     DEFAULT_BUDGET.unregister(key)
     # close() unregisters whatever remains
     me.execute("i", "Count(Row(f=1))")
-    assert ("stack", id(me.mesh_exec), ckey) in DEFAULT_BUDGET._entries
-    mid = id(me.mesh_exec)
+    (blk,) = sc[ckey][4]
+    assert blk.skey != key and blk.skey in DEFAULT_BUDGET._entries
     me.close()
-    assert ("stack", mid, ckey) not in DEFAULT_BUDGET._entries
+    assert blk.skey not in DEFAULT_BUDGET._entries
 
 
 def test_server_config_sets_device_budget(tmp_path):

@@ -183,7 +183,6 @@ def test_chunked_batch_dispatch(holder, classic, monkeypatch):
     # 2 shards over the 8-device test mesh (1 stacked shard per device),
     # chunk = budget / (2*1*SHARD_WORDS*4) = 16 rows per dispatch
     monkeypatch.setattr(exmod, "BATCH_TEMP_BYTES", 2 * 2 * 32768 * 4 * 8)
-    monkeypatch.setattr(exmod, "BATCH_CHUNK_MIN", 1)
 
     rng = np.random.default_rng(11)
     pairs = [(int(a), int(b))

@@ -536,18 +536,167 @@ def test_stack_fast_hit_share_reads_the_two_counters():
     assert vars_ratio.read(spec, ctx) is None
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # additions come last and the sixteen accepted metrics keep their
-    # places (benchmark/tests/test_layer_metrics_spans.py pins the list
-    # to those sixteen and may not be edited by the PR that adds one)
-    assert [m["name"] for m in bench["per_layer"][:-1]] == [
+    # additions come last and the sixteen metrics accepted before this
+    # one keep their places, as does this one after them (a later PR
+    # appends; benchmark/tests/test_layer_metrics_spans.py pins the
+    # list and may not be edited by the PR that adds one)
+    assert [m["name"] for m in bench["per_layer"][:16]] == [
         "handler_ms_mean", "prepared_hit_share", "wq_fallback_share",
         "launches_per_query", "compiles_in_window",
         "upload_bytes_per_query", "kernels_roofline", "device_idle_share",
         "handler_self_ms_mean", "plan_ms_mean", "ticket_wait_ms_mean",
         "dispatcher_ms_per_query", "place_ms_per_query",
         "enqueue_ms_per_query", "scatter_ms_per_query", "fetch_ms_mean"]
-    entry_ = bench["per_layer"][-1]
+    entry_ = bench["per_layer"][16]
     assert entry_ == {
         "name": "stack_fast_hit_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "stack and place",
         "moves": "qps", "workloads": [w["name"] for w in bench["workloads"]]}
+
+
+# -- a (field, view)'s rows are resident once -----------------------------------
+
+def test_programs_share_a_fields_stacked_block():
+    """Two key lists over one field stack it once (the second entry
+    takes the first's block), ``stackCache.blockBytes`` counts it once,
+    and an ingest flush journaled after the block was stacked reaches
+    both entries — the one that overlays it and the one that finds the
+    block already overlaid."""
+    from pilosa_tpu.core import SHARD_WIDTH
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.ingest import GroupCommitter
+    from pilosa_tpu.storage import Holder
+    h = Holder(None)
+    idx = h.create_index("s")
+    idx.create_field("f")
+    idx.create_field("g")
+    rng = np.random.default_rng(5)
+    cols = rng.integers(0, 3 * SHARD_WIDTH, 4000)
+    rows_f, rows_g = rng.integers(0, 6, 4000), rng.integers(0, 3, 4000)
+    h.index("s").field("f").import_bits(rows_f, cols)
+    h.index("s").field("g").import_bits(rows_g, cols)
+    f1, g1 = set(cols[rows_f == 1].tolist()), set(cols[rows_g == 1].tolist())
+    queries = ("Count(Row(f=1))", "Count(Intersect(Row(g=1), Row(f=1)))")
+    ex = Executor(h, use_mesh=True)
+    com = GroupCommitter(h, flush_ms=0)
+    mesh = ex.mesh_exec
+    shards = [0, 1, 2]
+    try:
+        one = mesh._placed_groups([("f", "standard")], h, "s", shards)
+        alone = mesh.stack_block_bytes()
+        two = mesh._placed_groups([("g", "standard"), ("f", "standard")],
+                                  h, "s", shards)
+        assert two[0][1][1] is one[0][1][0]           # f: the same block
+        assert mesh.stack_block_bytes() == alone + two[0][1][0].nbytes
+        assert [ex.execute("s", q)[0] for q in queries] == \
+            [len(f1), len(g1 & f1)]
+        # new columns in row 1 of f and g, journaled, not re-staged
+        fresh = set((np.arange(7) * 1000 + 17).tolist())
+        for field in ("f", "g"):
+            seq = com.submit("s", field, rows=np.ones(7, dtype=np.int64),
+                             cols=np.array(sorted(fresh)))
+            com.wait_flushed(seq)
+        assert sum(fr.delta_bytes()
+                   for *_x, fr in h.iter_fragments("s")) > 0
+        assert [ex.execute("s", q)[0] for q in queries] == \
+            [len(f1 | fresh), len((g1 | fresh) & (f1 | fresh))]
+        one = mesh._placed_groups([("f", "standard")], h, "s", shards)
+        two = mesh._placed_groups([("g", "standard"), ("f", "standard")],
+                                  h, "s", shards)
+        assert two[0][1][1] is one[0][1][0]           # still one block
+        assert [ex.execute("s", q)[0] for q in queries] == \
+            [len(f1 | fresh), len((g1 | fresh) & (f1 | fresh))]
+    finally:
+        ex.close()
+
+
+@pytest.fixture
+def two_fields():
+    """Index ``s`` over three shards with set fields f and g, one
+    executor, and f's and g's budget keys read off its blocks."""
+    from pilosa_tpu.core import SHARD_WIDTH
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.storage import Holder
+    h = Holder(None)
+    idx = h.create_index("s")
+    rng = np.random.default_rng(6)
+    cols = rng.integers(0, 3 * SHARD_WIDTH, 3000)
+    for name, n_rows in (("f", 6), ("g", 3)):
+        idx.create_field(name).import_bits(
+            rng.integers(0, n_rows, 3000), cols)
+    ex = Executor(h, use_mesh=True)
+    yield h, ex, ex.mesh_exec
+    ex.close()
+
+
+F, G = ("f", "standard"), ("g", "standard")
+KEY_LISTS = {"f": [F], "gf": [G, F], "g": [G]}
+
+
+def _registered(mesh) -> int:
+    """What the device budget counts for this executor's blocks."""
+    from pilosa_tpu.storage.membudget import DEFAULT_BUDGET
+    return sum(e[0] for k, e in DEFAULT_BUDGET._entries.items()
+               if k[:2] == ("block", id(mesh)))
+
+
+@pytest.mark.parametrize("leave", ["trim", "evict", "write", "close"])
+def test_the_budget_counts_each_block_once(two_fields, leave):
+    """The block is the budget's unit: registered once however many
+    entries read it, counted while ANY entry reads it, and gone from
+    the budget exactly when no entry does — after the entry that
+    placed it is trimmed, after the budget evicts it (every reader
+    goes), after a write displaces it, after close()."""
+    from pilosa_tpu.storage.membudget import DEFAULT_BUDGET
+    h, ex, mesh = two_fields
+    shards = [0, 1, 2]
+    for keys in KEY_LISTS.values():
+        mesh._placed_groups(keys, h, "s", shards)
+    blocks = {b.bkey[1]: b for b in mesh._blocks.values()}
+    assert set(blocks) == {F, G} and len(mesh._stack_cache) == 3
+    both = blocks[F].nbytes + blocks[G].nbytes
+    assert _registered(mesh) == mesh.stack_block_bytes() == both
+    if leave == "trim":
+        # the entries that placed f and g go; a new list reads both
+        mesh.stack_cache_max = 1
+        mesh._placed_groups([F, G], h, "s", shards)
+        assert [ck[1] for ck in mesh._stack_cache] == [(F, G)]
+        assert _registered(mesh) == mesh.stack_block_bytes() == both
+        # ... and a list that reads g alone drops f with its last reader
+        mesh._placed_groups(KEY_LISTS["g"], h, "s", shards)
+        assert set(mesh._blocks) == {blocks[G].bkey}
+        assert _registered(mesh) == blocks[G].nbytes
+    elif leave == "evict":
+        DEFAULT_BUDGET._entries[blocks[F].skey][1]()   # as eviction does
+        DEFAULT_BUDGET.unregister(blocks[F].skey)
+        assert [ck[1] for ck in mesh._stack_cache] == [(G,)]
+        assert _registered(mesh) == mesh.stack_block_bytes() == \
+            blocks[G].nbytes
+    elif leave == "write":
+        h.index("s").field("f").set_bit(1, 5)
+        out = mesh._placed_groups(KEY_LISTS["f"], h, "s", shards)
+        now = {b.bkey[1]: b for b in mesh._blocks.values()}
+        assert now[F] is not blocks[F] and now[G] is blocks[G]
+        assert now[F].arrays is out[0][1][0]
+        assert blocks[F].skey not in DEFAULT_BUDGET._entries
+        # [g, f] read the displaced block: gone, rebuilt from the new
+        assert [ck[1] for ck in mesh._stack_cache] == [(G,), (F,)]
+        assert _registered(mesh) == mesh.stack_block_bytes() == both
+    else:
+        ex.close()
+        assert _registered(mesh) == 0 and not mesh._blocks
+
+
+def test_a_slice_pins_its_blocks(two_fields):
+    """``_pin_stack`` pins every block of the slice's stack and names
+    them for the unpin; a stack that is not cached pins nothing."""
+    from pilosa_tpu.storage.membudget import DEFAULT_BUDGET
+    h, ex, mesh = two_fields
+    mesh._placed_groups(KEY_LISTS["gf"], h, "s", [0, 1, 2])
+    pinned = mesh._pin_stack(KEY_LISTS["gf"], "s", [0, 1, 2])
+    assert sorted(pinned) == sorted(b.skey for b in mesh._blocks.values())
+    assert all(DEFAULT_BUDGET._entries[k][2] == 1 for k in pinned)
+    for k in pinned:
+        DEFAULT_BUDGET.unpin(k)
+    assert all(DEFAULT_BUDGET._entries[k][2] == 0 for k in pinned)
+    assert mesh._pin_stack(KEY_LISTS["f"], "s", [0, 1]) == []

@@ -294,6 +294,62 @@ def test_retrace_keeps_results(corpus):
         ex.close()
 
 
+CROSSING = ["Count(Intersect(Row(a=11), Row(a=2)))",
+            "Sum(Row(v > 17), field=v)",
+            "TopN(a, Row(b=1), n=3)"]
+
+
+@pytest.mark.parametrize("blocked", [False, True], ids=["whole", "blocked"])
+@pytest.mark.parametrize("q", CROSSING, ids=["count", "sum", "topn"])
+def test_bucket_steps_with_a_cached_program(corpus, monkeypatch, q, blocked):
+    """A cached whole-query program outlives its shard bucket: on one
+    device 16 stacked shards grow to 24 at the 17th (a step 16 does not
+    divide), shrink back, and pass 8 and 16 again.  Whole, the program
+    re-traces over whatever its arrays hold; walked in shard blocks
+    (the bound forced under the compiler's figure for it), every bucket
+    has its own program and blocks.  Exact against the legacy path at
+    every size, and never a fallback."""
+    import jax
+    from pilosa_tpu.executor import executor as exmod
+    from pilosa_tpu.parallel.mesh_exec import default_mesh
+    legacy = Executor(corpus, use_mesh=True, whole_query=False)
+    ex = Executor(corpus, mesh=default_mesh(jax.devices()[:1]),
+                  whole_query_fallback="error")
+    mesh = ex.mesh_exec
+    try:
+        first = ex.execute("w", q, shards=list(range(16)))[0]
+        assert mesh.stacked_per_device(16) == 16
+        assert mesh.stacked_per_device(17) == 24
+        if blocked:
+            (fn,) = [f for k, f in mesh._cache.items()
+                     if k[0] == "wholequery"]
+            (temp,) = fn._temps.values()
+            monkeypatch.setattr(exmod, "BATCH_TEMP_BYTES", temp // 3)
+        splits = mesh.temp_splits
+        for size in (16, 17, 20, 16, 9, 8, 17):
+            shards = list(range(size))
+            assert _norm(ex.execute("w", q, shards=shards)[0]) == \
+                _norm(legacy.execute("w", q, shards=shards)[0]), size
+        assert _norm(first) == \
+            _norm(legacy.execute("w", q, shards=list(range(16)))[0])
+        keys = [k for k in mesh._cache if k[0] == "wholequery"]
+        walked = {k[5][1:] for k in keys if len(k) > 5}
+        if blocked:
+            assert mesh.temp_splits >= splits + 7
+            # a blocked program is keyed by its buckets (one a shape
+            # group: set field a is ragged), and its blocks divide them
+            assert len({local for local, _ in walked}) >= 2
+            for local, blocks in walked:
+                assert all(n % b == 0 for n, b in zip(local, blocks))
+                assert any(b < n for n, b in zip(local, blocks))
+        else:
+            assert mesh.temp_splits == splits and not walked
+            assert len(keys) == 1
+    finally:
+        ex.close()
+        legacy.close()
+
+
 def test_fused_wholequery_tickets(corpus):
     """Concurrent same-shape requests fuse in the dispatch batcher: the
     batched parameter axis rides ONE compiled program (docs/batching.md
