@@ -8,11 +8,14 @@ shard) triple is a *fragment*.  Inside a fragment a bit is addressed by
 
 Where the reference stores a fragment as a 64-bit roaring bitmap (adaptive
 array/bitmap/run containers, ``roaring/roaring.go:64-69``), this engine stores
-it as a dense ``uint32[n_rows, SHARD_WORDS]`` bitset tensor: TPU VPUs operate
-on 32-bit lanes natively and ``SHARD_WORDS = 32768 = 256*128`` keeps the minor
-dimension a multiple of the 128-wide lane tiling so XLA never pads.
-Container-level sparsity collapses to dense tiles in HBM — the round-trip and
-branching cost of adaptive representations dwarfs the bandwidth saving on TPU.
+it as a dense ``uint32[n_rows, SHARD_WORDS]`` bitset tensor on the host and
+as ``uint32[n_rows, 256, 128]`` on the device: TPU VPUs operate on 32-bit lanes
+natively, and a row whose ``SHARD_WORDS = 32768`` words are a whole
+``WORD_TILE = (256, 128)`` is 32 full (8, 128) vector registers, with the row
+and shard axes untiled major dimensions beside it (ops/bitset.py
+"Representation").  Container-level sparsity collapses to dense tiles in HBM —
+the round-trip and branching cost of adaptive representations dwarfs the
+bandwidth saving on TPU.
 """
 
 from __future__ import annotations
@@ -29,6 +32,16 @@ SHARD_WIDTH = 1 << SHARD_WIDTH_EXP
 WORD_BITS = 32
 WORD_BITS_EXP = 5
 SHARD_WORDS = SHARD_WIDTH // WORD_BITS  # 32768 = 256 * 128
+
+# A row's words as every device array carries them: the trailing two
+# dimensions of a segment u32[256, 128], a fragment mirror u32[R, 256, 128]
+# and a stacked block u32[S, R, 256, 128].  The TPU tiles an array's two
+# minor dimensions (8 sublanes x 128 lanes), so with the words flat the
+# row axis would be the sublane axis: one row an eighth of every tile.
+# Host numpy stays [R, SHARD_WORDS]; the reshape at the device_put /
+# device_get boundary is a view (ops/bitset.py to_tile / from_tile).
+WORD_LANES = 128
+WORD_TILE = (SHARD_WORDS // WORD_LANES, WORD_LANES)
 
 # A roaring "container" covers 2^16 bits (roaring/roaring.go:64); we keep the
 # same granularity for block-level bookkeeping (checksums, sparsity masks).

@@ -415,8 +415,9 @@ def _wq_seg_result(hp, b, groups, empty, attrs):
     segs: dict[int, np.ndarray] = {}
     zero = np.zeros(SHARD_WORDS, dtype=np.uint32)
     for shard_list, arr in zip(groups, hp):
+        flat = bitset.from_tile(arr)        # [S, B, W]: a host view
         for i, shard in enumerate(shard_list):
-            segs[shard] = arr[i, b]
+            segs[shard] = flat[i, b]
     for shard in empty:
         segs[shard] = zero
     return RowResult(segs, attrs=attrs)
@@ -1361,9 +1362,11 @@ class Executor:
         if self.mesh_exec is not None:
             return self.batcher.segments(plan, self.holder, index,
                                          shards)
+        # host [W] words, as the mesh path returns them: the word tile
+        # is flattened after the fetch, never in a program
         return {
-            shard: self.compiler.execute_shard(plan, self.holder, index,
-                                               shard)
+            shard: bitset.from_tile(np.asarray(self.compiler.execute_shard(
+                plan, self.holder, index, shard)))
             for shard in shards
         }
 
@@ -1406,6 +1409,13 @@ class Executor:
         plan = self._resolve(index, c.children[0])
         return self._plan_segments(plan, index, shards)
 
+    @staticmethod
+    def _filter_tile(filters, shard):
+        """A shard's filter segment as the device kernels take it (the
+        host's [W] words viewed as the word tile), or None."""
+        seg = None if filters is None else filters.get(shard)
+        return None if seg is None else bitset.to_tile(np.asarray(seg))
+
     def _filter_plan(self, index: str, c: Call):
         """Resolve the optional filter child to a plan (mesh path fuses it
         into the same shard_map computation instead of materialising
@@ -1438,7 +1448,7 @@ class Executor:
             frag = self.holder.fragment(index, f.name, view, shard)
             if frag is None or frag.n_rows < bsi.OFFSET_ROW + 1:
                 continue
-            filt = None if filters is None else filters.get(shard)
+            filt = self._filter_tile(filters, shard)
             counts = np.asarray(bsi.sum_counts(frag.device(), filt))
             s, cnt = bsi.weighted_sum(counts)
             total += s
@@ -1466,7 +1476,7 @@ class Executor:
             frag = self.holder.fragment(index, f.name, view, shard)
             if frag is None or frag.n_rows < bsi.OFFSET_ROW + 1:
                 continue
-            filt = None if filters is None else filters.get(shard)
+            filt = self._filter_tile(filters, shard)
             bits, neg, cnt = bsi.min_max_bits(frag.device(), filt,
                                               want_max=want_max)
             val, cnt = bsi.reconstruct_min_max(
@@ -1612,10 +1622,10 @@ class Executor:
             if frag is None or frag.n_rows == 0:
                 continue
             dev = frag.device()
-            filt = None if filters is None else filters.get(shard)
+            filt = self._filter_tile(filters, shard)
             if filt is not None:
                 counts_dev = bitset.row_counts(
-                    bitset.intersect(dev, filt[None, :]))
+                    bitset.intersect(dev, filt[None]))
             else:
                 counts_dev = bitset.row_counts(dev)
             counts = acc_counts(counts, np.asarray(counts_dev))
@@ -1671,7 +1681,8 @@ class Executor:
                 if column is not None:
                     col_local = column % SHARD_WIDTH
                     w, bit = bitset.word_bit_np(col_local)
-                    present = np.asarray(dev[:, w]) & bit > 0
+                    present = np.asarray(
+                        dev[(slice(None),) + bitset.word_at(w)]) & bit > 0
                     ids = np.nonzero(present)[0]
                 else:
                     counts = np.asarray(bitset.row_counts(dev))
@@ -1892,7 +1903,7 @@ class Executor:
                     cnts = np.asarray(bitset.row_counts(sel))
                 else:
                     cnts = np.asarray(bitset.row_counts(
-                        bitset.intersect(sel, prefix_seg[None, :])))
+                        bitset.intersect(sel, prefix_seg[None])))
                 for j, r in enumerate(valid):
                     counts_acc[last_pos[r]] += int(cnts[j])
             for j, rid in enumerate(last_ids):

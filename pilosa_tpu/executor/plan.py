@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import SHARD_WORDS, VIEW_STANDARD
+from ..core import VIEW_STANDARD, WORD_TILE
 from ..ops import bitset, bsi
 from ..pql import BETWEEN, Call, Condition, EQ, GT, GTE, LT, LTE, NEQ
 from ..storage.field import FIELD_TYPE_INT, Field
@@ -467,14 +467,16 @@ def plan_inputs(plan) -> list[tuple[str, str]]:
 
 def eval_plan(plan, frags: dict[tuple[str, str], Any], params=None):
     """Trace a plan over fragment tensors.  ``frags`` maps (field, view) to a
-    uint32[n_rows, W] array or None (missing fragment).  Returns uint32[W].
+    tiled uint32[n_rows, 256, 128] array (ops/bitset.py "Representation")
+    or None (missing fragment).  Returns a segment uint32[256, 128]; a row
+    take is an offset on the untiled row axis.
 
     Literal plans trace their constants into the program; slotted plans
     (``parametrize``) read row ids / predicate bits from the traced
     ``params`` vector so the compiled program is value-independent."""
 
     def zero():
-        return jnp.zeros(SHARD_WORDS, dtype=jnp.uint32)
+        return jnp.zeros(WORD_TILE, dtype=jnp.uint32)
 
     def get_row(field, view, row_id):
         frag = frags.get((field, view))
@@ -489,7 +491,7 @@ def eval_plan(plan, frags: dict[tuple[str, str], Any], params=None):
                 jax.lax.dynamic_index_in_dim(
                     frag, jnp.minimum(rid, frag.shape[0] - 1), axis=0,
                     keepdims=False),
-                jnp.zeros(frag.shape[-1], dtype=frag.dtype))
+                jnp.zeros(frag.shape[1:], dtype=frag.dtype))
         if row_id >= frag.shape[0]:
             return None
         return frag[row_id]

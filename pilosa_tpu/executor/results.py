@@ -19,7 +19,7 @@ class RowResult:
 
     def __init__(self, segments: dict[int, Any] | None = None,
                  keys: list[str] | None = None, attrs: dict | None = None):
-        self.segments = segments or {}   # shard -> uint32[W] (jnp or np)
+        self.segments = segments or {}   # shard -> host uint32[W] words
         self.keys = keys or []
         self.attrs = attrs or {}         # row attrs (row.go Row.Attrs)
         # [{"id", "attrs"}] filled by Options(columnAttrs=true); lifted to

@@ -17,7 +17,9 @@ add equals the OR, and (unlike a plain ``.set``) it stays correct when
 masked-out lanes collide on a dummy index, because adding zero commutes
 with everything.  Indices travel as (row, word) int32 pairs, never a
 flattened int64 — jax's default int width would silently truncate a
-``row * 32768 + word`` offset past 2^31 on large fragments.
+``row * 32768 + word`` offset past 2^31 on large fragments.  On the
+device a row's words are a (256, 128) word tile (ops/bitset.py
+"Representation"): the program finds word ``w`` at ``[w // 128, w % 128]``.
 """
 
 from __future__ import annotations
@@ -75,18 +77,20 @@ _JIT_CACHE: dict = {}
 
 def apply_overlay(mirror, flat_idx: np.ndarray, vals: np.ndarray,
                   words: int):
-    """OR deduplicated journal words into a dense [rows, words] device
-    mirror; returns the updated array (the old one stays valid for any
-    in-flight computation that captured it)."""
+    """OR deduplicated journal words into a dense [rows, 256, 128] device
+    mirror of ``words`` words a row; returns the updated array (the old
+    one stays valid for any in-flight computation that captured it)."""
     import jax
+
+    from ..ops.bitset import word_at
 
     row, word, val = pad_overlay(flat_idx, vals, words)
     key = ("mirror", mirror.shape, row.size)
     fn = _JIT_CACHE.get(key)
     if fn is None:
         def body(m, r, w, v):
-            cur = m[r, w]
-            return m.at[r, w].add(v & ~cur)
+            at = (r,) + word_at(w)
+            return m.at[at].add(v & ~m[at])
 
         fn = _JIT_CACHE[key] = jax.jit(body)
     # index/value args stay uncommitted numpy: the computation follows

@@ -4,22 +4,43 @@ union, difference, xor, shift, flip, intersectionCount, Count/CountRange).
 
 Representation
 --------------
-A *segment* is one shard-row of bits as a dense ``uint32[SHARD_WORDS]`` vector
-(little-endian within each word: shard-column ``c`` lives at word ``c >> 5``,
-bit ``c & 31``).  A *fragment tensor* stacks rows: ``uint32[n_rows,
-SHARD_WORDS]``.  All ops here are pure jax functions over those arrays; they
-are shape-polymorphic so one jitted executable serves every fragment with the
-same row count.  The adaptive array/bitmap/run container forms of the
-reference survive, but split across two layers: COMPUTE is always dense —
-the VPU processes 8x128 lanes of uint32 per cycle, and the branchy
+On the host a *segment* is one shard-row of bits as a dense
+``uint32[SHARD_WORDS]`` vector (little-endian within each word:
+shard-column ``c`` lives at word ``c >> 5``, bit ``c & 31``) and a
+*fragment tensor* stacks rows: ``uint32[n_rows, SHARD_WORDS]``.
+
+On the device the word axis is a trailing ``(words // 128, 128)`` *word
+tile* — ``core.WORD_TILE = (256, 128)`` at shard width: a segment is
+``uint32[256, 128]``, a fragment mirror ``uint32[n_rows, 256, 128]``, a
+stacked block ``uint32[S, n_rows, 256, 128]``; word ``w`` sits at
+``[w >> 7, w & 127]``, so the row-major bytes are the host's.  The TPU
+tiles an array's two minor dimensions into (8 sublanes x 128 lanes) vector
+registers.  With the word axis flat the row axis was the sublane axis: one
+row was 512 B of every 4 KiB tile, a row take read eight rows to keep one
+and every pass over a segment filled one sublane of eight (PERF.md §6,
+PR 32).  With the tile, a row is 32 whole registers, ``n_rows`` and ``S``
+are untiled major dimensions, a row take is an offset, and every
+elementwise pass and popcount-reduce runs on full registers.  There is one
+device shape and every kernel below takes it: ``to_tile`` / ``from_tile``
+are the host boundary (a view of host numpy at ``device_put`` and after
+``device_get``, never an op in a program); ``row_counts`` reduces over
+the tile.  Kernels that need the linear word order (``shift``, ``set_bits`` /
+``clear_bits``) say so and flatten at their own edges.  Ops are pure jax
+functions, shape-polymorphic in the row count and in ``words`` (a
+multiple of 128), so one jitted executable serves every fragment with the
+same row count.
+
+The adaptive array/bitmap/run container forms of the reference survive,
+but split across two layers: COMPUTE is always dense — the branchy
 (op x container-type^2) dispatch matrix of the reference would defeat XLA
 fusion — while RESIDENCY may be compressed (ops/containers.py): sparse
 fragments stay HBM-resident as packed array/bitmap/run container streams
 and are decoded to dense tiles on device at op time, inside the same
-executable that runs these kernels.  Decode-at-op-time keeps every op
-below this line a branch-free dense kernel yet lets residency cost
-compressed bytes instead of the 100x dense blowup (docs/memory-budget.md
-"Compressed residency").
+executable that runs these kernels (a 2048-word container is sixteen
+whole sublane-groups, ``(16, 128)``, of its row's tile).  Decode-at-op-time
+keeps every op below this line a branch-free dense kernel yet lets
+residency cost compressed bytes instead of the 100x dense blowup
+(docs/memory-budget.md "Compressed residency").
 
 Host-side packing/unpacking helpers (numpy) live at the bottom; they are the
 import/export boundary, mirroring roaring's serializer role.
@@ -33,9 +54,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import SHARD_WORDS, WORD_BITS, WORD_BITS_EXP
+from ..core import SHARD_WORDS, WORD_BITS, WORD_BITS_EXP, WORD_LANES
 
 _FULL_WORD = np.uint32(0xFFFFFFFF)
+
+
+# ---------------------------------------------------------------------------
+# The word tile (module docstring, "Representation").
+# ---------------------------------------------------------------------------
+
+def tile_shape(words: int = SHARD_WORDS) -> tuple[int, int]:
+    """The trailing device dimensions of a row of ``words`` words."""
+    if words % WORD_LANES:
+        raise ValueError(f"{words} words are no whole number of "
+                         f"{WORD_LANES}-lane rows")
+    return words // WORD_LANES, WORD_LANES
+
+
+def to_tile(x):
+    """[..., W] -> [..., W // 128, 128]: the host side of ``device_put``
+    (a view of a numpy array)."""
+    return x.reshape(x.shape[:-1] + tile_shape(x.shape[-1]))
+
+
+def from_tile(x):
+    """[..., T, 128] -> [..., T * 128]: the host side of ``device_get``
+    (a view of a numpy array), and the edge of the few kernels that need
+    the linear word order."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+def word_at(w):
+    """Word index (host or traced) -> its position in the tile, to follow
+    a row index: ``frag[(r,) + word_at(w)]``."""
+    return w // WORD_LANES, w % WORD_LANES
 
 
 def word_bit_np(cols):
@@ -78,7 +130,8 @@ def xor(a, b):
 
 def union_many(segs):
     """n-way union (roaring/roaring.go:739 unionInPlace).  ``segs`` is a
-    stacked ``uint32[n, W]`` tensor; reduces along axis 0 in one pass."""
+    stacked ``uint32[n, T, 128]`` tensor; reduces along axis 0 in one
+    pass."""
     return jax.lax.reduce(
         segs, np.uint32(0), jax.lax.bitwise_or, dimensions=(0,)
     )
@@ -95,14 +148,16 @@ def popcount_words(a):
 
 
 def count(seg):
-    """Total set bits of a segment (or of each row if given [n, W]: reduces
-    over every axis — use row_counts for per-row)."""
+    """Total set bits of a segment (or of all rows if given [n, T, 128]:
+    reduces over every axis — use row_counts for per-row)."""
     return jnp.sum(popcount_words(seg), dtype=jnp.int32)
 
 
 def row_counts(frag):
-    """Per-row popcount of a fragment tensor uint32[n, W] -> int32[n]."""
-    return jnp.sum(popcount_words(frag), axis=-1, dtype=jnp.int32)
+    """Per-row popcount of a fragment tensor uint32[n, T, 128] ->
+    int32[n]; any leading dimensions stay ([B, n, T, 128] -> [B, n]).
+    Tiles add elementwise and lanes are crossed once a row."""
+    return jnp.sum(popcount_words(frag), axis=(-2, -1), dtype=jnp.int32)
 
 
 def intersection_count(a, b):
@@ -114,15 +169,13 @@ def intersection_count(a, b):
 @jax.jit
 def intersection_counts_matrix(a, b):
     """Pairwise intersection counts between two row sets:
-    uint32[n, W] x uint32[m, W] -> int32[n, m].
+    uint32[n, T, 128] x uint32[m, T, 128] -> int32[n, m].
 
     This is the GroupBy hot loop (executor.go:3058 groupByIterator does it
     pair-at-a-time over roaring containers); batching it into one
     popcount-and-reduce lets the VPU stream both operand sets once per tile.
     """
-    return jnp.sum(
-        popcount_words(a[:, None, :] & b[None, :, :]), axis=-1, dtype=jnp.int32
-    )
+    return row_counts(a[:, None] & b[None])
 
 
 # ---------------------------------------------------------------------------
@@ -130,12 +183,14 @@ def intersection_counts_matrix(a, b):
 # :562 OffsetRange).
 # ---------------------------------------------------------------------------
 
-def _range_mask(start: int, end: int, words: int = SHARD_WORDS):
-    """uint32[words] mask with bits [start, end) set.  start/end are traced or
-    static scalars in [0, words*32]."""
+def _range_mask(start: int, end: int, tile: tuple[int, int]):
+    """uint32[tile] mask with bits [start, end) set.  start/end are traced
+    or static scalars in [0, words*32].  Needs each word's linear index:
+    an iota over the flat words, viewed as the tile."""
     start = jnp.asarray(start, jnp.int32)
     end = jnp.asarray(end, jnp.int32)
-    base = jnp.arange(words, dtype=jnp.int32) * WORD_BITS
+    base = jnp.arange(tile[0] * tile[1], dtype=jnp.int32).reshape(tile) \
+        * WORD_BITS
     lo = jnp.clip(start - base, 0, WORD_BITS)
     hi = jnp.clip(end - base, 0, WORD_BITS)
     # (1<<hi)-1 with hi==32 overflows 32-bit shifts; build from the top:
@@ -152,18 +207,18 @@ def _range_mask(start: int, end: int, words: int = SHARD_WORDS):
 
 def count_range(seg, start, end):
     """Count bits in [start, end) (roaring/roaring.go:436)."""
-    mask = _range_mask(start, end, seg.shape[-1])
+    mask = _range_mask(start, end, seg.shape[-2:])
     return jnp.sum(popcount_words(seg & mask), dtype=jnp.int32)
 
 
 def flip(seg, start, end):
     """Toggle bits in [start, end) (roaring/roaring.go:2982)."""
-    return seg ^ _range_mask(start, end, seg.shape[-1])
+    return seg ^ _range_mask(start, end, seg.shape[-2:])
 
 
 def keep_range(seg, start, end):
     """Zero every bit outside [start, end)."""
-    return seg & _range_mask(start, end, seg.shape[-1])
+    return seg & _range_mask(start, end, seg.shape[-2:])
 
 
 # ---------------------------------------------------------------------------
@@ -173,10 +228,13 @@ def keep_range(seg, start, end):
 # ---------------------------------------------------------------------------
 
 def shift(seg, n: int = 1):
-    """Shift bits toward higher column ids by static ``n`` >= 0."""
+    """Shift bits toward higher column ids by static ``n`` >= 0.  A carry
+    crosses words in their linear order, so the pass runs on the flat
+    words and re-tiles its result."""
     if n == 0:
         return seg
     word_shift, bit_shift = divmod(n, WORD_BITS)
+    seg = from_tile(seg)
     w = seg.shape[-1]
     if word_shift:
         pad = [(0, 0)] * (seg.ndim - 1) + [(word_shift, 0)]
@@ -187,7 +245,7 @@ def shift(seg, n: int = 1):
         pad = [(0, 0)] * (seg.ndim - 1) + [(1, 0)]
         carry = jnp.pad(carry, pad)[..., :w]
         seg = lo | carry
-    return seg
+    return to_tile(seg)
 
 
 # ---------------------------------------------------------------------------
@@ -207,9 +265,10 @@ def _word_updates(frag, rows, cols):
     Returns (targets, masks): int32 flat word indices (invalid/duplicate
     entries pointed one-past-the-end, to be dropped) and the OR-mask per
     entry.  Fragment must have < 2^31 / W rows (always true: W=32768 allows
-    65k rows; real fragments are far smaller).
+    65k rows; real fragments are far smaller).  The flat word index
+    ``row * W + word`` is the tiled fragment's row-major offset too.
     """
-    n_words = frag.shape[-1]
+    n_words = frag.shape[-2] * frag.shape[-1]
     total = frag.size
     if total >= 2**31:
         raise ValueError(
@@ -241,7 +300,7 @@ def _word_updates(frag, rows, cols):
 
 @functools.partial(jax.jit, donate_argnums=0)
 def set_bits(frag, rows, cols):
-    """Set bits (rows[i], cols[i]) in fragment uint32[n, W].  Duplicate
+    """Set bits (rows[i], cols[i]) in fragment uint32[n, T, 128].  Duplicate
     positions and positions sharing a word are handled correctly; padding
     entries may use row == -1 (ignored)."""
     targets, masks = _word_updates(frag, rows, cols)

@@ -1,7 +1,10 @@
 """Bit-sliced-index (BSI) kernels for integer fields.
 
 Mirrors the reference layout exactly (fragment.go:90-93, field.go:1564-1647):
-a BSI fragment tensor is ``uint32[2 + depth, SHARD_WORDS]`` with
+a BSI fragment tensor is ``uint32[2 + depth, SHARD_WORDS]`` on the host and
+``uint32[2 + depth, 256, 128]`` on the device (ops/bitset.py
+"Representation": every kernel here takes the tiled form, so a bit plane
+``bsi_frag[OFFSET_ROW + i]`` is an offset into the mirror, not a gather) with
 
 * row 0 — existence ("not null") bit per column     (bsiExistsBit)
 * row 1 — sign bit (set = negative)                 (bsiSignBit)
@@ -25,7 +28,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from .bitset import popcount_words, word_bit_np
+from .bitset import popcount_words, row_counts, word_bit_np
 
 EXISTS_ROW = 0
 SIGN_ROW = 1
@@ -220,10 +223,8 @@ def sum_counts(bsi_frag, filter_seg=None):
     neg = exists & sign
     depth = depth_of(bsi_frag)
     slices = bsi_frag[OFFSET_ROW:OFFSET_ROW + depth]
-    pos_counts = jnp.sum(popcount_words(slices & pos[None, :]), axis=-1,
-                         dtype=jnp.int32)
-    neg_counts = jnp.sum(popcount_words(slices & neg[None, :]), axis=-1,
-                         dtype=jnp.int32)
+    pos_counts = row_counts(slices & pos[None])
+    neg_counts = row_counts(slices & neg[None])
     pos_total = jnp.sum(popcount_words(pos), dtype=jnp.int32)
     neg_total = jnp.sum(popcount_words(neg), dtype=jnp.int32)
     return jnp.stack([

@@ -329,10 +329,10 @@ def _decode_jit(rows: int, words: int, a_bucket: int, r_bucket: int,
         # per-bucket compile detector (docs/observability.md)
         from ..utils import devobs
         devobs.COMPILES.mark_traced()
-        if backend == "pallas":
-            from . import kernels
-            return kernels.decode_block(*a, **k)
-        return decode_block(*a, **k)
+        from . import bitset, kernels
+        dec = kernels.decode_block if backend == "pallas" else decode_block
+        # the mirror is the device's word tile, [rows, 256, 128]
+        return bitset.to_tile(dec(*a, **k))
 
     return jax.jit(functools.partial(
         _traced, rows=rows, words=words, a_bucket=a_bucket,
@@ -342,7 +342,8 @@ def _decode_jit(rows: int, words: int, a_bucket: int, r_bucket: int,
 def upload_decode(p: Packed, rows: int, target=None,
                   words: int = SHARD_WORDS):
     """Ship a packed stream to the device and decode it there to the
-    dense mirror — Fragment.device()'s compressed upload path.  The
+    dense mirror, uint32[rows, 256, 128] (ops/bitset.py
+    "Representation") — Fragment.device()'s compressed upload path.  The
     transfer moves compressed bytes; the sparse->dense expansion happens
     on device instead of in host memory + on the wire.  Each (rows,
     buckets) decode bucket reports its compiles to the device compile

@@ -293,7 +293,9 @@ def fused_row_counts(keys, types, counts, offsets, payload, filt=None, *,
                      a_bucket: int = 0, r_bucket: int = 0):
     """Decode + optional AND-with-filter + per-row popcount in ONE
     kernel launch: int32[rows] set-bit counts of a packed fragment,
-    optionally masked by a dense ``uint32[words]`` segment.  The decoded
+    optionally masked by a dense segment (the device's ``uint32[256,
+    128]`` word tile: its sixteen (16, 128) container tiles are whole
+    sublane-groups, blocked here without a copy).  The decoded
     words exist only as the grid step's VMEM tile — no dense
     ``[rows, words]`` temporary at all (the jnp path's decode output)."""
     import jax

@@ -2,8 +2,11 @@
 
 The reference fans per-shard jobs to a goroutine pool and a star reduce
 (executor.go:2455 mapReduce, :2482 coordinator-side reduce).  Here shards
-with identical plan input shapes are STACKED into [S, rows, W] tensors,
-sharded over a 1-d "shards" mesh axis, and the whole batch executes as one
+with identical plan input shapes are STACKED into [S, rows, 256, 128]
+tensors (a row's words are a whole word tile, ops/bitset.py
+"Representation": S and rows are untiled major dimensions, so a row take
+inside a program is an offset, not a gather), sharded over a 1-d "shards"
+mesh axis, and the whole batch executes as one
 XLA computation under shard_map: each device runs the vmapped plan on its
 local shard block and cross-shard reductions ride ICI collectives (psum)
 instead of host gather — the star reduce becomes an all-reduce.
@@ -70,8 +73,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core import CONTAINER_WORDS, SHARD_WORDS
-from ..ops import bsi
+from ..core import CONTAINER_WORDS, SHARD_WORDS, WORD_TILE
+from ..ops import bitset, bsi
 from ..executor.plan import eval_plan, parametrize, plan_inputs
 from ..utils import devobs as _devobs
 from ..utils import profile as qprof
@@ -92,7 +95,8 @@ DECODE_WORKSPACE_BYTES = 1 << 30
 
 def _sig_rows(shape) -> int:
     """Row count of a per-key group-signature entry — dense entries are
-    (rows, words), compressed ones ('z', rows, C, P, A, R)."""
+    the device shape (rows, 256, 128), compressed ones
+    ('z', rows, C, P, A, R)."""
     return shape[1] if shape[0] == "z" else shape[0]
 
 
@@ -116,9 +120,11 @@ def _flatten_present(present):
 
 def _unpack_frags(layout, arrays):
     """Inside a per-shard (vmapped) body: decode compressed inputs to
-    dense [rows, W] tiles — the decode-at-op-time step, fused into the
-    op's own executable so dense tiles exist only as launch-local XLA
-    temporaries — and map every key to its dense fragment.  Each entry's
+    dense [rows, 256, 128] fragments — the decode-at-op-time step, fused
+    into the op's own executable so dense tiles exist only as
+    launch-local XLA temporaries; the decoders' [rows, W] output is
+    viewed as the word tile here, the one funnel — and map every key to
+    its dense fragment.  Each entry's
     signature carries the container-kernels backend it was planned under
     (storage/fragment.py device_sig), so the dispatch here is static per
     layout: 'pallas' entries decode through the ops/kernels.py Pallas
@@ -133,9 +139,9 @@ def _unpack_frags(layout, arrays):
             dec = kernels.decode_block \
                 if kernels.sig_backend(s) == "pallas" \
                 else containers.decode_block
-            out[k] = dec(
+            out[k] = bitset.to_tile(dec(
                 *arrays[i: i + n], rows=s[1], words=SHARD_WORDS,
-                a_bucket=s[4], r_bucket=s[5])
+                a_bucket=s[4], r_bucket=s[5]))
         i += n
     return out
 
@@ -173,7 +179,7 @@ _DISPATCH_LOCK = make_lock("dispatch")
 
 def field_rows(holder, index: str, field: str, view: str) -> int:
     """Max fragment row count for (field, view) — the ``rows`` axis of
-    a batched/fused row_counts launch's [B, rows, W] masked temp, fed
+    a batched/fused row_counts launch's [B, rows, 256, 128] masked temp, fed
     into the batch-temp workspace sizing (executor.batch_chunk_size and
     the batcher's fusion cap).  0 when the view holds no fragments."""
     idx = holder.index(index)
@@ -472,15 +478,14 @@ class MeshExecutor:
 
         if reducer == "count":
             def block_fn(params, *arrays):
-                segs = vmapped(params, *arrays)  # [S_local, W]
-                local = jnp.sum(
-                    jax.lax.population_count(segs).astype(jnp.int32))
+                segs = vmapped(params, *arrays)  # [S_local, 256, 128]
+                local = bitset.count(segs)
                 return jax.lax.psum(local, axis_name=SHARD_AXIS)
 
             out_specs = P()
         elif self.multiprocess:
             def block_fn(params, *arrays):
-                segs = vmapped(params, *arrays)    # [S_local, W]
+                segs = vmapped(params, *arrays)    # [S_local, 256, 128]
                 return jax.lax.all_gather(segs, SHARD_AXIS, tiled=True)
 
             in_specs = (P(),) + tuple(P(SHARD_AXIS)
@@ -489,7 +494,7 @@ class MeshExecutor:
                                        check_vma=False, layout=layout)
         else:
             def block_fn(params, *arrays):
-                return vmapped(params, *arrays)    # [S_local, W]
+                return vmapped(params, *arrays)    # [S_local, 256, 128]
 
             out_specs = P(SHARD_AXIS)
 
@@ -707,7 +712,8 @@ class MeshExecutor:
             return self._place_packed_block(frs, shape)
         # Two staging paths.  Warm (mirrors already resident, one
         # device): stack on device — no host transfer at all.  Cold:
-        # build the dense [S, rows, W] block on host and ship it as ONE
+        # build the dense [S, rows, W] block on host and ship it (viewed
+        # as [S, rows, 256, 128]) as ONE
         # sharded transfer instead of one upload per fragment.  On a
         # mesh of several devices the warm path would first build the
         # whole stack on the default device, where every mirror lives,
@@ -850,10 +856,11 @@ class MeshExecutor:
 
     def _overlay_stack(self, stacked, member, flat_idx, vals):
         """One scatter-OR launch: ``stacked`` is the mesh-sharded
-        [S, rows, W] block; (member, flat_idx, vals) name the overlay
-        words.  Indices ship as (member, row, word) int32 triples (a
-        flattened int64 offset would exceed jax's default index width on
-        large fragments) and the add-of-missing-bits formulation keeps
+        [S, rows, 256, 128] block; (member, flat_idx, vals) name the
+        overlay words.  Indices ship as (member, row, word) int32 triples
+        (a flattened int64 offset would exceed jax's default index width
+        on large fragments), the program finds word ``w`` at tile position
+        ``[w // 128, w % 128]``, and the add-of-missing-bits formulation keeps
         padding collisions harmless (ingest/delta.py).  Not routed
         through _InstrumentedExec: its shard/padding attribution reads
         reducer-shaped args, and a KB-scale maintenance scatter would
@@ -870,9 +877,9 @@ class MeshExecutor:
                 loc = m_ - base
                 ok = (loc >= 0) & (loc < s_local)
                 loc = jnp.where(ok, loc, 0)
-                cur = block[loc, r_, w_]
-                contrib = jnp.where(ok, v_ & ~cur, jnp.uint32(0))
-                return block.at[loc, r_, w_].add(contrib)
+                at = (loc, r_) + bitset.word_at(w_)
+                contrib = jnp.where(ok, v_ & ~block[at], jnp.uint32(0))
+                return block.at[at].add(contrib)
 
             block_fn.__name__ = "ptpu_overlay"
             fn = jax.jit(jax.shard_map(
@@ -936,8 +943,9 @@ class MeshExecutor:
         return self._bucket(max(1, n_shards)) // self.n_devices
 
     def _pad_and_place(self, arrays_list, shape, n: int):
-        """Stack n member arrays, pad the shard axis to its bucket, and
-        place sharded over the mesh axis."""
+        """Stack n member arrays (fragment mirrors, [rows, 256, 128]
+        each), pad the shard axis to its bucket, and place sharded over
+        the mesh axis."""
         pad = self._bucket(n) - n
         mats = list(arrays_list)
         if pad:
@@ -961,27 +969,30 @@ class MeshExecutor:
         sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
         bucket = self._bucket(n)
 
-        def fill(block, lo):
-            for i in range(lo, min(lo + block.shape[0], n)):
+        def fill(n_block, lo):
+            """Shards [lo, lo + n_block) as the host has them,
+            [n_block, rows, W], handed over as the device's word tile
+            (a view)."""
+            block = np.zeros((n_block, shape[0], SHARD_WORDS), np.uint32)
+            for i in range(lo, min(lo + n_block, n)):
                 # staged_dense: re-stages after an HBM eviction copy from
                 # the host staging cache instead of re-expanding the
                 # sparse store (read-only — the slice-assign copies)
                 dense = frs[i].staged_dense()
                 r = min(dense.shape[0], shape[0])  # cap may race a grow
                 block[i - lo, :r] = dense[:r]
-            return block
+            return bitset.to_tile(block)
 
         if self.multiprocess:
             def cb(index):
                 s = index[0]
                 lo = s.start or 0
                 hi = s.stop if s.stop is not None else bucket
-                return fill(np.zeros((hi - lo,) + shape, np.uint32), lo)
+                return fill(hi - lo, lo)
 
             return jax.make_array_from_callback(
                 (bucket,) + shape, sharding, cb)
-        return jax.device_put(
-            fill(np.zeros((bucket,) + shape, np.uint32), 0), sharding)
+        return jax.device_put(fill(bucket, 0), sharding)
 
     def _frag_sig(self, fr) -> tuple:
         """Per-fragment group-signature entry.  Multi-process meshes pin
@@ -989,7 +1000,7 @@ class MeshExecutor:
         processes, and remote placeholder fragments have no packed data
         to ship."""
         if self.multiprocess:
-            return (fr.n_rows, SHARD_WORDS)
+            return (fr.n_rows,) + WORD_TILE
         return fr.device_sig()
 
     def _place_packed_block(self, frs, sig):
@@ -1207,7 +1218,8 @@ class MeshExecutor:
             # circular wait); device_get copies shards with no collective.
             # Consumers (serialization, Store, filter masks) all coerce
             # to host or mix numpy into jnp ops anyway.
-            host = np.asarray(jax.device_get(segs))
+            # (the word tile is flattened here, on the host: a view)
+            host = bitset.from_tile(np.asarray(jax.device_get(segs)))
             for i, shard in enumerate(shard_list):
                 out[shard] = host[i]
         return out
@@ -1255,13 +1267,13 @@ class MeshExecutor:
                     frags = _unpack_frags(_layout, arrays)
                     return jax.vmap(
                         lambda p: eval_plan(slotted, frags, p))(
-                            params_)                   # [B, W]
+                            params_)               # [B, 256, 128]
 
                 vmapped = jax.vmap(per_shard,
                                    in_axes=(None,) + (0,) * len(flat))
                 if self.multiprocess:
                     def block_fn(params_, *arrays, _vm=vmapped):
-                        segs = _vm(params_, *arrays)   # [S_local, B, W]
+                        segs = _vm(params_, *arrays)   # [S_local, B, tile]
                         return jax.lax.all_gather(segs, SHARD_AXIS,
                                                   tiled=True)
 
@@ -1271,7 +1283,7 @@ class MeshExecutor:
                         P(), check_vma=False, layout=layout)
                 else:
                     def block_fn(params_, *arrays, _vm=vmapped):
-                        return _vm(params_, *arrays)   # [S_local, B, W]
+                        return _vm(params_, *arrays)   # [S_local, B, tile]
 
                     fn = self._jit_shard_map(
                         key, block_fn,
@@ -1279,7 +1291,8 @@ class MeshExecutor:
                         P(SHARD_AXIS), layout=layout)
             with _DISPATCH_LOCK:
                 segs = fn(params, *flat, _launch_meta=len(shard_list))
-            host = np.asarray(jax.device_get(segs))    # [S, B, W]
+            host = bitset.from_tile(
+                np.asarray(jax.device_get(segs)))      # [S, B, W]
             for i, shard in enumerate(shard_list):
                 out[shard] = host[i]
         return out
@@ -1343,15 +1356,13 @@ class MeshExecutor:
                             words=SHARD_WORDS, a_bucket=fs[4],
                             r_bucket=fs[5])        # [rows]
                     frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]              # [rows, W]
+                    frag = frags[_k0]              # [rows, 256, 128]
                     if fplan is None:
                         masked = frag
                     else:
-                        seg = eval_plan(fplan, frags, params_)   # [W]
-                        masked = frag & seg[None, :]
-                    return jnp.sum(
-                        jax.lax.population_count(masked).astype(jnp.int32),
-                        axis=-1)                   # [rows]
+                        seg = eval_plan(fplan, frags, params_)
+                        masked = frag & seg[None]
+                    return bitset.row_counts(masked)   # [rows]
 
                 def block_fn(params_, *arrays, _ps=per_shard,
                              _n=len(flat)):
@@ -1534,9 +1545,7 @@ class MeshExecutor:
                     frags = _unpack_frags(_layout, arrays)
                     segs = jax.vmap(
                         lambda p: eval_plan(slotted, frags, p))(params_)
-                    return jnp.sum(
-                        jax.lax.population_count(segs).astype(jnp.int32),
-                        axis=-1)                       # [B]
+                    return bitset.row_counts(segs)      # [B]
 
                 def block_fn(params_, *arrays, _ps=per_shard,
                              _n=len(flat)):
@@ -1580,19 +1589,15 @@ class MeshExecutor:
                 def per_shard(params_, *arrays, _layout=layout,
                               _k0=pkeys[0], _fplan=slotted_filter):
                     frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]                  # [rows, W]
+                    frag = frags[_k0]                  # [rows, 256, 128]
                     if _fplan is None:
-                        counts = jnp.sum(
-                            jax.lax.population_count(frag).astype(jnp.int32),
-                            axis=-1)                   # [rows]
+                        counts = bitset.row_counts(frag)   # [rows]
                         return jnp.broadcast_to(
                             counts, (params_.shape[0],) + counts.shape)
                     masks = jax.vmap(
                         lambda p: eval_plan(_fplan, frags, p))(params_)
-                    masked = frag[None, :, :] & masks[:, None, :]
-                    return jnp.sum(
-                        jax.lax.population_count(masked).astype(jnp.int32),
-                        axis=-1)                       # [B, rows]
+                    masked = frag[None] & masks[:, None]
+                    return bitset.row_counts(masked)    # [B, rows]
 
                 def block_fn(params_, *arrays, _ps=per_shard,
                              _n=len(flat)):
@@ -1735,7 +1740,7 @@ class MeshExecutor:
                         pfrag = frags[pk]
                         rid = rids_row[j]
                         if pfrag.shape[0] == 0:
-                            seg = jnp.zeros(pfrag.shape[-1],
+                            seg = jnp.zeros(pfrag.shape[1:],
                                             dtype=pfrag.dtype)
                         else:
                             seg = jnp.where(
@@ -1749,15 +1754,13 @@ class MeshExecutor:
                     if fplan is not None:
                         fseg = eval_plan(fplan, frags, params_)
                         mask = fseg if mask is None else mask & fseg
-                    masked = frag if mask is None else frag & mask[None, :]
-                    return jnp.sum(
-                        jax.lax.population_count(masked).astype(jnp.int32),
-                        axis=-1)                       # [rows]
+                    masked = frag if mask is None else frag & mask[None]
+                    return bitset.row_counts(masked)    # [rows]
 
                 def per_shard(rids_, params_, *arrays, _layout=layout,
                               _k0=pkeys[0], _oc=one_combo):
                     frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]                  # [rows, W]
+                    frag = frags[_k0]                  # [rows, 256, 128]
                     return jax.vmap(
                         lambda r: _oc(r, params_, frags, frag))(
                             rids_)                     # [C, rows]

@@ -63,7 +63,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS
 from ..executor.plan import eval_plan, plan_inputs
-from ..ops import bsi
+from ..ops import bitset, bsi
 from ..utils import devobs as _devobs
 from ..utils import profile as qprof
 from ..utils.deadline import check_current
@@ -302,28 +302,24 @@ class _InstrumentedWhole:
 
 def _node_shard(node, mat, frags):
     """One reducer node's per-shard contribution, traced inside the
-    vmapped per-shard pass (decode has already produced dense tiles in
-    ``frags``).  Shapes mirror the legacy per-stage executables exactly
+    vmapped per-shard pass (decode has already produced dense
+    [rows, 256, 128] fragments in ``frags``; a segment is one word tile,
+    [256, 128]).  Shapes mirror the legacy per-stage executables exactly
     — including int32 accumulation — so results stay byte-identical."""
     if node.kind in ("count", "segments"):
         segs = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
         if node.kind == "segments":
-            return segs                                    # [B, W]
-        return jnp.sum(
-            jax.lax.population_count(segs).astype(jnp.int32),
-            axis=-1)                                       # [B]
+            return segs                                    # [B, 256, 128]
+        return bitset.row_counts(segs)              # [B]
     frag = frags[node.primary]
     if node.kind == "row_counts":
         if node.plan is None:
-            counts = jnp.sum(
-                jax.lax.population_count(frag).astype(jnp.int32), axis=-1)
+            counts = bitset.row_counts(frag)
             return jnp.broadcast_to(counts,
                                     (mat.shape[0],) + counts.shape)
         masks = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
-        masked = frag[None, :, :] & masks[:, None, :]
-        return jnp.sum(
-            jax.lax.population_count(masked).astype(jnp.int32),
-            axis=-1)                                       # [B, rows]
+        masked = frag[None] & masks[:, None]
+        return bitset.row_counts(masked)            # [B, rows]
     if node.kind == "bsi_sum":
         if node.plan is None:
             counts = bsi.sum_counts(frag, None)
@@ -351,7 +347,7 @@ def _node_shard(node, mat, frags):
             pfrag = frags[pk]
             rid = rids_row[j]
             if pfrag.shape[0] == 0:
-                seg = jnp.zeros(pfrag.shape[-1], dtype=pfrag.dtype)
+                seg = jnp.zeros(pfrag.shape[1:], dtype=pfrag.dtype)
             else:
                 seg = jnp.where(
                     rid < pfrag.shape[0],
@@ -362,10 +358,8 @@ def _node_shard(node, mat, frags):
             mask = seg if mask is None else mask & seg
         if fseg is not None:
             mask = fseg if mask is None else mask & fseg
-        masked = frag if mask is None else frag & mask[None, :]
-        return jnp.sum(
-            jax.lax.population_count(masked).astype(jnp.int32),
-            axis=-1)                                       # [rows]
+        masked = frag if mask is None else frag & mask[None]
+        return bitset.row_counts(masked)            # [rows]
 
     return jax.vmap(one_combo)(rids)                       # [C, rows]
 
@@ -682,7 +676,9 @@ class WholeQueryRunner:
             for ni, node in enumerate(program):
                 parts = [per_group_raw[gi][ni] for gi in sched[ni]]
                 if node.kind == "segments":
-                    flat_outs.extend(parts)   # [S_local, B, W] per group
+                    # [S_local, B, 256, 128] per group: the host
+                    # flattens the tile after the fetch
+                    flat_outs.extend(parts)
                 elif node.kind == "bsi_minmax":
                     for p in parts:                 # (bits, neg, cnt)
                         flat_outs.extend(p)

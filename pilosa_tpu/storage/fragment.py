@@ -51,6 +51,7 @@ from ..core import (
     HASH_BLOCK_SIZE,
     SHARD_WIDTH,
     SHARD_WORDS,
+    WORD_TILE,
 )
 from ..ops import bitset, bsi, kernels as _kernels
 from ..utils import events
@@ -1240,7 +1241,8 @@ class Fragment:
 
     def device_sig(self) -> tuple:
         """Stacked-group shape signature for the mesh executor: dense
-        fragments keep the (rows, words) tensor shape; compressed ones
+        fragments keep the device tensor's shape (rows, 256, 128) — a
+        row's words are a word tile, ops/bitset.py; compressed ones
         carry ('z', rows, C, P, A, R, backend) with pow2-bucketed
         container, payload, array-entry and run counts so one compiled
         decode executable serves every fragment in a bucket.  The
@@ -1251,7 +1253,7 @@ class Fragment:
         compiles — instead of replaying a jnp-compiled program through
         the pallas path (the PR 7 retrace class)."""
         if self.device_form() == "dense":
-            return (self.n_rows, SHARD_WORDS)
+            return (self.n_rows,) + WORD_TILE
         from ..ops import kernels
         from ..ops.containers import pow2_bucket
         knob = kernels.CONTAINER_KERNELS
@@ -1357,9 +1359,12 @@ class Fragment:
             return True
 
     def device(self, target=None):
-        """The HBM-resident mirror (uploads if stale).  This is the query
-        hot path's input — equivalent to the mmap'd storage the reference
-        queries against (fragment.go:311).
+        """The HBM-resident mirror, uint32[cap_rows, 256, 128] (uploads if
+        stale): the host's dense [cap_rows, SHARD_WORDS] words handed over
+        as the device's word tile, a view (ops/bitset.py
+        "Representation").  This is the query hot path's input —
+        equivalent to the mmap'd storage the reference queries against
+        (fragment.go:311).
 
         ``target``: an optional jax Device to place the mirror on.  Mesh
         executors pass a device from their own mesh when the mesh's platform
@@ -1411,7 +1416,8 @@ class Fragment:
                     mirror = upload_decode(self.packed_host(),
                                            self._cap_rows, target)
                 else:
-                    mirror = jax.device_put(self.staged_dense(), target)
+                    mirror = jax.device_put(
+                        bitset.to_tile(self.staged_dense()), target)
                 self._mirrors[target] = mirror
                 # fresh uploads stage from the sparse store, which holds
                 # every journaled bit already

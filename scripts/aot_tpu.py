@@ -51,7 +51,7 @@ def kernel_cases(bucket, stacked: int = 2) -> dict:
     """{name: (fn, avals)} — every container kernel of ops/kernels.py
     over one (rows, C, P, A, R) decode bucket, under the call sites'
     vmap over ``stacked`` fragments."""
-    from pilosa_tpu.core import SHARD_WORDS
+    from pilosa_tpu.core import WORD_TILE
     from pilosa_tpu.ops import kernels
     rows, C, P, A, R = bucket
     kw = dict(rows=rows, a_bucket=A, r_bucket=R)
@@ -68,7 +68,7 @@ def kernel_cases(bucket, stacked: int = 2) -> dict:
             packed),
         "fused_row_counts+filter": (
             jax.vmap(lambda *a: kernels.fused_row_counts(*a, **kw)),
-            packed + [aval((SHARD_WORDS,), jnp.uint32)]),
+            packed + [aval(WORD_TILE, jnp.uint32)]),
     }
 
 

@@ -10,7 +10,9 @@ divergence."""
 import numpy as np
 import pytest
 
-from pilosa_tpu.core import CONTAINER_WORDS, SHARD_WIDTH, SHARD_WORDS
+from pilosa_tpu.core import CONTAINER_WORDS, SHARD_WIDTH, SHARD_WORDS, \
+    WORD_TILE
+from pilosa_tpu.ops.bitset import from_tile
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.ops import containers
 from pilosa_tpu.ops.containers import (
@@ -43,8 +45,10 @@ def _roundtrip(idx, val, rows):
     p = pack_words(idx, val)
     want = _oracle(idx, val, rows)
     np.testing.assert_array_equal(unpack_packed(p, rows), want)
+    # the decoded mirror is the device's word tile; the host flattens it
     got = np.asarray(upload_decode(p, rows))
-    np.testing.assert_array_equal(got, want)
+    assert got.shape == (rows,) + WORD_TILE
+    np.testing.assert_array_equal(from_tile(got), want)
     return p
 
 
@@ -199,7 +203,7 @@ def test_dense_data_stays_dense(rng):
         f.set_row(row, rng.integers(1, 1 << 32, size=SHARD_WORDS,
                                     dtype=np.uint32))
     assert f.device_form() == "dense"
-    assert f.device_sig() == (f.n_rows, SHARD_WORDS)
+    assert f.device_sig() == (f.n_rows,) + WORD_TILE
 
 
 def test_compressed_device_mirror_equals_dense():
@@ -211,7 +215,8 @@ def test_compressed_device_mirror_equals_dense():
     f.bulk_import(rng.integers(0, 6, 4000), rng.integers(0, SHARD_WIDTH, 4000))
     assert f.device_form() == "compressed"
     got = np.asarray(f.device())
-    np.testing.assert_array_equal(got, f.to_dense())
+    assert got.shape == (f.n_rows,) + WORD_TILE
+    np.testing.assert_array_equal(from_tile(got), f.to_dense())
 
 
 # -- differential: compressed-resident vs dense-resident --------------------

@@ -412,3 +412,159 @@ def test_debug_vars_section(corpus):
         assert ex.wq_last_fallback
     finally:
         ex.close()
+
+
+# -- the device shape of a row: a (256, 128) word tile ----------------------
+# (ops/bitset.py "Representation"): every stacked argument a program takes
+# is u32[S, R, 256, 128], a fragment inside the per-shard pass
+# u32[R, 256, 128]; the host flattens a fetched segment to [W].
+
+NODE_QUERIES = {
+    "count": "Count(Intersect(Row(a=1), Row(b=2)))",
+    "segments": "Row(a=3)",
+    "row_counts": "TopN(a, Row(b=1), n=3)",
+    "bsi_sum": "Sum(Row(v > 17), field=v)",
+    "bsi_minmax": "Min(Row(a=2), field=v)",
+    "group_counts": "GroupBy(Rows(b), Rows(a))",
+}
+
+
+@pytest.mark.parametrize("kind", list(NODE_QUERIES))
+def test_node_takes_tiled_stacks(corpus, monkeypatch, kind):
+    """One ``_node_shard`` kind a case: the program's stacked arguments
+    are rank 4 with trailing (256, 128), the fragments its per-shard
+    pass sees rank 3 with the same tile, and the answer is the legacy
+    path's."""
+    from pilosa_tpu.core import SHARD_WORDS, WORD_TILE
+    from pilosa_tpu.parallel import wholequery as wq
+    stacked, seen = [], []
+    real_over, real_node = wq._over_shards, wq._node_shard
+
+    def over(per_shard, arrs, block):
+        stacked.extend(a.shape for a in arrs)
+        return real_over(per_shard, arrs, block)
+
+    def node_shard(node, mat, frags):
+        seen.append((node.kind, [f.shape for f in frags.values()]))
+        return real_node(node, mat, frags)
+
+    monkeypatch.setattr(wq, "_over_shards", over)
+    monkeypatch.setattr(wq, "_node_shard", node_shard)
+    # a fresh executor traces its programs anew: the spies see them
+    ex = Executor(corpus, use_mesh=True, whole_query_fallback="error")
+    legacy = Executor(corpus, use_mesh=True, whole_query=False)
+    q = NODE_QUERIES[kind]
+    try:
+        got = ex.execute("w", q)
+        assert [_norm(r) for r in got] == \
+            [_norm(r) for r in legacy.execute("w", q)]
+        assert kind in {k for k, _ in seen}
+        assert stacked and all(
+            len(s) == 4 and s[-2:] == WORD_TILE for s in stacked), stacked
+        assert all(len(s) == 3 and s[1:] == WORD_TILE
+                   for _, shapes in seen for s in shapes), seen
+        if kind == "segments":
+            # the client gets the host's [W] words, the fragment's own
+            segs = got[0].segments
+            assert set(segs) == set(range(18))   # 18-19 hold no bits
+            for shard, seg in segs.items():
+                assert isinstance(seg, np.ndarray)
+                assert seg.shape == (SHARD_WORDS,)
+                fr = corpus.fragment("w", "a", "standard", shard)
+                want = fr.row(3) if fr is not None and fr.n_rows > 3 \
+                    else np.zeros(SHARD_WORDS, dtype=np.uint32)
+                assert np.array_equal(seg, want), shard
+    finally:
+        ex.close()
+        legacy.close()
+
+
+def test_overlay_sets_exactly_the_named_words(rng):
+    """An ingest overlay on a tiled block [S, R, 256, 128] ORs exactly
+    the words (member, row, word) named — across the tile's lane rows
+    (word 127 | 128) — and leaves every other word as it was."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from pilosa_tpu.core import SHARD_WORDS, WORD_TILE
+    from pilosa_tpu.ops import bitset
+    from pilosa_tpu.parallel.mesh_exec import MeshExecutor, SHARD_AXIS
+    mesh = MeshExecutor()
+    try:
+        n_s, n_r = 2 * mesh.n_devices, 3
+        host = np.zeros((n_s, n_r, SHARD_WORDS), dtype=np.uint32)
+        host[:, :, ::97] = rng.integers(
+            1, 1 << 32, size=host[:, :, ::97].shape, dtype=np.uint32)
+        block = jax.device_put(
+            bitset.to_tile(host), NamedSharding(mesh.mesh, P(SHARD_AXIS)))
+        assert block.shape == (n_s, n_r) + WORD_TILE
+        words = np.array([0, 97, 127, 128, 129, 255, 256, 4095, 4096,
+                          SHARD_WORDS - 1] * 3)
+        member = rng.integers(0, n_s, size=words.size).astype(np.int32)
+        rows = rng.integers(0, n_r, size=words.size)
+        # one flat (row, word) index a member: dedupe as merge_chunks does
+        _, keep = np.unique(
+            np.stack([member, rows, words]), axis=1, return_index=True)
+        member, rows, words = member[keep], rows[keep], words[keep]
+        vals = rng.integers(1, 1 << 32, size=words.size, dtype=np.uint32)
+        out = mesh._overlay_stack(block, member,
+                                  rows * SHARD_WORDS + words, vals)
+        assert out.shape == block.shape
+        want = host.copy()
+        want[member, rows, words] |= vals
+        assert np.array_equal(bitset.from_tile(np.asarray(out)), want)
+    finally:
+        mesh.close()
+
+
+def test_compressed_beside_dense_in_one_launch(rng, monkeypatch):
+    """A compressed (``decode_block``) entry and a dense one in the same
+    launch: both reach the per-shard pass as [R, 256, 128] fragments,
+    and the count is numpy's."""
+    from pilosa_tpu.core import SHARD_WORDS, WORD_TILE
+    from pilosa_tpu.parallel import wholequery as wq
+    n_shards = 4
+    h = Holder(None)
+    idx = h.create_index("m")
+    sparse = idx.create_field("sparse")
+    dense = idx.create_field("dense")
+    cols = rng.integers(0, n_shards * SHARD_WIDTH, size=6000)
+    sparse.import_bits(rng.integers(0, 4, size=cols.size), cols)
+    dense.import_bits(np.zeros(n_shards, dtype=np.int64),
+                      np.arange(n_shards) * SHARD_WIDTH)
+    want = 0
+    for shard in range(n_shards):
+        fr = h.fragment("m", "dense", "standard", shard)
+        for row in range(fr._cap_rows):     # no zero word: stays dense
+            fr.set_row(row, rng.integers(1, 1 << 32, size=SHARD_WORDS,
+                                         dtype=np.uint32))
+        both = fr.row(0) & h.fragment("m", "sparse", "standard",
+                                      shard).row(1)
+        want += int(np.unpackbits(both.view(np.uint8)).sum())
+    layouts, shapes = [], []
+    real = wq._unpack_frags
+
+    def unpack(layout, arrays):
+        out = real(layout, arrays)
+        layouts.append([n for _, n, _ in layout])
+        shapes.extend(f.shape for f in out.values())
+        return out
+
+    monkeypatch.setattr(wq, "_unpack_frags", unpack)
+    old = DEFAULT_BUDGET.limit_bytes
+    ex = Executor(h, use_mesh=True, whole_query_fallback="error")
+    try:
+        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        DEFAULT_BUDGET.shrink_to_limit()
+        forms = {h.fragment("m", f, "standard", 0).device_form()
+                 for f in ("sparse", "dense")}
+        assert forms == {"compressed", "dense"}
+        got = ex.execute(
+            "m", "Count(Intersect(Row(sparse=1), Row(dense=0)))")[0]
+        assert got == want
+        # one launch decoded the packed entry (5 tables) beside the
+        # dense one (1 array), and both came out tiled
+        assert layouts and all(sorted(l) == [1, 5] for l in layouts)
+        assert all(len(s) == 3 and s[1:] == WORD_TILE for s in shapes)
+    finally:
+        DEFAULT_BUDGET.limit_bytes = old
+        ex.close()
