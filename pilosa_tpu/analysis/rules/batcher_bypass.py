@@ -1,15 +1,15 @@
 """batcher-bypass: direct mesh reducer dispatch outside parallel/.
 
 Device dispatch must flow through the dispatch batcher
-(docs/batching.md): a direct shard_map-reducer call bypasses cross-query
-fusion, the queued-deadline drop-out, and the dispatch stats.  Only
-``parallel/`` touches the executables; everything else goes through
-``executor.batcher``'s same-named wrappers (or its explicit
-disabled-mode fallback).
+(docs/batching.md): a direct call of the per-stage launcher bypasses
+cross-query fusion, the queued-deadline drop-out, and the dispatch
+stats.  Only ``parallel/`` touches the executables; everything else
+goes through ``executor.batcher.reduce`` (or its explicit disabled-mode
+fallback).
 
 Replaces the check.sh grep with a receiver-aware pass: besides literal
-``mesh.segments(...)`` shapes it tracks simple local aliases
-(``m = self.executor.mesh; m.segments(...)`` and
+``mesh.reduce_async(...)`` shapes it tracks simple local aliases
+(``m = self.executor.mesh; m.reduce_async(...)`` and
 ``m = MeshExecutor(...)``), which the grep could never see.
 """
 
@@ -19,10 +19,8 @@ import ast
 
 from ..astlint import rule
 
-REDUCERS = {
-    "count_async", "count_batch_async", "segments", "segments_batch",
-    "row_counts", "bsi_sum", "bsi_min_max", "group_counts",
-}
+# MeshExecutor's one per-stage launcher (parallel/mesh_exec.py)
+REDUCERS = {"reduce_async"}
 
 
 def _chain_names(node) -> list[str]:

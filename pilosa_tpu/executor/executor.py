@@ -9,7 +9,6 @@ only scalars/count-vectors back from the device.
 
 from __future__ import annotations
 
-import functools
 from datetime import datetime
 from typing import Any
 
@@ -20,7 +19,12 @@ from ..ops import bitset, bsi
 from ..pql import Call, parse
 from ..storage.field import FIELD_TYPE_INT, FIELD_TYPE_BOOL
 from ..storage import time_quantum as tq
-from .plan import PlanCompiler, Resolver, parametrize, plan_inputs
+from .plan import PlanCompiler, ReduceNode, Resolver, parametrize
+# (after .plan: parallel/ imports it, and this package through it)
+from ..parallel.nodes import (
+    ROW_BYTES, batch_temp_bound, node_keys, node_temp_rows, pad_pow2_rows,
+    pow2_rows,
+)
 from .results import (
     FieldRow, GroupCount, Pair, RowIdentifiers, RowResult, ValCount,
     acc_counts, rank_counts, sort_pairs,
@@ -89,80 +93,15 @@ class _PendingGroup:
                                if hp else [0] * nB))
 
 
-# What a launch costs in program temporaries, and what it may cost.
-#
-# A batched executable materializes device temporaries beside its
-# stacked inputs, per stacked shard of the device.  The per-stage
-# batched launches state theirs in [SHARD_WORDS] u32 rows (128 KiB) a
-# batch row (``node_temp_rows``, the one function ``_batch_chunks`` and
-# the cross-query batcher's per-stage tickets share):
-#
-# * count / segments: one gather temp per params slot (measured: an
-#   8-slot Intersect batch at B=16384 on one shard exhausts a 16 GB HBM
-#   with 8 x 2 GB gather temps);
-# * filtered row_counts / TopN: one [B, rows, W] masked temp, rows = the
-#   fragment row count (BENCH_r07's small-RAM OOM gap);
-# * filtered bsi_sum: the summed field's bit rows under the filter, so
-#   its depth + 2 rows.
-#
-# A whole-query program asks the compiler instead: its launch reads
-# ``memory_analysis().temp_size_in_bytes`` of the executable it is about
-# to run, once per compiled shape, and walks the device's shards in
-# blocks where that figure would pass the bound
-# (parallel/wholequery.py ``run``).  ``batch_temp_bound`` is what a
-# launch may cost: what the device has left — its ``bytes_limit`` less
-# what the device budget counts resident, less BATCH_TEMP_MARGIN — with
-# the ``batch-temp-mb`` knob (BATCH_TEMP_BYTES; process-wide, most
-# recent Server wins) as a ceiling only.  Per-stage batches are
-# dispatched in chunks whose temporaries stay under the bound, every
-# chunk padded up to a power of two (repeating its last row — always
-# in-range) so arbitrary client batch sizes reuse a bounded set of
-# compiled executables.  BATCH_CHUNK_MAX was sized when one compile cost
-# 20-40 s on a remote device that no longer exists; it awaits
-# re-derivation on the chip (ROADMAP.md S9).
-BATCH_TEMP_BYTES = 4 << 30
+# Per-stage batches are dispatched in chunks whose temporaries stay
+# under the batch-temp bound (parallel/nodes.py: ``node_temp_rows`` a
+# batch row, held against ``batch_temp_bound``), every chunk padded up
+# to a power of two (repeating its last row — always in-range) so
+# arbitrary client batch sizes reuse a bounded set of compiled
+# executables.  BATCH_CHUNK_MAX was sized when one compile cost 20-40 s
+# on a remote device that no longer exists; it awaits re-derivation on
+# the chip (ROADMAP.md S9).
 BATCH_CHUNK_MAX = 32768
-# Device bytes the bound leaves free beside resident blocks and one
-# launch's temporaries: outputs, params, the temporaries of launches
-# still in flight at B = 1 (0.4-0.5 GB each at 176 stacked shards) and
-# the allocator's own slack.
-BATCH_TEMP_MARGIN = 1 << 30
-ROW_BYTES = SHARD_WORDS * 4
-
-
-def node_temp_rows(kind: str, plan, P: int, primary_rows: int = 0) -> int:
-    """[SHARD_WORDS] u32 rows one batch row of a per-stage batched
-    launch keeps per stacked shard.  ``kind`` is the batched call-group
-    name or its node kind (sum = bsi_sum, topn = row_counts); ``plan``
-    the slotted filter (None: a B-independent broadcast pass, 0), ``P``
-    its params slots, ``primary_rows`` the rows of the (field, view)
-    reduced."""
-    if kind in ("count", "segments"):
-        return max(1, P)
-    if plan is None:
-        return 0
-    return max(1, P, primary_rows)
-
-
-@functools.cache
-def device_bytes_limit() -> int | None:
-    """The smallest ``bytes_limit`` over the local devices, where the
-    backend reports one (the CPU does not).  Read once."""
-    import jax
-    limits = [(d.memory_stats() or {}).get("bytes_limit")
-              for d in jax.local_devices()]
-    return min((b for b in limits if b), default=None)
-
-
-def batch_temp_bound() -> int:
-    """What one launch's temporaries may cost now: what the device has
-    left, ``batch-temp-mb`` as a ceiling."""
-    limit = device_bytes_limit()
-    if limit is None:
-        return BATCH_TEMP_BYTES
-    from ..storage.membudget import DEFAULT_BUDGET
-    free = limit - DEFAULT_BUDGET.resident_bytes - BATCH_TEMP_MARGIN
-    return max(0, min(BATCH_TEMP_BYTES, free))
 
 
 def batch_chunk_size(rows: int, n_shards: int) -> int:
@@ -193,32 +132,19 @@ def _batch_chunks(params_mat: np.ndarray, n_shards: int, rows: int = 0):
         chunk = batch_chunk_size(max(1, P, rows), n_shards)
     for lo in range(0, B, chunk):
         sub = params_mat[lo: lo + chunk]
-        n = sub.shape[0]
-        pad = 1 << max(0, n - 1).bit_length()
-        if pad != n:
-            sub = np.concatenate([sub, np.repeat(sub[-1:], pad - n,
-                                                 axis=0)])
-        yield lo, n, sub
-
-
-def _group_key_list(mesh, kind, slotted, extra):
-    """The exact (field, view) key list the mesh dispatch for this group
-    will stack (mesh.batch_keys is the single definition), so the shard
-    schedule's prefetch stages the stacks the dispatch will actually
-    read."""
-    if kind == "count":
-        return plan_inputs(slotted)
-    return mesh.batch_keys((extra["field"], extra["view"]), slotted)
+        yield lo, sub.shape[0], pad_pow2_rows(sub)
 
 
 def _run_batched_groups(batcher, holder, index, shards, groups, results):
     """Dispatch batched call groups chunk-wise and fill ``results``.
 
-    ``groups``: iterable of (kind, slotted, params_mat, call_idxs, extra);
-    extra carries kind-specific fields — sum: field/view/base, topn:
-    field/view/ids_n with one (ids, n) pair per call.  Shared by the
-    classic grouped path and the prepared-statement cache so the chunking
-    policy lives in exactly one place.
+    ``groups``: iterable of (node, params_mat, call_idxs, extra) — the
+    group's reducer node (count, bsi_sum or row_counts), its [B, P]
+    params matrix, the calls its rows answer, and what its finisher
+    needs beside the parts: bsi_sum ``base``, row_counts ``ids_n`` with
+    one (ids, n) pair per call.  Shared by the classic grouped path and
+    the prepared-statement cache so the chunking policy lives in exactly
+    one place.
 
     Dispatch flows through the cross-query batcher
     (parallel/batcher.py): on the common single-slice schedule each
@@ -239,8 +165,8 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
     mesh = batcher.mesh
 
     key_lists: list = []
-    for kind, slotted, _pm, _ci, extra in groups:
-        kl = _group_key_list(mesh, kind, slotted, extra)
+    for node, _pm, _ci, _extra in groups:
+        kl = node_keys(node)
         if kl not in key_lists:
             key_lists.append(kl)
     sched = mesh.shard_schedule(holder, index, key_lists, shards)
@@ -251,18 +177,20 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
     # dispatch; batching a streamed working set would re-stage it whole
     fuse = len(sched.slices) == 1
 
-    def _n_split(kind, slotted):
-        # count plans always gather per-row temps; sum/topn without a
-        # filter broadcast one pass — single chunk (see _batch_chunks)
-        return per_dev if (kind == "count" or slotted is not None) else 0
-
-    def _temp_rows(kind, slotted, params_mat, extra):
+    def _chunks(node, params_mat):
+        # count plans always gather per-row temps; bsi_sum/row_counts
+        # without a filter broadcast one pass — single chunk (see
+        # _batch_chunks)
+        if node.kind != "count" and node.plan is None:
+            return _batch_chunks(params_mat, 0)
         rows = 0
-        if kind != "count" and slotted is not None:
+        if node.kind != "count":
             from ..parallel.mesh_exec import field_rows
-            rows = field_rows(holder, index, extra["field"],
-                              extra.get("view", VIEW_STANDARD))
-        return node_temp_rows(kind, slotted, params_mat.shape[1], rows)
+            rows = field_rows(holder, index, *node.primary)
+        return _batch_chunks(
+            params_mat, per_dev,
+            node_temp_rows(node.kind, node.plan, params_mat.shape[1],
+                           rows))
 
     # chunk layouts computed ONCE; on the multi-slice direct path the
     # padded params also go to device once (slice-major iteration would
@@ -272,10 +200,8 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
     import jax.numpy as jnp
     group_chunks = [
         [(lo, n_c, sub if fuse else jnp.asarray(sub))
-         for lo, n_c, sub in
-         _batch_chunks(params_mat, _n_split(kind, slotted),
-                       _temp_rows(kind, slotted, params_mat, extra))]
-        for kind, slotted, params_mat, _ci, extra in groups]
+         for lo, n_c, sub in _chunks(node, params_mat)]
+        for node, params_mat, _ci, _extra in groups]
     # the batch axis split to honor the bound: visible, not silent
     # (docs/observability.md — `query.batch_temp_splits`, and
     # `batchTemp.splits` with the batcher's and the block walks')
@@ -286,63 +212,42 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
 
     parts_acc: dict[tuple[int, int], list] = {}
     for shard_slice in sched:
-        for gi, (kind, slotted, params_mat, call_idxs, extra) \
-                in enumerate(groups):
+        for gi, (node, _pm, _ci, _extra) in enumerate(groups):
             for lo, _n, sub in group_chunks[gi]:
-                if kind == "count":
-                    parts = batcher.count_batch(
-                        slotted, sub, holder, index, shard_slice,
-                        fuse=fuse)
-                elif kind == "sum":
-                    parts = batcher.bsi_sum_batch(
-                        extra["field"], extra["view"], slotted, sub,
-                        holder, index, shard_slice, fuse=fuse)
-                else:  # topn
-                    parts = batcher.row_counts_batch(
-                        extra["field"], extra["view"], slotted, sub,
-                        holder, index, shard_slice, fuse=fuse)
+                # the slice is this schedule's: not to be scheduled again
+                parts, _ = batcher.reduce(node, sub, holder, index,
+                                          shard_slice, scheduled=True,
+                                          fuse=fuse)
                 parts_acc.setdefault((gi, lo), []).extend(parts)
 
     # all parts dispatched; build the pendings (finalizers sum/merge the
-    # per-slice parts exactly as they previously merged per-shape-group
-    # parts — every reduction here is additive over shards)
-    for gi, (kind, slotted, params_mat, call_idxs, extra) \
-            in enumerate(groups):
-        if kind == "sum":
-            base = extra["base"]
-
-            def _sum_fin(hp, b, base=base):
-                total, cnt = 0, 0
-                for p in hp:
-                    s, c_ = bsi.weighted_sum(p[b])
-                    total += s
-                    cnt += c_
-                return ValCount(total + cnt * base, cnt)
-        elif kind == "topn":
-            def _topn_fin(hp, b, ids, n):
-                counts = mesh.merge_counts([p[b] for p in hp])
-                return rank_counts(counts, n or None, ids)
-
-            ids_n = extra["ids_n"]
+    # per-slice parts exactly as they merge per-shape-group parts —
+    # every reduction here is additive over shards)
+    for gi, (node, _pm, call_idxs, extra) in enumerate(groups):
         for lo, n_c, _sub in group_chunks[gi]:
-            parts = parts_acc.get((gi, lo), [])
-            if kind == "count":
-                grp = _PendingGroup.counts(parts, call_idxs[lo: lo + n_c])
-                for i in call_idxs[lo: lo + n_c]:
-                    results[i] = grp
-            elif kind == "sum":
-                # fin=_sum_fin binds THIS group's finalizer: a free-
-                # variable reference would late-bind to the last group's
-                # base when one invocation carries several sum groups
-                for b in range(n_c):
-                    results[call_idxs[lo + b]] = _Pending(
-                        parts, lambda hp, b=b, fin=_sum_fin: fin(hp, b))
-            else:
-                for b in range(n_c):
-                    ids, n = ids_n[lo + b]
-                    results[call_idxs[lo + b]] = _Pending(
-                        parts, lambda hp, b=b, ids=ids, n=n,
-                        fin=_topn_fin: fin(hp, b, ids, n))
+            _wire_group(node, parts_acc.get((gi, lo), []),
+                        call_idxs[lo: lo + n_c], extra, lo, mesh, results)
+
+
+def _wire_group(node, parts, call_idxs, extra, lo, mesh, results):
+    """Pendings of one batched call group (or one chunk of it, whose
+    first row is call ``lo`` of the group) over its fetched ``parts``,
+    whichever way they were launched."""
+    if node.kind == "count":
+        grp = _PendingGroup.counts(parts, call_idxs)
+        for i in call_idxs:
+            results[i] = grp
+    elif node.kind == "bsi_sum":
+        for b, i in enumerate(call_idxs):
+            results[i] = _Pending(
+                parts, lambda hp, b=b, base=extra["base"]:
+                _sum_fin(hp, b, base))
+    else:   # row_counts
+        for b, i in enumerate(call_idxs):
+            ids, n = extra["ids_n"][lo + b]
+            results[i] = _Pending(
+                parts, lambda hp, b=b, ids=ids, n=n:
+                _topn_rank(mesh, hp, b, ids, n))
 
 
 class _Pending:
@@ -393,11 +298,14 @@ def _resolve_pendings(results):
     return out
 
 
-# -- whole-query host finalizers (docs/whole-query.md) ----------------------
-# Applied to the fetched device parts of one whole-query launch; each
-# mirrors the corresponding legacy per-stage reduction byte-for-byte.
+# -- host finalizers -------------------------------------------------------
+# Applied to the fetched device parts of a reducer node, whichever way it
+# was launched: every part has the batch axis leading (segments: after
+# the shard axis), a whole-query launch returns one part a node (one a
+# shape group for segments and bsi_minmax), a per-stage launch one a
+# shape group and shard slice.
 
-def _wq_sum_fin(hp, b, base):
+def _sum_fin(hp, b, base):
     total, cnt = 0, 0
     for p in hp:
         s, c_ = bsi.weighted_sum(np.asarray(p[b]))
@@ -406,24 +314,27 @@ def _wq_sum_fin(hp, b, base):
     return ValCount(total + cnt * base, cnt)
 
 
-def _wq_topn_rank(mesh, hp, b, ids, n):
+def _topn_rank(mesh, hp, b, ids, n):
     counts = mesh.merge_counts([p[b] for p in hp])
     return rank_counts(counts, n or None, ids)
 
 
-def _wq_seg_result(hp, b, groups, empty, attrs):
+def _segments(hp, b, groups, shards) -> dict:
+    """{shard: host [W] words} of params row ``b``: ``groups`` are the
+    shard lists of the parts ``hp``, in order; a shard of ``shards`` in
+    none of them holds no fragment and reads empty."""
     segs: dict[int, np.ndarray] = {}
-    zero = np.zeros(SHARD_WORDS, dtype=np.uint32)
     for shard_list, arr in zip(groups, hp):
         flat = bitset.from_tile(arr)        # [S, B, W]: a host view
         for i, shard in enumerate(shard_list):
             segs[shard] = flat[i, b]
-    for shard in empty:
-        segs[shard] = zero
-    return RowResult(segs, attrs=attrs)
+    zero = np.zeros(SHARD_WORDS, dtype=np.uint32)
+    for shard in shards:
+        segs.setdefault(shard, zero)
+    return segs
 
 
-def _wq_minmax_fin(hp, groups, base, want_max):
+def _minmax_fin(hp, groups, base, want_max):
     acc = ValCount()
     j = 0
     for shard_list in groups:
@@ -437,7 +348,7 @@ def _wq_minmax_fin(hp, groups, base, want_max):
     return acc
 
 
-def _wq_minrow_fin(hp, want_max):
+def _minrow_fin(hp, want_max):
     counts = np.asarray(hp[0][0], dtype=np.int64) if hp \
         else np.zeros(0, dtype=np.int64)
     nz = np.nonzero(counts)[0]
@@ -447,7 +358,7 @@ def _wq_minrow_fin(hp, want_max):
     return ValCount(rid, int(counts[rid]))
 
 
-def _wq_rows_fin(hp, limit, previous):
+def _rows_fin(hp, limit, previous):
     row_ids: set[int] = set()
     for p in hp:
         row_ids.update(int(i) for i in np.nonzero(np.asarray(p[0]))[0])
@@ -459,7 +370,9 @@ def _wq_rows_fin(hp, limit, previous):
     return RowIdentifiers(rows=out)
 
 
-def _wq_groupby_fin(hp, combos, last_ids, last_field, prev_ids, limit):
+def _group_rows(hp, combos, last_ids, last_field) -> list[GroupCount]:
+    """The non-empty groups of the prefix ``combos`` of one group_counts
+    launch, from its fetched [C, rows] count parts."""
     acc = None
     for p in hp:
         a = np.asarray(p, dtype=np.int64)
@@ -473,6 +386,12 @@ def _wq_groupby_fin(hp, combos, last_ids, last_field, prev_ids, limit):
                 group = [FieldRow(fn, ri) for fn, ri in combo]
                 group.append(FieldRow(last_field, rid))
                 out.append(GroupCount(group, cnt))
+    return out
+
+
+def _group_page(out: list[GroupCount], prev_ids, limit):
+    """GroupBy's answer from its groups: ordered, resumed strictly
+    after ``prev_ids``, cut to ``limit``."""
     out.sort(key=lambda g: tuple(
         (fr.field, fr.row_id) for fr in g.group))
     if prev_ids is not None:
@@ -501,8 +420,12 @@ class Executor:
         docs/batching.md) — with it off, the batcher still fronts every
         mesh dispatch but delegates directly.  ``whole_query``: compile
         each read request into ONE pjit program over the mesh
-        (parallel/wholequery.py, docs/whole-query.md); off restores the
-        legacy per-stage dispatch exactly.  ``whole_query_fallback``:
+        (parallel/wholequery.py, docs/whole-query.md); off, every call
+        (or batched call group) is lowered to the same reducer node and
+        launched per stage, one launch a node
+        (``MeshExecutor.reduce_async`` through ``batcher.reduce``): the
+        two paths share their node bodies (parallel/nodes.py).
+        ``whole_query_fallback``:
         "legacy" reroutes unsupported shapes to the per-stage path
         (counted + logged); "error" raises instead — a debugging mode
         that makes every silent slow path loud."""
@@ -773,36 +696,41 @@ class Executor:
     GROUP_GRID_MAX = 1 << 20
     GROUP_GRID_PREFIX_MAX = 16384
 
+    def _node(self, kind: str, plan, primary=(), extra=()):
+        """(reducer node, its params row [P]) of one call: ``plan`` is
+        the resolved bitmap plan (count, segments) or the optional
+        filter plan of a field reducer, slotted here."""
+        slotted, params = (None, self._EMPTY_PARAMS) if plan is None \
+            else parametrize(plan)
+        return ReduceNode(kind, slotted, primary, extra), params
+
     def _batch_desc(self, index: str, c: Call):
-        """(group_key, desc) for calls that can batch into one vmapped
-        executable with per-call params rows; None for everything else."""
+        """{node, params, finisher facts} for calls that can batch into
+        one vmapped launch with per-call params rows (same-node calls
+        group); None for everything else."""
         if c.name == "Count" and len(c.children) == 1:
-            slotted, params = parametrize(self._resolve(index,
-                                                        c.children[0]))
-            return (("count", repr(slotted)),
-                    {"kind": "count", "slotted": slotted, "params": params})
+            node, params = self._node(
+                "count", self._resolve(index, c.children[0]))
+            return {"node": node, "params": params}
         if c.name == "Sum":
             f = self._bsi_field(index, c)
-            fp = self._filter_plan(index, c)
-            slotted, params = (None, self._EMPTY_PARAMS) if fp is None \
-                else parametrize(fp)
-            return (("sum", f.name, repr(slotted)),
-                    {"kind": "sum", "slotted": slotted, "params": params,
-                     "field": f.name, "view": f.bsi_view_name(),
-                     "base": f.options.base})
+            node, params = self._node(
+                "bsi_sum", self._filter_plan(index, c),
+                (f.name, f.bsi_view_name()))
+            return {"node": node, "params": params,
+                    "base": f.options.base}
         if c.name == "TopN":
             if any(k in c.args for k in TOPN_EXTRAS):
                 return None  # extras need extra passes: per-call path
             field_name, ok = c.string_arg("_field")
             if not ok or self.holder.field(index, field_name) is None:
                 return None  # per-call path raises the proper error
-            fp = self._filter_plan(index, c)
-            slotted, params = (None, self._EMPTY_PARAMS) if fp is None \
-                else parametrize(fp)
+            node, params = self._node(
+                "row_counts", self._filter_plan(index, c),
+                (field_name, VIEW_STANDARD))
             n, _ = c.uint_arg("n")
-            return (("topn", field_name, repr(slotted)),
-                    {"kind": "topn", "slotted": slotted, "params": params,
-                     "field": field_name, "ids": c.args.get("ids"), "n": n})
+            return {"node": node, "params": params,
+                    "ids": c.args.get("ids"), "n": n}
         return None
 
     def _execute_calls_grouped(self, index: str, calls, shards):
@@ -811,32 +739,29 @@ class Executor:
         equivalent for a multi-call query (executor.go:80-110), minus N-1
         dispatch round trips."""
         descs: list = [None] * len(calls)
-        groups: dict[tuple, list[int]] = {}
+        groups: dict[str, list[int]] = {}
         for i, c in enumerate(calls):
-            kd = self._batch_desc(index, c)
-            if kd is not None:
-                key, d = kd
+            d = self._batch_desc(index, c)
+            if d is not None:
                 descs[i] = d
-                groups.setdefault(key, []).append(i)
+                groups.setdefault(repr(d["node"]), []).append(i)
 
         results: list = [None] * len(calls)
         batched: set[int] = set()
         to_run = []
-        for key, idxs in groups.items():
+        for idxs in groups.values():
             if len(idxs) < 2:
                 continue
             ds = [descs[i] for i in idxs]
-            kind = ds[0]["kind"]
+            node = ds[0]["node"]
             params_mat = np.stack([d["params"] for d in ds])
-            if kind == "sum":
-                extra = {"field": ds[0]["field"], "view": ds[0]["view"],
-                         "base": ds[0]["base"]}
-            elif kind == "topn":
-                extra = {"field": ds[0]["field"], "view": VIEW_STANDARD,
-                         "ids_n": [(d["ids"], d["n"]) for d in ds]}
+            if node.kind == "bsi_sum":
+                extra = {"base": ds[0]["base"]}
+            elif node.kind == "row_counts":
+                extra = {"ids_n": [(d["ids"], d["n"]) for d in ds]}
             else:
                 extra = None
-            to_run.append((kind, ds[0]["slotted"], params_mat, idxs, extra))
+            to_run.append((node, params_mat, idxs, extra))
             batched.update(idxs)
         # ONE invocation for every group: they share one residency-aware
         # shard schedule, so under budget pressure the whole multi-group
@@ -854,7 +779,7 @@ class Executor:
     # one params matrix per node, and the WHOLE request launches as one
     # compiled program over the mesh (parallel/wholequery.py).  Shapes
     # the program cannot express raise WholeQueryUnsupported and the
-    # request reroutes to the legacy per-stage dispatch with
+    # request reroutes to the per-stage dispatch with
     # ``wholequery.fallback`` counted and a structured log event naming
     # the unsupported node — no silent slow paths.
 
@@ -902,36 +827,24 @@ class Executor:
         temporaries against the batch-temp bound and walks the device's
         shards in blocks where the whole would not fit; only a batch
         whose temporaries do not fit over ONE stacked shard raises
-        ``batch-chunks`` and stays on the legacy chunked path, which
+        ``batch-chunks`` and stays on the per-stage chunked path, which
         cuts the batch axis."""
         return self.batcher.whole_query(self.wholequery, program, mats,
                                         self.holder, index, shards)
 
     def _wq_run_batched(self, index: str, shards, groups, results):
         """Whole-query dispatch of standard batched call groups —
-        (kind, slotted, params_mat, call_idxs, extra) with kind in
-        count/sum/topn, the _run_batched_groups contract — as ONE
-        program launch.  Used by the prepared-statement replay so a
-        whole template is one launch; raises WholeQueryUnsupported for
-        shapes the program can't take (caller falls back)."""
-        from ..core import VIEW_STANDARD as _STD
-        from .plan import ReduceNode
+        (node, params_mat, call_idxs, extra), the _run_batched_groups
+        contract — as ONE program launch.  Used by the
+        prepared-statement replay so a whole template is one launch;
+        raises WholeQueryUnsupported for shapes the program can't take
+        (caller falls back)."""
         groups = list(groups)
         if not groups:
             return
-        nodes, mats = [], []
-        for kind, slotted, params_mat, call_idxs, extra in groups:
-            if kind == "count":
-                nodes.append(ReduceNode("count", slotted))
-            elif kind == "sum":
-                nodes.append(ReduceNode(
-                    "bsi_sum", slotted, (extra["field"], extra["view"])))
-            else:  # topn
-                nodes.append(ReduceNode(
-                    "row_counts", slotted,
-                    (extra["field"], extra.get("view", _STD))))
-            mats.append(params_mat)
-        out = self._wq_dispatch(index, shards, tuple(nodes), mats)
+        nodes = tuple(g[0] for g in groups)
+        out = self._wq_dispatch(index, shards, nodes,
+                                [g[1] for g in groups])
         if self.warm_recorder is not None:
             self.warm_recorder.note_sig(out.sig)
         from ..utils import explain as qexplain
@@ -940,27 +853,9 @@ class Executor:
             "compile": "cold" if out.compiled else "warm",
             "nodes": [n.kind for n in nodes],
             "shards": len(shards)})
-        mesh = self.mesh_exec
-        for gi, (kind, slotted, params_mat, call_idxs, extra) \
-                in enumerate(groups):
-            parts = out.parts[gi]
-            if kind == "count":
-                grp = _PendingGroup.counts(parts, call_idxs)
-                for i in call_idxs:
-                    results[i] = grp
-            elif kind == "sum":
-                base = extra["base"]
-                for b, i in enumerate(call_idxs):
-                    results[i] = _Pending(
-                        parts, lambda hp, b=b, base=base:
-                        _wq_sum_fin(hp, b, base))
-            else:
-                ids_n = extra["ids_n"]
-                for b, i in enumerate(call_idxs):
-                    ids, n = ids_n[b]
-                    results[i] = _Pending(
-                        parts, lambda hp, b=b, ids=ids, n=n, mesh=mesh:
-                        _wq_topn_rank(mesh, hp, b, ids, n))
+        for gi, (node, _pm, call_idxs, extra) in enumerate(groups):
+            _wire_group(node, out.parts[gi], call_idxs, extra, 0,
+                        self.mesh_exec, results)
 
     def _wq_execute(self, index: str, calls, shards):
         """Lower every call of a read request to reducer nodes, launch
@@ -969,7 +864,6 @@ class Executor:
         anything outside the program's fallback matrix
         (docs/whole-query.md); real validation errors raise exactly as
         the legacy path would."""
-        from .plan import ReduceNode
         idx = self.holder.index(index)
         if idx is None:
             raise ExecutionError(f"index not found: {index}")
@@ -1065,8 +959,8 @@ class Executor:
 
     def _wq_wire(self, unit, out, lo, hi, results):
         """Attach _Pending finalizers for one unit's calls over its
-        nodes' device parts — each finalizer mirrors the legacy path's
-        host reduction exactly (results stay byte-identical)."""
+        nodes' device parts — the host finalizers the per-stage call
+        sites use (results stay byte-identical)."""
         kind, ds, idxs = unit["kind"], unit["descs"], unit["idxs"]
         mesh = self.mesh_exec
         if kind == "count":
@@ -1081,7 +975,8 @@ class Executor:
                 results[i] = _Pending(
                     parts, lambda hp, b=b, groups=meta["groups"],
                     empty=meta["empty"], attrs=attrs:
-                    _wq_seg_result(hp, b, groups, empty, attrs))
+                    RowResult(_segments(hp, b, groups, empty),
+                              attrs=attrs))
             return
         if kind == "sum":
             parts = out.parts[lo]
@@ -1089,7 +984,7 @@ class Executor:
                 base = ds[b]["base"]
                 results[i] = _Pending(
                     parts, lambda hp, b=b, base=base:
-                    _wq_sum_fin(hp, b, base))
+                    _sum_fin(hp, b, base))
             return
         if kind == "topn":
             d0 = ds[0]
@@ -1118,20 +1013,20 @@ class Executor:
                 out.parts[lo],
                 lambda hp, groups=out.meta[lo]["groups"],
                 base=d0["base"], want_max=d0["want_max"]:
-                _wq_minmax_fin(hp, groups, base, want_max))
+                _minmax_fin(hp, groups, base, want_max))
             return
         if kind == "minrow":
             results[idxs[0]] = _Pending(
                 out.parts[lo],
                 lambda hp, want_max=ds[0]["want_max"]:
-                _wq_minrow_fin(hp, want_max))
+                _minrow_fin(hp, want_max))
             return
         if kind == "rows":
             d0 = ds[0]
             parts = [p for j in range(lo, hi) for p in out.parts[j]]
             results[idxs[0]] = _Pending(
                 parts, lambda hp, limit=d0["limit"],
-                previous=d0["previous"]: _wq_rows_fin(hp, limit,
+                previous=d0["previous"]: _rows_fin(hp, limit,
                                                       previous))
             return
         # groupby
@@ -1141,12 +1036,12 @@ class Executor:
             lambda hp, combos=d0["combos"], last_ids=d0["last_ids"],
             last_field=d0["last_field"], prev_ids=d0["prev_ids"],
             limit=d0["limit"]:
-            _wq_groupby_fin(hp, combos, last_ids, last_field, prev_ids,
-                            limit))
+            _group_page(_group_rows(hp, combos, last_ids, last_field),
+                        prev_ids, limit))
 
     def _wq_desc(self, index: str, c: Call, shards) -> dict:
         """Lower one call to a whole-query unit descriptor, running the
-        same validation (and raising the same errors) as the legacy
+        same validation (and raising the same errors) as the per-stage
         per-call path.  Raises WholeQueryUnsupported for call shapes
         outside the program's vocabulary."""
         from ..parallel.wholequery import WholeQueryUnsupported
@@ -1296,7 +1191,7 @@ class Executor:
         rids = np.asarray([[rid for _, rid in cb] for cb in combos],
                           dtype=np.int32).reshape(len(combos),
                                                   len(prefix_fields))
-        pad_c = 1 << max(0, len(combos) - 1).bit_length()
+        pad_c = pow2_rows(len(combos))
         return {"kind": "groupby", "gkey": None, "slotted": slotted,
                 "params": params, "rids": rids, "pad_c": pad_c,
                 "prefix_keys": [(fname, VIEW_STANDARD)
@@ -1360,8 +1255,9 @@ class Executor:
 
     def _plan_segments(self, plan, index: str, shards) -> dict:
         if self.mesh_exec is not None:
-            return self.batcher.segments(plan, self.holder, index,
-                                         shards)
+            import jax
+            parts, groups = self._reduce(index, shards, "segments", plan)
+            return _segments(jax.device_get(parts), 0, groups, shards)
         # host [W] words, as the mesh path returns them: the word tile
         # is flattened after the fetch, never in a program
         return {
@@ -1372,15 +1268,33 @@ class Executor:
 
     # -- aggregations ------------------------------------------------------
 
+    def _reduce(self, index: str, shards, kind: str, plan, primary=(),
+                extra=()):
+        """One call as one reducer node at B = 1, launched per stage
+        through the dispatch batcher: (unfetched parts, their groups'
+        shard lists) — every part's batch axis holds the one row."""
+        node, params = self._node(kind, plan, primary, extra)
+        return self.batcher.reduce(
+            node, np.asarray(params, dtype=np.int32).reshape(1, -1),
+            self.holder, index, shards)
+
+    def _row_counts_now(self, index: str, shards, field_name: str,
+                        view: str) -> np.ndarray:
+        """Unfiltered per-row counts of (field, view) over ``shards``,
+        fetched and merged now (Rows and MinRow/MaxRow answer from
+        them on the spot)."""
+        parts, _ = self._reduce(index, shards, "row_counts", None,
+                                (field_name, view))
+        return self.mesh_exec.merge_counts(np.asarray(p)[0] for p in parts)
+
     def _execute_count(self, index: str, c: Call, shards) -> int:
         """(executor.go:1790 executeCount)"""
         if len(c.children) != 1:
             raise ExecutionError("Count() requires one input")
         plan = self._resolve(index, c.children[0])
         if self.mesh_exec is not None:
-            parts = self.batcher.count_async(plan, self.holder, index,
-                                             shards)
-            return _Pending(parts, lambda hp: sum(int(x) for x in hp))
+            parts, _ = self._reduce(index, shards, "count", plan)
+            return _Pending(parts, lambda hp: sum(int(x[0]) for x in hp))
         counts = [
             self.compiler.execute_shard(plan, self.holder, index, shard,
                                         reducer="count")
@@ -1429,19 +1343,11 @@ class Executor:
         f = self._bsi_field(index, c)
         view = f.bsi_view_name()
         if self.mesh_exec is not None:
-            parts = self.batcher.bsi_sum_async(
-                f.name, view, self._filter_plan(index, c), self.holder,
-                index, shards)
-
-            def _fin(hp, base=f.options.base):
-                total, n = 0, 0
-                for p in hp:
-                    s, cnt = bsi.weighted_sum(p)
-                    total += s
-                    n += cnt
-                return ValCount(total + n * base, n)
-
-            return _Pending(parts, _fin)
+            parts, _ = self._reduce(
+                index, shards, "bsi_sum", self._filter_plan(index, c),
+                (f.name, view))
+            return _Pending(parts, lambda hp, base=f.options.base:
+                            _sum_fin(hp, 0, base))
         filters = self._filter_segments(index, c, shards)
         total, n = 0, 0
         for shard in shards:
@@ -1462,15 +1368,13 @@ class Executor:
         """(executor.go:437 executeMin/:472 executeMax)"""
         f = self._bsi_field(index, c)
         view = f.bsi_view_name()
-        acc = ValCount()
         if self.mesh_exec is not None:
-            per_shard = self.batcher.bsi_min_max(
-                f.name, view, self._filter_plan(index, c), self.holder,
-                index, shards, want_max=want_max)
-            for val, cnt in per_shard:
-                vc = ValCount(val + f.options.base if cnt else 0, cnt)
-                acc = acc.larger(vc) if want_max else acc.smaller(vc)
-            return acc
+            parts, groups = self._reduce(
+                index, shards, "bsi_minmax", self._filter_plan(index, c),
+                (f.name, view), ("max" if want_max else "min",))
+            return _Pending(parts, lambda hp, base=f.options.base:
+                            _minmax_fin(hp, groups, base, want_max))
+        acc = ValCount()
         filters = self._filter_segments(index, c, shards)
         for shard in shards:
             frag = self.holder.fragment(index, f.name, view, shard)
@@ -1496,13 +1400,9 @@ class Executor:
         if f is None:
             raise ExecutionError(f"field not found: {field_name}")
         if self.mesh_exec is not None:
-            counts = self.batcher.row_counts(
-                field_name, VIEW_STANDARD, None, self.holder, index, shards)
-            nz = np.nonzero(counts)[0]
-            if nz.size == 0:
-                return ValCount(0, 0)
-            rid = int(nz[-1] if want_max else nz[0])
-            return ValCount(rid, int(counts[rid]))
+            return _minrow_fin(
+                [self._row_counts_now(index, shards, field_name,
+                                      VIEW_STANDARD)[None]], want_max)
         best, best_count = None, 0
         v = f.view(VIEW_STANDARD)
         for shard in shards:
@@ -1566,10 +1466,14 @@ class Executor:
         # cache.go rankCache hot path).  Candidate pruning stays EXACT:
         # the cache answers only when it can prove the pruned rows cannot
         # reach the top n, and otherwise this falls through to the full
-        # scan below.
+        # scan below.  Not on a multi-process mesh: the caches are
+        # host-side and hold this process's slice of the shards only,
+        # so their answer would miss every other process's rows.
         if not c.children and ids is None and tan_thresh is None \
                 and attr_name is None \
-                and f.options.cache_type in ("ranked", "lru"):
+                and f.options.cache_type in ("ranked", "lru") \
+                and not (self.mesh_exec is not None
+                         and self.mesh_exec.multiprocess):
             from ..cache.rank import topn_from_rank
             pairs = topn_from_rank(f, shards, n, stats=self.stats)
             if pairs is not None:
@@ -1582,23 +1486,24 @@ class Executor:
             # unfiltered pass + the src count, all dispatched before the
             # single blocking fetch
             filter_plan = self._filter_plan(index, c)
-            parts = self.batcher.row_counts_async(
-                field_name, VIEW_STANDARD, filter_plan,
-                self.holder, index, shards)
+            primary = (field_name, VIEW_STANDARD)
+            parts, _ = self._reduce(index, shards, "row_counts",
+                                    filter_plan, primary)
             parts_u, parts_src = [], []
             if tan_thresh:
-                parts_u = self.batcher.row_counts_async(
-                    field_name, VIEW_STANDARD, None, self.holder, index,
-                    shards)
-                parts_src = self.batcher.count_async(
-                    filter_plan, self.holder, index, shards)
+                parts_u, _ = self._reduce(index, shards, "row_counts",
+                                          None, primary)
+                parts_src, _ = self._reduce(index, shards, "count",
+                                            filter_plan)
             k, ku = len(parts), len(parts_u)
 
             def _fin(hp, ids=ids, n=n):
-                counts = self.mesh_exec.merge_counts(hp[:k])
-                row_tot = self.mesh_exec.merge_counts(hp[k: k + ku]) \
+                merge = self.mesh_exec.merge_counts
+                counts = merge(p[0] for p in hp[:k])
+                row_tot = merge(p[0] for p in hp[k: k + ku]) \
                     if tan_thresh else None
-                src = sum(int(x) for x in hp[k + ku:]) if tan_thresh else 0
+                src = sum(int(x[0]) for x in hp[k + ku:]) \
+                    if tan_thresh else 0
                 return self._topn_finalize(
                     counts, row_tot, src, ids, n, tan_thresh, attr_name,
                     attr_values, f)
@@ -1667,8 +1572,8 @@ class Executor:
             if v is None:
                 continue
             if self.mesh_exec is not None and column is None:
-                counts = self.batcher.row_counts(
-                    field_name, vname, None, self.holder, index, shards)
+                counts = self._row_counts_now(index, shards, field_name,
+                                              vname)
                 row_ids.update(int(i) for i in np.nonzero(counts)[0])
                 continue
             for shard in shards:
@@ -1700,7 +1605,7 @@ class Executor:
 
     def _group_by_parse(self, index: str, c: Call):
         """(names, rows_calls, filt_call, limit) with the reference's
-        argument validation — shared by the legacy path and the
+        argument validation — shared by the per-stage path and the
         whole-query lowering (_wq_desc_group_by)."""
         if not c.children:
             raise ExecutionError("GroupBy requires at least one Rows() child")
@@ -1790,15 +1695,6 @@ class Executor:
 
         prev_ids = self._group_by_previous(c, fields)
 
-        def _paginate(groups_out):
-            if prev_ids is not None:
-                groups_out = [
-                    g for g in groups_out
-                    if tuple(fr.row_id for fr in g.group) > prev_ids]
-            if limit is not None:
-                groups_out = groups_out[:limit]
-            return groups_out
-
         # Count each combination: per shard, AND the group rows' segments +
         # optional filter, popcount.  The innermost field is batched on
         # device; on the mesh path the whole inner loop is ONE psum'd
@@ -1827,37 +1723,34 @@ class Executor:
                 [[rid for _, rid in combo] for combo in combos],
                 dtype=np.int32).reshape(len(combos), len(prefix_fields))
             # A handful of executable invocations cover every combo
-            # (vmapped combo axis, chunked to bound device memory) — the
-            # odometer's per-combo round trips (executor.go:3058) collapse
-            # into one dispatch per 256 combos, resolved by a single fetch
-            chunked = self.batcher.group_counts_batch_async(
-                (last_field, VIEW_STANDARD), prefix_keys, mat, filter_plan,
-                self.holder, index, shards)
+            # (vmapped combo axis, chunked to bound device memory: one
+            # group_counts node a chunk of GROUP_CHUNK combos, full
+            # chunks sharing one executable) — the odometer's per-combo
+            # round trips (executor.go:3058) collapse into one dispatch
+            # per 256 combos, resolved by a single fetch
+            slotted, params = (None, self._EMPTY_PARAMS) \
+                if filter_plan is None else parametrize(filter_plan)
+            chunk = self.mesh_exec.GROUP_CHUNK
+            chunked = []
+            for lo in range(0, len(combos), chunk):
+                sub = mat[lo: lo + chunk]
+                pad_c = pow2_rows(len(sub))
+                parts, _ = self.batcher.reduce(
+                    ReduceNode("group_counts", slotted,
+                               (last_field, VIEW_STANDARD),
+                               tuple(prefix_keys) + (pad_c,)),
+                    (sub, params), self.holder, index, shards)
+                chunked.append((lo, lo + len(sub), parts))
             all_parts = [p for _, _, ps in chunked for p in ps]
 
             def _fin(hp, combos=combos, last_ids=last_ids):
                 out: list[GroupCount] = []
                 i = 0
                 for lo, hi, ps in chunked:
-                    acc = None
-                    for p in hp[i: i + len(ps)]:
-                        a = np.asarray(p, dtype=np.int64)
-                        acc = a.copy() if acc is None else acc_counts(acc, a)
+                    out += _group_rows(hp[i: i + len(ps)], combos[lo: hi],
+                                       last_ids, last_field)
                     i += len(ps)
-                    for ci in range(lo, hi):
-                        combo = combos[ci]
-                        for rid in last_ids:
-                            cnt = (int(acc[ci - lo, rid])
-                                   if acc is not None
-                                   and rid < acc.shape[1] else 0)
-                            if cnt > 0:
-                                group = [FieldRow(fn, ri)
-                                         for fn, ri in combo]
-                                group.append(FieldRow(last_field, rid))
-                                out.append(GroupCount(group, cnt))
-                out.sort(key=lambda g: tuple(
-                    (fr.field, fr.row_id) for fr in g.group))
-                return _paginate(out)
+                return _group_page(out, prev_ids, limit)
 
             return _Pending(all_parts, _fin)
 
@@ -1912,9 +1805,7 @@ class Executor:
                     group.append(FieldRow(last_field, rid))
                     results.append(GroupCount(group, int(counts_acc[j])))
 
-        results.sort(key=lambda g: tuple(
-            (fr.field, fr.row_id) for fr in g.group))
-        return _paginate(results)
+        return _group_page(results, prev_ids, limit)
 
     # -- Options (executor.go executeOptionsCall) --------------------------
 

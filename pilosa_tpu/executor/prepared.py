@@ -48,7 +48,7 @@ from ..native import fingerprint_native
 from ..pql import parse
 from ..pql.ast import LitInt, Query
 from ..utils.locks import make_lock
-from .plan import Resolver, parametrize
+from .plan import ReduceNode, Resolver, parametrize
 
 # Integer literals only: quoted strings and bare timestamps pass through
 # unchanged (they stay part of the template).  The lookaround classes keep
@@ -119,20 +119,19 @@ _EMPTY_PARAMS = np.zeros(0, dtype=np.int32)
 
 
 class _Group:
-    """One batched dispatch: B same-shape calls -> one executable invocation.
+    """One batched dispatch: B same-shape calls -> one launch of their
+    reducer node (count, bsi_sum or row_counts; parallel/nodes.py).
 
     ``build_params(values)`` reconstructs the [B, P] int32 params matrix:
     params[b, j] = (sgn*(values[lit]+add) >> shift) & mask for dynamic
     slots, the prepared constant for the rest — all vectorized.
     """
 
-    __slots__ = ("kind", "slotted", "call_idxs", "const", "lit", "add",
+    __slots__ = ("node", "call_idxs", "const", "lit", "add",
                  "sgn", "shift", "mask", "extra")
 
-    def __init__(self, kind, slotted, call_idxs, params_rows, prov_rows,
-                 extra):
-        self.kind = kind
-        self.slotted = slotted
+    def __init__(self, node, call_idxs, params_rows, prov_rows, extra):
+        self.node = node
         self.call_idxs = call_idxs
         self.extra = extra
         B = len(call_idxs)
@@ -193,8 +192,8 @@ class PreparedEntry:
     def bind(self, values: np.ndarray) -> list:
         """The call groups with this request's literals in their params:
         the last of its planning."""
-        return [(g.kind, g.slotted, g.build_params(values),
-                 g.call_idxs, g.extra) for g in self.groups]
+        return [(g.node, g.build_params(values), g.call_idxs, g.extra)
+                for g in self.groups]
 
     def run(self, ex, index: str, groups: list, shards):
         """Dispatch all groups, then resolve with one device fetch.
@@ -351,21 +350,20 @@ class PreparedCache:
             if i not in dyn_lits:
                 guards.append((i, v, v))
 
-        groups: dict[tuple, list[int]] = {}
+        groups: dict[str, list[int]] = {}
         for i, d in enumerate(descs):
-            groups.setdefault(d["key"], []).append(i)
+            groups.setdefault(repr(d["node"]), []).append(i)
         built = []
-        for key, idxs in groups.items():
+        for idxs in groups.values():
             ds = [descs[i] for i in idxs]
             extra = ds[0]["extra"]
-            if ds[0]["kind"] == "topn":
-                # the group key omits n/ids, so calls in one group may
-                # carry different ones — keep them per call, matching the
+            if ds[0]["node"].kind == "row_counts":
+                # the node omits n/ids, so calls in one group may carry
+                # different ones — keep them per call, matching the
                 # classic grouped path
-                extra = {"field": extra["field"], "view": extra["view"],
-                         "ids_n": [(d["extra"]["ids"], d["extra"]["n"])
+                extra = {"ids_n": [(d["extra"]["ids"], d["extra"]["n"])
                                    for d in ds]}
-            built.append(_Group(ds[0]["kind"], ds[0]["slotted"], idxs,
+            built.append(_Group(ds[0]["node"], idxs,
                                 [d["params"] for d in ds],
                                 [d["prov"] for d in ds], extra))
         return PreparedEntry(epoch, len(q.calls), built, guards)
@@ -389,9 +387,8 @@ class PreparedCache:
                 return None
             guards.extend(sink)
             guards.extend(pg)
-            return {"kind": "count", "key": ("count", repr(slotted)),
-                    "slotted": slotted, "params": params, "prov": prov,
-                    "extra": None}
+            return {"node": ReduceNode("count", slotted),
+                    "params": params, "prov": prov, "extra": None}
         if c.name == "Sum":
             f = ex._bsi_field(index, c)
             if c.children:
@@ -402,10 +399,10 @@ class PreparedCache:
                 return None
             guards.extend(sink)
             guards.extend(pg)
-            return {"kind": "sum", "key": ("sum", f.name, repr(slotted)),
-                    "slotted": slotted, "params": params, "prov": prov,
-                    "extra": {"field": f.name, "view": f.bsi_view_name(),
-                              "base": f.options.base}}
+            return {"node": ReduceNode("bsi_sum", slotted,
+                                       (f.name, f.bsi_view_name())),
+                    "params": params, "prov": prov,
+                    "extra": {"base": f.options.base}}
         # TopN
         from .executor import TOPN_EXTRAS
         if any(k in c.args for k in TOPN_EXTRAS):
@@ -434,7 +431,7 @@ class PreparedCache:
         if ids is not None:
             ids = [int(x) for x in ids]
         from ..core import VIEW_STANDARD
-        return {"kind": "topn", "key": ("topn", field_name, repr(slotted)),
-                "slotted": slotted, "params": params, "prov": prov,
-                "extra": {"field": field_name, "view": VIEW_STANDARD,
-                          "ids": ids, "n": n}}
+        return {"node": ReduceNode("row_counts", slotted,
+                                   (field_name, VIEW_STANDARD)),
+                "params": params, "prov": prov,
+                "extra": {"ids": ids, "n": n}}

@@ -12,20 +12,20 @@ compatible in-flight queries into ONE fused device launch — the
 continuous/dynamic-batching shape serving stacks use to amortize kernel
 dispatch.
 
-Mechanics: each per-shard reducer call (``count``, ``row_counts``,
-``bsi_sum``, ``segments``) is enqueued as a ticket keyed by its
-executable signature (reducer kind, slotted-plan repr, primary
-field/view, index, shard set, holder); a dispatcher thread drains
-compatible tickets — stacking their parametrized row/filter argument
-rows along a leading query axis, launching one jitted shard_map
-executable vmapped over that axis (mesh_exec's ``*_batch_async``
-executables), and scattering per-query result slices back to waiting
+Mechanics: each per-stage reducer node with a batch axis (``count``,
+``row_counts``, ``bsi_sum``, ``segments``; parallel/nodes.py) is enqueued
+as a ticket carrying the node and its params matrix, keyed by what its
+launch would share (node kind and repr, index, shard set, holder); a
+dispatcher thread drains compatible tickets — stacking their params rows
+along the leading query axis, launching the node ONCE over the stacked
+matrix (``MeshExecutor.reduce_async``, the node's body vmapped over that
+axis), and scattering per-ticket slices of the results back to waiting
 futures.  Launch policy is adaptive: fire when the queue reaches
 ``max_batch`` tickets or the oldest ticket has waited ``window_us``
 microseconds; fused query-axis sizes pad up to powers of two so
-compile-cache churn stays bounded.  A group that drains to a single
-singleton ticket falls through to the existing un-vmapped executables,
-so solo-query latency is unchanged (modulo the window wait).
+compile-cache churn stays bounded.  A ticket that drains alone launches
+the same node over its own rows: a lone call is B = 1 of the program a
+fused pack runs, not another executable.
 
 Deadlines (docs/robustness.md): time queued here counts against the
 query budget — tickets carry their QueryContext, and an expired or
@@ -50,8 +50,6 @@ from contextlib import contextmanager
 
 import numpy as np
 
-from ..core import SHARD_WORDS
-from ..executor.plan import parametrize, plan_inputs
 from ..utils import devobs
 from ..utils import profile as qprof
 from ..utils.deadline import DeadlineExceeded, activate, current
@@ -59,29 +57,28 @@ from ..utils.faults import FAULTS
 from ..utils.locks import make_condition
 from ..utils.stats import BucketHistogram, NopStatsClient, ReservoirTimer
 from ..utils.tracing import GLOBAL_TRACER, layer_span
-from .mesh_exec import _DISPATCH_LOCK
+from .mesh_exec import _DISPATCH_LOCK, field_rows
+from .nodes import BATCH_KINDS, ROW_BYTES, batch_temp_bound, node_keys, \
+    node_temp_rows, pad_pow2_rows
 
-_EMPTY_PARAMS = np.zeros(0, dtype=np.int32)
-
-# Total fused query-axis rows per launch: matrix tickets are pre-chunked
-# by executor._batch_chunks to keep per-device gather temps bounded, but
-# fusing k of them multiplies those temps by k — cap the fused row count
-# so a burst of large prepared batches cannot OOM the device.  A ticket
-# that alone exceeds the cap launches un-fused.
+# Total fused query-axis rows per launch: a batched call group's tickets
+# are pre-chunked by executor._batch_chunks to keep per-device gather
+# temps bounded, but fusing k of them multiplies those temps by k — cap
+# the fused row count so a burst of large prepared batches cannot OOM
+# the device.  A ticket that alone exceeds the cap launches un-fused.
 FUSED_ROWS_MAX = 4096
 
 
 class _Ticket:
-    __slots__ = ("kind", "key", "params", "scalar", "payload", "ctx",
+    __slots__ = ("kind", "key", "params", "payload", "ctx",
                  "enq", "future", "background", "trace", "prof",
                  "prof_node", "temp_weight")
 
-    def __init__(self, kind, key, params, scalar, payload, background,
+    def __init__(self, kind, key, params, payload, background,
                  temp_weight: int = 0):
         self.kind = kind
         self.key = key
         self.params = params          # [B_local, P] int32
-        self.scalar = scalar          # True: un-vmapped caller, scatter p[i]
         self.payload = payload
         # device-temp bytes one fused B-row of this ticket costs (the
         # [B, rows, W] masked temp of filtered row_counts; 0 = only the
@@ -126,13 +123,14 @@ class _RoundTimings:
 class DispatchBatcher:
     """Front door for every mesh reducer dispatch (docs/batching.md).
 
-    Request threads call the same-named wrappers below instead of the
-    MeshExecutor entry points; when batching is enabled the call becomes
-    a ticket and blocks until the dispatcher thread has LAUNCHED it
-    (results stay unfetched device arrays, preserving the executor's
-    dispatch-all-then-fetch-once pipeline).  Disabled (``dispatch-batch =
-    off``), every wrapper is a plain delegation — the explicit fallback
-    the check.sh dispatch lint allows."""
+    Request threads call ``reduce`` (one reducer node, per stage) and
+    ``whole_query`` (a request's whole program) instead of the
+    MeshExecutor and WholeQueryRunner entry points; when batching is
+    enabled the call becomes a ticket and blocks until the dispatcher
+    thread has LAUNCHED it (results stay unfetched device arrays,
+    preserving the executor's dispatch-all-then-fetch-once pipeline).
+    Disabled (``dispatch-batch = off``), both are plain delegations —
+    the explicit fallback the batcher-bypass lint allows."""
 
     def __init__(self, mesh, enabled: bool = True, max_batch: int = 32,
                  window_us: float = 200.0, stats=None):
@@ -186,11 +184,10 @@ class DispatchBatcher:
         return (self.enabled and not self.mesh.multiprocess
                 and threading.get_ident() != self._tid)
 
-    def _submit(self, kind, key, params, scalar, payload,
-                temp_weight: int = 0):
+    def _submit(self, kind, key, params, payload, temp_weight: int = 0):
         bg = getattr(self._bg_local, "flag", False)
         t = _Ticket(kind, key, np.ascontiguousarray(params, dtype=np.int32),
-                    scalar, payload, bg, temp_weight=temp_weight)
+                    payload, bg, temp_weight=temp_weight)
         with self._cond:
             if self._closed:
                 return None
@@ -228,108 +225,48 @@ class DispatchBatcher:
         with self._cond:
             return len(self._queue)
 
-    # -- public reducer surface (executor-facing) --------------------------
+    # -- the ticket surface (executor-facing) ------------------------------
 
-    def count_async(self, plan, holder, index, shards) -> list:
-        if not self._use_ticket():
-            return self.mesh.count_async(plan, holder, index, shards)
-        slotted, params = parametrize(plan)
-        out = self._submit(
-            "count",
-            ("count", repr(slotted), index, tuple(shards), id(holder)),
-            np.asarray(params, dtype=np.int32).reshape(1, -1), True,
-            {"plan": plan, "slotted": slotted, "holder": holder,
-             "index": index, "shards": list(shards)})
-        if out is None:  # closed mid-flight: direct
-            return self.mesh.count_async(plan, holder, index, shards)
-        return out
-
-    def segments(self, plan, holder, index, shards) -> dict:
-        if not self._use_ticket():
-            return self.mesh.segments(plan, holder, index, shards)
-        slotted, params = parametrize(plan)
-        out = self._submit(
-            "segments",
-            ("segments", repr(slotted), index, tuple(shards), id(holder)),
-            np.asarray(params, dtype=np.int32).reshape(1, -1), True,
-            {"plan": plan, "slotted": slotted, "holder": holder,
-             "index": index, "shards": list(shards)})
-        if out is None:
-            return self.mesh.segments(plan, holder, index, shards)
-        return out
-
-    def _filter_slotted(self, filter_plan):
-        if filter_plan is None:
-            return None, _EMPTY_PARAMS
-        return parametrize(filter_plan)
-
-    def _rowcount_weight(self, field, view, slotted, holder, index,
-                         shards) -> int:
+    def _temp_weight(self, node, holder, index, shards) -> int:
         """Per-fused-B-row device-temp bytes of a filtered row_counts
         launch ([rows, W] masked temp per stacked shard per device, by
-        executor.node_temp_rows) — the fusion packer's unit.  0 for the
-        filter-less broadcast pass (B-independent)."""
-        if slotted is None:
+        nodes.node_temp_rows) — the fusion packer's unit.  0 for the
+        filter-less broadcast pass (B-independent) and for the kinds
+        only the fused-row cap holds."""
+        if node.kind != "row_counts" or node.plan is None:
             return 0
-        from .mesh_exec import field_rows
-        from ..executor.executor import node_temp_rows
-        rows = node_temp_rows("row_counts", slotted, 0,
-                              field_rows(holder, index, field, view))
+        rows = node_temp_rows(node.kind, node.plan, 0,
+                              field_rows(holder, index, *node.primary))
         per_dev = self.mesh.stacked_per_device(max(len(shards), 1))
-        return rows * per_dev * SHARD_WORDS * 4
+        return rows * per_dev * ROW_BYTES
 
-    def row_counts_async(self, field, view, filter_plan, holder, index,
-                         shards) -> list:
-        if not self._use_ticket():
-            return self.mesh.row_counts_async(field, view, filter_plan,
-                                              holder, index, shards)
-        slotted, params = self._filter_slotted(filter_plan)
-        out = self._submit(
-            "row_counts",
-            ("row_counts", field, view, repr(slotted), index,
-             tuple(shards), id(holder)),
-            np.asarray(params, dtype=np.int32).reshape(1, -1), True,
-            {"filter_plan": filter_plan, "slotted": slotted, "field": field,
-             "view": view, "holder": holder, "index": index,
-             "shards": list(shards)},
-            temp_weight=self._rowcount_weight(field, view, slotted,
-                                              holder, index, shards))
-        if out is None:
-            return self.mesh.row_counts_async(field, view, filter_plan,
-                                              holder, index, shards)
-        return out
-
-    def row_counts(self, field, view, filter_plan, holder, index,
-                   shards) -> np.ndarray:
-        return self.mesh.merge_counts(self.row_counts_async(
-            field, view, filter_plan, holder, index, shards))
-
-    def bsi_sum_async(self, field, view, filter_plan, holder, index,
-                      shards) -> list:
-        if not self._use_ticket():
-            return self.mesh.bsi_sum_async(field, view, filter_plan,
-                                           holder, index, shards)
-        slotted, params = self._filter_slotted(filter_plan)
-        out = self._submit(
-            "bsi_sum",
-            ("bsi_sum", field, view, repr(slotted), index, tuple(shards),
-             id(holder)),
-            np.asarray(params, dtype=np.int32).reshape(1, -1), True,
-            {"filter_plan": filter_plan, "slotted": slotted, "field": field,
-             "view": view, "holder": holder, "index": index,
-             "shards": list(shards)})
-        if out is None:
-            return self.mesh.bsi_sum_async(field, view, filter_plan,
-                                           holder, index, shards)
-        return out
-
-    # untouched-by-fusion reducers: explicit fallbacks so every dispatch
-    # still flows through one front door (check.sh lint)
-    def bsi_min_max(self, *args, **kwargs):
-        return self.mesh.bsi_min_max(*args, **kwargs)
-
-    def group_counts_batch_async(self, *args, **kwargs):
-        return self.mesh.group_counts_batch_async(*args, **kwargs)
+    def reduce(self, node, mat, holder, index, shards,
+               scheduled: bool = False, fuse: bool = True):
+        """One reducer node (parallel/nodes.py) with its params matrix
+        ``mat`` [B, P] over ``shards``, per stage: what
+        ``MeshExecutor.reduce_async`` returns, (unfetched parts, their
+        groups' shard lists).  A single call is B = 1; a batched call
+        group brings its B rows.  A node with a batch axis becomes a
+        ticket that fuses with every other of its key — the node's
+        repr, index, shard set and holder — whatever their row counts;
+        bsi_minmax and group_counts have none and launch from the
+        calling thread, as every node does with batching off, on a
+        multi-process mesh and with ``fuse`` off (a caller that walks a
+        multi-slice schedule of its own).  ``scheduled``: ``shards`` is
+        a slice of the caller's shard schedule, not to be scheduled
+        again."""
+        if fuse and node.kind in BATCH_KINDS and self._use_ticket():
+            out = self._submit(
+                node.kind,
+                (node.kind, repr(node), index, tuple(shards), id(holder)),
+                mat,
+                {"node": node, "holder": holder, "index": index,
+                 "shards": list(shards), "scheduled": scheduled},
+                temp_weight=self._temp_weight(node, holder, index, shards))
+            if out is not None:     # None: closed mid-flight, go direct
+                return out
+        return self.mesh.reduce_async(node, mat, holder, index, shards,
+                                      scheduled=scheduled)
 
     # -- whole-query programs (docs/whole-query.md) ------------------------
 
@@ -358,63 +295,12 @@ class DispatchBatcher:
                    for m in mats)
         out = self._submit(
             "wholequery", key,
-            np.zeros((max(rows, 1), 0), dtype=np.int32), False,
+            np.zeros((max(rows, 1), 0), dtype=np.int32),
             {"runner": runner, "program": program, "mats": mats,
              "holder": holder, "index": index, "shards": list(shards)})
         if out is None:  # closed mid-flight: direct
             return runner.run(program, mats, holder, index, shards)
         return out
-
-    # -- matrix surface (_run_batched_groups / prepared replay) ------------
-
-    def count_batch(self, slotted, params_mat, holder, index, shards,
-                    fuse: bool = True) -> list:
-        params_mat = np.asarray(params_mat, dtype=np.int32)
-        if fuse and self._use_ticket():
-            out = self._submit(
-                "count",
-                ("count", repr(slotted), index, tuple(shards), id(holder)),
-                params_mat, False,
-                {"slotted": slotted, "holder": holder, "index": index,
-                 "shards": list(shards)})
-            if out is not None:
-                return out
-        return self.mesh.count_batch_async(slotted, params_mat, holder,
-                                           index, shards)
-
-    def row_counts_batch(self, field, view, slotted, params_mat, holder,
-                         index, shards, fuse: bool = True) -> list:
-        params_mat = np.asarray(params_mat, dtype=np.int32)
-        if fuse and self._use_ticket():
-            out = self._submit(
-                "row_counts",
-                ("row_counts", field, view, repr(slotted), index,
-                 tuple(shards), id(holder)),
-                params_mat, False,
-                {"slotted": slotted, "field": field, "view": view,
-                 "holder": holder, "index": index, "shards": list(shards)},
-                temp_weight=self._rowcount_weight(field, view, slotted,
-                                                  holder, index, shards))
-            if out is not None:
-                return out
-        return self.mesh.row_counts_batch_async(
-            field, view, slotted, params_mat, holder, index, shards)
-
-    def bsi_sum_batch(self, field, view, slotted, params_mat, holder,
-                      index, shards, fuse: bool = True) -> list:
-        params_mat = np.asarray(params_mat, dtype=np.int32)
-        if fuse and self._use_ticket():
-            out = self._submit(
-                "bsi_sum",
-                ("bsi_sum", field, view, repr(slotted), index,
-                 tuple(shards), id(holder)),
-                params_mat, False,
-                {"slotted": slotted, "field": field, "view": view,
-                 "holder": holder, "index": index, "shards": list(shards)})
-            if out is not None:
-                return out
-        return self.mesh.bsi_sum_batch_async(
-            field, view, slotted, params_mat, holder, index, shards)
 
     # -- dispatcher --------------------------------------------------------
 
@@ -481,7 +367,6 @@ class DispatchBatcher:
                 self.stats.count("dispatch.expired_drop")
                 continue
             groups.setdefault(t.key, []).append(t)
-        from ..executor.executor import batch_temp_bound
         bound = batch_temp_bound()
         for key, tickets in groups.items():
             # foreground first, then pack under the ticket, fused-row,
@@ -583,45 +468,15 @@ class DispatchBatcher:
         self._launch_fused(kind, tickets, queue_s)
 
     def _direct(self, t):
-        """Un-fused launch: scalar tickets take the existing un-vmapped
-        executables (solo-query latency unchanged); matrix tickets take
-        their batch executable directly."""
+        """A lone ticket's launch: the node over its own rows (B = 1
+        for a single call), or the whole-query program."""
         p = t.payload
-        mesh = self.mesh
         if t.kind == "wholequery":
             return p["runner"].run(p["program"], p["mats"], p["holder"],
                                    p["index"], p["shards"])
-        if t.scalar:
-            if t.kind == "count":
-                return mesh.count_async(p["plan"], p["holder"], p["index"],
-                                        p["shards"])
-            if t.kind == "segments":
-                return mesh.segments(p["plan"], p["holder"], p["index"],
-                                     p["shards"])
-            if t.kind == "row_counts":
-                return mesh.row_counts_async(
-                    p["field"], p["view"], p["filter_plan"], p["holder"],
-                    p["index"], p["shards"])
-            return mesh.bsi_sum_async(
-                p["field"], p["view"], p["filter_plan"], p["holder"],
-                p["index"], p["shards"])
-        if t.kind == "count":
-            return mesh.count_batch_async(p["slotted"], t.params,
-                                          p["holder"], p["index"],
-                                          p["shards"])
-        if t.kind == "row_counts":
-            return mesh.row_counts_batch_async(
-                p["field"], p["view"], p["slotted"], t.params, p["holder"],
-                p["index"], p["shards"])
-        return mesh.bsi_sum_batch_async(
-            p["field"], p["view"], p["slotted"], t.params, p["holder"],
-            p["index"], p["shards"])
-
-    def _group_key_lists(self, kind, p):
-        if kind in ("count", "segments"):
-            return [plan_inputs(p["slotted"])]
-        return [self.mesh.batch_keys((p["field"], p["view"]),
-                                     p["slotted"])]
+        return self.mesh.reduce_async(
+            p["node"], t.params, p["holder"], p["index"], p["shards"],
+            scheduled=p["scheduled"])
 
     def _note_fused(self, tickets, dur_s, batch_rows=0, padded_rows=0):
         """Attribute one fused launch back to EVERY participating query:
@@ -665,7 +520,7 @@ class DispatchBatcher:
             # no pre-schedule here: runner.run's precheck walks the
             # shard schedule exactly once; an over-budget working set
             # raises WholeQueryUnsupported into every waiter below and
-            # the executors reroute to the legacy streaming path
+            # the executors reroute to the per-stage streaming path
             n_nodes = len(program)
             node_mats, node_lo = [], []
             for ni in range(n_nodes):
@@ -717,6 +572,7 @@ class DispatchBatcher:
         if kind == "wholequery":
             return self._launch_fused_whole(tickets, queue_s)
         p0 = tickets[0].payload
+        node, holder, index = p0["node"], p0["holder"], p0["index"]
         mesh = self.mesh
         t_launch0 = time.perf_counter()
         try:
@@ -725,8 +581,7 @@ class DispatchBatcher:
             # so stream each ticket through its direct path instead
             with layer_span("dispatch.place", devobs.LEDGER):
                 sched = mesh.shard_schedule(
-                    p0["holder"], p0["index"],
-                    self._group_key_lists(kind, p0), p0["shards"])
+                    holder, index, [node_keys(node)], p0["shards"])
             if len(sched.slices) > 1:
                 self.stream_fallbacks += 1
                 self.stats.count("dispatch.launch.stream_fallback")
@@ -736,34 +591,21 @@ class DispatchBatcher:
             mats = [t.params for t in tickets]
             mat = np.concatenate(mats) if len(mats) > 1 else mats[0]
             B = mat.shape[0]
-            pad = 1 << max(0, B - 1).bit_length()
-            if pad != B:  # pow-2 query axis bounds compile-cache churn
-                mat = np.concatenate(
-                    [mat, np.repeat(mat[-1:], pad - B, axis=0)])
+            # pow-2 query axis bounds compile-cache churn
+            mat = pad_pow2_rows(mat)
             # one failpoint/chaos gate per fused launch, matching the
             # per-slice gate of the direct path
-            FAULTS.hit("mesh.slice", key=p0["index"])
+            FAULTS.hit("mesh.slice", key=index)
             # launch ledger context: the queued wait and the ACTUAL fused
             # row count ride into the device launch so padding waste is
             # measured, not inferred (docs/observability.md)
             ltok = devobs.set_launch_ctx(queue_s=queue_s,
                                          tickets=len(tickets), rows=B)
             try:
-                if kind == "count":
-                    parts = mesh.count_batch_async(
-                        p0["slotted"], mat, p0["holder"], p0["index"],
-                        p0["shards"])
-                elif kind == "row_counts":
-                    parts = mesh.row_counts_batch_async(
-                        p0["field"], p0["view"], p0["slotted"], mat,
-                        p0["holder"], p0["index"], p0["shards"])
-                elif kind == "bsi_sum":
-                    parts = mesh.bsi_sum_batch_async(
-                        p0["field"], p0["view"], p0["slotted"], mat,
-                        p0["holder"], p0["index"], p0["shards"])
-                else:  # segments
-                    self._scatter_segments(tickets, mat, p0, pad - B)
-                    return
+                # the schedule is this launch's own: one slice, so the
+                # launcher takes the shards as scheduled
+                parts, groups = mesh.reduce_async(
+                    node, mat, holder, index, p0["shards"], scheduled=True)
             finally:
                 devobs.reset_launch_ctx(ltok)
             # attribute the launch BEFORE resolving any future: once a
@@ -771,46 +613,27 @@ class DispatchBatcher:
             # tree, and late appends would race that (profile.py's
             # owner-blocked invariant)
             self._note_fused(tickets, time.perf_counter() - t_launch0,
-                             batch_rows=B, padded_rows=pad - B)
-            # scatter: per-ticket views into the fused device results.
-            # Outputs are replicated (psum, P() specs), so slicing is a
-            # local per-device gather — but hold the collective-launch
-            # lock anyway to keep one global program-enqueue order.
+                             batch_rows=B, padded_rows=mat.shape[0] - B)
+            # scatter: per-ticket views into the fused device results,
+            # cut along the batch axis (segments keep the shard axis
+            # first).  Summed outputs are replicated (psum, P() specs),
+            # so slicing is a local per-device gather — but hold the
+            # collective-launch lock anyway to keep one global
+            # program-enqueue order.
             with _DISPATCH_LOCK, layer_span(
                     "dispatch.scatter", self._round_stats,
                     tickets=len(tickets)):
                 lo = 0
                 for t in tickets:
-                    n = t.params.shape[0]
-                    if t.scalar:
-                        t.future.set_result([part[lo] for part in parts])
-                    else:
-                        t.future.set_result(
-                            [part[lo: lo + n] for part in parts])
-                    lo += n
+                    rows = slice(lo, lo + t.params.shape[0])
+                    at = (slice(None), rows) if kind == "segments" else rows
+                    t.future.set_result(
+                        ([part[at] for part in parts], groups))
+                    lo = rows.stop
         except BaseException as e:
             self._fail_all(tickets, e if isinstance(e, Exception)
                            else RuntimeError(repr(e)))
             return
-        self.fused_launches += 1
-        self.stats.count("dispatch.launch.fused")
-        self.stats.count("dispatch.fused_queries", len(tickets))
-
-    def _scatter_segments(self, tickets, mat, p0, padded_rows=0):
-        t_launch0 = time.perf_counter()
-        by_shard = self.mesh.segments_batch(
-            p0["slotted"], mat, p0["holder"], p0["index"], p0["shards"])
-        # as in _launch_fused: attribute before any future resolves
-        self._note_fused(tickets, time.perf_counter() - t_launch0,
-                         batch_rows=mat.shape[0] - padded_rows,
-                         padded_rows=padded_rows)
-        with layer_span("dispatch.scatter", self._round_stats,
-                        tickets=len(tickets)):
-            lo = 0
-            for t in tickets:  # segments tickets are always scalar (B=1)
-                t.future.set_result(
-                    {shard: arr[lo] for shard, arr in by_shard.items()})
-                lo += t.params.shape[0]
         self.fused_launches += 1
         self.stats.count("dispatch.launch.fused")
         self.stats.count("dispatch.fused_queries", len(tickets))

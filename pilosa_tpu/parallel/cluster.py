@@ -44,7 +44,7 @@ from typing import Any
 import numpy as np
 
 from ..core import SHARD_WIDTH, SHARD_WORDS
-from ..executor.executor import TOPN_EXTRAS
+from ..executor import TOPN_EXTRAS
 from ..executor.results import (
     GroupCount, FieldRow, Pair, RowIdentifiers, RowResult, ValCount,
     merge_pairs, sort_pairs,
@@ -1863,7 +1863,7 @@ class Cluster:
         """Write-call names inside ``c``, looking through Options
         wrappers (Options(Set(...)) must not slip past the resize write
         block)."""
-        from ..executor.executor import WRITE_CALLS
+        from ..executor import WRITE_CALLS
         if c.name in WRITE_CALLS:
             yield c.name
         elif c.name == "Options":
@@ -1876,7 +1876,7 @@ class Cluster:
         must keep execution order, Options can override shards per call,
         and TopN extras need the coordinator's global finalize — those
         stay on the per-call path."""
-        from ..executor.executor import WRITE_CALLS
+        from ..executor import WRITE_CALLS
         if c.name in WRITE_CALLS or c.name == "Options":
             return False
         if c.name == "TopN" and any(k in c.args for k in TOPN_EXTRAS):
@@ -2411,7 +2411,7 @@ class Cluster:
         n-stripping — apply to the real call, not the wrapper) and shape
         the merged result here (executor.go:340-403; attr stores are
         replicated on every node)."""
-        from ..executor.executor import Executor
+        from ..executor import Executor
 
         if len(c.children) != 1:
             raise ClusterError("Options() requires exactly one child")
@@ -2458,7 +2458,7 @@ class Cluster:
         out raw filtered counts (plus, for tanimoto, the unfiltered counts
         and the source-row count), then applies Executor._topn_finalize on
         the merged totals (fragment.go:1704 semantics, exact)."""
-        from ..executor.executor import Executor, topn_extras
+        from ..executor import Executor, topn_extras
 
         tan_thresh, attr_name, attr_values = topn_extras(c)
         base = c.clone()

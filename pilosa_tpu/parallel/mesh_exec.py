@@ -11,26 +11,12 @@ XLA computation under shard_map: each device runs the vmapped plan on its
 local shard block and cross-shard reductions ride ICI collectives (psum)
 instead of host gather — the star reduce becomes an all-reduce.
 
-Reducers (each one compiled executable per input-shape signature):
-
-* ``count``      — popcount-sum of the plan result, psum over shards
-                   (Count; executor.go:1790).
-* ``segments``   — raw per-shard plan results (bitmap calls).
-* ``row_counts`` — per-row popcounts of a field fragment masked by an
-                   optional filter plan, psum over shards (TopN phase,
-                   Rows, MinRow/MaxRow; fragment.go:1570 top).
-* ``bsi_sum``    — per-bit-slice popcounts of a BSI fragment under an
-                   optional filter, psum over shards; host does the exact
-                   2^i weighting (Sum; fragment.go:1111).
-* ``bsi_min_max``— per-shard MSB-first extremum scan, gathered to host
-                   for the final (tiny) cross-shard reduce (Min/Max;
-                   fragment.go:1147).
-* ``group_counts`` — per-row popcounts of a field fragment masked by the
-                   intersection of dynamically-indexed prefix rows + an
-                   optional filter plan, psum over shards (GroupBy inner
-                   loop; executor.go:1068).  Prefix row ids are dynamic
-                   arguments so every combo of a GroupBy shares ONE
-                   compiled executable.
+The reducers are the six node kinds of parallel/nodes.py (count,
+segments, row_counts, bsi_sum, bsi_minmax, group_counts), each defined
+there once: ``reduce_async`` below launches one node per stage — one
+compiled executable per input-shape signature, the node's per-shard body
+vmapped over the device's shards — and parallel/wholequery.py launches a
+whole request's nodes as one program over the same body.
 
 On a single device this degrades gracefully to one stacked call (one
 dispatch instead of one per shard).
@@ -74,14 +60,16 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS, WORD_TILE
-from ..ops import bitset, bsi
-from ..executor.plan import eval_plan, parametrize, plan_inputs
+from ..ops import bitset
+from ..executor.plan import eval_plan
 from ..utils import devobs as _devobs
 from ..utils import profile as qprof
 from ..utils.deadline import check_current
 from ..utils.faults import FAULTS
 from ..utils.locks import make_lock, make_rlock
 from ..utils.tracing import GLOBAL_TRACER, layer_span
+from .nodes import PER_SHARD_KINDS, mat_rows, node_keys, node_shard, \
+    pad_pow2_rows, participates
 
 SHARD_AXIS = "shards"
 
@@ -91,13 +79,6 @@ SHARD_AXIS = "shards"
 # transient dense tiles one executable materialises.  Process-wide, set
 # from the server config (decode-workspace-mb) like DEFAULT_BUDGET.
 DECODE_WORKSPACE_BYTES = 1 << 30
-
-
-def _sig_rows(shape) -> int:
-    """Row count of a per-key group-signature entry — dense entries are
-    the device shape (rows, 256, 128), compressed ones
-    ('z', rows, C, P, A, R)."""
-    return shape[1] if shape[0] == "z" else shape[0]
 
 
 def _flatten_present(present):
@@ -204,7 +185,7 @@ class _InstrumentedExec:
     batcher, and the streaming slice position installed by
     _ShardSchedule."""
 
-    __slots__ = ("fn", "sig", "kind", "detail", "n_fixed",
+    __slots__ = ("fn", "sig", "kind", "detail",
                  "decode_per_shard", "kernels_per_shard",
                  "kernel_tiles_per_shard")
 
@@ -214,8 +195,6 @@ class _InstrumentedExec:
         self.kind = key[0] if key and isinstance(key[0], str) else "exec"
         self.sig = _devobs.sig_of(key)
         self.detail = repr(key[1])[:120] if len(key) > 1 else ""
-        # leading replicated (P()) args before the stacked fragment args
-        self.n_fixed = 2 if self.kind == "group_countsB" else 1
         # transient dense tiles this executable decodes per stacked
         # shard row (compressed layout entries expand inside the launch).
         # Pallas-backend entries don't materialise that workspace — they
@@ -232,15 +211,14 @@ class _InstrumentedExec:
 
     def __call__(self, *args, _launch_meta=None):
         # call-site meta: actual shard count, or (shards, actual batch
-        # rows) where the call site pads its own batch axis outside the
-        # batcher (group_countsB's pow-2 combo padding)
+        # rows) where the launcher pads the batch axis itself
+        # (group_counts' pow-2 combo padding).  args: the node's params
+        # matrix (replicated), then the stacked fragment arrays
         meta_rows = None
         if isinstance(_launch_meta, tuple):
             _launch_meta, meta_rows = _launch_meta
-        params = args[0] if self.kind == "group_countsB" \
-            else args[self.n_fixed - 1]
-        b_pad = params.shape[0] if getattr(params, "ndim", 0) == 2 else 1
-        stacked = args[self.n_fixed] if len(args) > self.n_fixed else None
+        b_pad = mat_rows(args[0])
+        stacked = args[1] if len(args) > 1 else None
         shards_pad = stacked.shape[0] if stacked is not None else 0
         shards = _launch_meta if _launch_meta is not None else shards_pad
         ctx = _devobs.launch_ctx() or {}
@@ -451,56 +429,6 @@ class MeshExecutor:
         # signal that must stay trustworthy)
         return (kind, repr(plan), tuple(input_keys), tuple(shapes),
                 tuple(extra), self._exec_seq)
-
-    def _compiled(self, slotted_plan, input_keys, shapes, layout, reducer):
-        """``slotted_plan`` comes from ``parametrize``: the executable is
-        keyed by plan SHAPE; row ids / predicate bits ride in the params
-        vector (replicated across the mesh, P() spec).  ``layout`` (from
-        _flatten_present, fully determined by ``shapes``) maps the flat
-        device args back to per-key dense fragments, decoding compressed
-        entries inside the executable."""
-        key = self._plan_key(reducer or "segments", slotted_plan, input_keys,
-                             shapes)
-        fn = self._cache.get(key)
-        if fn is not None:
-            return fn
-        n_args = sum(n for _, n, _ in layout)
-
-        # input_keys here are only the PRESENT fragments; missing ones are
-        # omitted from the arg list entirely (shard_map specs must map 1:1
-        # to array args)
-        def per_shard(params, *arrays):
-            frags = _unpack_frags(layout, arrays)
-            return eval_plan(slotted_plan, frags, params)
-
-        vmapped = jax.vmap(per_shard,
-                           in_axes=(None,) + (0,) * n_args)
-
-        if reducer == "count":
-            def block_fn(params, *arrays):
-                segs = vmapped(params, *arrays)  # [S_local, 256, 128]
-                local = bitset.count(segs)
-                return jax.lax.psum(local, axis_name=SHARD_AXIS)
-
-            out_specs = P()
-        elif self.multiprocess:
-            def block_fn(params, *arrays):
-                segs = vmapped(params, *arrays)    # [S_local, 256, 128]
-                return jax.lax.all_gather(segs, SHARD_AXIS, tiled=True)
-
-            in_specs = (P(),) + tuple(P(SHARD_AXIS)
-                                      for _ in range(n_args))
-            return self._jit_shard_map(key, block_fn, in_specs, P(),
-                                       check_vma=False, layout=layout)
-        else:
-            def block_fn(params, *arrays):
-                return vmapped(params, *arrays)    # [S_local, 256, 128]
-
-            out_specs = P(SHARD_AXIS)
-
-        in_specs = (P(),) + tuple(P(SHARD_AXIS) for _ in range(n_args))
-        return self._jit_shard_map(key, block_fn, in_specs, out_specs,
-                                   layout=layout)
 
     # -- shard grouping ----------------------------------------------------
 
@@ -1039,19 +967,6 @@ class MeshExecutor:
         return [(k, a, s) for k, a, s in zip(keys, placed, sig)
                 if s is not None]
 
-    def _filter_keys(self, filter_plan) -> list[tuple[str, str]]:
-        return plan_inputs(filter_plan) if filter_plan is not None else []
-
-    def batch_keys(self, primary: tuple[str, str],
-                   filter_plan) -> list[tuple[str, str]]:
-        """The exact stacked key list for a primary-fragment dispatch
-        with an optional (slotted) filter plan.  The ONLY definition —
-        executor._group_key_list calls this so the shard schedule
-        prefetches and pins precisely the stacks the dispatch reads; a
-        divergent copy would silently turn prefetching into waste."""
-        return [primary] + [k for k in self._filter_keys(filter_plan)
-                            if k != primary]
-
     # -- out-of-core shard streaming --------------------------------------
 
     # Slice target as a fraction of the budget: half, so the next slice
@@ -1161,143 +1076,125 @@ class MeshExecutor:
         for sl in self.shard_schedule(holder, index, [keys], shards):
             yield from self._placed_groups(keys, holder, index, sl)
 
-    # -- public entry points ----------------------------------------------
+    # -- the per-stage launcher -------------------------------------------
 
-    def count_async(self, plan, holder, index, shards) -> list:
-        """Dispatch the count computation; returns unblocked device scalars
-        (one per shape group).  jax's async dispatch lets a batch of calls
-        overlap on device; block once via int() at the end
-        (``Executor.execute`` resolves all calls' pendings after dispatch)."""
-        keys = plan_inputs(plan)
-        slotted, params = parametrize(plan)
-        params = jnp.asarray(params)
-        parts = []
-        for shard_list, placed, sig in self._stream_groups(
-                keys, holder, index, shards):
-            if all(s is None for s in sig):
-                continue  # no fragments -> plan evaluates to empty
-            present = self._present(keys, placed, sig)
-            flat, layout = _flatten_present(present)
-            fn = self._compiled(slotted, tuple(k for k, _, _ in present),
-                                tuple(s for _, _, s in present), layout,
-                                "count")
-            with _DISPATCH_LOCK:
-                parts.append(fn(params, *flat,
-                                _launch_meta=len(shard_list)))
-        return parts
+    # Max combos per group_counts launch: bounds the [S_local, chunk,
+    # rows] int32 intermediate (8 stacked shards x 256 combos x 1024 rows
+    # = 8 MB) so a large odometer cannot OOM HBM; full chunks share one
+    # executable.  A GroupBy past one chunk is cut by the executor
+    # (``_execute_group_by``), one node a chunk.
+    GROUP_CHUNK = 256
 
-    def count(self, plan, holder, index, shards) -> int:
-        return sum(int(x) for x in self.count_async(
-            plan, holder, index, shards))
+    def reduce_async(self, node, mat, holder, index, shards,
+                     scheduled: bool = False) -> tuple[list, list]:
+        """Launch one reducer node (parallel/nodes.py) over ``shards``,
+        per stage: one executable invocation per shape group (and per
+        shard slice of an over-budget working set).  ``mat`` is the
+        node's int32 params matrix [B, P] — a single call is B = 1, B
+        same-shape calls ride one vmapped invocation — or, for
+        group_counts, the pair (prefix row ids [C, Pk], filter params
+        [P]); the combo axis is padded to a power of two here.
 
-    def segments(self, plan, holder, index, shards) -> dict[int, np.ndarray]:
-        from ..core import SHARD_WORDS
+        Walks ``_stream_groups`` unless the caller owns the slice
+        schedule (``scheduled``: _run_batched_groups and the batcher's
+        fused launch pass pre-scheduled shard slices — re-scheduling
+        would re-walk the holder per (group x chunk)).
 
-        keys = plan_inputs(plan)
-        slotted, params = parametrize(plan)
-        params = jnp.asarray(params)
-        out: dict[int, np.ndarray] = {}
-        for shard_list, placed, sig in self._stream_groups(
-                keys, holder, index, shards):
-            if all(s is None for s in sig):
-                zero = np.zeros(SHARD_WORDS, dtype=np.uint32)
-                for shard in shard_list:
-                    out[shard] = zero
+        Returns (parts, groups): the unfetched device outputs of the
+        groups that contribute, batch axis leading — [B, ...] summed
+        over the shards (count, row_counts, bsi_sum, group_counts), or
+        per shard: segments [S, B, 256, 128], bsi_minmax three arrays
+        (bits, neg, cnt) a group — and those groups' shard lists, in the
+        same order.  jax's async dispatch lets a batch of calls overlap
+        on device; ``Executor.execute`` fetches all calls' parts once."""
+        keys = node_keys(node)
+        rows = None
+        if node.kind == "group_counts":
+            rids = np.asarray(mat[0], dtype=np.int32)
+            rows = rids.shape[0]
+            mat = (jnp.asarray(pad_pow2_rows(rids, repeat=False)),
+                   jnp.asarray(mat[1]))
+        else:
+            mat = jnp.asarray(mat)
+        walk = self._placed_groups if scheduled else self._stream_groups
+        parts, groups = [], []
+        for shard_list, placed, sig in walk(keys, holder, index, shards):
+            if not participates(node, dict(zip(keys, sig))):
                 continue
             present = self._present(keys, placed, sig)
             flat, layout = _flatten_present(present)
-            fn = self._compiled(slotted, tuple(k for k, _, _ in present),
-                                tuple(s for _, _, s in present), layout,
-                                None)
-            with _DISPATCH_LOCK:
-                segs = fn(params, *flat, _launch_meta=len(shard_list))
-            # ONE addressable-shard host assembly.  Indexing the sharded
-            # output per row (`segs[i]`) launched a collective reshard
-            # program per shard, and per-row collectives from concurrent
-            # request threads wedged XLA's device queues (rendezvous
-            # circular wait); device_get copies shards with no collective.
-            # Consumers (serialization, Store, filter masks) all coerce
-            # to host or mix numpy into jnp ops anyway.
-            # (the word tile is flattened here, on the host: a view)
-            host = bitset.from_tile(np.asarray(jax.device_get(segs)))
-            for i, shard in enumerate(shard_list):
-                out[shard] = host[i]
-        return out
-
-    def segments_batch(self, slotted, params_mat, holder, index,
-                       shards) -> dict[int, np.ndarray]:
-        """B same-shape bitmap plans in one executable invocation: the
-        query-axis variant of ``segments`` for the dispatch batcher
-        (parallel/batcher.py).  Returns {shard: [B, W] host array};
-        caller b's segment for a shard is ``out[shard][b]``.  Host
-        assembly mirrors ``segments`` (one device_get per shape group, no
-        per-row collectives)."""
-        keys = plan_inputs(slotted)
-        params = jnp.asarray(params_mat)
-        B = params.shape[0]
-        out: dict[int, np.ndarray] = {}
-        # pre-scheduled single-slice callers only (the batcher checks the
-        # shard schedule before fusing); multi-slice working sets stream
-        # through the un-fused ``segments`` path instead
-        for shard_list, placed, sig in self._placed_groups(
-                keys, holder, index, shards):
-            if all(s is None for s in sig):
-                zero = np.zeros((B, SHARD_WORDS), dtype=np.uint32)
-                for shard in shard_list:
-                    out[shard] = zero
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("segmentsB", slotted, pkeys, pshapes)
+            key = self._plan_key(node.kind, node.plan,
+                                 (k for k, _, _ in present),
+                                 (s for _, _, s in present),
+                                 extra=node.extra)
             fn = self._cache.get(key)
             if fn is None:
-                # Loop-local values (layout, per_shard, len(flat)) are
-                # FROZEN into the closures as keyword defaults, here and
-                # in every executable builder below: jax re-traces a
-                # cached executable when a later call changes the stacked
-                # group size, and a re-trace reads the closure CELLS —
-                # which a later loop iteration has rebound to the next
-                # group's values.  A compressed group re-traced with
-                # another group's layout decodes with the wrong
-                # container buckets (e.g. r_bucket=0 silently drops
-                # every run container).
-                def per_shard(params_, *arrays, _layout=layout):
-                    frags = _unpack_frags(_layout, arrays)
-                    return jax.vmap(
-                        lambda p: eval_plan(slotted, frags, p))(
-                            params_)               # [B, 256, 128]
-
-                vmapped = jax.vmap(per_shard,
-                                   in_axes=(None,) + (0,) * len(flat))
-                if self.multiprocess:
-                    def block_fn(params_, *arrays, _vm=vmapped):
-                        segs = _vm(params_, *arrays)   # [S_local, B, tile]
-                        return jax.lax.all_gather(segs, SHARD_AXIS,
-                                                  tiled=True)
-
-                    fn = self._jit_shard_map(
-                        key, block_fn,
-                        (P(),) + tuple(P(SHARD_AXIS) for _ in flat),
-                        P(), check_vma=False, layout=layout)
-                else:
-                    def block_fn(params_, *arrays, _vm=vmapped):
-                        return _vm(params_, *arrays)   # [S_local, B, tile]
-
-                    fn = self._jit_shard_map(
-                        key, block_fn,
-                        (P(),) + tuple(P(SHARD_AXIS) for _ in flat),
-                        P(SHARD_AXIS), layout=layout)
+                fn = self._build(key, node, layout)
+            # (shards, C): group_counts' pow-2 combo padding must count
+            # as padding waste, not actual work
+            meta = len(shard_list) if rows is None \
+                else (len(shard_list), rows)
             with _DISPATCH_LOCK:
-                segs = fn(params, *flat, _launch_meta=len(shard_list))
-            host = bitset.from_tile(
-                np.asarray(jax.device_get(segs)))      # [S, B, W]
-            for i, shard in enumerate(shard_list):
-                out[shard] = host[i]
-        return out
+                out = fn(mat, *flat, _launch_meta=meta)
+            if isinstance(out, tuple):
+                parts.extend(out)
+            else:
+                parts.append(out)
+            groups.append(shard_list)
+        return parts, groups
 
-    # -- row_counts: TopN/Rows/MinRow/MaxRow (fragment.go:1570 top) --------
+    def _build(self, key, node, layout):
+        """The per-stage executable of ``node`` over one shape group:
+        ``nodes.node_shard`` — the body the whole-query program traces —
+        vmapped over the device's shards under one of two combines, by
+        the kind: summed over them and ``psum``ed to every device, or
+        left per shard (gathered over the shard axis on a multi-process
+        mesh, where no process can address them all).  Everything the
+        traced closures read is an argument or a single assignment of
+        this call, so a re-trace (another batch size, another shard
+        bucket) reads what the first trace read."""
+        n_flat = sum(n for _, n, _ in layout)
+        per_shard_out = node.kind in PER_SHARD_KINDS
+        gather = per_shard_out and self.multiprocess
+        fused = _fused_entry(layout, node.primary) \
+            if node.kind == "row_counts" else None
+
+        def per_shard(mat, *arrays):
+            if fused is not None and mat.shape[0] == 1:
+                # the headline fusion (ops/kernels.py): decode +
+                # filter-AND + per-row popcount in ONE Pallas kernel;
+                # the field fragment's dense words never leave the
+                # kernel's VMEM tile.  Other layout entries still decode
+                # normally for the filter plan (XLA drops the unused
+                # field decode).
+                from ..ops import kernels
+                i0, fs = fused
+                filt = None
+                if node.plan is not None:
+                    filt = eval_plan(node.plan,
+                                     _unpack_frags(layout, arrays), mat[0])
+                return kernels.fused_row_counts(
+                    *arrays[i0: i0 + 5], filt, rows=fs[1],
+                    words=SHARD_WORDS, a_bucket=fs[4],
+                    r_bucket=fs[5])[None]              # [1, rows]
+            return node_shard(node, mat, _unpack_frags(layout, arrays))
+
+        def block_fn(mat, *arrays):
+            outs = jax.vmap(per_shard, in_axes=(None,) + (0,) * n_flat)(
+                mat, *arrays)                          # [S_local, ...]
+            if gather:
+                return jax.tree_util.tree_map(
+                    lambda o: jax.lax.all_gather(o, SHARD_AXIS,
+                                                 tiled=True), outs)
+            if per_shard_out:
+                return outs
+            return jax.lax.psum(jnp.sum(outs, axis=0),
+                                axis_name=SHARD_AXIS)
+
+        return self._jit_shard_map(
+            key, block_fn, (P(),) + (P(SHARD_AXIS),) * n_flat,
+            P(SHARD_AXIS) if per_shard_out and not gather else P(),
+            check_vma=not gather, layout=layout)
 
     @staticmethod
     def merge_counts(parts) -> np.ndarray:
@@ -1308,481 +1205,6 @@ class MeshExecutor:
         for p in parts:
             acc = acc_counts(acc, np.asarray(p, dtype=np.int64))
         return acc
-
-    def row_counts_async(self, field: str, view: str, filter_plan, holder,
-                         index, shards) -> list:
-        """Dispatch per-row popcounts of (field, view) fragments across all
-        shards, masked by ``filter_plan``'s result when given.  Returns
-        unblocked per-group device vectors; combine with
-        ``merge_counts``."""
-        keys = self.batch_keys((field, view), filter_plan)
-        slotted, params = (None, np.zeros(0, dtype=np.int32)) \
-            if filter_plan is None else parametrize(filter_plan)
-        params = jnp.asarray(params)
-        parts = []
-        for shard_list, placed, sig in self._stream_groups(
-                keys, holder, index, shards):
-            if sig[0] is None:
-                continue  # field fragment absent everywhere in this group
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("row_counts", slotted, pkeys, pshapes)
-            fn = self._cache.get(key)
-            if fn is None:
-                fplan = slotted
-
-                # loop-local captures frozen as defaults (re-trace safety;
-                # see segments_batch)
-                def per_shard(params_, *arrays, _layout=layout,
-                              _k0=pkeys[0],
-                              _fused=_fused_entry(layout, pkeys[0])):
-                    if _fused is not None:
-                        # the headline fusion (ops/kernels.py): decode +
-                        # filter-AND + per-row popcount in ONE Pallas
-                        # kernel; the field fragment's dense words never
-                        # leave the kernel's VMEM tile.  Other layout
-                        # entries still decode normally for the filter
-                        # plan (XLA drops the unused field decode).
-                        from ..ops import kernels
-                        i0, fs = _fused
-                        filt = None
-                        if fplan is not None:
-                            frags = _unpack_frags(_layout, arrays)
-                            filt = eval_plan(fplan, frags, params_)
-                        return kernels.fused_row_counts(
-                            *arrays[i0: i0 + 5], filt, rows=fs[1],
-                            words=SHARD_WORDS, a_bucket=fs[4],
-                            r_bucket=fs[5])        # [rows]
-                    frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]              # [rows, 256, 128]
-                    if fplan is None:
-                        masked = frag
-                    else:
-                        seg = eval_plan(fplan, frags, params_)
-                        masked = frag & seg[None]
-                    return bitset.row_counts(masked)   # [rows]
-
-                def block_fn(params_, *arrays, _ps=per_shard,
-                             _n=len(flat)):
-                    counts = jnp.sum(jax.vmap(
-                        _ps, in_axes=(None,) + (0,) * _n)(
-                            params_, *arrays), axis=0)
-                    return jax.lax.psum(counts, axis_name=SHARD_AXIS)
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(),) + tuple(P(SHARD_AXIS) for _ in flat), P(),
-                    layout=layout)
-            with _DISPATCH_LOCK:
-                parts.append(fn(params, *flat,
-                                _launch_meta=len(shard_list)))
-        return parts
-
-    def row_counts(self, field: str, view: str, filter_plan, holder,
-                   index, shards) -> np.ndarray:
-        return self.merge_counts(self.row_counts_async(
-            field, view, filter_plan, holder, index, shards))
-
-    # -- BSI aggregations (fragment.go:1111 sum, :1147 min/max) ------------
-
-    def bsi_sum_async(self, field: str, view: str, filter_plan, holder,
-                      index, shards) -> list:
-        """Dispatch the per-slice popcounts; returns unblocked [2, depth+1]
-        device matrices (one per shape group); combine via
-        ``bsi.weighted_sum`` per part and add."""
-        keys = self.batch_keys((field, view), filter_plan)
-        slotted, params = (None, np.zeros(0, dtype=np.int32)) \
-            if filter_plan is None else parametrize(filter_plan)
-        params = jnp.asarray(params)
-        parts = []
-        for shard_list, placed, sig in self._stream_groups(
-                keys, holder, index, shards):
-            if sig[0] is None or _sig_rows(sig[0]) < bsi.OFFSET_ROW + 1:
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("bsi_sum", slotted, pkeys, pshapes)
-            fn = self._cache.get(key)
-            if fn is None:
-                fplan = slotted
-
-                def per_shard(params_, *arrays, _layout=layout,
-                              _k0=pkeys[0]):
-                    frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]
-                    filt = None
-                    if fplan is not None:
-                        filt = eval_plan(fplan, frags, params_)
-                    return bsi.sum_counts(frag, filt)   # [2, depth+1]
-
-                def block_fn(params_, *arrays, _ps=per_shard,
-                             _n=len(flat)):
-                    counts = jnp.sum(jax.vmap(
-                        _ps, in_axes=(None,) + (0,) * _n)(
-                            params_, *arrays), axis=0)
-                    return jax.lax.psum(counts, axis_name=SHARD_AXIS)
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(),) + tuple(P(SHARD_AXIS) for _ in flat), P(),
-                    layout=layout)
-            with _DISPATCH_LOCK:
-                parts.append(fn(params, *flat,
-                                _launch_meta=len(shard_list)))
-        return parts
-
-    def bsi_sum(self, field: str, view: str, filter_plan, holder,
-                index, shards) -> tuple[int, int]:
-        """(sum-of-base-values, non-null-count) over all shards."""
-        total, count = 0, 0
-        for p in self.bsi_sum_async(field, view, filter_plan, holder,
-                                    index, shards):
-            s, cnt = bsi.weighted_sum(np.asarray(p))
-            total += s
-            count += cnt
-        return total, count
-
-    def bsi_min_max(self, field: str, view: str, filter_plan, holder,
-                    index, shards, want_max: bool):
-        """Per-shard extremum bits gathered to host; returns a list of
-        (value, count) per shard (padded shards yield count 0)."""
-        keys = self.batch_keys((field, view), filter_plan)
-        slotted, params = (None, np.zeros(0, dtype=np.int32)) \
-            if filter_plan is None else parametrize(filter_plan)
-        params = jnp.asarray(params)
-        out = []
-        for shard_list, placed, sig in self._stream_groups(
-                keys, holder, index, shards):
-            if sig[0] is None or _sig_rows(sig[0]) < bsi.OFFSET_ROW + 1:
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("bsi_minmax", slotted, pkeys, pshapes,
-                                 extra=(want_max,))
-            fn = self._cache.get(key)
-            if fn is None:
-                fplan = slotted
-
-                def per_shard(params_, *arrays, _layout=layout,
-                              _k0=pkeys[0]):
-                    frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]
-                    filt = None
-                    if fplan is not None:
-                        filt = eval_plan(fplan, frags, params_)
-                    return bsi.min_max_bits(frag, filt, want_max=want_max)
-
-                if self.multiprocess:
-                    def block_fn(params_, *arrays, _ps=per_shard,
-                                 _n=len(flat)):
-                        outs = jax.vmap(
-                            _ps, in_axes=(None,) + (0,) * _n)(
-                                params_, *arrays)
-                        return tuple(
-                            jax.lax.all_gather(o, SHARD_AXIS, tiled=True)
-                            for o in outs)
-
-                    out_specs = (P(), P(), P())
-                    check_vma = False
-                else:
-                    def block_fn(params_, *arrays, _ps=per_shard,
-                                 _n=len(flat)):
-                        return jax.vmap(
-                            _ps, in_axes=(None,) + (0,) * _n)(
-                                params_, *arrays)
-
-                    out_specs = (P(SHARD_AXIS), P(SHARD_AXIS),
-                                 P(SHARD_AXIS))
-                    check_vma = True
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(),) + tuple(P(SHARD_AXIS) for _ in flat),
-                    out_specs, check_vma=check_vma, layout=layout)
-            with _DISPATCH_LOCK:
-                outs = fn(params, *flat, _launch_meta=len(shard_list))
-            bits, neg, cnt = (np.asarray(x) for x in outs)
-            for i in range(len(shard_list)):
-                out.append(bsi.reconstruct_min_max(
-                    bits[i], int(neg[i]), int(cnt[i])))
-        return out
-
-    # -- batched variants: B same-shape calls, ONE executable invocation ---
-    # A multi-call query's same-shape calls (e.g. 64 distinct Counts)
-    # execute as one vmapped computation over a [B, P] params matrix —
-    # collapsing B dispatch round trips into one.  This is the TPU-native
-    # replacement for the reference's worker pool soaking up concurrent
-    # queries (executor.go:80-110).
-
-    def count_batch_async(self, slotted, params_mat, holder, index,
-                          shards) -> list:
-        """B counts that share one plan shape; parts are [B] vectors."""
-        keys = plan_inputs(slotted)
-        params = jnp.asarray(params_mat)               # [B, P]
-        parts = []
-        # no _stream_groups here: the callers (_run_batched_groups and
-        # the dispatch batcher) own the slice schedule and pass
-        # pre-scheduled shard slices — re-scheduling would re-walk the
-        # holder per (group x chunk)
-        for shard_list, placed, sig in self._placed_groups(
-                keys, holder, index, shards):
-            if all(s is None for s in sig):
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("countB", slotted, pkeys, pshapes)
-            fn = self._cache.get(key)
-            if fn is None:
-                def per_shard(params_, *arrays, _layout=layout):
-                    frags = _unpack_frags(_layout, arrays)
-                    segs = jax.vmap(
-                        lambda p: eval_plan(slotted, frags, p))(params_)
-                    return bitset.row_counts(segs)      # [B]
-
-                def block_fn(params_, *arrays, _ps=per_shard,
-                             _n=len(flat)):
-                    counts = jnp.sum(jax.vmap(
-                        _ps, in_axes=(None,) + (0,) * _n)(
-                            params_, *arrays), axis=0)
-                    return jax.lax.psum(counts, axis_name=SHARD_AXIS)
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(),) + tuple(P(SHARD_AXIS) for _ in flat), P(),
-                    layout=layout)
-            with _DISPATCH_LOCK:
-                parts.append(fn(params, *flat,
-                                _launch_meta=len(shard_list)))
-        return parts
-
-    def row_counts_batch_async(self, field: str, view: str, slotted_filter,
-                               params_mat, holder, index, shards) -> list:
-        """B row-count passes sharing one filter shape; parts are
-        [B, rows] matrices."""
-        keys = self.batch_keys((field, view), slotted_filter)
-        params = jnp.asarray(params_mat)
-        parts = []
-        # no _stream_groups here: the callers (_run_batched_groups and
-        # the dispatch batcher) own the slice schedule and pass
-        # pre-scheduled shard slices — re-scheduling would re-walk the
-        # holder per (group x chunk)
-        for shard_list, placed, sig in self._placed_groups(
-                keys, holder, index, shards):
-            if sig[0] is None:
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("row_countsB", slotted_filter, pkeys,
-                                 pshapes)
-            fn = self._cache.get(key)
-            if fn is None:
-                def per_shard(params_, *arrays, _layout=layout,
-                              _k0=pkeys[0], _fplan=slotted_filter):
-                    frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]                  # [rows, 256, 128]
-                    if _fplan is None:
-                        counts = bitset.row_counts(frag)   # [rows]
-                        return jnp.broadcast_to(
-                            counts, (params_.shape[0],) + counts.shape)
-                    masks = jax.vmap(
-                        lambda p: eval_plan(_fplan, frags, p))(params_)
-                    masked = frag[None] & masks[:, None]
-                    return bitset.row_counts(masked)    # [B, rows]
-
-                def block_fn(params_, *arrays, _ps=per_shard,
-                             _n=len(flat)):
-                    counts = jnp.sum(jax.vmap(
-                        _ps, in_axes=(None,) + (0,) * _n)(
-                            params_, *arrays), axis=0)
-                    return jax.lax.psum(counts, axis_name=SHARD_AXIS)
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(),) + tuple(P(SHARD_AXIS) for _ in flat), P(),
-                    layout=layout)
-            with _DISPATCH_LOCK:
-                parts.append(fn(params, *flat,
-                                _launch_meta=len(shard_list)))
-        return parts
-
-    def bsi_sum_batch_async(self, field: str, view: str, slotted_filter,
-                            params_mat, holder, index, shards) -> list:
-        """B BSI sums sharing one filter shape; parts are [B, 2, depth+1]."""
-        keys = self.batch_keys((field, view), slotted_filter)
-        params = jnp.asarray(params_mat)
-        parts = []
-        # no _stream_groups here: the callers (_run_batched_groups and
-        # the dispatch batcher) own the slice schedule and pass
-        # pre-scheduled shard slices — re-scheduling would re-walk the
-        # holder per (group x chunk)
-        for shard_list, placed, sig in self._placed_groups(
-                keys, holder, index, shards):
-            if sig[0] is None or _sig_rows(sig[0]) < bsi.OFFSET_ROW + 1:
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("bsi_sumB", slotted_filter, pkeys, pshapes)
-            fn = self._cache.get(key)
-            if fn is None:
-                def per_shard(params_, *arrays, _layout=layout,
-                              _k0=pkeys[0], _fplan=slotted_filter):
-                    frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]
-                    if _fplan is None:
-                        counts = bsi.sum_counts(frag, None)
-                        return jnp.broadcast_to(
-                            counts, (params_.shape[0],) + counts.shape)
-
-                    def one(p):
-                        return bsi.sum_counts(frag, eval_plan(_fplan, frags,
-                                                              p))
-
-                    return jax.vmap(one)(params_)      # [B, 2, depth+1]
-
-                def block_fn(params_, *arrays, _ps=per_shard,
-                             _n=len(flat)):
-                    counts = jnp.sum(jax.vmap(
-                        _ps, in_axes=(None,) + (0,) * _n)(
-                            params_, *arrays), axis=0)
-                    return jax.lax.psum(counts, axis_name=SHARD_AXIS)
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(),) + tuple(P(SHARD_AXIS) for _ in flat), P(),
-                    layout=layout)
-            with _DISPATCH_LOCK:
-                parts.append(fn(params, *flat,
-                                _launch_meta=len(shard_list)))
-        return parts
-
-    # -- GroupBy inner loop (executor.go:1068 executeGroupBy) --------------
-
-    # Max combos per dispatch: bounds the [S_local, chunk, rows] int32
-    # intermediate (8 stacked shards x 256 combos x 1024 rows = 8 MB) so a
-    # large odometer cannot OOM HBM; full chunks share one executable.
-    GROUP_CHUNK = 256
-
-    def group_counts_batch_async(self, last_key: tuple[str, str],
-                                 prefix_keys: list[tuple[str, str]],
-                                 combos: np.ndarray, filter_plan, holder,
-                                 index, shards) -> list:
-        """All C prefix combos of a GroupBy in a handful of executable
-        invocations: ``combos`` is a [C, P] int32 matrix of prefix row ids.
-        Returns [(lo, hi, parts)] where ``parts`` are [chunk, rows] count
-        matrices covering combos[lo:hi] (rows beyond hi-lo are padding).
-        The odometer's per-combo device round trips (executor.go:3058
-        groupByIterator) collapse into a vmap over the combo axis, chunked
-        to GROUP_CHUNK combos per dispatch to bound device memory."""
-        combos = np.asarray(combos, dtype=np.int32)
-        out = []
-        for lo in range(0, combos.shape[0], self.GROUP_CHUNK):
-            sub = combos[lo: lo + self.GROUP_CHUNK]
-            out.append((lo, lo + sub.shape[0],
-                        self._group_counts_chunk(
-                            last_key, prefix_keys, sub, filter_plan,
-                            holder, index, shards)))
-        return out
-
-    def _group_counts_chunk(self, last_key, prefix_keys, combos,
-                            filter_plan, holder, index, shards) -> list:
-        C = combos.shape[0]
-        pad_c = 1
-        while pad_c < C:
-            pad_c *= 2
-        if pad_c != C:
-            combos = np.vstack(
-                [combos, np.zeros((pad_c - C, combos.shape[1]), np.int32)])
-        keys = [last_key]
-        for k in prefix_keys:
-            if k not in keys:
-                keys.append(k)
-        for k in self._filter_keys(filter_plan):
-            if k not in keys:
-                keys.append(k)
-        rids = jnp.asarray(combos)
-        slotted, params = (None, np.zeros(0, dtype=np.int32)) \
-            if filter_plan is None else parametrize(filter_plan)
-        params = jnp.asarray(params)
-        parts = []
-        for shard_list, placed, sig in self._stream_groups(
-                keys, holder, index, shards):
-            if sig[0] is None:
-                continue
-            key_to_sig = dict(zip(keys, sig))
-            if any(key_to_sig[k] is None for k in prefix_keys):
-                continue
-            present = self._present(keys, placed, sig)
-            pkeys = tuple(k for k, _, _ in present)
-            pshapes = tuple(s for _, _, s in present)
-            flat, layout = _flatten_present(present)
-            key = self._plan_key("group_countsB", slotted, pkeys, pshapes,
-                                 extra=(tuple(prefix_keys), pad_c))
-            fn = self._cache.get(key)
-            if fn is None:
-                fplan = slotted
-                pk_list = list(prefix_keys)
-
-                def one_combo(rids_row, params_, frags, frag):
-                    mask = None
-                    for j, pk in enumerate(pk_list):
-                        pfrag = frags[pk]
-                        rid = rids_row[j]
-                        if pfrag.shape[0] == 0:
-                            seg = jnp.zeros(pfrag.shape[1:],
-                                            dtype=pfrag.dtype)
-                        else:
-                            seg = jnp.where(
-                                rid < pfrag.shape[0],
-                                jax.lax.dynamic_index_in_dim(
-                                    pfrag,
-                                    jnp.minimum(rid, pfrag.shape[0] - 1),
-                                    axis=0, keepdims=False),
-                                jnp.zeros_like(pfrag[0]))
-                        mask = seg if mask is None else mask & seg
-                    if fplan is not None:
-                        fseg = eval_plan(fplan, frags, params_)
-                        mask = fseg if mask is None else mask & fseg
-                    masked = frag if mask is None else frag & mask[None]
-                    return bitset.row_counts(masked)    # [rows]
-
-                def per_shard(rids_, params_, *arrays, _layout=layout,
-                              _k0=pkeys[0], _oc=one_combo):
-                    frags = _unpack_frags(_layout, arrays)
-                    frag = frags[_k0]                  # [rows, 256, 128]
-                    return jax.vmap(
-                        lambda r: _oc(r, params_, frags, frag))(
-                            rids_)                     # [C, rows]
-
-                def block_fn(rids_, params_, *arrays, _ps=per_shard,
-                             _n=len(flat)):
-                    counts = jnp.sum(jax.vmap(
-                        _ps,
-                        in_axes=(None, None) + (0,) * _n)(
-                            rids_, params_, *arrays), axis=0)
-                    return jax.lax.psum(counts, axis_name=SHARD_AXIS)
-
-                fn = self._jit_shard_map(
-                    key, block_fn,
-                    (P(), P()) + tuple(P(SHARD_AXIS) for _ in flat), P(),
-                    layout=layout)
-            with _DISPATCH_LOCK:
-                # (shards, C): the pow-2 combo padding (pad_c - C rows)
-                # must count as padding waste, not actual work
-                parts.append(fn(rids, params, *flat,
-                                _launch_meta=(len(shard_list), C)))
-        return parts
 
 
 class _ShardSchedule:

@@ -1,0 +1,247 @@
+"""Reducer nodes: the one definition of each reducer.
+
+A ``plan.ReduceNode`` (kind, slotted plan, primary, extra) plus its
+int32 params matrix ``[B, P]`` is the unit every layer passes: the
+executor lowers a call or a batched call group to a node, a dispatch
+batcher ticket carries one, and both ways of launching it — the
+per-stage launcher (``MeshExecutor.reduce_async``: one node, one
+executable a shape group, shard slices streamed under a budget) and the
+whole-query program (``WholeQueryRunner.run``: every node of a request
+in one executable) — trace the SAME per-shard body, read the same key
+list and apply the same skip rule, all defined here.  A single call is
+``B = 1``.
+
+The six kinds:
+
+* ``count``        — popcount of the plan's result per params row.
+* ``segments``     — the plan's raw result per params row (bitmap calls).
+* ``row_counts``   — per-row popcounts of the primary fragment under an
+                     optional filter plan (TopN, Rows, MinRow/MaxRow).
+* ``bsi_sum``      — per-bit-slice popcounts of a BSI fragment under an
+                     optional filter; the host does the 2^i weighting.
+* ``bsi_minmax``   — the MSB-first extremum scan (no batch axis: the
+                     first params row); ``extra`` = ("max",) | ("min",).
+* ``group_counts`` — per-row popcounts under the intersection of
+                     dynamically indexed prefix rows and an optional
+                     filter (GroupBy); its matrix is the pair (prefix
+                     row ids [C, Pk], filter params [P]) and ``extra`` =
+                     (prefix keys..., padded C).
+
+What a launch costs in program temporaries, and what it may cost, is a
+property of these programs and lives beside them (``node_temp_rows``,
+``batch_temp_bound``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..core import SHARD_WORDS
+from ..executor.plan import eval_plan, plan_inputs
+from ..ops import bitset, bsi
+
+KINDS = ("count", "segments", "row_counts", "bsi_sum", "bsi_minmax",
+         "group_counts")
+# Kinds with a genuine batch axis: their tickets and programs fuse
+# across concurrent requests (params concatenate along B).  bsi_minmax
+# has none and group_counts' leading axis is the combo grid.
+BATCH_KINDS = frozenset({"count", "segments", "row_counts", "bsi_sum"})
+# Kinds whose outputs stay per shard (the host assembles them after the
+# fetch); the others sum over the shards in the program.
+PER_SHARD_KINDS = frozenset({"segments", "bsi_minmax"})
+
+
+def sig_rows(shape) -> int:
+    """Row count of a per-key group-signature entry — dense entries are
+    the device shape (rows, 256, 128), compressed ones
+    ('z', rows, C, P, A, R)."""
+    return shape[1] if shape[0] == "z" else shape[0]
+
+
+def node_keys(node) -> list[tuple[str, str]]:
+    """The (field, view) key list a node stacks, primary first.  The
+    ONLY definition: a shard schedule built from it prefetches and pins
+    precisely the stacks the launch reads."""
+    keys = [node.primary] if node.primary else []
+    if node.kind == "group_counts":
+        keys += node.extra[:-1]
+    if node.plan is not None:
+        keys += plan_inputs(node.plan)
+    return list(dict.fromkeys(keys))
+
+
+def participates(node, sig_map) -> bool:
+    """Whether a shape group (``sig_map``: key -> signature, None where
+    the fragment is absent in the whole group) contributes to a node —
+    the one skip rule."""
+    if node.kind in ("count", "segments"):
+        # no fragment at all: the plan evaluates to empty
+        return any(s is not None for s in sig_map.values())
+    s0 = sig_map.get(node.primary)
+    if s0 is None:
+        return False
+    if node.kind in ("bsi_sum", "bsi_minmax") and \
+            sig_rows(s0) < bsi.OFFSET_ROW + 1:
+        return False
+    if node.kind == "group_counts":
+        return all(sig_map.get(pk) is not None for pk in node.extra[:-1])
+    return True
+
+
+def node_shard(node, mat, frags):
+    """One reducer node's per-shard contribution, traced inside the
+    vmapped per-shard pass of either launcher (decode has already
+    produced dense [rows, 256, 128] fragments in ``frags``; a segment is
+    one word tile, [256, 128]).  Counts accumulate in int32."""
+    if node.kind in ("count", "segments"):
+        segs = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
+        if node.kind == "segments":
+            return segs                                    # [B, 256, 128]
+        return bitset.row_counts(segs)              # [B]
+    frag = frags[node.primary]
+    if node.kind == "row_counts":
+        if node.plan is None:
+            counts = bitset.row_counts(frag)
+            return jnp.broadcast_to(counts,
+                                    (mat.shape[0],) + counts.shape)
+        masks = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
+        masked = frag[None] & masks[:, None]
+        return bitset.row_counts(masked)            # [B, rows]
+    if node.kind == "bsi_sum":
+        if node.plan is None:
+            counts = bsi.sum_counts(frag, None)
+            return jnp.broadcast_to(counts,
+                                    (mat.shape[0],) + counts.shape)
+        return jax.vmap(
+            lambda p: bsi.sum_counts(frag, eval_plan(node.plan, frags,
+                                                     p)))(
+            mat)                                           # [B, 2, d+1]
+    if node.kind == "bsi_minmax":
+        filt = None
+        if node.plan is not None:
+            filt = eval_plan(node.plan, frags, mat[0])
+        return bsi.min_max_bits(frag, filt,
+                                want_max=node.extra[0] == "max")
+    # group_counts: combos ride the leading axis of mat[0]
+    rids, params = mat
+    pk_list = node.extra[:-1]
+    fseg = eval_plan(node.plan, frags, params) \
+        if node.plan is not None else None
+
+    def one_combo(rids_row):
+        mask = None
+        for j, pk in enumerate(pk_list):
+            pfrag = frags[pk]
+            rid = rids_row[j]
+            if pfrag.shape[0] == 0:
+                seg = jnp.zeros(pfrag.shape[1:], dtype=pfrag.dtype)
+            else:
+                seg = jnp.where(
+                    rid < pfrag.shape[0],
+                    jax.lax.dynamic_index_in_dim(
+                        pfrag, jnp.minimum(rid, pfrag.shape[0] - 1),
+                        axis=0, keepdims=False),
+                    jnp.zeros_like(pfrag[0]))
+            mask = seg if mask is None else mask & seg
+        if fseg is not None:
+            mask = fseg if mask is None else mask & fseg
+        masked = frag if mask is None else frag & mask[None]
+        return bitset.row_counts(masked)            # [rows]
+
+    return jax.vmap(one_combo)(rids)                       # [C, rows]
+
+
+def mat_rows(mat) -> int:
+    """Leading (batch or combo) rows of a node's params matrix."""
+    return mat[0].shape[0] if isinstance(mat, tuple) else mat.shape[0]
+
+
+def pow2_rows(n: int) -> int:
+    """The batch rows a launch of ``n`` rows is padded to: the next
+    power of two, so arbitrary batch sizes reuse a bounded set of
+    compiled programs."""
+    return 1 << max(0, n - 1).bit_length()
+
+
+def pad_pow2_rows(mat: np.ndarray, repeat: bool = True) -> np.ndarray:
+    """Pad a params matrix's row count up to ``pow2_rows`` of it.
+    ``repeat`` duplicates the last row (always in-range); otherwise zero
+    rows (GroupBy combo grids)."""
+    B = mat.shape[0]
+    pad = pow2_rows(B)
+    if pad == B:
+        return mat
+    if repeat:
+        return np.concatenate([mat, np.repeat(mat[-1:], pad - B, axis=0)])
+    return np.concatenate(
+        [mat, np.zeros((pad - B,) + mat.shape[1:], mat.dtype)])
+
+
+# -- what a launch costs in program temporaries, and what it may cost -------
+#
+# A batched executable materializes device temporaries beside its
+# stacked inputs, per stacked shard of the device.  The per-stage
+# launches state theirs in [SHARD_WORDS] u32 rows (128 KiB) a batch row
+# (``node_temp_rows``, the one function the executor's batch chunking
+# and the cross-query batcher's per-stage tickets share):
+#
+# * count / segments: one gather temp per params slot (measured: an
+#   8-slot Intersect batch at B=16384 on one shard exhausts a 16 GB HBM
+#   with 8 x 2 GB gather temps);
+# * filtered row_counts: one [B, rows, W] masked temp, rows = the
+#   fragment row count (BENCH_r07's small-RAM OOM gap);
+# * filtered bsi_sum: the summed field's bit rows under the filter, so
+#   its depth + 2 rows.
+#
+# A whole-query program asks the compiler instead: its launch reads
+# ``memory_analysis().temp_size_in_bytes`` of the executable it is about
+# to run, once per compiled shape, and walks the device's shards in
+# blocks where that figure would pass the bound
+# (parallel/wholequery.py ``run``).  ``batch_temp_bound`` is what a
+# launch may cost: what the device has left — its ``bytes_limit`` less
+# what the device budget counts resident, less BATCH_TEMP_MARGIN — with
+# the ``batch-temp-mb`` knob (BATCH_TEMP_BYTES; process-wide, most
+# recent Server wins) as a ceiling only.
+BATCH_TEMP_BYTES = 4 << 30
+# Device bytes the bound leaves free beside resident blocks and one
+# launch's temporaries: outputs, params, the temporaries of launches
+# still in flight at B = 1 (0.4-0.5 GB each at 176 stacked shards) and
+# the allocator's own slack.
+BATCH_TEMP_MARGIN = 1 << 30
+ROW_BYTES = SHARD_WORDS * 4
+
+
+def node_temp_rows(kind: str, plan, P: int, primary_rows: int = 0) -> int:
+    """[SHARD_WORDS] u32 rows one batch row of a per-stage launch of
+    node kind ``kind`` keeps per stacked shard.  ``plan`` is the slotted
+    filter (None: a B-independent broadcast pass, 0), ``P`` its params
+    slots, ``primary_rows`` the rows of the (field, view) reduced."""
+    if kind in ("count", "segments"):
+        return max(1, P)
+    if plan is None:
+        return 0
+    return max(1, P, primary_rows)
+
+
+@functools.cache
+def device_bytes_limit() -> int | None:
+    """The smallest ``bytes_limit`` over the local devices, where the
+    backend reports one (the CPU does not).  Read once."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in jax.local_devices()]
+    return min((b for b in limits if b), default=None)
+
+
+def batch_temp_bound() -> int:
+    """What one launch's temporaries may cost now: what the device has
+    left, ``batch-temp-mb`` as a ceiling."""
+    limit = device_bytes_limit()
+    if limit is None:
+        return BATCH_TEMP_BYTES
+    from ..storage.membudget import DEFAULT_BUDGET
+    free = limit - DEFAULT_BUDGET.resident_bytes - BATCH_TEMP_MARGIN
+    return max(0, min(BATCH_TEMP_BYTES, free))

@@ -2,19 +2,22 @@
 
 PAPER.md's stated design is that "PQL calls (Intersect/Union/TopN/
 GroupBy/Count) compile to a single XLA computation per request" — the
-pjit/PartitionSpec pattern of SNIPPETS.md [1][3].  The legacy executor
-dispatches one shard_map executable per reducer stage per shape group,
-with a Python hop between every PQL stage; this module compiles the
-ENTIRE parsed request — every call, every shape group, the PR 7
-container decode, and the cross-shard reductions — into one jitted
-program over global mesh-sharded arrays (docs/whole-query.md).
+pjit/PartitionSpec pattern of SNIPPETS.md [1][3].  The per-stage path
+(``MeshExecutor.reduce_async``) launches one shard_map executable per
+reducer node per shape group, with a Python hop between every PQL
+stage; this module compiles the ENTIRE parsed request — every call,
+every shape group, the PR 7 container decode, and the cross-shard
+reductions — into one jitted program over global mesh-sharded arrays
+(docs/whole-query.md).  Both trace the same node bodies: what a
+reducer computes on a shard, which keys it stacks and which shape
+groups it skips are defined once, in parallel/nodes.py.
 
 Mechanics: the executor lowers a read query to a tuple of
 ``plan.ReduceNode``s (Count popcount-sums, TopN/Rows row-count
 accumulations, BSI slice counts, Min/Max extremum scans, GroupBy combo
 grids, raw segments) plus one params matrix per node.  ``run`` stacks
 the request's fragment inputs with the SAME residency machinery the
-legacy path uses — ``MeshExecutor._placed_groups`` with its stack
+per-stage path uses — ``MeshExecutor._placed_groups`` with its stack
 cache, device-budget accounting, compressed staging, and ingest
 overlays all compose unchanged — and places them sharded over the
 named ``shards`` mesh axis (``PartitionSpec(SHARD_AXIS)``); params ride
@@ -22,8 +25,8 @@ replicated (``P()``).  The whole program is ONE ``shard_map`` over
 that axis: the body decodes compressed stacks once per shape group,
 evaluates every node's per-shard contribution in one vmapped pass over
 the device-local block, and reduces IN PROGRAM — local sums +
-``lax.psum`` over the shard axis replace the per-shard ``segments()``
-the legacy path assembled host-side.  (Manual partitioning on purpose:
+``lax.psum`` over the shard axis, the shape groups combined in the
+program where the per-stage path returns a part a group.  (Manual partitioning on purpose:
 auto-partitioned jit replicates the vmapped row-gathers — a 4096-wide
 Count batch allocated a 279 GB gather temp — while shard_map pins the
 per-device shapes the batch-temp bound is held against.)  Where the
@@ -38,7 +41,7 @@ launch per request — the launch ledger (utils/devobs.py) records it as
 kind ``wholequery``.
 
 Shapes the program cannot express raise ``WholeQueryUnsupported`` and
-the executor reroutes to the legacy per-stage dispatch, counting
+the executor reroutes to the per-stage dispatch, counting
 ``wholequery.fallback`` (docs/whole-query.md has the fallback matrix):
 multi-process meshes (per-process staging must stay deterministic),
 over-budget working sets (the streaming slice planner owns those),
@@ -62,22 +65,22 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS
-from ..executor.plan import eval_plan, plan_inputs
-from ..ops import bitset, bsi
+from ..ops import bsi
 from ..utils import devobs as _devobs
 from ..utils import profile as qprof
 from ..utils.deadline import check_current
 from ..utils.faults import FAULTS
 from ..utils.tracing import layer_span
-from .mesh_exec import _DISPATCH_LOCK, _flatten_present, _sig_rows, \
-    _unpack_frags, SHARD_AXIS
+from . import nodes
+from .mesh_exec import _DISPATCH_LOCK, _flatten_present, _unpack_frags, \
+    SHARD_AXIS
 
 
 class WholeQueryUnsupported(Exception):
     """A request (or runtime shape) the whole-query program cannot
     express.  The executor counts ``wholequery.fallback``, emits a
     structured log event naming the unsupported node, and reroutes to
-    the legacy per-stage dispatch — never a silent slow path."""
+    the per-stage dispatch — never a silent slow path."""
 
     def __init__(self, node: str, detail: str = ""):
         super().__init__(f"{node}: {detail}" if detail else node)
@@ -85,40 +88,12 @@ class WholeQueryUnsupported(Exception):
         self.detail = detail
 
 
-# Node kinds that carry a genuine batch axis: programs made only of
-# these can fuse across concurrent requests in the dispatch batcher
-# (params concatenate along B).  bsi_minmax has no batch axis and
-# group_counts' leading axis is the combo grid, so programs containing
-# them launch un-fused.
-_BATCH_KINDS = frozenset({"count", "segments", "row_counts", "bsi_sum"})
-
-
-def node_keys(node, mesh) -> list[tuple[str, str]]:
-    """Deterministic (field, view) key list one reducer node reads."""
-    if node.kind in ("count", "segments"):
-        return plan_inputs(node.plan)
-    if node.kind == "group_counts":
-        keys = [node.primary]
-        for k in node.extra[:-1]:
-            if k not in keys:
-                keys.append(k)
-        for k in (plan_inputs(node.plan) if node.plan is not None else []):
-            if k not in keys:
-                keys.append(k)
-        return keys
-    return mesh.batch_keys(node.primary, node.plan)
-
-
-def program_keys(program, mesh) -> list[tuple[str, str]]:
+def program_keys(program) -> list[tuple[str, str]]:
     """Union of every node's keys, order-deterministic — the single
     stacked key list the whole request stages (and the shard schedule
     prefetches) once."""
-    out: list[tuple[str, str]] = []
-    for node in program:
-        for k in node_keys(node, mesh):
-            if k not in out:
-                out.append(k)
-    return out
+    return list(dict.fromkeys(
+        k for node in program for k in nodes.node_keys(node)))
 
 
 PROGRAM_NAME_NODES = 6   # node kinds spelled out in a program's name
@@ -134,26 +109,6 @@ def program_name(program) -> str:
     if len(kinds) > PROGRAM_NAME_NODES:
         name += f"_n{len(kinds)}"
     return name
-
-
-def pad_pow2_rows(mat: np.ndarray, repeat: bool = True) -> np.ndarray:
-    """Pad a params matrix's row count up to a power of two so arbitrary
-    batch sizes reuse a bounded set of compiled programs (the batcher's
-    convention).  ``repeat`` duplicates the last row (always in-range);
-    otherwise zero rows (GroupBy combo grids, matching the legacy
-    chunk padding)."""
-    B = mat.shape[0]
-    pad = 1 << max(0, B - 1).bit_length()
-    if pad == B:
-        return mat
-    if repeat:
-        return np.concatenate([mat, np.repeat(mat[-1:], pad - B, axis=0)])
-    return np.concatenate(
-        [mat, np.zeros((pad - B,) + mat.shape[1:], mat.dtype)])
-
-
-def _mat_rows(mat) -> int:
-    return mat[0].shape[0] if isinstance(mat, tuple) else mat.shape[0]
 
 
 def _divisor_at_most(n: int, m: int) -> int:
@@ -300,70 +255,6 @@ class _InstrumentedWhole:
         return out
 
 
-def _node_shard(node, mat, frags):
-    """One reducer node's per-shard contribution, traced inside the
-    vmapped per-shard pass (decode has already produced dense
-    [rows, 256, 128] fragments in ``frags``; a segment is one word tile,
-    [256, 128]).  Shapes mirror the legacy per-stage executables exactly
-    — including int32 accumulation — so results stay byte-identical."""
-    if node.kind in ("count", "segments"):
-        segs = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
-        if node.kind == "segments":
-            return segs                                    # [B, 256, 128]
-        return bitset.row_counts(segs)              # [B]
-    frag = frags[node.primary]
-    if node.kind == "row_counts":
-        if node.plan is None:
-            counts = bitset.row_counts(frag)
-            return jnp.broadcast_to(counts,
-                                    (mat.shape[0],) + counts.shape)
-        masks = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
-        masked = frag[None] & masks[:, None]
-        return bitset.row_counts(masked)            # [B, rows]
-    if node.kind == "bsi_sum":
-        if node.plan is None:
-            counts = bsi.sum_counts(frag, None)
-            return jnp.broadcast_to(counts,
-                                    (mat.shape[0],) + counts.shape)
-        return jax.vmap(
-            lambda p: bsi.sum_counts(frag, eval_plan(node.plan, frags,
-                                                     p)))(
-            mat)                                           # [B, 2, d+1]
-    if node.kind == "bsi_minmax":
-        filt = None
-        if node.plan is not None:
-            filt = eval_plan(node.plan, frags, mat[0])
-        return bsi.min_max_bits(frag, filt,
-                                want_max=node.extra[0] == "max")
-    # group_counts: combos ride the leading axis of mat[0]
-    rids, params = mat
-    pk_list = node.extra[:-1]
-    fseg = eval_plan(node.plan, frags, params) \
-        if node.plan is not None else None
-
-    def one_combo(rids_row):
-        mask = None
-        for j, pk in enumerate(pk_list):
-            pfrag = frags[pk]
-            rid = rids_row[j]
-            if pfrag.shape[0] == 0:
-                seg = jnp.zeros(pfrag.shape[1:], dtype=pfrag.dtype)
-            else:
-                seg = jnp.where(
-                    rid < pfrag.shape[0],
-                    jax.lax.dynamic_index_in_dim(
-                        pfrag, jnp.minimum(rid, pfrag.shape[0] - 1),
-                        axis=0, keepdims=False),
-                    jnp.zeros_like(pfrag[0]))
-            mask = seg if mask is None else mask & seg
-        if fseg is not None:
-            mask = fseg if mask is None else mask & fseg
-        masked = frag if mask is None else frag & mask[None]
-        return bitset.row_counts(masked)            # [rows]
-
-    return jax.vmap(one_combo)(rids)                       # [C, rows]
-
-
 def _over_shards(per_shard, arrs, block: int | None):
     """``per_shard`` over the leading (device-local shard) axis of
     ``arrs``: one vmapped pass over whatever that axis is where
@@ -408,10 +299,10 @@ class WholeQueryRunner:
         return self._row_temp.get((program_repr, index), 0)
 
     def program_keys(self, program):
-        return program_keys(program, self.mesh)
+        return program_keys(program)
 
     def fusible(self, program) -> bool:
-        return all(n.kind in _BATCH_KINDS for n in program)
+        return all(n.kind in nodes.BATCH_KINDS for n in program)
 
     def precheck(self, program, holder, index, shards):
         """Raise WholeQueryUnsupported for shapes the single-program
@@ -429,23 +320,6 @@ class WholeQueryRunner:
                     "streamed-working-set",
                     f"{len(sched.slices)} shard slices")
         return keys
-
-    @staticmethod
-    def _participates(node, sig_map) -> bool:
-        """Whether a shape group contributes to a node (mirrors the
-        legacy per-stage skip conditions exactly)."""
-        if node.kind in ("count", "segments"):
-            return True
-        s0 = sig_map.get(node.primary)
-        if s0 is None:
-            return False
-        if node.kind in ("bsi_sum", "bsi_minmax") and \
-                _sig_rows(s0) < bsi.OFFSET_ROW + 1:
-            return False
-        if node.kind == "group_counts":
-            return all(sig_map.get(pk) is not None
-                       for pk in node.extra[:-1])
-        return True
 
     # -- execution ---------------------------------------------------------
 
@@ -479,19 +353,19 @@ class WholeQueryRunner:
             if node.kind == "group_counts":
                 rids, params = mat
                 actual_b.append(rids.shape[0])
-                pad_mats.append((pad_pow2_rows(
+                pad_mats.append((nodes.pad_pow2_rows(
                     np.asarray(rids, dtype=np.int32), repeat=False),
                     np.asarray(params, dtype=np.int32)))
             else:
                 m = np.ascontiguousarray(mat, dtype=np.int32)
                 actual_b.append(m.shape[0])
-                pad_mats.append(pad_pow2_rows(m))
+                pad_mats.append(nodes.pad_pow2_rows(m))
         pad_mats = tuple(pad_mats)
 
         # per-node schedule: which live groups contribute (static)
         sched = tuple(
             tuple(gi for gi, g in enumerate(live)
-                  if self._participates(node, g[1]))
+                  if nodes.participates(node, g[1]))
             for node in program)
         meta = self._node_meta(program, actual_b, live, sched,
                                empty_shards)
@@ -513,7 +387,7 @@ class WholeQueryRunner:
         local = tuple(b // mesh.n_devices for b in buckets)
         fn, blocks, temp_bytes, fresh = self._fit(
             key, local, program, live, sched, pad_mats, flat_all)
-        rows_padded = sum(_mat_rows(m) for m in pad_mats)
+        rows_padded = sum(nodes.mat_rows(m) for m in pad_mats)
         if len(self._row_temp) >= self.ROW_TEMP_MAX:
             self._row_temp.clear()      # the packer fuses unweighed once
         self._row_temp[key[1], index] = temp_bytes // (
@@ -576,8 +450,7 @@ class WholeQueryRunner:
         a program was built); raises ``batch-chunks`` where not even
         one shard at a time fits (the chunked path cuts the batch
         axis)."""
-        from ..executor.executor import batch_temp_bound
-        mesh, bound = self.mesh, batch_temp_bound()
+        mesh, bound = self.mesh, nodes.batch_temp_bound()
         blocks, fresh, top = None, False, max(local)
         while True:
             ckey = key if blocks is None else \
@@ -599,7 +472,7 @@ class WholeQueryRunner:
             if rung < 1:
                 raise WholeQueryUnsupported(
                     "batch-chunks",
-                    f"B={max(_mat_rows(m) for m in pad_mats)}")
+                    f"B={max(nodes.mat_rows(m) for m in pad_mats)}")
             blocks = tuple(_divisor_at_most(s, rung) for s in local)
 
     def _node_meta(self, program, actual_b, live, sched, empty_shards):
@@ -633,11 +506,11 @@ class WholeQueryRunner:
         def _combine_info(ni, node):
             if node.kind in ("row_counts", "group_counts"):
                 return {"rows": max(
-                    (_sig_rows(sig_maps[gi][node.primary])
+                    (nodes.sig_rows(sig_maps[gi][node.primary])
                      for gi in sched[ni]), default=0)}
             if node.kind == "bsi_sum":
                 return {"depth": max(
-                    (_sig_rows(sig_maps[gi][node.primary])
+                    (nodes.sig_rows(sig_maps[gi][node.primary])
                      - bsi.OFFSET_ROW for gi in sched[ni]), default=0)}
             return {}
 
@@ -649,7 +522,7 @@ class WholeQueryRunner:
             # of the stacked arrays ([S_local, ...]); mats are
             # replicated.  Reductions sum locally and psum over the
             # named shard axis — the in-program collective that replaces
-            # the legacy host-assembled per-shard reductions.
+            # the per-stage path's host merge of a part a group.
             per_group_raw: list[dict] = [dict() for _ in groups_static]
             i = 0
             for gi, (layout_g, n_g) in enumerate(groups_static):
@@ -664,7 +537,7 @@ class WholeQueryRunner:
                               _nis=node_ids):
                     frags = _unpack_frags(_layout, arrays)
                     return tuple(
-                        _node_shard(program[ni], mats[ni], frags)
+                        nodes.node_shard(program[ni], mats[ni], frags)
                         for ni in _nis)
 
                 outs_g = _over_shards(
@@ -706,7 +579,7 @@ class WholeQueryRunner:
                         jax.lax.psum(acc, axis_name=SHARD_AXIS))
                 else:  # row_counts / group_counts
                     R = combine[ni]["rows"]
-                    B = _mat_rows(mats[ni])
+                    B = nodes.mat_rows(mats[ni])
                     acc = jnp.zeros((B, R), dtype=jnp.int32)
                     for p in parts:
                         s = p.sum(axis=0)           # [B, rows_g]
@@ -723,7 +596,7 @@ class WholeQueryRunner:
         out_specs: list = []
         n_out = 0
         for ni, node in enumerate(program):
-            if node.kind in ("segments", "bsi_minmax"):
+            if node.kind in nodes.PER_SHARD_KINDS:
                 n_here = len(sched[ni]) * (3 if node.kind == "bsi_minmax"
                                            else 1)
                 out_specs.extend([P(SHARD_AXIS)] * n_here)

@@ -163,7 +163,7 @@ def build_debug_vars(api: API, server=None) -> dict:
         # the batch-temp bound (docs/batching.md): what one launch's
         # temporaries may cost now, and how often it cut a
         # pack, chunked a batch or sent a launch to shard blocks
-        from ..executor.executor import batch_temp_bound
+        from ..parallel.nodes import batch_temp_bound
         out["batchTemp"] = {
             "boundBytes": batch_temp_bound(),
             "splits": ex.mesh_exec.temp_splits,

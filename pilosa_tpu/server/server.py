@@ -47,8 +47,8 @@ class Config:
     # -- whole-query pjit programs (docs/whole-query.md) -------------------
     # Compile each read request into ONE pjit program over the mesh
     # (every call, every shape group, reductions in-program) instead of
-    # one executable per reducer stage.  Off restores the legacy
-    # per-stage dispatch exactly (the kill switch).
+    # one executable per reducer node.  Off sends every request to the
+    # per-stage dispatch of the same node bodies (the kill switch).
     whole_query: bool = True
     # Fallback policy for shapes the program can't express: "legacy"
     # reroutes to the per-stage path (counted `wholequery.fallback` +
@@ -301,7 +301,7 @@ class Config:
     flight_recorder_mb: int = 64
     # Ceiling (MB) on the batch-temp bound: one launch's
     # program temporaries may cost what the device has left, and never
-    # more than this (executor.batch_temp_bound; docs/batching.md).
+    # more than this (nodes.batch_temp_bound; docs/batching.md).
     # Under the bound a whole-query program walks its shards in
     # blocks, a batch axis chunks (query.batch_temp_splits) and the
     # cross-query batcher cuts a pack (dispatch.fused_temp_split).
@@ -596,9 +596,8 @@ class Server:
         _kernels.CONTAINER_KERNELS = str(self.config.container_kernels)
         # the batch-temp bound's ceiling (docs/batching.md);
         # process-wide, most recent Server wins
-        from ..executor import executor as _executor_mod
-        _executor_mod.BATCH_TEMP_BYTES = \
-            max(self.config.batch_temp_mb, 1) << 20
+        from ..parallel import nodes as _nodes
+        _nodes.BATCH_TEMP_BYTES = max(self.config.batch_temp_mb, 1) << 20
         # streaming ingest (docs/ingest.md): the delta-overlay budget is
         # process-wide like the others (most recent Server wins)
         from ..storage import membudget as _membudget

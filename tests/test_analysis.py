@@ -255,7 +255,7 @@ def test_swallow_suppressed_with_reason():
 def test_batcher_bypass_direct_dispatch_flagged():
     src = """
         def run(self, plan):
-            return self.executor.mesh.segments(plan)
+            return self.executor.mesh.reduce_async(node, mat)
     """
     assert len(lint(src, "batcher-bypass")) == 1
 
@@ -264,7 +264,7 @@ def test_batcher_bypass_alias_tracking_beats_the_grep():
     src = """
         def run(self, plan):
             m = MeshExecutor()
-            return m.row_counts(plan)
+            return m.reduce_async(node, mat)
     """
     assert len(lint(src, "batcher-bypass")) == 1
 
@@ -272,13 +272,13 @@ def test_batcher_bypass_alias_tracking_beats_the_grep():
 def test_batcher_bypass_allowed_inside_parallel_and_via_batcher():
     src = """
         def run(self, plan):
-            return self.mesh.segments(plan)
+            return self.mesh.reduce_async(node, mat)
     """
     assert lint(src, "batcher-bypass",
                 rel="pilosa_tpu/parallel/batcher.py") == []
     via = """
         def run(self, plan):
-            return self.batcher.segments(plan)
+            return self.batcher.reduce(node, mat)
     """
     assert lint(via, "batcher-bypass") == []
 

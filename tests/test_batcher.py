@@ -1,5 +1,5 @@
 """Cross-query dynamic batching (parallel/batcher.py, docs/batching.md):
-differential correctness under concurrency, the singleton fall-through,
+differential correctness under concurrency, the lone ticket's launch,
 queued-deadline drop-out, knob plumbing, and the client-abort stat."""
 
 import json
@@ -94,9 +94,9 @@ def test_batched_vs_off_byte_identical(corpus_holder):
         ex_off.close()
 
 
-def test_solo_query_takes_unvmapped_fallthrough(corpus_holder):
-    """A lone ticket falls through to the existing un-vmapped executables
-    (the solo-latency guarantee): singleton launches, no fused ones."""
+def test_solo_query_is_one_unfused_launch(corpus_holder):
+    """A lone ticket launches alone, at its own rows (B = 1 of the
+    program a pack would run): singleton launches, no fused ones."""
     ex = Executor(corpus_holder, use_mesh=True, dispatch_batch=True,
                   dispatch_batch_window_us=100)
     try:

@@ -166,7 +166,7 @@ def test_launch_ledger_populates_on_query(corpus):  # noqa: F811
         ex.close()
     assert devobs.LEDGER.launches_total > before
     entry = devobs.LEDGER.snapshot()["entries"][-1]
-    assert entry["kind"] in ("count", "countB", "wholequery")
+    assert entry["kind"] in ("count", "wholequery")
     assert entry["shards"] == 3
     # 3 shards bucket-pad to the 8-device mesh width
     assert entry["shardsPadded"] == 8

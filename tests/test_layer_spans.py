@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from pilosa_tpu.parallel.mesh_exec import SHARD_AXIS, MeshExecutor
+from pilosa_tpu.parallel.nodes import KINDS
 from pilosa_tpu.parallel.wholequery import (PROGRAM_NAME_NODES,
                                             _InstrumentedWhole, program_name)
 from pilosa_tpu.utils import devobs
@@ -248,10 +249,6 @@ def test_profiler_trace_holds_the_spans(served, tmp_path):
         trace_gaps.trace_reduce.read_events(path))
     assert trace_gaps.overlap_ns(
         spans, ("dispatch.idle", "dispatch.window", "dispatch.round")) == 0
-
-
-KINDS = ("count", "segments", "row_counts", "bsi_sum", "bsi_minmax",
-         "countB", "segmentsB", "row_countsB", "bsi_sumB", "group_countsB")
 
 
 @pytest.mark.parametrize("kind", KINDS)

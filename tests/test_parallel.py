@@ -4,6 +4,8 @@ Placement mirrors cluster_internal_test.go (TestCluster_Partition /
 partitionNodes); mesh execution runs real shard_map over the 8 virtual CPU
 devices from conftest and must agree with the per-shard executor."""
 
+import threading
+
 import jax
 import numpy as np
 import pytest
@@ -13,7 +15,13 @@ from pilosa_tpu.executor import Executor
 from pilosa_tpu.parallel import (
     JmpHasher, MeshExecutor, ModHasher, Placement, default_mesh, jump_hash,
 )
-from pilosa_tpu.storage import FieldOptions, Holder
+from pilosa_tpu.parallel.nodes import KINDS
+from pilosa_tpu.pql import parse
+from pilosa_tpu.storage import FieldOptions, Holder, fragment
+from pilosa_tpu.storage.membudget import DEFAULT_BUDGET
+from pilosa_tpu.utils import devobs
+
+from test_differential import _norm
 
 
 # -- placement --------------------------------------------------------------
@@ -372,3 +380,189 @@ def test_mesh_single_shard(tmp_path):
     assert meshy.execute("i", "Count(Row(f=1))") == [1]
     res = meshy.execute("i", "Row(f=1)")[0]
     assert res.columns().tolist() == [42]
+
+
+# -- one body per reducer: the per-stage launcher ---------------------------
+# Every node kind of parallel/nodes.py, launched per stage
+# (MeshExecutor.reduce_async) resident and streamed, against the
+# whole-query program over the same body and the host reference.
+
+STAGE_SHARDS = 16       # two mesh-width slices on the 8-device test mesh
+STAGE_QUERIES = {
+    "count": "Count(Intersect(Row(a=1), Row(b=2)))",
+    "segments": "Union(Row(a=3), Row(b=1))",
+    "row_counts": "TopN(a, Row(b=1), n=3)",
+    "bsi_sum": "Sum(Row(v > 17), field=v)",
+    "bsi_minmax": "Min(Row(a=2), field=v)",
+    "group_counts": "GroupBy(Rows(b), Rows(a))",
+}
+# the same shapes three at a time: Count, TopN and Sum batch into one
+# launch at B = 3 (padded to 4), the others launch a call each
+STAGE_TRIPLES = {
+    "count": " ".join(f"Count(Intersect(Row(a={i}), Row(b={i})))"
+                      for i in (1, 2, 3)),
+    "segments": "Union(Row(a=3), Row(b=1)) Union(Row(a=4), Row(b=2)) "
+                "Union(Row(a=5), Row(b=3))",
+    "row_counts": " ".join(f"TopN(a, Row(b={i}), n=3)" for i in (1, 2, 3)),
+    "bsi_sum": " ".join(f"Sum(Row(v > {i}), field=v)" for i in (17, 400, 9)),
+    "bsi_minmax": "Min(Row(a=2), field=v) Min(Row(a=3), field=v) "
+                  "Min(Row(a=4), field=v)",
+    "group_counts": "GroupBy(Rows(b), Rows(a)) GroupBy(Rows(b), Rows(a)) "
+                    "GroupBy(Rows(b), Rows(a))",
+}
+
+
+@pytest.fixture(scope="module")
+def staged():
+    h = Holder(None)
+    idx = h.create_index("p")
+    a = idx.create_field("a")
+    b = idx.create_field("b")
+    v = idx.create_field("v", FieldOptions(type="int", min=0, max=1000))
+    rng = np.random.default_rng(33)
+    n = 30_000
+    cols = rng.integers(0, STAGE_SHARDS * SHARD_WIDTH, size=n)
+    a.import_bits(rng.integers(0, 10, size=n), cols)
+    b.import_bits(rng.integers(0, 4, size=n), cols)
+    vcols = np.unique(cols[: n // 2])
+    v.import_values(vcols, rng.integers(0, 1000, size=vcols.size))
+    idx.add_existence(cols)
+    yield h
+    h.close()
+
+
+def _launched(since: int) -> list[dict]:
+    """The launch ledger's entries after its ``since``-th launch."""
+    n = devobs.LEDGER.launches_total - since
+    return devobs.LEDGER.snapshot()["entries"][-n:] if n else []
+
+
+@pytest.mark.parametrize("streamed", [False, True],
+                         ids=["resident", "streamed"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_per_stage_equals_whole_query_and_host(staged, monkeypatch, kind,
+                                               streamed):
+    """The per-stage answer (``whole_query=False``: one launch of the
+    node a shape group and shard slice) is the whole-query program's and
+    the ``use_mesh=False`` reference's, with the working set resident and
+    under a device budget that streams it in two slices; every launch
+    it makes is named by the node kind."""
+    # the dense form: compressed residency would fit the budget whole
+    monkeypatch.setattr(fragment, "COMPRESSED_RESIDENT", False)
+    q = STAGE_QUERIES[kind]
+    host = Executor(staged)
+    whole = Executor(staged, use_mesh=True, whole_query_fallback="error")
+    stage = Executor(staged, use_mesh=True, whole_query=False)
+    old = DEFAULT_BUDGET.limit_bytes
+    try:
+        DEFAULT_BUDGET.limit_bytes = None
+        want = _norm(host.execute("p", q))
+        assert _norm(whole.execute("p", q)) == want
+        whole.close()
+        if streamed:
+            # each query stacks a or v: 16 shards x 16 rows x 128 KiB
+            DEFAULT_BUDGET.limit_bytes = 12 << 20
+            DEFAULT_BUDGET.shrink_to_limit()
+        # the text replays a prepared template where there is one (a
+        # call group of one row), the parsed query takes the call's own
+        # lowering: both launch the node at B = 1
+        assert _norm(stage.execute("p", q)) == want
+        since = devobs.LEDGER.launches_total
+        assert _norm(stage.execute("p", parse(q))) == want
+        entries = _launched(since)
+        assert {e["kind"] for e in entries} == {kind}
+        assert {e["slices"] for e in entries} == {2 if streamed else 1}
+        # one row; a GroupBy's leading axis is its prefix combos (b: 4)
+        rows = 4 if kind == "group_counts" else 1
+        assert all(e["batchRows"] == rows for e in entries)
+    finally:
+        DEFAULT_BUDGET.limit_bytes = old
+        for ex in (host, whole, stage):
+            ex.close()
+
+
+def _stage_kinds(ex) -> list[str]:
+    return [k[0] for k in ex.mesh_exec._cache]
+
+
+def test_lone_and_batched_count_share_one_executable(staged):
+    """A lone Count is B = 1 of the program a three-call query runs at
+    B = 3: one ``count`` executable, not a plain and a batched one."""
+    ex = Executor(staged, use_mesh=True, whole_query=False)
+    host = Executor(staged)
+    try:
+        lone = "Count(Intersect(Row(a=4), Row(b=0)))"
+        assert ex.execute("p", lone) == host.execute("p", lone)
+        assert _stage_kinds(ex) == ["count"]
+        since = devobs.LEDGER.launches_total
+        triple = STAGE_TRIPLES["count"]
+        assert ex.execute("p", triple) == host.execute("p", triple)
+        assert _stage_kinds(ex) == ["count"]
+        # (the call group's chunk is padded to a power of two before
+        # it becomes a ticket: the ledger sees the ticket's four rows)
+        (entry,) = _launched(since)
+        assert (entry["kind"], entry["batchRowsPadded"]) == ("count", 4)
+    finally:
+        ex.close()
+        host.close()
+
+
+def test_executable_kinds_are_the_node_kinds(staged):
+    """After every node kind has run per stage at B = 1 and B = 3 and
+    through the whole-query program, no executable's kind is outside the
+    six node kinds, ``wholequery`` and ``overlay``."""
+    stage = Executor(staged, use_mesh=True, whole_query=False)
+    whole = Executor(staged, use_mesh=True)
+    host = Executor(staged)
+    try:
+        for kind in KINDS:
+            for q in (STAGE_QUERIES[kind], STAGE_TRIPLES[kind]):
+                want = _norm(host.execute("p", q))
+                assert _norm(stage.execute("p", q)) == want, q
+                assert _norm(whole.execute("p", q)) == want, q
+        assert set(_stage_kinds(stage)) == set(KINDS)
+        assert set(_stage_kinds(whole)) == {"wholequery"}
+        # an ingest flush overlays the resident stack (docs/ingest.md)
+        staged.field("p", "a").set_bit(1, 5)
+        assert stage.execute("p", "Count(Row(a=1))") == \
+            host.execute("p", "Count(Row(a=1))")
+        assert set(_stage_kinds(stage)) <= set(KINDS) | {"overlay"}
+    finally:
+        for ex in (stage, whole, host):
+            ex.close()
+
+
+def test_single_call_and_call_group_fuse_into_one_launch(staged):
+    """A single call (B = 1) and a batched call group (B = 3) of one
+    node, submitted together, are one ticket kind: they fuse into one
+    launch (one row and the group's chunk of three padded to four: five,
+    padded to eight)."""
+    ex = Executor(staged, use_mesh=True, whole_query=False,
+                  dispatch_batch_window_us=500_000)
+    host = Executor(staged)
+    lone = "Count(Intersect(Row(a=4), Row(b=0)))"
+    triple = STAGE_TRIPLES["count"]
+    got: dict = {}
+    barrier = threading.Barrier(2)
+
+    def client(q):
+        barrier.wait()
+        got[q] = ex.execute("p", q)
+
+    try:
+        threads = [threading.Thread(target=client, args=(q,))
+                   for q in (lone, triple)]
+        since = devobs.LEDGER.launches_total
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert got == {q: host.execute("p", q) for q in (lone, triple)}
+        assert (ex.batcher.fused_launches, ex.batcher.single_launches) \
+            == (1, 0)
+        (entry,) = _launched(since)
+        assert (entry["kind"], entry["tickets"], entry["batchRows"],
+                entry["batchRowsPadded"]) == ("count", 2, 5, 8)
+    finally:
+        ex.close()
+        host.close()

@@ -177,12 +177,12 @@ def test_chunked_batch_dispatch(holder, classic, monkeypatch):
     padded power-of-two dispatches (bounding per-dispatch HBM gather
     temps) and still return per-call-exact results, on both the prepared
     and the classic grouped paths."""
-    from pilosa_tpu.executor import executor as exmod
+    from pilosa_tpu.parallel import nodes
 
     # shrink the temp budget so chunking kicks in at tiny B: with P=2 and
     # 2 shards over the 8-device test mesh (1 stacked shard per device),
     # chunk = budget / (2*1*SHARD_WORDS*4) = 16 rows per dispatch
-    monkeypatch.setattr(exmod, "BATCH_TEMP_BYTES", 2 * 2 * 32768 * 4 * 8)
+    monkeypatch.setattr(nodes, "BATCH_TEMP_BYTES", 2 * 2 * 32768 * 4 * 8)
 
     rng = np.random.default_rng(11)
     pairs = [(int(a), int(b))

@@ -110,17 +110,17 @@ def forced_bound(deployment, monkeypatch):
     """The bound forced between the compiler's figure for one lone Q1.2
     request over ONE stacked shard and its figure over the two a device
     holds at 12 shards."""
-    from pilosa_tpu.executor import executor as exmod
+    from pilosa_tpu.parallel import nodes
     dep = deployment(12)
     assert dep.srv.api.executor.mesh_exec.stacked_per_device(12) == 2
     lone = dep.body(dep.pick(1, 1))
     dep.client.query(dep.index, lone)
     (whole,) = _q12_programs(dep)[1, None]._temps.values()
-    monkeypatch.setattr(exmod, "BATCH_TEMP_BYTES", whole - 1)
+    monkeypatch.setattr(nodes, "BATCH_TEMP_BYTES", whole - 1)
     dep.client.query(dep.index, lone)
     (one,) = _q12_programs(dep)[1, (1,)]._temps.values()
     assert 0 < one < whole
-    monkeypatch.setattr(exmod, "BATCH_TEMP_BYTES", (one + whole) // 2)
+    monkeypatch.setattr(nodes, "BATCH_TEMP_BYTES", (one + whole) // 2)
     return dep
 
 
@@ -259,32 +259,33 @@ def test_bound_is_what_the_device_has_left(monkeypatch):
     ``batch-temp-mb`` ceiling; the ceiling alone where the backend
     reports no limit (here)."""
     from pilosa_tpu.executor import executor as exmod
+    from pilosa_tpu.parallel import nodes
     from pilosa_tpu.storage.membudget import DEFAULT_BUDGET
-    assert exmod.device_bytes_limit() is None
-    assert exmod.batch_temp_bound() == exmod.BATCH_TEMP_BYTES
+    assert nodes.device_bytes_limit() is None
+    assert nodes.batch_temp_bound() == nodes.BATCH_TEMP_BYTES
     resident = DEFAULT_BUDGET.resident_bytes
 
     def limit(nbytes):
-        monkeypatch.setattr(exmod, "device_bytes_limit", lambda: nbytes)
+        monkeypatch.setattr(nodes, "device_bytes_limit", lambda: nbytes)
 
     limit(resident + (16 << 30))
-    assert exmod.batch_temp_bound() == exmod.BATCH_TEMP_BYTES
+    assert nodes.batch_temp_bound() == nodes.BATCH_TEMP_BYTES
     limit(resident + (3 << 30))
-    assert exmod.batch_temp_bound() == 2 << 30
+    assert nodes.batch_temp_bound() == 2 << 30
     limit(resident)
-    assert exmod.batch_temp_bound() == 0
+    assert nodes.batch_temp_bound() == 0
     # nothing fits: a chunk is still one row, the launch still runs
     assert exmod.batch_chunk_size(2, 4) == 1
     # a filtered Sum over a 32-row field at 16 stacked shards, the bound
     # in rows a shard: no 8-row floor, a power of two under the bound
-    rows = exmod.node_temp_rows("sum", object(), 3, 32)
-    assert rows == 32 == exmod.node_temp_rows("topn", object(), 3, 32)
-    assert exmod.node_temp_rows("sum", None, 0, 32) == 0
-    assert exmod.node_temp_rows("count", object(), 3) == 3
+    rows = nodes.node_temp_rows("bsi_sum", object(), 3, 32)
+    assert rows == 32 == nodes.node_temp_rows("row_counts", object(), 3, 32)
+    assert nodes.node_temp_rows("bsi_sum", None, 0, 32) == 0
+    assert nodes.node_temp_rows("count", object(), 3) == 3
     for left, chunk in ((31, 1), (32, 1), (63, 1), (64, 2), (255, 4),
                         (256, 8)):
-        limit(resident + exmod.BATCH_TEMP_MARGIN
-              + left * 16 * exmod.ROW_BYTES)
+        limit(resident + nodes.BATCH_TEMP_MARGIN
+              + left * 16 * nodes.ROW_BYTES)
         assert exmod.batch_chunk_size(rows, 16) == chunk, left
 
 

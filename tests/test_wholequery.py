@@ -310,7 +310,7 @@ def test_bucket_steps_with_a_cached_program(corpus, monkeypatch, q, blocked):
     has its own program and blocks.  Exact against the legacy path at
     every size, and never a fallback."""
     import jax
-    from pilosa_tpu.executor import executor as exmod
+    from pilosa_tpu.parallel import nodes
     from pilosa_tpu.parallel.mesh_exec import default_mesh
     legacy = Executor(corpus, use_mesh=True, whole_query=False)
     ex = Executor(corpus, mesh=default_mesh(jax.devices()[:1]),
@@ -324,7 +324,7 @@ def test_bucket_steps_with_a_cached_program(corpus, monkeypatch, q, blocked):
             (fn,) = [f for k, f in mesh._cache.items()
                      if k[0] == "wholequery"]
             (temp,) = fn._temps.values()
-            monkeypatch.setattr(exmod, "BATCH_TEMP_BYTES", temp // 3)
+            monkeypatch.setattr(nodes, "BATCH_TEMP_BYTES", temp // 3)
         splits = mesh.temp_splits
         for size in (16, 17, 20, 16, 9, 8, 17):
             shards = list(range(size))
@@ -431,14 +431,14 @@ NODE_QUERIES = {
 
 @pytest.mark.parametrize("kind", list(NODE_QUERIES))
 def test_node_takes_tiled_stacks(corpus, monkeypatch, kind):
-    """One ``_node_shard`` kind a case: the program's stacked arguments
+    """One ``nodes.node_shard`` kind a case: the program's stacked arguments
     are rank 4 with trailing (256, 128), the fragments its per-shard
     pass sees rank 3 with the same tile, and the answer is the legacy
     path's."""
     from pilosa_tpu.core import SHARD_WORDS, WORD_TILE
-    from pilosa_tpu.parallel import wholequery as wq
+    from pilosa_tpu.parallel import nodes, wholequery as wq
     stacked, seen = [], []
-    real_over, real_node = wq._over_shards, wq._node_shard
+    real_over, real_node = wq._over_shards, nodes.node_shard
 
     def over(per_shard, arrs, block):
         stacked.extend(a.shape for a in arrs)
@@ -449,7 +449,7 @@ def test_node_takes_tiled_stacks(corpus, monkeypatch, kind):
         return real_node(node, mat, frags)
 
     monkeypatch.setattr(wq, "_over_shards", over)
-    monkeypatch.setattr(wq, "_node_shard", node_shard)
+    monkeypatch.setattr(nodes, "node_shard", node_shard)
     # a fresh executor traces its programs anew: the spies see them
     ex = Executor(corpus, use_mesh=True, whole_query_fallback="error")
     legacy = Executor(corpus, use_mesh=True, whole_query=False)
