@@ -21,6 +21,7 @@ from ..storage.field import FIELD_TYPE_INT, FIELD_TYPE_BOOL
 from ..storage import time_quantum as tq
 from .plan import PlanCompiler, ReduceNode, Resolver, parametrize
 # (after .plan: parallel/ imports it, and this package through it)
+from ..parallel.fetch import fetch_parts
 from ..parallel.nodes import (
     ROW_BYTES, batch_temp_bound, node_keys, node_temp_rows, pad_pow2_rows,
     pow2_rows,
@@ -270,21 +271,17 @@ class _Pending:
 
 
 def _resolve_pendings(results):
-    """Resolve all _Pending results with a single device->host fetch.
-    Parts shared between pendings (batched call groups) fetch once;
-    ``jax.device_get`` on the whole list rides one transfer round trip
-    where N serial fetches pay N."""
+    """Resolve all _Pending results with a single device->host fetch
+    (``fetch.fetch_parts``).  Parts shared between pendings (batched
+    call groups) fetch once; a part that is a view of a fused launch's
+    shared fetch becomes its rows of the host copy here, so a finalizer
+    sees its ticket's batch axis leading whichever way it was served."""
     unique: dict[int, Any] = {}
     for r in results:
         if isinstance(r, (_Pending, _PendingGroup)):
             for p in r.parts:
                 unique.setdefault(id(p), p)
-    host: dict[int, np.ndarray] = {}
-    if unique:
-        import jax
-        fetched = jax.device_get(list(unique.values()))
-        for pid, arr in zip(unique.keys(), fetched):
-            host[pid] = np.asarray(arr)
+    host = dict(zip(unique, fetch_parts(list(unique.values()))))
     out = []
     for i, r in enumerate(results):
         if isinstance(r, _Pending):
@@ -1255,9 +1252,8 @@ class Executor:
 
     def _plan_segments(self, plan, index: str, shards) -> dict:
         if self.mesh_exec is not None:
-            import jax
             parts, groups = self._reduce(index, shards, "segments", plan)
-            return _segments(jax.device_get(parts), 0, groups, shards)
+            return _segments(fetch_parts(parts), 0, groups, shards)
         # host [W] words, as the mesh path returns them: the word tile
         # is flattened after the fetch, never in a program
         return {
@@ -1285,7 +1281,8 @@ class Executor:
         them on the spot)."""
         parts, _ = self._reduce(index, shards, "row_counts", None,
                                 (field_name, view))
-        return self.mesh_exec.merge_counts(np.asarray(p)[0] for p in parts)
+        return self.mesh_exec.merge_counts(
+            p[0] for p in fetch_parts(parts))
 
     def _execute_count(self, index: str, c: Call, shards) -> int:
         """(executor.go:1790 executeCount)"""

@@ -19,13 +19,15 @@ launch would share (node kind and repr, index, shard set, holder); a
 dispatcher thread drains compatible tickets — stacking their params rows
 along the leading query axis, launching the node ONCE over the stacked
 matrix (``MeshExecutor.reduce_async``, the node's body vmapped over that
-axis), and scattering per-ticket slices of the results back to waiting
-futures.  Launch policy is adaptive: fire when the queue reaches
-``max_batch`` tickets or the oldest ticket has waited ``window_us``
-microseconds; fused query-axis sizes pad up to powers of two so
-compile-cache churn stays bounded.  A ticket that drains alone launches
-the same node over its own rows: a lone call is B = 1 of the program a
-fused pack runs, not another executable.
+axis), and handing each waiting future its rows of the results: views
+of ONE host copy the launch's tickets share for the reduced kinds, a
+device slice a ticket for ``segments`` (parallel/fetch.py).  Launch
+policy is adaptive: fire when the queue reaches ``max_batch`` tickets or
+the oldest ticket has waited ``window_us`` microseconds; fused
+query-axis sizes pad up to powers of two so compile-cache churn stays
+bounded.  A ticket that drains alone launches the same node over its
+own rows: a lone call is B = 1 of the program a fused pack runs, not
+another executable.
 
 Deadlines (docs/robustness.md): time queued here counts against the
 query budget — tickets carry their QueryContext, and an expired or
@@ -57,9 +59,10 @@ from ..utils.faults import FAULTS
 from ..utils.locks import make_condition
 from ..utils.stats import BucketHistogram, NopStatsClient, ReservoirTimer
 from ..utils.tracing import GLOBAL_TRACER, layer_span
+from .fetch import HostView, SharedFetch
 from .mesh_exec import _DISPATCH_LOCK, field_rows
-from .nodes import BATCH_KINDS, ROW_BYTES, batch_temp_bound, node_keys, \
-    node_temp_rows, pad_pow2_rows
+from .nodes import BATCH_KINDS, PER_SHARD_KINDS, ROW_BYTES, \
+    batch_temp_bound, node_keys, node_temp_rows, pad_pow2_rows
 
 # Total fused query-axis rows per launch: a batched call group's tickets
 # are pre-chunked by executor._batch_chunks to keep per-device gather
@@ -505,12 +508,45 @@ class DispatchBatcher:
                      "paddedRows": padded_rows},
                     collect=t.trace.collect)
 
+    def _scatter(self, tickets, kinds, parts, spans, result):
+        """Resolve every ticket of a fused launch with its rows of the
+        outputs: ``parts[ni]`` are node ni's device arrays (kind
+        ``kinds[ni]``) over the fused batch axis, ``spans[ti][ni]`` is
+        ticket ti's (first row, rows) on it, and ``result`` makes a
+        ticket's result of its parts (one list a node, the ticket's
+        batch axis where the launch's was) and spans.  The one rule
+        (docs/batching.md): reduced kinds are handed out as views of
+        the launch's ONE shared fetch (parallel/fetch.py) — no jax
+        call, so the collective-launch lock, which orders enqueues, is
+        not held and this thread goes on to its next launch; kinds in
+        ``PER_SHARD_KINDS`` (the shard axis first, the batch axis
+        second) keep a device slice a ticket, an eager op under that
+        lock."""
+        with layer_span("dispatch.scatter", self._round_stats,
+                        tickets=len(tickets)):
+            reduced, first = [], []
+            for kind, ps in zip(kinds, parts):
+                first.append(len(reduced))
+                if kind not in PER_SHARD_KINDS:
+                    reduced.extend(ps)
+            shared = SharedFetch(reduced) if reduced else None
+            for t, span in zip(tickets, spans):
+                mine = []
+                for kind, ps, j0, (lo, b) in zip(kinds, parts, first, span):
+                    if kind in PER_SHARD_KINDS:
+                        with _DISPATCH_LOCK:
+                            mine.append([a[:, lo:lo + b] for a in ps])
+                    else:
+                        mine.append([HostView(shared, j0 + k, lo, b)
+                                     for k in range(len(ps))])
+                t.future.set_result(result(mine, span))
+
     def _launch_fused_whole(self, tickets, queue_s):
         """Fuse same-shape whole-query programs: concatenate each
         node's params matrix along the batch axis and launch the shared
-        compiled program ONCE; per-ticket results are batch-axis slices
-        (WholeOut.slice_batch).  Fusibility (batch-kind nodes only) was
-        decided at ticket creation via the key."""
+        compiled program ONCE; each ticket gets its rows of the outputs
+        (``_scatter``).  Fusibility (batch-kind nodes only) was decided
+        at ticket creation via the key."""
         from .wholequery import WholeQueryUnsupported
         p0 = tickets[0].payload
         runner = p0["runner"]
@@ -521,15 +557,14 @@ class DispatchBatcher:
             # shard schedule exactly once; an over-budget working set
             # raises WholeQueryUnsupported into every waiter below and
             # the executors reroute to the per-stage streaming path
-            n_nodes = len(program)
-            node_mats, node_lo = [], []
-            for ni in range(n_nodes):
+            node_mats = []
+            spans = [[] for _ in tickets]
+            for ni in range(len(program)):
                 mats_n = [t.payload["mats"][ni] for t in tickets]
-                lows, lo = [], 0
-                for m in mats_n:
-                    lows.append(lo)
+                lo = 0
+                for span, m in zip(spans, mats_n):
+                    span.append((lo, m.shape[0]))
                     lo += m.shape[0]
-                node_lo.append(lows)
                 node_mats.append(np.concatenate(mats_n)
                                  if len(mats_n) > 1 else mats_n[0])
             B = sum(m.shape[0] for m in node_mats)
@@ -547,15 +582,8 @@ class DispatchBatcher:
                 devobs.reset_launch_ctx(ltok)
             self._note_fused(tickets, time.perf_counter() - t_launch0,
                              batch_rows=B, padded_rows=pad_total)
-            with _DISPATCH_LOCK, layer_span(
-                    "dispatch.scatter", self._round_stats,
-                    tickets=len(tickets)):
-                for ti, t in enumerate(tickets):
-                    t.future.set_result(out.slice_batch(
-                        program,
-                        [node_lo[ni][ti] for ni in range(n_nodes)],
-                        [t.payload["mats"][ni].shape[0]
-                         for ni in range(n_nodes)]))
+            self._scatter(tickets, [n.kind for n in program], out.parts,
+                          spans, out.for_ticket)
         except BaseException as e:
             if isinstance(e, WholeQueryUnsupported) and \
                     e.node == "streamed-working-set":
@@ -614,22 +642,12 @@ class DispatchBatcher:
             # owner-blocked invariant)
             self._note_fused(tickets, time.perf_counter() - t_launch0,
                              batch_rows=B, padded_rows=mat.shape[0] - B)
-            # scatter: per-ticket views into the fused device results,
-            # cut along the batch axis (segments keep the shard axis
-            # first).  Summed outputs are replicated (psum, P() specs),
-            # so slicing is a local per-device gather — but hold the
-            # collective-launch lock anyway to keep one global
-            # program-enqueue order.
-            with _DISPATCH_LOCK, layer_span(
-                    "dispatch.scatter", self._round_stats,
-                    tickets=len(tickets)):
-                lo = 0
-                for t in tickets:
-                    rows = slice(lo, lo + t.params.shape[0])
-                    at = (slice(None), rows) if kind == "segments" else rows
-                    t.future.set_result(
-                        ([part[at] for part in parts], groups))
-                    lo = rows.stop
+            spans, lo = [], 0
+            for t in tickets:
+                spans.append([(lo, t.params.shape[0])])
+                lo += t.params.shape[0]
+            self._scatter(tickets, [kind], [parts], spans,
+                          lambda mine, _span: (mine[0], groups))
         except BaseException as e:
             self._fail_all(tickets, e if isinstance(e, Exception)
                            else RuntimeError(repr(e)))

@@ -120,7 +120,8 @@ class WholeOut:
     """One whole-query launch's unfetched device outputs.
 
     ``parts[i]`` is node i's device arrays (unfetched, so the executor
-    keeps its dispatch-all-then-fetch-once pipeline); ``meta[i]``
+    keeps its dispatch-all-then-fetch-once pipeline; to a ticket of a
+    fused launch, views of the launch's one shared fetch); ``meta[i]``
     carries the host-assembly facts the finalizers need (per-group
     shard lists, fragment-less shards, actual batch rows)."""
 
@@ -140,20 +141,13 @@ class WholeOut:
         # post-deploy compile is visible per request (docs/warmup.md)
         self.compiled = compiled
 
-    def slice_batch(self, program, node_lo: list[int], node_b: list[int]):
-        """A fused launch's per-ticket view: slice every node's batch
-        axis back out (batch-kind nodes only — fusibility is checked
-        before tickets coalesce)."""
-        parts, meta = [], []
-        for ni, node in enumerate(program):
-            lo, b = node_lo[ni], node_b[ni]
-            m = dict(self.meta[ni])
-            m["B"] = b
-            if node.kind == "segments":
-                parts.append([arr[:, lo:lo + b] for arr in self.parts[ni]])
-            else:
-                parts.append([arr[lo:lo + b] for arr in self.parts[ni]])
-            meta.append(m)
+    def for_ticket(self, parts, spans):
+        """A fused launch's output as one of its tickets sees it:
+        ``parts`` are the ticket's own (``DispatchBatcher._scatter``:
+        views of the launch's shared fetch, a device slice for a
+        per-shard kind), ``spans[ni]`` its (first row, rows) on node
+        ni's batch axis."""
+        meta = [dict(m, B=b) for m, (_lo, b) in zip(self.meta, spans)]
         return WholeOut(parts, meta, self.sig, self.compiled)
 
 

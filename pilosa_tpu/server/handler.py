@@ -251,7 +251,8 @@ def build_debug_vars(api: API, server=None) -> dict:
     from ..utils import devobs
     out["device"] = {**devobs.device_info(),
                      "compiles": devobs.COMPILES.totals(),
-                     "launches": devobs.LEDGER.aggregates()}
+                     "launches": devobs.LEDGER.aggregates(),
+                     "fetches": devobs.FETCHES.snapshot()}
     # warm start (docs/warmup.md): phase, replay progress, and the
     # compile-seconds-saved headline for the deploy dashboard
     warm = getattr(server, "warmup", None) if server is not None else None

@@ -433,7 +433,27 @@ class LaunchLedger:
         return "\n".join(lines) + "\n"
 
 
+class FetchCounts:
+    """How often results crossed to the host (parallel/fetch.py;
+    /debug/vars ``device.fetches``): ``transfers`` counts every
+    ``jax.device_get`` the result-fetch layer made, shared or not;
+    ``shared_tickets`` the tickets of fused launches served from a
+    transfer another ticket's thread made.  Plain ints, written and
+    read without a lock: a lock every request thread takes is dear."""
+
+    __slots__ = ("transfers", "shared_tickets")
+
+    def __init__(self):
+        self.transfers = 0
+        self.shared_tickets = 0
+
+    def snapshot(self) -> dict:
+        return {"transfers": self.transfers,
+                "sharedTickets": self.shared_tickets}
+
+
 # Process-wide singletons, like DEFAULT_BUDGET: one device runtime per
 # process, one telemetry surface.  Tests use deltas or private instances.
 COMPILES = CompileRegistry()
 LEDGER = LaunchLedger()
+FETCHES = FetchCounts()

@@ -34,6 +34,7 @@ tests/test_durability.py
 tests/test_events.py
 tests/test_executor.py
 tests/test_explain.py
+tests/test_fused_fetch.py
 tests/test_fuzz.py
 tests/test_ingest.py
 tests/test_kernels.py

@@ -220,7 +220,7 @@ def test_profiler_trace_holds_the_spans(served, tmp_path):
         (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
                                 / "*.xplane.pb"))
         by_line: dict = {}
-        roots, enqueues, jits = [], [], set()
+        roots, enqueues, scatters, jits = [], [], [], set()
         for plane in ProfileData.from_file(path).planes:
             if plane.name != "/host:CPU":
                 continue
@@ -230,6 +230,8 @@ def test_profiler_trace_holds_the_spans(served, tmp_path):
                         by_line.setdefault(i, set()).add(e.name)
                     if e.name == "dispatch.enqueue":
                         enqueues.append(dict(e.stats))
+                    elif e.name == "dispatch.scatter":
+                        scatters.append(dict(e.stats))
                     elif e.name == "http.query":
                         roots.append(dict(e.stats))
                     elif e.name.startswith("PjitFunction(ptpu_"):
@@ -243,12 +245,41 @@ def test_profiler_trace_holds_the_spans(served, tmp_path):
     assert enqueues and all(
         {"kind", "sig", "rows", "rows_padded", "tickets", "compiled"}
         <= set(e) for e in enqueues)
+    # one scatter a fused launch, tagged with the tickets it resolved
+    fused = [e for e in enqueues if int(e["tickets"]) > 1]
+    assert fused and len(scatters) == len(fused)
+    assert sorted(int(e["tickets"]) for e in scatters) \
+        == sorted(int(e["tickets"]) for e in fused)
     assert any(j.startswith("PjitFunction(ptpu_wq_") for j in jits)
     # the arithmetic half reads the same spans
     spans = trace_gaps.host_spans(
         trace_gaps.trace_reduce.read_events(path))
     assert trace_gaps.overlap_ns(
         spans, ("dispatch.idle", "dispatch.window", "dispatch.round")) == 0
+
+
+def test_scatter_holds_no_dispatch_lock(served, monkeypatch):
+    """``dispatch.scatter`` hands out views of one shared fetch and
+    enqueues nothing, so the collective-launch lock — only the
+    dispatcher thread launches in a burst of tickets — is free while
+    the span is open."""
+    from pilosa_tpu.parallel import batcher, mesh_exec
+    held = []
+
+    class Watched(batcher.HostView):
+        __slots__ = ()
+
+        def __init__(self, *a):
+            held.append(mesh_exec._DISPATCH_LOCK.locked())
+            super().__init__(*a)
+
+    monkeypatch.setattr(batcher, "HostView", Watched)
+    b = served["srv"].api.executor.batcher
+    before = b.fused_launches
+    with limit(60):
+        _burst(served["port"])
+    assert b.fused_launches > before
+    assert held and not any(held)
 
 
 @pytest.mark.parametrize("kind", KINDS)

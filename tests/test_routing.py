@@ -406,6 +406,9 @@ def test_local_residency_summary_tiers(rcluster):
     HBM-resident (stacked blocks count as resident)."""
     servers = rcluster
     query(servers[0].port, "sk", "Count(Row(a=1))")
+    for s in servers:
+        # a probe may have cached the summary (2 s TTL) before the query
+        s.cluster._residency_cache = None
     summaries = [s.cluster.residency_summary() for s in servers]
     assert any("sk" in s and s["sk"]["hbm"] for s in summaries), \
         f"no node reports sk resident: {summaries}"
