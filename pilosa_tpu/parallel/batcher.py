@@ -607,7 +607,8 @@ class DispatchBatcher:
             # PR1 composition: an over-budget working set streams in shard
             # slices — the fused single-slice path would stage it whole,
             # so stream each ticket through its direct path instead
-            with layer_span("dispatch.place", devobs.LEDGER):
+            with layer_span("dispatch.place", devobs.LEDGER,
+                            devices=mesh.n_devices):
                 sched = mesh.shard_schedule(
                     holder, index, [node_keys(node)], p0["shards"])
             if len(sched.slices) > 1:

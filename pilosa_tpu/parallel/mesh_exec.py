@@ -185,13 +185,14 @@ class _InstrumentedExec:
     batcher, and the streaming slice position installed by
     _ShardSchedule."""
 
-    __slots__ = ("fn", "sig", "kind", "detail",
+    __slots__ = ("fn", "sig", "kind", "detail", "devices",
                  "decode_per_shard", "kernels_per_shard",
                  "kernel_tiles_per_shard")
 
-    def __init__(self, fn, key, layout):
+    def __init__(self, fn, key, layout, devices: int):
         from ..ops import kernels as _kernels
         self.fn = fn
+        self.devices = devices      # of the mesh the program runs over
         self.kind = key[0] if key and isinstance(key[0], str) else "exec"
         self.sig = _devobs.sig_of(key)
         self.detail = repr(key[1])[:120] if len(key) > 1 else ""
@@ -234,7 +235,8 @@ class _InstrumentedExec:
         with layer_span("dispatch.enqueue", kind=self.kind, sig=self.sig,
                         rows=rows, rows_padded=b_pad, tickets=tickets,
                         shards=shards, shards_padded=shards_pad,
-                        temp_bytes=ctx.get("temp_bytes", 0)) as span:
+                        temp_bytes=ctx.get("temp_bytes", 0),
+                        devices=self.devices) as span:
             t0 = _time.perf_counter()
             out = self.fn(*args)
             dt = _time.perf_counter() - t0
@@ -284,17 +286,26 @@ class _Block:
     list), ``token`` its signature and fragments' device generations,
     ``arrays`` the placed block (the five packed tables of a compressed
     one), ``epochs`` the ingest epochs it reflects; the last two move
-    together, under the executor's stack-cache lock."""
+    together, under the executor's stack-cache lock.  ``nbytes`` is what
+    it holds over all of this process's devices, ``device_bytes`` what
+    the fullest of those ``devices`` holds, read off the arrays' own
+    shards: the budget's limit is one device's (storage/membudget.py)."""
 
     __slots__ = ("bkey", "skey", "token", "arrays", "epochs", "nbytes",
-                 "compressed")
+                 "compressed", "device_bytes", "devices")
 
     def __init__(self, exec_id, bkey, token, arrays, epochs):
         self.bkey, self.token = bkey, token
         self.skey = ("block", exec_id, next(_BLOCK_SEQ))
         self.arrays, self.epochs = arrays, epochs
-        self.nbytes = sum(a.nbytes for a in arrays) \
-            if isinstance(arrays, tuple) else arrays.nbytes
+        per_device: dict = {}
+        for a in arrays if isinstance(arrays, tuple) else (arrays,):
+            for sh in a.addressable_shards:
+                per_device[sh.device] = \
+                    per_device.get(sh.device, 0) + sh.data.nbytes
+        self.nbytes = sum(per_device.values())
+        self.device_bytes = max(per_device.values())
+        self.devices = len(per_device)
         self.compressed = self.nbytes if token[0][0] == "z" else 0
 
 
@@ -417,7 +428,7 @@ class MeshExecutor:
                     traced_body, mesh=self.mesh,
                     in_specs=in_specs, out_specs=out_specs,
                     check_vma=check_vma)),
-                key, layout)
+                key, layout, self.n_devices)
             self._cache[key] = fn
         return fn
 
@@ -433,7 +444,8 @@ class MeshExecutor:
     # -- shard grouping ----------------------------------------------------
 
     def _placed_groups(self, keys, holder, index, shards):
-        with layer_span("dispatch.place", _devobs.LEDGER):
+        with layer_span("dispatch.place", _devobs.LEDGER,
+                        devices=self.n_devices):
             return self._place_groups(keys, holder, index, shards)
 
     def _place_groups(self, keys, holder, index, shards):
@@ -590,7 +602,8 @@ class MeshExecutor:
             self._budget.register(
                 blk.skey, blk.nbytes, functools.partial(
                     MeshExecutor._evict_block, wself, blk.bkey, blk.skey),
-                compressed_bytes=blk.compressed)
+                compressed_bytes=blk.compressed,
+                device_bytes=blk.device_bytes, devices=blk.devices)
             with self._sc_lock:
                 held = self._blocks.get(blk.bkey) is blk
             if not held:    # swept or displaced before it was registered
@@ -1016,7 +1029,13 @@ class MeshExecutor:
         # bytes are estimated over the union of the lists' keys: a
         # (field, view) that two lists stack is one resident block
         all_keys = list(dict.fromkeys(k for kl in key_lists for k in kl))
+        # the limit is one device's, and a stacked block lies over the
+        # mesh in equal shares (one shape a group, the bucket a multiple
+        # of the devices): the shards of a slice may hold the limit
+        # times the devices between them
         limit = self._budget.limit_bytes
+        if limit:
+            limit *= self.n_devices
         slices = [shards]
         if limit and not self.multiprocess and \
                 len(shards) > self.n_devices:

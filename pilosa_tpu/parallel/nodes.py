@@ -202,10 +202,10 @@ def pad_pow2_rows(mat: np.ndarray, repeat: bool = True) -> np.ndarray:
 # to run, once per compiled shape, and walks the device's shards in
 # blocks where that figure would pass the bound
 # (parallel/wholequery.py ``run``).  ``batch_temp_bound`` is what a
-# launch may cost: what the device has left — its ``bytes_limit`` less
-# what the device budget counts resident, less BATCH_TEMP_MARGIN — with
-# the ``batch-temp-mb`` knob (BATCH_TEMP_BYTES; process-wide, most
-# recent Server wins) as a ceiling only.
+# launch may cost: what a device has left — its ``bytes_limit`` less
+# what the device budget counts resident on the fullest device, less
+# BATCH_TEMP_MARGIN — with the ``batch-temp-mb`` knob (BATCH_TEMP_BYTES;
+# process-wide, most recent Server wins) as a ceiling only.
 BATCH_TEMP_BYTES = 4 << 30
 # Device bytes the bound leaves free beside resident blocks and one
 # launch's temporaries: outputs, params, the temporaries of launches
@@ -237,11 +237,14 @@ def device_bytes_limit() -> int | None:
 
 
 def batch_temp_bound() -> int:
-    """What one launch's temporaries may cost now: what the device has
-    left, ``batch-temp-mb`` as a ceiling."""
+    """What one launch's temporaries may cost now: what a device has
+    left — one device's limit less what the fullest device holds, as
+    the device budget counts it — with ``batch-temp-mb`` as a
+    ceiling."""
     limit = device_bytes_limit()
     if limit is None:
         return BATCH_TEMP_BYTES
     from ..storage.membudget import DEFAULT_BUDGET
-    free = limit - DEFAULT_BUDGET.resident_bytes - BATCH_TEMP_MARGIN
+    free = limit - DEFAULT_BUDGET.resident_bytes_max_device \
+        - BATCH_TEMP_MARGIN
     return max(0, min(BATCH_TEMP_BYTES, free))

@@ -158,10 +158,11 @@ class _InstrumentedWhole:
     detection), and every invocation lands in the launch ledger with
     the call site's actual-vs-padded shard and batch rows."""
 
-    __slots__ = ("fn", "sig", "detail", "out_index", "_temps")
+    __slots__ = ("fn", "sig", "detail", "out_index", "devices", "_temps")
 
-    def __init__(self, fn, key, out_index):
+    def __init__(self, fn, key, out_index, devices: int):
         self.fn = fn
+        self.devices = devices      # of the mesh the program runs over
         self.sig = _devobs.sig_of(key)
         self.detail = repr(key[1])[:120]
         self.out_index = out_index
@@ -211,7 +212,8 @@ class _InstrumentedWhole:
                         sig=self.sig, rows=rows, rows_padded=rows_padded,
                         tickets=tickets, shards=m.get("shards", 0),
                         shards_padded=m.get("shards_padded", 0),
-                        temp_bytes=m.get("temp_bytes", 0)) as span:
+                        temp_bytes=m.get("temp_bytes", 0),
+                        devices=self.devices) as span:
             t0 = _time.perf_counter()
             out = self.fn(mats, *flat)
             dt = _time.perf_counter() - t0
@@ -619,4 +621,4 @@ class WholeQueryRunner:
             traced, mesh=self.mesh.mesh,
             in_specs=(P(),) + (P(SHARD_AXIS),) * n_flat_all,
             out_specs=tuple(out_specs), check_vma=check))
-        return _InstrumentedWhole(fn, key, out_index)
+        return _InstrumentedWhole(fn, key, out_index, self.mesh.n_devices)

@@ -250,6 +250,7 @@ def build_debug_vars(api: API, server=None) -> dict:
     # /debug/launches, /debug/timeseries
     from ..utils import devobs
     out["device"] = {**devobs.device_info(),
+                     "memory": devobs.device_memory(),
                      "compiles": devobs.COMPILES.totals(),
                      "launches": devobs.LEDGER.aggregates(),
                      "fetches": devobs.FETCHES.snapshot()}

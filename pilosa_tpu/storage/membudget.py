@@ -17,6 +17,15 @@ buffer out from under a dispatch.  The budget also keeps streaming
 counters — cumulative upload bytes, prefetch hits/misses, evictions —
 surfaced through ``stats()`` at /debug/vars and the runtime gauges.
 
+The limit is ONE DEVICE's: every entry registers, beside the bytes it
+holds over all devices, what the fullest device holds of it (a stacked
+block sharded over a mesh of four: a quarter; a fragment mirror: all of
+it), and ``limit_bytes``, the tenant quota and eviction pressure are held
+against the sum of those shares — exactly the fullest device's bytes
+where entries are spread alike or sit on one device, an upper bound on
+them otherwise.  ``resident_bytes`` and ``upload_bytes`` stay sums over
+all devices.  With one device the two ledgers are one.
+
 One process-wide default budget keeps wiring simple (Server config
 ``device_budget_mb`` / PILOSA_TPU_DEVICE_BUDGET_MB sets it); tests construct
 private instances.  ``HOST_STAGE_BUDGET`` is a second instance bounding the
@@ -43,11 +52,13 @@ class DeviceBudget:
         # entries — one index's working set cannot flush the fleet's
         # (docs/robustness.md "Tenant isolation").
         self.tenant_quota_bytes = tenant_quota_bytes
-        # key -> [nbytes, evict cb, pin count, compressed bytes, tenant]
+        # key -> [nbytes, evict cb, pin count, compressed bytes, tenant,
+        #         fullest device's bytes, devices]
         self._entries: OrderedDict[tuple, list] = OrderedDict()
-        self._tenant_bytes: dict[str, int] = {}
+        self._tenant_bytes: dict[str, int] = {}   # per device, like _held
         self.quota_evictions = 0
-        self._total = 0
+        self._total = 0       # bytes over all devices
+        self._held = 0        # the fullest device's: what the limit is of
         self._compressed = 0  # portion of _total held in packed form
         self._peak = 0
         self.evictions = 0
@@ -76,15 +87,22 @@ class DeviceBudget:
     def resident_bytes(self) -> int:
         return self._total
 
+    @property
+    def resident_bytes_max_device(self) -> int:
+        """What the fullest device holds (module docstring)."""
+        return self._held
+
     def _pop_locked(self, key: tuple) -> list:
-        """Pop ``key`` keeping the byte ledgers (total, compressed,
-        per-tenant) consistent.  Caller must hold self._lock."""
+        """Pop ``key`` keeping the byte ledgers (total, per-device,
+        compressed, per-tenant) consistent.  Caller must hold
+        self._lock."""
         e = self._entries.pop(key)
         self._total -= e[0]
+        self._held -= e[5]
         self._compressed -= e[3]
         t = e[4]
         if t is not None:
-            left = self._tenant_bytes.get(t, 0) - e[0]
+            left = self._tenant_bytes.get(t, 0) - e[5]
             if left > 0:
                 self._tenant_bytes[t] = left
             else:
@@ -98,10 +116,10 @@ class DeviceBudget:
                 if b > self.tenant_quota_bytes}
 
     def _evict_lru_locked(self, incoming: int) -> list[Callable[[], None]]:
-        """Pop LRU entries until ``incoming`` more bytes fit the limit;
-        returns their callbacks for the caller to run OUTSIDE the lock
-        (owners may take their own locks without ordering against this
-        one).  Caller must hold self._lock.
+        """Pop LRU entries until ``incoming`` more bytes on a device fit
+        the limit; returns their callbacks for the caller to run OUTSIDE
+        the lock (owners may take their own locks without ordering
+        against this one).  Caller must hold self._lock.
 
         Pinned entries are NEVER popped — an in-flight dispatch or a
         prefetch holds them — so eviction takes the unpinned-coldest,
@@ -112,7 +130,7 @@ class DeviceBudget:
         to_evict: list[Callable[[], None]] = []
         if self.limit_bytes is None:
             return to_evict
-        while self._entries and self._total + incoming > self.limit_bytes:
+        while self._entries and self._held + incoming > self.limit_bytes:
             victim = None
             over = self._over_quota_locked()
             if over:
@@ -174,9 +192,12 @@ class DeviceBudget:
                     self.evict_errors += 1
 
     def register(self, key: tuple, nbytes: int, evict: Callable[[], None],
-                 compressed_bytes: int = 0, tenant: str | None = None):
+                 compressed_bytes: int = 0, tenant: str | None = None,
+                 device_bytes: int | None = None, devices: int = 1):
         """Account ``nbytes`` under ``key``; ``evict`` drops the owner's
-        reference when called.  Evicts LRU entries first if needed (never
+        reference when called.  ``device_bytes`` is what the fullest of
+        the ``devices`` it is spread over holds of them (None: all of
+        them, on one device).  Evicts LRU entries first if needed (never
         evicting the incoming entry itself).  Re-registering an existing
         key keeps its pin count (the owner re-staged data an in-flight
         user still holds pinned).  ``compressed_bytes`` is the portion of
@@ -187,20 +208,23 @@ class DeviceBudget:
         falls back to the ambient request tenant)."""
         if tenant is None:
             tenant = qtenant.current_or_none()
+        if device_bytes is None:
+            device_bytes = nbytes
         with self._lock:
             pins = 0
             if key in self._entries:
                 pins = self._pop_locked(key)[2]
-            evicted0 = self.evicted_bytes
-            to_evict = self._evict_lru_locked(nbytes)
-            freed = self.evicted_bytes - evicted0
+            held0 = self._held
+            to_evict = self._evict_lru_locked(device_bytes)
+            freed = held0 - self._held
             self._entries[key] = [nbytes, evict, pins, compressed_bytes,
-                                  tenant]
+                                  tenant, device_bytes, devices]
             self._total += nbytes
+            self._held += device_bytes
             self._compressed += compressed_bytes
             if tenant is not None:
                 self._tenant_bytes[tenant] = \
-                    self._tenant_bytes.get(tenant, 0) + nbytes
+                    self._tenant_bytes.get(tenant, 0) + device_bytes
                 quota0 = self.evicted_bytes
                 quota_evict = self._evict_tenant_locked(tenant, key)
                 quota_freed = self.evicted_bytes - quota0
@@ -215,10 +239,10 @@ class DeviceBudget:
         self._run_evictions(to_evict)
 
     def _note_pressure(self, freed: int, n_evicted: int):
-        """Journal an eviction storm: one make-room pass that evicted a
-        large slice of the budget (rate-limited — sustained thrash is
-        one timeline entry per interval, with the counters carrying the
-        magnitude)."""
+        """Journal an eviction storm: one make-room pass that freed a
+        large slice of a device's budget (rate-limited — sustained
+        thrash is one timeline entry per interval, with the counters
+        carrying the magnitude)."""
         if self.limit_bytes is None or freed < max(
                 int(self.limit_bytes * self.PRESSURE_EVENT_FRACTION), 1):
             return
@@ -231,7 +255,7 @@ class DeviceBudget:
         from ..utils import events
         events.emit("membudget.pressure", freedBytes=freed,
                     entries=n_evicted, limitBytes=self.limit_bytes,
-                    residentBytes=self._total)
+                    residentBytes=self._held)
 
     def reset_peak(self):
         """Restart the high-water mark from the current residency (bench /
@@ -294,6 +318,12 @@ class DeviceBudget:
                                if e[2] > 0)
             return {
                 "residentBytes": self._total,
+                # the fullest device's share, which limitBytes and the
+                # quota are held against, and how many devices the
+                # widest-spread entry lies on
+                "residentBytesMaxDevice": self._held,
+                "devices": max((e[6] for e in self._entries.values()),
+                               default=1),
                 "compressedBytes": self._compressed,
                 "denseBytes": self._total - self._compressed,
                 "peakBytes": self._peak,

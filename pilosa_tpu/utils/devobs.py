@@ -60,6 +60,23 @@ def device_info() -> dict:
             "deviceCount": len(devs)}
 
 
+def device_memory() -> list:
+    """What each local device's allocator reports now — /debug/vars'
+    ``device.memory``, one entry a device in jax's order.  A stack
+    sharded over a mesh reads the same on every device; one replicated
+    by mistake reads as the whole of it on each.  A backend that
+    reports nothing (the CPU) gives nulls."""
+    import jax
+    out = []
+    for d in jax.local_devices():
+        m = d.memory_stats() or {}
+        out.append({"id": d.id,
+                    "bytesInUse": m.get("bytes_in_use"),
+                    "peakBytesInUse": m.get("peak_bytes_in_use"),
+                    "bytesLimit": m.get("bytes_limit")})
+    return out
+
+
 def fingerprint(args) -> str:
     """Compact argument-shape fingerprint of one executable call —
     ``8x4:int32|16x12x32768:uint32|...`` — the thing a retrace DIFFS:

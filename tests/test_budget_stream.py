@@ -71,6 +71,85 @@ def test_pin_unknown_key_and_counters():
     assert b.stats()["pinnedBytes"] == 50
 
 
+# -- the limit is one device's (PERF.md PR 35) --------------------------------
+
+def test_limit_is_held_against_the_fullest_device():
+    """An entry spread over four devices counts a quarter against the
+    limit and the tenant quota, and whole in ``resident_bytes`` and
+    ``uploadBytes``; one on a single device counts whole in both, so with
+    one device nothing moved."""
+    b = DeviceBudget(limit_bytes=100, tenant_quota_bytes=60)
+    dropped = []
+    b.register(("wide",), 200, lambda: dropped.append("wide"),
+               device_bytes=50, devices=4, tenant="t")
+    b.register(("one",), 40, lambda: dropped.append("one"), tenant="u")
+    assert dropped == []            # 50 + 40 a device, 240 in all
+    s = b.stats()
+    assert (s["residentBytes"], s["residentBytesMaxDevice"],
+            s["devices"], s["uploadBytes"]) == (240, 90, 4, 240)
+    assert b.resident_bytes == 240 and b.resident_bytes_max_device == 90
+    assert s["tenantBytes"] == {"t": 50, "u": 40}   # under the quota
+    b.register(("more",), 20, lambda: dropped.append("more"))
+    assert dropped == ["wide"]      # 90 + 20 > 100: the coldest goes
+    s = b.stats()
+    assert (s["residentBytes"], s["residentBytesMaxDevice"],
+            s["devices"], s["evictedBytes"]) == (60, 60, 1, 200)
+    assert s["tenantBytes"] == {"u": 40}
+    # one device: the two ledgers are one, as before
+    one = DeviceBudget(limit_bytes=100)
+    one.register(("a",), 60, lambda: None)
+    one.register(("b",), 30, lambda: None)
+    s = one.stats()
+    assert s["residentBytes"] == s["residentBytesMaxDevice"] == 90
+    assert s["devices"] == 1 and s["peakBytes"] == 90
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_block_registers_what_the_fullest_device_holds(wide, n_devices):
+    """A stacked block over four devices registers a quarter of its
+    bytes a device, read off the array's own shards; over one device,
+    all of them: ``blockBytes`` = ``residentBytes`` either way."""
+    import jax
+    from pilosa_tpu.parallel.mesh_exec import default_mesh
+    ex = Executor(wide, mesh=default_mesh(jax.devices()[:n_devices]))
+    me = ex.mesh_exec
+    me._budget = DeviceBudget()
+    try:
+        assert ex.execute("w", "Count(Row(f=1))")[0] > 0
+        with me._sc_lock:
+            (blk,) = me._blocks.values()
+        assert blk.nbytes == blk.arrays.nbytes == 16 * 16 * (128 << 10)
+        assert blk.devices == n_devices
+        assert blk.device_bytes * n_devices == blk.nbytes
+        s = me._budget.stats()
+        assert s["residentBytes"] == me.stack_block_bytes() == blk.nbytes
+        assert s["residentBytesMaxDevice"] == blk.device_bytes
+        assert s["uploadBytes"] == blk.nbytes and s["devices"] == n_devices
+    finally:
+        ex.close()
+
+
+def test_batch_temp_bound_is_of_one_device(monkeypatch):
+    """The taxi-1b-mesh4 cell's figures: 16.9 GB a chip, 11.58 GB of
+    stacks over four chips.  A quarter of them is on each, so the bound
+    is the 4 GiB ceiling (12.9 GB are free); held against the sum, as
+    before this PR, it was 4.27 GB, under the ceiling, and one more
+    field would have made it 0.  On one chip the same stacks leave what
+    they always left."""
+    from pilosa_tpu.parallel import nodes
+    limit, resident = 16_900_000_000, 11_580_000_000
+    monkeypatch.setattr(nodes, "device_bytes_limit", lambda: limit)
+    for devices, bound in ((4, nodes.BATCH_TEMP_BYTES),
+                           (1, limit - resident - nodes.BATCH_TEMP_MARGIN)):
+        budget = DeviceBudget()
+        budget.register(("stacks",), resident, lambda: None,
+                        device_bytes=resident // devices, devices=devices)
+        monkeypatch.setattr("pilosa_tpu.storage.membudget.DEFAULT_BUDGET",
+                            budget)
+        assert nodes.batch_temp_bound() == bound
+    assert bound == 4_246_258_176 < nodes.BATCH_TEMP_BYTES
+
+
 # -- filter-less chunk fix (r5 advisor) -------------------------------------
 
 def test_filterless_group_dispatches_single_chunk():
@@ -164,9 +243,11 @@ def test_shard_schedule_slices_and_orders_by_residency(wide, monkeypatch):
         DEFAULT_BUDGET.limit_bytes = None
         assert me.shard_schedule(wide, "w", [keys], shards).slices == \
             [shards]
-        # 16 shards x 16 rows x 128KB = 32MB working set; a 12MB budget
-        # must carve mesh-width slices
-        DEFAULT_BUDGET.limit_bytes = 12 << 20
+        # 16 shards x 16 rows x 128KB = 32MB working set, 4MB a device
+        # of the mesh's eight; a budget of 1.5MB a device (12MB over
+        # the mesh) must carve mesh-width slices
+        per_device = (12 << 20) // me.n_devices
+        DEFAULT_BUDGET.limit_bytes = per_device
         sched = me.shard_schedule(wide, "w", [keys], shards)
         assert sched.slices == [shards[:8], shards[8:]]
         assert sched.max_slice_len == 8
@@ -176,7 +257,7 @@ def test_shard_schedule_slices_and_orders_by_residency(wide, monkeypatch):
         assert sched.slices == [shards[8:], shards[:8]]
         # streamed execution over the schedule equals the unbudgeted run
         want = None
-        for limit in (None, 12 << 20):
+        for limit in (None, per_device):
             DEFAULT_BUDGET.limit_bytes = limit
             got = ex.execute("w", "Count(Union(Row(f=1), Row(f=3)))")
             if want is None:
@@ -224,8 +305,9 @@ def test_budgeted_run_matches_unbudgeted(wide, rng):
         DEFAULT_BUDGET.limit_bytes = None
         want = [_norm(r) for bt in batches for r in ex.execute("w", bt)]
         # under the whole set's compressed bytes, 7.1 MB now that a
-        # (field, view) is resident once however many key lists read it
-        DEFAULT_BUDGET.limit_bytes = 4 << 20
+        # (field, view) is resident once however many key lists read it:
+        # 4 MB over the mesh, and the limit is one device's
+        DEFAULT_BUDGET.limit_bytes = (4 << 20) // ex.mesh_exec.n_devices
         DEFAULT_BUDGET.shrink_to_limit()
         ev0 = DEFAULT_BUDGET.evictions
         got = [_norm(r) for bt in batches for r in ex.execute("w", bt)]

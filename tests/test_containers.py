@@ -7,6 +7,7 @@ results byte-identical to the dense-resident run.  A decode bug would
 corrupt query results silently; the differential catches it as a
 divergence."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -282,8 +283,9 @@ def test_compressed_differential(corpus):
             "exercised only the dense path"
         assert st["compressedBytes"] < 16 * 16 * SHARD_WORDS * 4
 
-        # tight budget: eviction + re-staging of packed stacks
-        DEFAULT_BUDGET.limit_bytes = 1 << 20
+        # tight budget (1 MiB over the mesh; the limit is one device's):
+        # eviction + re-staging of packed stacks
+        DEFAULT_BUDGET.limit_bytes = (1 << 20) // jax.device_count()
         DEFAULT_BUDGET.shrink_to_limit()
         ev0 = DEFAULT_BUDGET.evictions
         assert _run_corpus(ex, queries) == want

@@ -460,8 +460,9 @@ def test_per_stage_equals_whole_query_and_host(staged, monkeypatch, kind,
         assert _norm(whole.execute("p", q)) == want
         whole.close()
         if streamed:
-            # each query stacks a or v: 16 shards x 16 rows x 128 KiB
-            DEFAULT_BUDGET.limit_bytes = 12 << 20
+            # each query stacks a or v: 16 shards x 16 rows x 128 KiB,
+            # 32 MiB over the mesh; the limit is one device's share
+            DEFAULT_BUDGET.limit_bytes = (12 << 20) // jax.device_count()
             DEFAULT_BUDGET.shrink_to_limit()
         # the text replays a prepared template where there is one (a
         # call group of one row), the parsed query takes the call's own

@@ -21,6 +21,7 @@ The load-bearing guarantees tested here:
 
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -139,8 +140,9 @@ def test_differential_three_legs(corpus):
         assert DEFAULT_BUDGET.stats()["compressedBytes"] > 0, \
             "compressed leg never staged a packed stream"
 
-        # eviction pressure: tight budget forces the streaming planner
-        DEFAULT_BUDGET.limit_bytes = 1 << 20
+        # eviction pressure: a tight budget (1 MiB over the mesh; the
+        # limit is one device's) forces the streaming planner
+        DEFAULT_BUDGET.limit_bytes = (1 << 20) // jax.device_count()
         DEFAULT_BUDGET.shrink_to_limit()
         ev0 = DEFAULT_BUDGET.evictions
         fb0 = wq.wq_fallbacks
