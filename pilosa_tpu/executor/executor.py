@@ -23,8 +23,8 @@ from .plan import PlanCompiler, ReduceNode, Resolver, parametrize
 # (after .plan: parallel/ imports it, and this package through it)
 from ..parallel.fetch import fetch_parts
 from ..parallel.nodes import (
-    ROW_BYTES, batch_temp_bound, node_keys, node_temp_rows, pad_pow2_rows,
-    pow2_rows,
+    ROW_BYTES, TOPN_EXTRA, batch_temp_bound, node_keys, node_temp_rows,
+    pad_pow2_rows, pow2_rows, topn_extra, with_n,
 )
 from .results import (
     FieldRow, GroupCount, Pair, RowIdentifiers, RowResult, ValCount,
@@ -143,7 +143,8 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
     group's reducer node (count, bsi_sum or row_counts), its [B, P]
     params matrix, the calls its rows answer, and what its finisher
     needs beside the parts: bsi_sum ``base``, row_counts ``ids_n`` with
-    one (ids, n) pair per call.  Shared by the classic grouped path and
+    one (ids, n) pair per call (a top-n node's matrix carries n as its
+    last column besides).  Shared by the classic grouped path and
     the prepared-statement cache so the chunking policy lives in exactly
     one place.
 
@@ -230,10 +231,12 @@ def _run_batched_groups(batcher, holder, index, shards, groups, results):
                         call_idxs[lo: lo + n_c], extra, lo, mesh, results)
 
 
-def _wire_group(node, parts, call_idxs, extra, lo, mesh, results):
+def _wire_group(node, parts, call_idxs, extra, lo, mesh, results,
+                walked: bool = False):
     """Pendings of one batched call group (or one chunk of it, whose
     first row is call ``lo`` of the group) over its fetched ``parts``,
-    whichever way they were launched."""
+    whichever way they were launched; ``walked``: a top-n node's launch
+    answered by ``nodes.topn_walk`` (its meta says so)."""
     if node.kind == "count":
         grp = _PendingGroup.counts(parts, call_idxs)
         for i in call_idxs:
@@ -248,7 +251,9 @@ def _wire_group(node, parts, call_idxs, extra, lo, mesh, results):
             ids, n = extra["ids_n"][lo + b]
             results[i] = _Pending(
                 parts, lambda hp, b=b, ids=ids, n=n:
-                _topn_rank(mesh, hp, b, ids, n))
+                rank_counts(_topn_counts(
+                    mesh, hp, b, node.extra == TOPN_EXTRA, walked),
+                    n or None, ids))
 
 
 class _Pending:
@@ -311,9 +316,22 @@ def _sum_fin(hp, b, base):
     return ValCount(total + cnt * base, cnt)
 
 
-def _topn_rank(mesh, hp, b, ids, n):
-    counts = mesh.merge_counts([p[b] for p in hp])
-    return rank_counts(counts, n or None, ids)
+def _topn_counts(mesh, hp, b, topn: bool, walked: bool):
+    """Row counts of params row ``b`` of a row_counts node from its
+    fetched parts.  A walked launch (``nodes.topn_walk``) has one part,
+    [B, R + 1]: the counts — exact for the rows visited, 0 for those
+    that cannot reach the top n — and the rows visited.  Counts a top-n
+    question in ``topnPrune.*`` either way: the walk by how far it
+    went, the full pass as a full scan."""
+    if walked:
+        row = np.asarray(hp[0][b])
+        mesh.topn_queries += 1
+        mesh.topn_rows_visited += int(row[-1])
+        mesh.topn_rows_stacked += row.size - 1
+        return row[:-1].astype(np.int64)
+    if topn:
+        mesh.topn_full_scans += 1
+    return mesh.merge_counts([p[b] for p in hp])
 
 
 def _segments(hp, b, groups, shards) -> dict:
@@ -722,12 +740,15 @@ class Executor:
             field_name, ok = c.string_arg("_field")
             if not ok or self.holder.field(index, field_name) is None:
                 return None  # per-call path raises the proper error
-            node, params = self._node(
-                "row_counts", self._filter_plan(index, c),
-                (field_name, VIEW_STANDARD))
             n, _ = c.uint_arg("n")
-            return {"node": node, "params": params,
-                    "ids": c.args.get("ids"), "n": n}
+            ids = c.args.get("ids")
+            plan = self._filter_plan(index, c)
+            node, params = self._node(
+                "row_counts", plan, (field_name, VIEW_STANDARD),
+                topn_extra(plan, n, ids))
+            if node.extra:
+                params = with_n(params, n)
+            return {"node": node, "params": params, "ids": ids, "n": n}
         return None
 
     def _execute_calls_grouped(self, index: str, calls, shards):
@@ -852,7 +873,8 @@ class Executor:
             "shards": len(shards)})
         for gi, (node, _pm, call_idxs, extra) in enumerate(groups):
             _wire_group(node, out.parts[gi], call_idxs, extra, 0,
-                        self.mesh_exec, results)
+                        self.mesh_exec, results,
+                        walked=out.meta[gi].get("walk", False))
 
     def _wq_execute(self, index: str, calls, shards):
         """Lower every call of a read request to reducer nodes, launch
@@ -901,8 +923,11 @@ class Executor:
             elif kind == "topn":
                 mat = np.stack([d["params"] for d in ds])
                 nodes.append(ReduceNode("row_counts", d0["slotted"],
-                                        (d0["field"], VIEW_STANDARD)))
-                mats.append(mat)
+                                        (d0["field"], VIEW_STANDARD),
+                                        d0["extra"]))
+                mats.append(np.stack([with_n(d["params"], d["n"])
+                                      for d in ds])
+                            if d0["extra"] else mat)
                 if d0["tan"]:
                     # tanimoto rides two extra reducers in the SAME
                     # program: unfiltered row totals + the source count
@@ -989,6 +1014,8 @@ class Executor:
             k = len(out.parts[lo])
             ku = len(out.parts[lo + 1]) if d0["tan"] else 0
             f = d0["f"]
+            topn = d0["extra"] == TOPN_EXTRA
+            walked = out.meta[lo].get("walk", False)
             for b, i in enumerate(idxs):
                 d = ds[b]
                 results[i] = _Pending(
@@ -997,7 +1024,7 @@ class Executor:
                     tan=d["tan"], an=d["attr_name"], av=d["attr_values"],
                     f=f, mesh=mesh:
                     self._topn_finalize(
-                        mesh.merge_counts([p[b] for p in hp[:k]]),
+                        _topn_counts(mesh, hp[:k], b, topn, walked),
                         mesh.merge_counts([p[0] for p in hp[k:k + ku]])
                         if tan else None,
                         sum(int(p[0]) for p in hp[k + ku:]) if tan
@@ -1121,9 +1148,10 @@ class Executor:
         slotted, params = (None, self._EMPTY_PARAMS) if fp is None \
             else parametrize(fp)
         extras = tan_thresh is not None or attr_name is not None
-        return {"kind": "topn",
+        extra = topn_extra(slotted, n, ids, extras)
+        return {"kind": "topn", "extra": extra,
                 "gkey": None if extras
-                else ("topn", field_name, repr(slotted)),
+                else ("topn", field_name, repr(slotted), extra),
                 "slotted": slotted, "params": params,
                 "field": field_name, "ids": ids, "n": n,
                 "tan": tan_thresh, "attr_name": attr_name,
@@ -1494,9 +1522,14 @@ class Executor:
                                             filter_plan)
             k, ku = len(parts), len(parts_u)
 
+            topn = bool(topn_extra(
+                filter_plan, n, ids,
+                tan_thresh is not None or attr_name is not None))
+
             def _fin(hp, ids=ids, n=n):
                 merge = self.mesh_exec.merge_counts
-                counts = merge(p[0] for p in hp[:k])
+                counts = _topn_counts(self.mesh_exec, hp[:k], 0, topn,
+                                      False)
                 row_tot = merge(p[0] for p in hp[k: k + ku]) \
                     if tan_thresh else None
                 src = sum(int(x[0]) for x in hp[k + ku:]) \

@@ -431,7 +431,13 @@ class PreparedCache:
         if ids is not None:
             ids = [int(x) for x in ids]
         from ..core import VIEW_STANDARD
+        from ..parallel.nodes import topn_extra, with_n
+        extra = topn_extra(slotted, n, ids)
+        if extra:
+            # n is a structural literal of the template (pinned by an
+            # equality guard below): a constant column of the matrix
+            params, prov = with_n(params, n), list(prov) + [None]
         return {"node": ReduceNode("row_counts", slotted,
-                                   (field_name, VIEW_STANDARD)),
+                                   (field_name, VIEW_STANDARD), extra),
                 "params": params, "prov": prov,
                 "extra": {"ids": ids, "n": n}}

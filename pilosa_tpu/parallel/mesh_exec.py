@@ -69,7 +69,7 @@ from ..utils.faults import FAULTS
 from ..utils.locks import make_lock, make_rlock
 from ..utils.tracing import GLOBAL_TRACER, layer_span
 from .nodes import PER_SHARD_KINDS, mat_rows, node_keys, node_shard, \
-    pad_pow2_rows, participates
+    pad_pow2_rows, participates, row_totals, walk_order
 
 SHARD_AXIS = "shards"
 
@@ -286,18 +286,21 @@ class _Block:
     list), ``token`` its signature and fragments' device generations,
     ``arrays`` the placed block (the five packed tables of a compressed
     one), ``epochs`` the ingest epochs it reflects; the last two move
-    together, under the executor's stack-cache lock.  ``nbytes`` is what
+    together (``move``), under the executor's stack-cache lock, and
+    ``walk`` — the TopN walk's plan from these very arrays' row totals
+    (``MeshExecutor.walk_plan``), None until a top-n node first reads
+    the block — goes when they move.  ``nbytes`` is what
     it holds over all of this process's devices, ``device_bytes`` what
     the fullest of those ``devices`` holds, read off the arrays' own
     shards: the budget's limit is one device's (storage/membudget.py)."""
 
-    __slots__ = ("bkey", "skey", "token", "arrays", "epochs", "nbytes",
-                 "compressed", "device_bytes", "devices")
+    __slots__ = ("bkey", "skey", "token", "arrays", "epochs", "walk",
+                 "nbytes", "compressed", "device_bytes", "devices")
 
     def __init__(self, exec_id, bkey, token, arrays, epochs):
         self.bkey, self.token = bkey, token
         self.skey = ("block", exec_id, next(_BLOCK_SEQ))
-        self.arrays, self.epochs = arrays, epochs
+        self.move(arrays, epochs)
         per_device: dict = {}
         for a in arrays if isinstance(arrays, tuple) else (arrays,):
             for sh in a.addressable_shards:
@@ -307,6 +310,11 @@ class _Block:
         self.device_bytes = max(per_device.values())
         self.devices = len(per_device)
         self.compressed = self.nbytes if token[0][0] == "z" else 0
+
+    def move(self, arrays, epochs):
+        """The block's words changed (stacked, or an ingest overlay
+        rewrote them): what was derived from the old ones goes."""
+        self.arrays, self.epochs, self.walk = arrays, epochs, None
 
 
 class MeshExecutor:
@@ -371,6 +379,16 @@ class MeshExecutor:
         # blocks, each for the batch-temp bound's sake.  A plain int
         # like the two above.
         self.temp_splits = 0
+        # /debug/vars topnPrune.*: top-n nodes the walk answered
+        # (nodes.topn_walk), the rows it visited of the rows stacked,
+        # and top-n nodes that took the full pass (a per-stage launch,
+        # several shape groups, a compressed or shard-blocked stack,
+        # more params rows than the walk unrolls).
+        # Plain ints like the above, bumped by the finishers.
+        self.topn_queries = 0
+        self.topn_rows_visited = 0
+        self.topn_rows_stacked = 0
+        self.topn_full_scans = 0
         self._budget = DEFAULT_BUDGET
         # single-worker background uploader for streamed shard slices
         # (created on first over-budget query; one worker serializes
@@ -788,7 +806,7 @@ class MeshExecutor:
                         np.concatenate(idxs), np.concatenate(vals))
                     if blk is not None and blk in cur[4]:
                         with self._sc_lock:
-                            blk.arrays, blk.epochs = placed[ki], at
+                            blk.move(placed[ki], at)
             with self._sc_lock:
                 cur2 = self._stack_cache.get(ckey)
                 if cur2 is not None and cur2[0] == token:
@@ -830,6 +848,40 @@ class MeshExecutor:
             self._cache[key] = fn
         with _DISPATCH_LOCK:
             return fn(stacked, m, r, w, v)
+
+    def walk_plan(self, index, key, shard_list, stacked):
+        """The TopN walk's plan over one (field, view)'s dense stacked
+        block: ``nodes.walk_order`` of the block's unfiltered row totals
+        (summed over its shards and the mesh), two small replicated
+        device arrays that ride into the program as arguments.  Made
+        from ``stacked`` itself — the array the launch is about to read,
+        so the walk's bound holds for exactly those words — in one
+        device pass, and kept on the ``_Block`` while ``stacked`` is
+        its ``arrays``: an ingest overlay or a re-stage moves the block
+        and the next top-n launch counts anew."""
+        bkey = (index, key, tuple(shard_list))
+        with self._sc_lock:
+            blk = self._blocks.get(bkey)
+            walk = blk.walk if blk is not None else None
+        if walk is not None and walk[0] is stacked:
+            return walk[1]
+        ckey = ("walkplan", tuple(stacked.shape))
+        fn = self._cache.get(ckey)
+        if fn is None:
+            def block_fn(block):
+                return walk_order(row_totals(block, SHARD_AXIS))
+
+            block_fn.__name__ = "ptpu_walkplan"
+            fn = self._cache[ckey] = jax.jit(jax.shard_map(
+                block_fn, mesh=self.mesh, in_specs=P(SHARD_AXIS),
+                out_specs=P()))
+        with _DISPATCH_LOCK:
+            plan = fn(stacked)
+        if blk is not None:
+            with self._sc_lock:
+                if blk.arrays is stacked:
+                    blk.walk = (stacked, plan)
+        return plan
 
     @staticmethod
     def _cleanup_budget(budget, stack_cache, blocks):

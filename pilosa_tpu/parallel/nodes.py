@@ -17,6 +17,10 @@ The six kinds:
 * ``segments``     — the plan's raw result per params row (bitmap calls).
 * ``row_counts``   — per-row popcounts of the primary fragment under an
                      optional filter plan (TopN, Rows, MinRow/MaxRow).
+                     ``extra`` = ("topn",) marks a top-n question
+                     (``topn_extra``): its matrix carries each row's n as
+                     the last column, and a launch that reduces every
+                     shard itself may answer it by ``topn_walk``.
 * ``bsi_sum``      — per-bit-slice popcounts of a BSI fragment under an
                      optional filter; the host does the 2^i weighting.
 * ``bsi_minmax``   — the MSB-first extremum scan (no batch axis: the
@@ -109,8 +113,7 @@ def node_shard(node, mat, frags):
             return jnp.broadcast_to(counts,
                                     (mat.shape[0],) + counts.shape)
         masks = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
-        masked = frag[None] & masks[:, None]
-        return bitset.row_counts(masked)            # [B, rows]
+        return masked_counts(frag, masks)           # [B, rows]
     if node.kind == "bsi_sum":
         if node.plan is None:
             counts = bsi.sum_counts(frag, None)
@@ -153,6 +156,157 @@ def node_shard(node, mat, frags):
         return bitset.row_counts(masked)            # [rows]
 
     return jax.vmap(one_combo)(rids)                       # [C, rows]
+
+
+def masked_counts(rows, masks):
+    """Per-row popcounts of ``rows`` [k, 256, 128] under each of
+    ``masks`` [B, 256, 128] on one shard: [B, k].  The one count body:
+    the full pass calls it over a fragment's every row, ``topn_walk``
+    over a block of them."""
+    return bitset.row_counts(rows[None] & masks[:, None])
+
+
+# -- a filtered TopN stops at the n-th count --------------------------------
+#
+# TopN(field, filter, n) keeps n of the field's R rows, and
+# |row & filter| <= |row|: a row whose unfiltered total is below the
+# n-th largest filtered count found so far cannot be among them
+# (upstream's fragment.top: "heap with threshold pruning").  The totals
+# are the stacked block's own (``row_totals``, kept with the block:
+# mesh_exec ``MeshExecutor.walk_plan``), so the bound is exact for the
+# very words the launch reads.  Rows are walked in blocks of
+# ``walk_block_rows`` — whole (256, 128) tiles on an untiled major axis,
+# so a block is a dynamic slice XLA reads in place — in descending order
+# of each block's largest total, and the walk ends before the first
+# block whose largest total is below the threshold.  Where rows are not
+# stacked in order of their totals the order of blocks keeps the walk
+# exact and only its grain is coarser.
+#
+# The params rows of a launch are unrolled, in the filter segments and
+# in the block's counts: one popcount-reduce a row over the one slice,
+# which XLA fuses into a single read of the block.  Under a batch axis
+# (vmap over B) the compiler copies each block out before it counts it
+# and reads whole filter stacks to take B rows of them (PR 36, traced on
+# the chip: a B = 2 launch cost six B = 1 launches), so a launch of
+# more than ``TOPN_WALK_ROWS`` params rows takes the full pass.
+
+TOPN_EXTRA = ("topn",)
+# Rows a block: measured on the chip (scripts/row_tile_bench.py, PR 36).
+TOPN_BLOCK_ROWS = 8
+# ...and never more blocks than this, so that a field of thousands of
+# near-equal rows, which stops nowhere, pays a bounded number of loop
+# steps (a threshold and an all-reduce each) beside its one pass.
+TOPN_MAX_BLOCKS = 16
+# Params rows (padded) a walked launch unrolls at most.
+TOPN_WALK_ROWS = 8
+
+
+def topn_extra(plan, n, ids, extras: bool = False) -> tuple:
+    """``extra`` of the row_counts node of TopN(field, plan, n, ids):
+    ``TOPN_EXTRA`` where the finisher is ``rank_counts(counts, n)`` with
+    n > 0 over a filter — the top n by count, ties by row id — and ()
+    where it reads every count: n = 0 (all rows), ``ids`` (named rows),
+    tanimoto / attribute filters (``extras``), no filter (the counts are
+    the totals).  Read off the call; nobody sets it."""
+    return TOPN_EXTRA if plan is not None and n and n > 0 \
+        and not ids and not extras else ()
+
+
+def with_n(params, n: int):
+    """A top-n node's params row: the plan's slots, then n."""
+    return np.append(np.asarray(params, dtype=np.int32), np.int32(
+        min(int(n), np.iinfo(np.int32).max)))
+
+
+def walk_block_rows(rows: int) -> int:
+    """Rows a block of the walk over a fragment of ``rows`` rows."""
+    return min(rows, max(TOPN_BLOCK_ROWS, -(-rows // TOPN_MAX_BLOCKS)))
+
+
+def row_totals(stack, axis_name):
+    """Unfiltered per-row popcounts of a device-local stacked block
+    [S_local, R, 256, 128], summed over its shards and the mesh: s32[R],
+    inside a shard_map over ``axis_name``."""
+    return jax.lax.psum(bitset.row_counts(stack).sum(axis=0), axis_name)
+
+
+def walk_order(totals):
+    """The walk's plan from a block's row totals s32[R]: (starts,
+    bounds), s32[nb] each — the first row of every block of
+    ``walk_block_rows(R)`` rows (the last block is moved back to end at
+    R, so every block is whole and a row may be counted twice, to the
+    same figure) in descending order of the block's largest total, and
+    those largest totals."""
+    R = totals.shape[0]
+    k = walk_block_rows(R)
+    starts = jnp.minimum(jnp.arange(-(-R // k), dtype=jnp.int32) * k, R - k)
+    bounds = jax.vmap(lambda s: jnp.max(
+        jax.lax.dynamic_slice_in_dim(totals, s, k)))(starts)
+    order = jnp.argsort(-bounds, stable=True)
+    return starts[order], bounds[order]
+
+
+def nth_largest(counts, ns):
+    """The ``ns[b]``-th largest of each row of ``counts`` [B, R] (>= 0),
+    0 where a row has fewer than n entries: the count a row must reach
+    to be among the top n."""
+    R = counts.shape[1]
+    ranked = jnp.sort(counts, axis=1)                      # ascending
+    at = jnp.clip(R - ns, 0, R - 1)[:, None]
+    return jnp.where(ns > R, 0,
+                     jnp.take_along_axis(ranked, at, axis=1)[:, 0])
+
+
+def block_counts(block, mask):
+    """Counts of a block's k rows under one params row's filter
+    segments, summed over the device's shards: [S_local, k, 256, 128]
+    and [S_local, 256, 128] -> s32[k]."""
+    return jax.vmap(lambda rows, m: masked_counts(rows, m[None])[0])(
+        block, mask).sum(axis=0)
+
+
+def topn_walk(stack, masks, ns, starts, bounds, axis_name):
+    """Filtered per-row counts of a top-n node, as far as they can
+    matter.  Inside the shard_map body, outside the per-shard vmap (the
+    bound is global): ``stack`` is the device-local block
+    [S_local, R, 256, 128], ``masks`` its filter segments, one
+    [S_local, 256, 128] a params row, ``ns`` s32[B], (``starts``,
+    ``bounds``) ``walk_order`` of the block's totals.  Returns (counts
+    s32[B, R], exact for every row visited and 0 for the others; rows
+    visited, one s32 for the launch: in a fused launch the walk goes on
+    while any params row needs the next block).
+
+    After each block its counts are global (summed over the shards,
+    ``psum``ed), so every device holds the same threshold and makes the
+    same number of steps.  The next block is read only if its largest
+    total is >= max(t, 1) for some params row, t the n-th largest count
+    so far (0 while fewer than n rows are non-zero): a total equal to t
+    can tie the n-th place and win it on the lower row id, so it is
+    read.  A block not visited is behind the ``while``: never read."""
+    R = stack.shape[1]
+    k = walk_block_rows(R)
+    nb = starts.shape[0]
+    ns = jnp.maximum(ns, 1)
+
+    def cond(state):
+        j, counts = state
+        t = jnp.maximum(nth_largest(counts, ns), 1)
+        # (both sides are evaluated: the index stays inside at j = nb)
+        return (j < nb) & jnp.any(bounds[jnp.minimum(j, nb - 1)] >= t)
+
+    def step(state):
+        j, counts = state
+        start = starts[j]
+        block = jax.lax.dynamic_slice_in_dim(stack, start, k, axis=1)
+        part = jnp.stack([block_counts(block, m) for m in masks])  # [B, k]
+        part = jax.lax.psum(part, axis_name)
+        return j + 1, jax.lax.dynamic_update_slice_in_dim(
+            counts, part, start, axis=1)
+
+    j, counts = jax.lax.while_loop(
+        cond, step,
+        (jnp.int32(0), jnp.zeros((len(masks), R), dtype=jnp.int32)))
+    return counts, jnp.minimum(j * k, R)
 
 
 def mat_rows(mat) -> int:

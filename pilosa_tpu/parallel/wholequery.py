@@ -65,6 +65,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS
+from ..executor.plan import eval_plan
 from ..ops import bsi
 from ..utils import devobs as _devobs
 from ..utils import profile as qprof
@@ -109,6 +110,27 @@ def program_name(program) -> str:
     if len(kinds) > PROGRAM_NAME_NODES:
         name += f"_n{len(kinds)}"
     return name
+
+
+def walk_nodes(program, live, sched, pad_mats) -> dict:
+    """{node index: (live group, position of the primary's stack among
+    the group's arrays)} of the top-n nodes this launch may answer by
+    ``nodes.topn_walk``: the program reduces every shard the call was
+    given, so what is left to ask is that the node reads ONE shape
+    group, that every stack of the group is dense (a compressed one is
+    decoded a shard at a time, inside the per-shard pass), and that the
+    params rows are few enough to unroll.  Static per program and
+    shapes, as the compile key is."""
+    out = {}
+    for ni, node in enumerate(program):
+        if node.extra != nodes.TOPN_EXTRA or len(sched[ni]) != 1 \
+                or pad_mats[ni].shape[0] > nodes.TOPN_WALK_ROWS:
+            continue
+        layout = live[sched[ni][0]][3]
+        if all(n == 1 for _k, n, _sig in layout):
+            out[ni] = (sched[ni][0],
+                       [k for k, _n, _sig in layout].index(node.primary))
+    return out
 
 
 def _divisor_at_most(n: int, m: int) -> int:
@@ -380,9 +402,18 @@ class WholeQueryRunner:
                                             pad_mats)),
                mesh._exec_seq)
         flat_all = [a for g in live for a in g[2]]
+        # a top-n node's walk plan rides behind the stacks, replicated:
+        # made from the very array the launch reads (mesh.walk_plan)
+        walks = walk_nodes(program, live, sched, pad_mats)
+        for ni, (gi, pos) in walks.items():
+            flat_all.extend(mesh.walk_plan(
+                index, program[ni].primary, live[gi][0], live[gi][2][pos]))
         local = tuple(b // mesh.n_devices for b in buckets)
         fn, blocks, temp_bytes, fresh = self._fit(
             key, local, program, live, sched, pad_mats, flat_all)
+        if blocks is None:      # a shard-blocked program takes the full pass
+            for ni in walks:
+                meta[ni]["walk"] = True
         rows_padded = sum(nodes.mat_rows(m) for m in pad_mats)
         if len(self._row_temp) >= self.ROW_TEMP_MAX:
             self._row_temp.clear()      # the packer fuses unweighed once
@@ -494,7 +525,13 @@ class WholeQueryRunner:
         share of it, else ``blocks[gi]`` is the divisor of that share
         group gi is walked in."""
         groups_static = tuple((g[3], len(g[2])) for g in live)
+        n_flat_all = sum(n for _, n in groups_static)
         sig_maps = tuple(g[1] for g in live)
+        walk_at = walk_nodes(program, live, sched, pad_mats)
+        # where each walked node's plan sits among the arguments
+        walk_arg = {ni: n_flat_all + 2 * i for i, ni in enumerate(walk_at)}
+        # the walk is outside the per-shard pass, which ``blocks`` cuts
+        walks = walk_at if blocks is None else {}
 
         # per-node static combine targets (max rows / max BSI depth);
         # single-assignment so the traced body's closure cell can never
@@ -520,12 +557,27 @@ class WholeQueryRunner:
             # named shard axis — the in-program collective that replaces
             # the per-stage path's host merge of a part a group.
             per_group_raw: list[dict] = [dict() for _ in groups_static]
+            group_arrs = []
             i = 0
             for gi, (layout_g, n_g) in enumerate(groups_static):
                 arrs = flat[i:i + n_g]
+                group_arrs.append(arrs)
                 i += n_g
+                # a walked node's filter segments, one per-shard pass a
+                # params row (unrolled: a row take under a batch axis
+                # reads the whole stack); its counts are taken outside
+                for ni, (wgi, _pos) in walks.items():
+                    if wgi == gi:
+                        per_group_raw[gi][ni] = [
+                            _over_shards(
+                                lambda *arrays, _l=layout_g, _n=ni, _b=b:
+                                eval_plan(program[_n].plan,
+                                          _unpack_frags(_l, arrays),
+                                          mats[_n][_b]), arrs, None)
+                            for b in range(mats[ni].shape[0])]
                 node_ids = tuple(
-                    ni for ni in range(len(program)) if gi in sched[ni])
+                    ni for ni in range(len(program))
+                    if gi in sched[ni] and ni not in walks)
                 if not node_ids:
                     continue
 
@@ -553,6 +605,17 @@ class WholeQueryRunner:
                         flat_outs.extend(p)
                 elif not parts:
                     pass                            # no contributing group
+                elif ni in walks:
+                    # [B, R] counts as far as they can matter, and the
+                    # rows visited as one more column of the same output
+                    gi, pos = walks[ni]
+                    w0 = walk_arg[ni]
+                    counts, visited = nodes.topn_walk(
+                        group_arrs[gi][pos], parts[0], mats[ni][:, -1],
+                        flat[w0], flat[w0 + 1], SHARD_AXIS)
+                    flat_outs.append(jnp.concatenate(
+                        [counts, jnp.broadcast_to(
+                            visited, (counts.shape[0], 1))], axis=1))
                 elif node.kind == "count":
                     total = parts[0].sum(axis=0)
                     for p in parts[1:]:
@@ -609,7 +672,6 @@ class WholeQueryRunner:
 
         traced.__name__ = program_name(program)
 
-        n_flat_all = sum(n for _, n in groups_static)
         from ..ops import kernels as _kernels
         # shard_map's replication checker has no rule for pallas_call;
         # disable it only when a group actually decodes through the
@@ -619,6 +681,7 @@ class WholeQueryRunner:
             for layout_g, _ in groups_static for _, n, s in layout_g)
         fn = jax.jit(jax.shard_map(
             traced, mesh=self.mesh.mesh,
-            in_specs=(P(),) + (P(SHARD_AXIS),) * n_flat_all,
+            in_specs=(P(),) + (P(SHARD_AXIS),) * n_flat_all
+            + (P(),) * (2 * len(walk_at)),
             out_specs=tuple(out_specs), check_vma=check))
         return _InstrumentedWhole(fn, key, out_index, self.mesh.n_devices)

@@ -168,6 +168,15 @@ def build_debug_vars(api: API, server=None) -> dict:
             "boundBytes": batch_temp_bound(),
             "splits": ex.mesh_exec.temp_splits,
         }
+        # a filtered TopN stops at the n-th count (docs/whole-query.md
+        # "The TopN walk"): how far the walks went, and top-n questions
+        # that took the full pass
+        out["topnPrune"] = {
+            "queries": ex.mesh_exec.topn_queries,
+            "rowsVisited": ex.mesh_exec.topn_rows_visited,
+            "rowsStacked": ex.mesh_exec.topn_rows_stacked,
+            "fullScans": ex.mesh_exec.topn_full_scans,
+        }
     # cross-query dynamic batching (docs/batching.md): fused/single
     # launch counters, the batch-size histogram, and the queue-wait
     # p50/p99 — the knobs' feedback loop for tuning window/max

@@ -48,6 +48,7 @@ tests/test_ssb_sf30.py
 tests/test_stack_epoch.py
 tests/test_storage.py
 tests/test_taxi_1b_mesh4.py
+tests/test_topn_prune.py
 tests/test_translate.py
 tests/test_wholequery.py
 "

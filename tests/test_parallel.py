@@ -511,7 +511,7 @@ def test_lone_and_batched_count_share_one_executable(staged):
 def test_executable_kinds_are_the_node_kinds(staged):
     """After every node kind has run per stage at B = 1 and B = 3 and
     through the whole-query program, no executable's kind is outside the
-    six node kinds, ``wholequery`` and ``overlay``."""
+    six node kinds, ``wholequery``, ``walkplan`` and ``overlay``."""
     stage = Executor(staged, use_mesh=True, whole_query=False)
     whole = Executor(staged, use_mesh=True)
     host = Executor(staged)
@@ -522,7 +522,9 @@ def test_executable_kinds_are_the_node_kinds(staged):
                 assert _norm(stage.execute("p", q)) == want, q
                 assert _norm(whole.execute("p", q)) == want, q
         assert set(_stage_kinds(stage)) == set(KINDS)
-        assert set(_stage_kinds(whole)) == {"wholequery"}
+        # (``walkplan``: the row totals a filtered TopN's walk is planned
+        # from, one small program a stacked block shape: nodes.topn_walk)
+        assert set(_stage_kinds(whole)) == {"wholequery", "walkplan"}
         # an ingest flush overlays the resident stack (docs/ingest.md)
         staged.field("p", "a").set_bit(1, 5)
         assert stage.execute("p", "Count(Row(a=1))") == \

@@ -173,8 +173,9 @@ def test_the_cell_is_declared():
     datagen = _bench()[0]
     assert config["source"] == datagen.load_json("configs", CONFIG)["source"]
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        "kernels_roofline_per_chip", "collective_share_top10"]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("kernels_roofline_per_chip")
+    assert at == 20 and names[at + 1] == "collective_share_top10"
     listed = {n for n, m in by_name.items() if CELL in m["workloads"]}
     assert set(by_name) - listed == {"kernels_roofline", "temp_split_share"}
     for name in ("kernels_roofline_per_chip", "collective_share_top10"):
