@@ -465,10 +465,39 @@ def plan_inputs(plan) -> list[tuple[str, str]]:
     return out
 
 
+def row_takes(plan) -> dict[tuple[str, str], int]:
+    """How many rows a plan takes of each (field, view) it reads through
+    ``Row`` leaves alone: a key a ``BSIPlan`` also reads is left out.
+    What a launch may serve by row takes of a compressed fragment, and
+    how many dense rows that costs it."""
+    takes: dict[tuple[str, str], int] = {}
+    whole: set = set()
+
+    def walk(p):
+        if isinstance(p, RowPlan):
+            for v in p.views:
+                takes[(p.field, v)] = takes.get((p.field, v), 0) + 1
+        elif isinstance(p, BSIPlan):
+            whole.add((p.field, p.view))
+        elif isinstance(p, NotPlan):
+            walk(p.existence)
+            walk(p.child)
+        elif isinstance(p, ShiftPlan):
+            walk(p.child)
+        elif isinstance(p, NaryPlan):
+            for ch in p.children:
+                walk(ch)
+
+    walk(plan)
+    return {k: n for k, n in takes.items() if k not in whole}
+
+
 def eval_plan(plan, frags: dict[tuple[str, str], Any], params=None):
     """Trace a plan over fragment tensors.  ``frags`` maps (field, view) to a
-    tiled uint32[n_rows, 256, 128] array (ops/bitset.py "Representation")
-    or None (missing fragment).  Returns a segment uint32[256, 128]; a row
+    tiled uint32[n_rows, 256, 128] array (ops/bitset.py "Representation"),
+    to what stands for one where the plan only takes rows of it (a
+    ``shape`` and ``take_row(row_id)``: mesh_exec ``PackedRows``) or to
+    None (missing fragment).  Returns a segment uint32[256, 128]; a row
     take is an offset on the untiled row axis.
 
     Literal plans trace their constants into the program; slotted plans
@@ -482,10 +511,13 @@ def eval_plan(plan, frags: dict[tuple[str, str], Any], params=None):
         frag = frags.get((field, view))
         if frag is None:
             return None
+        take = getattr(frag, "take_row", None)
         if isinstance(row_id, Slot):
             if frag.shape[0] == 0:
                 return None
             rid = params[row_id.idx]
+            if take is not None:    # a compressed fragment: the one row
+                return take(rid)
             return jnp.where(
                 rid < frag.shape[0],
                 jax.lax.dynamic_index_in_dim(
@@ -494,7 +526,7 @@ def eval_plan(plan, frags: dict[tuple[str, str], Any], params=None):
                 jnp.zeros(frag.shape[1:], dtype=frag.dtype))
         if row_id >= frag.shape[0]:
             return None
-        return frag[row_id]
+        return take(row_id) if take is not None else frag[row_id]
 
     def mag_bits(slot: Slot):
         return params[slot.idx:slot.idx + slot.width]

@@ -61,15 +61,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS, WORD_TILE
 from ..ops import bitset
-from ..executor.plan import eval_plan
+from ..executor.plan import row_takes
 from ..utils import devobs as _devobs
 from ..utils import profile as qprof
 from ..utils.deadline import check_current
 from ..utils.faults import FAULTS
 from ..utils.locks import make_lock, make_rlock
 from ..utils.tracing import GLOBAL_TRACER, layer_span
-from .nodes import PER_SHARD_KINDS, mat_rows, node_keys, node_shard, \
-    pad_pow2_rows, participates, row_totals, walk_order
+from . import nodes as _nodes
+from .nodes import PER_SHARD_KINDS, mat_rows, node_keys, pad_pow2_rows, \
+    participates, row_totals, walk_order
 
 SHARD_AXIS = "shards"
 
@@ -84,7 +85,7 @@ DECODE_WORKSPACE_BYTES = 1 << 30
 def _flatten_present(present):
     """Flatten present (key, placed, sig) entries into the device-arg
     list a compiled executable takes: a compressed entry contributes its
-    five stacked container arrays, a dense one a single tensor.  Returns
+    seven stacked container arrays, a dense one a single tensor.  Returns
     (flat_args, layout); ``layout`` drives _unpack_frags inside the
     executable and is fully determined by the entries' sigs (which key
     the executable cache), so one compiled body always sees one layout."""
@@ -99,49 +100,87 @@ def _flatten_present(present):
     return flat, tuple(layout)
 
 
-def _unpack_frags(layout, arrays):
+class PackedRows:
+    """A compressed fragment as a plan sees it where the plan only takes
+    rows of it (``executor.plan.eval_plan``): the fragment's shape, and
+    ``take_row``, which decodes the one row asked for
+    (``containers.decode_row``) where ``_unpack_frags`` would decode
+    them all — a filter over a 16-row field costs a launch one row's
+    tiles, not sixteen.  ``arrays`` are one shard's packed arrays inside
+    the vmapped per-shard body, ``stacked_idx`` the whole block's array
+    entries, closed over and so NOT batched over the shards: whether any
+    shard holds the row as array entries is one scalar a launch, and the
+    scatter that sets them a real conditional."""
+
+    __slots__ = ("arrays", "sig", "stacked_idx", "shape")
+
+    def __init__(self, arrays, sig, stacked_idx):
+        self.arrays, self.sig, self.stacked_idx = arrays, sig, stacked_idx
+        self.shape = (sig[1],) + WORD_TILE
+
+    def take_row(self, rid):
+        from ..ops import containers
+        s = self.sig
+        return bitset.to_tile(containers.decode_row(
+            *self.arrays, rid, rows=s[1], words=SHARD_WORDS,
+            a_bucket=s[4], r_bucket=s[5],
+            has_array=containers.row_has_entries(self.stacked_idx, rid)
+            if s[4] else None))
+
+
+def _unpack_frags(layout, arrays, rows_of=(), stacked=None):
     """Inside a per-shard (vmapped) body: decode compressed inputs to
     dense [rows, 256, 128] fragments — the decode-at-op-time step, fused
     into the op's own executable so dense tiles exist only as
-    launch-local XLA temporaries; the decoders' [rows, W] output is
+    launch-local XLA temporaries; the decoder's [rows, W] output is
     viewed as the word tile here, the one funnel — and map every key to
-    its dense fragment.  Each entry's
-    signature carries the container-kernels backend it was planned under
-    (storage/fragment.py device_sig), so the dispatch here is static per
-    layout: 'pallas' entries decode through the ops/kernels.py Pallas
-    kernel (tile-by-tile in VMEM), the rest through the jnp gather path."""
-    from ..ops import containers, kernels
+    its dense fragment.  A compressed key in ``rows_of`` (the keys whose
+    rows are all a launch takes: ``_row_keys``) is not decoded but
+    handed on as ``PackedRows``; ``stacked`` is then the launch's flat
+    argument list before the per-shard ``vmap``."""
+    from ..ops import containers
     out = {}
     i = 0
     for k, n, s in layout:
         if n == 1:
             out[k] = arrays[i]
+        elif k in rows_of:
+            out[k] = PackedRows(arrays[i: i + n], s, stacked[i + 5])
         else:
-            dec = kernels.decode_block \
-                if kernels.sig_backend(s) == "pallas" \
-                else containers.decode_block
-            out[k] = bitset.to_tile(dec(
+            out[k] = bitset.to_tile(containers.decode_block(
                 *arrays[i: i + n], rows=s[1], words=SHARD_WORDS,
                 a_bucket=s[4], r_bucket=s[5]))
         i += n
     return out
 
 
+def _row_keys(node, fused: bool) -> frozenset:
+    """The keys of ``node`` that its per-shard body reads only through
+    the plan's row takes, so that a compressed one need not be decoded
+    whole: the plan's ``Row`` inputs, less what the body reads as a
+    fragment — the primary (but where its rows are counted in the packed
+    stream, ``fused``) and a GroupBy's prefix fields."""
+    if node.plan is None:
+        return frozenset()
+    whole = set()
+    if node.kind not in ("count", "segments") and not fused:
+        whole.add(node.primary)
+    if node.kind == "group_counts":
+        whole.update(node.extra[:-1])
+    return frozenset(row_takes(node.plan)) - whole
+
+
 def _fused_entry(layout, key):
-    """(flat-arg index, sig) of ``key``'s layout entry when it is a
-    compressed entry planned for the Pallas backend — the condition
-    under which a per-shard body may route the whole decode+op+popcount
-    chain through one fused kernel (kernels.fused_row_counts) instead of
-    decode-then-op.  None otherwise (dense entry or jnp backend).
-    Static per layout, so the per-shard body's branch is resolved at
-    trace time."""
-    from ..ops import kernels
+    """(flat-arg index, arrays, sig) of ``key``'s layout entry when it
+    is a compressed entry — the condition under which a per-shard body
+    counts the field's rows where they lie in the packed stream
+    (kernels.fused_row_counts) instead of decode-then-op, by the backend
+    the entry's signature names.  None for a dense entry.  Static per
+    layout, so the per-shard body's branch is resolved at trace time."""
     i = 0
     for k, n, s in layout:
         if k == key:
-            if n > 1 and kernels.sig_backend(s) == "pallas":
-                return i, s
-            return None
+            return (i, n, s) if n > 1 else None
         i += n
     return None
 
@@ -171,6 +210,106 @@ def field_rows(holder, index: str, field: str, view: str) -> int:
     return max((fr.n_rows for fr in v.fragments.values()), default=0)
 
 
+def form_tag(sigs) -> str:
+    """The ``form`` tag of a ``dispatch.place`` annotation: ``dense``
+    for a placement whose stacked inputs are all dense, ``z:<backend>``
+    for one of compressed stacks, by the backend their signatures name
+    (``z:jnp``, ``z:pallas``)."""
+    from ..ops import kernels as _kernels
+    backends = sorted({_kernels.sig_backend(s) for s in sigs
+                       if s is not None and s[0] == "z"})
+    return "z:" + "+".join(backends) if backends else "dense"
+
+
+def _counts_in_place(layout, node, b_pad: int):
+    """``_fused_entry`` of a ``row_counts`` node's primary where a
+    launch of ``b_pad`` params rows counts it in the packed stream."""
+    from ..ops import kernels as _kernels
+    if node is None or node.kind != "row_counts" \
+            or b_pad > _kernels.FUSED_PARAMS_MAX:
+        return None
+    return _fused_entry(layout, node.primary)
+
+
+def launch_form(layout, node) -> str:
+    """The ``form`` tag of a ``dispatch.enqueue`` annotation: ``dense``
+    for a launch over dense stacks; ``z:pallas`` for one that runs the
+    Pallas kernel (a ``row_counts`` node over a compressed primary whose
+    signature names it); ``z:jnp`` for every other launch over
+    compressed stacks: XLA decodes and counts."""
+    from ..ops import kernels as _kernels
+    if all(n == 1 for _, n, _ in layout):
+        return "dense"
+    fused = _counts_in_place(layout, node, 1)
+    return "z:pallas" if fused is not None and fused[2][4] \
+        and _kernels.sig_backend(fused[2]) == "pallas" else "z:jnp"
+
+
+def launch_cost(layout, node, b_pad: int) -> tuple[int, int, int]:
+    """What one launch of ``b_pad`` params rows costs a stacked shard:
+    (dense tile bytes decoded from compressed stacks, Pallas kernel
+    launches, container tiles those count) — the launch ledger's
+    ``decodeBytesTotal`` / ``kernelLaunches`` / ``kernelTiles``.  A
+    fragment decoded whole costs its rows, a row take of a compressed
+    fragment one row a params row, and a field counted in the packed
+    stream decodes nothing: where the Pallas kernel counts it, that is
+    one kernel launch over the field's container tiles."""
+    from ..ops import kernels as _kernels
+    tile = SHARD_WORDS * 4
+    fused = _counts_in_place(layout, node, b_pad)
+    rows_of = _row_keys(node, fused is not None) if node else frozenset()
+    takes = row_takes(node.plan) if rows_of else {}
+    b = 1 if node is None or node.kind in ("bsi_minmax", "group_counts") \
+        else b_pad
+    decode = 0
+    for k, n, s in layout:
+        if n == 1:
+            continue
+        if k in rows_of:
+            decode += takes[k] * b * tile
+        elif fused is None or k != node.primary:
+            decode += s[1] * tile
+    if fused is not None and fused[2][4] \
+            and _kernels.sig_backend(fused[2]) == "pallas":
+        return decode, 1, fused[2][1] * (SHARD_WORDS // CONTAINER_WORDS)
+    return decode, 0, 0
+
+
+def node_over_layout(node, layout, mat, shard, stacked):
+    """One reducer node's per-shard contribution over a shape group's
+    ``layout`` — the body of both launchers' vmapped per-shard pass:
+    ``shard`` are one shard's arrays, ``stacked`` the launch's before
+    the ``vmap``.  Dense inputs go to ``nodes.node_shard`` as they are.
+    Of compressed ones a plan's row takes decode their one row
+    (``PackedRows``), and a ``row_counts`` node's compressed primary is
+    not decoded at all — the headline fusion (ops/kernels.py): its rows
+    are counted under the filters where they lie in the packed stream
+    (of at most ``FUSED_PARAMS_MAX`` params rows: ``nodes.plan_rows``
+    unrolls as many)."""
+    fused = _counts_in_place(layout, node, mat_rows(mat))
+    frags = _unpack_frags(layout, shard, _row_keys(node, fused is not None),
+                          stacked)
+    if fused is None:
+        return _nodes.node_shard(node, mat, frags)
+    from ..ops import kernels
+    i0, n0, fs = fused
+    filts = None if node.plan is None \
+        else _nodes.plan_rows(node.plan, frags, mat)
+    counts = kernels.fused_row_counts(
+        *shard[i0: i0 + n0], filts, rows=fs[1], words=SHARD_WORDS,
+        a_bucket=fs[4], r_bucket=fs[5],
+        backend=kernels.sig_backend(fs))                   # [B, rows]
+    return jnp.broadcast_to(counts, (mat.shape[0],) + counts.shape[1:])
+
+
+def slice_tags() -> dict:
+    """``slice`` and ``slices`` of the same two annotations: the
+    streaming schedule's position (``_ShardSchedule``), 0 of 1 for a
+    launch outside one."""
+    pos = _devobs.current_slice() or (0, 1)
+    return {"slice": pos[0], "slices": pos[1]}
+
+
 class _InstrumentedExec:
     """One compiled shard_map executable plus its device-runtime
     telemetry (utils/devobs.py, docs/observability.md "Device runtime").
@@ -185,30 +324,19 @@ class _InstrumentedExec:
     batcher, and the streaming slice position installed by
     _ShardSchedule."""
 
-    __slots__ = ("fn", "sig", "kind", "detail", "devices",
-                 "decode_per_shard", "kernels_per_shard",
-                 "kernel_tiles_per_shard")
+    __slots__ = ("fn", "sig", "kind", "detail", "devices", "form",
+                 "layout", "node")
 
-    def __init__(self, fn, key, layout, devices: int):
-        from ..ops import kernels as _kernels
+    def __init__(self, fn, key, layout, devices: int, node=None):
         self.fn = fn
         self.devices = devices      # of the mesh the program runs over
         self.kind = key[0] if key and isinstance(key[0], str) else "exec"
         self.sig = _devobs.sig_of(key)
         self.detail = repr(key[1])[:120] if len(key) > 1 else ""
-        # transient dense tiles this executable decodes per stacked
-        # shard row (compressed layout entries expand inside the launch).
-        # Pallas-backend entries don't materialise that workspace — they
-        # stream VMEM container tiles — so they count as embedded kernel
-        # launches + tiles instead of decode bytes.
-        self.decode_per_shard = sum(
-            s[1] * SHARD_WORDS * 4 for _, n, s in layout
-            if n > 1 and _kernels.sig_backend(s) != "pallas")
-        pallas = [s for _, n, s in layout
-                  if n > 1 and _kernels.sig_backend(s) == "pallas"]
-        self.kernels_per_shard = len(pallas)
-        self.kernel_tiles_per_shard = sum(
-            s[1] * (SHARD_WORDS // CONTAINER_WORDS) for s in pallas)
+        # what a launch decodes and which kernel it runs follow from the
+        # layout, the node and the params rows: ``launch_cost``
+        self.layout, self.node = layout, node
+        self.form = launch_form(layout, node)
 
     def __call__(self, *args, _launch_meta=None):
         # call-site meta: actual shard count, or (shards, actual batch
@@ -227,6 +355,8 @@ class _InstrumentedExec:
         if rows is None:
             rows = meta_rows if meta_rows is not None else b_pad
         tickets = ctx.get("tickets", 1)
+        decode, kernels_n, tiles = (
+            c * shards for c in launch_cost(self.layout, self.node, b_pad))
         reg = _devobs.COMPILES
         reg.begin_call()
         # dispatch.enqueue: host time to hand this program to the
@@ -236,7 +366,8 @@ class _InstrumentedExec:
                         rows=rows, rows_padded=b_pad, tickets=tickets,
                         shards=shards, shards_padded=shards_pad,
                         temp_bytes=ctx.get("temp_bytes", 0),
-                        devices=self.devices) as span:
+                        devices=self.devices, form=self.form,
+                        **slice_tags()) as span:
             t0 = _time.perf_counter()
             out = self.fn(*args)
             dt = _time.perf_counter() - t0
@@ -251,10 +382,9 @@ class _InstrumentedExec:
             batch_rows=rows, batch_rows_padded=b_pad,
             queue_s=ctx.get("queue_s", 0.0), tickets=tickets,
             dispatch_s=dt, compiled=compiled,
-            decode_bytes=self.decode_per_shard * shards,
+            decode_bytes=decode,
             slice_pos=_devobs.current_slice(),
-            kernel_launches=self.kernels_per_shard * shards,
-            kernel_tiles=self.kernel_tiles_per_shard * shards)
+            kernel_launches=kernels_n, kernel_tiles=tiles)
         prof = qprof.current()
         if prof is not None:
             # rows/padding/decode tags feed the EXPLAIN launches section
@@ -263,7 +393,7 @@ class _InstrumentedExec:
             prof.event("device.launch", dt, kind=self.kind, sig=self.sig,
                        shards=shards, shardsPadded=shards_pad,
                        batchRows=rows, batchRowsPadded=b_pad,
-                       decodeBytes=self.decode_per_shard * shards,
+                       decodeBytes=decode,
                        compiled=compiled)
         return out
 
@@ -389,7 +519,22 @@ class MeshExecutor:
         self.topn_rows_visited = 0
         self.topn_rows_stacked = 0
         self.topn_full_scans = 0
+        # /debug/vars stackCache.scheduleFastHits / .scheduleWalks: slice
+        # plans of an over-budget set served while the device epoch
+        # stood, and those that walked the fragments for their bytes
+        # (``shard_schedule``; a set that fits counts in neither: it is
+        # one slice, unplanned).  Plain ints like the above.
+        self.schedule_fast_hits = 0
+        self.schedule_walks = 0
+        # (index, keys, shards) -> (device epoch, limit, workspace,
+        # slices): the cuts of an over-budget working set, kept while
+        # no fragment's device form can have moved
+        self._slice_plans: dict = {}
         self._budget = DEFAULT_BUDGET
+        # what the budget's limit is one device's share of: a stacked
+        # block lies over this mesh (most recent executor wins, like
+        # the limit itself)
+        self._budget.spread = self.n_devices
         # single-worker background uploader for streamed shard slices
         # (created on first over-budget query; one worker serializes
         # prefetch transfers so they never contend with each other)
@@ -412,23 +557,22 @@ class MeshExecutor:
     # -- compiled executables ---------------------------------------------
 
     def _jit_shard_map(self, key, block_fn, in_specs, out_specs,
-                       check_vma: bool = True, layout=()):
+                       check_vma: bool = True, layout=(), node=None):
         """``check_vma=False`` for multiprocess gather executables: their
         P() outputs ARE replicated (all_gather over the shard axis), but
         shard_map's static varying-axes checker cannot infer that.
-        ``layout`` (from _flatten_present) sizes the launch ledger's
-        decode-workspace attribution; the cached object is the
+        ``layout`` (from _flatten_present) and ``node`` size the launch
+        ledger's decode and kernel attribution; the cached object is the
         executable wrapped in its telemetry hooks (_InstrumentedExec)."""
         fn = self._cache.get(key)
         if fn is None:
-            from ..ops import kernels as _kernels
-            if any(n > 1 and _kernels.sig_backend(s) == "pallas"
-                   for _, n, s in layout):
+            if any(n > 1 for _, n, _ in layout):
                 # shard_map's replication checker has no rule for
-                # pallas_call (jax suggests check_vma=False as the
-                # workaround); these bodies' outputs follow the same
-                # psum/P(SHARD_AXIS) patterns the checker validates on
-                # the jnp variants of the identical layouts
+                # pallas_call and trips over a conditional under vmap
+                # (``PackedRows.take_row``); jax suggests
+                # check_vma=False as the workaround.  These bodies'
+                # outputs follow the same psum/P(SHARD_AXIS) patterns
+                # the checker validates on the dense layouts
                 check_vma = False
 
             def traced_body(*a, _fn=block_fn):
@@ -446,7 +590,7 @@ class MeshExecutor:
                     traced_body, mesh=self.mesh,
                     in_specs=in_specs, out_specs=out_specs,
                     check_vma=check_vma)),
-                key, layout, self.n_devices)
+                key, layout, self.n_devices, node)
             self._cache[key] = fn
         return fn
 
@@ -463,8 +607,12 @@ class MeshExecutor:
 
     def _placed_groups(self, keys, holder, index, shards):
         with layer_span("dispatch.place", _devobs.LEDGER,
-                        devices=self.n_devices):
-            return self._place_groups(keys, holder, index, shards)
+                        devices=self.n_devices, **slice_tags()) as span:
+            groups = self._place_groups(keys, holder, index, shards)
+            if span.recording:      # the launch path pays for no tag
+                span.tag(form=form_tag(
+                    s for _, _, sig in groups for s in sig))
+            return groups
 
     def _place_groups(self, keys, holder, index, shards):
         """Group shards by input-shape signature over fragment keys
@@ -534,9 +682,8 @@ class MeshExecutor:
                 return cached[1]
 
         groups: dict[tuple, list[tuple[int, list]]] = {}
-        for shard, row in zip(shards, frags):
-            sig = tuple(None if fr is None
-                        else self._frag_sig(fr) for fr in row)
+        for shard, row, sig in zip(shards, frags,
+                                   self._group_sigs(frags, len(keys))):
             groups.setdefault(sig, []).append((shard, row))
         nk = len(keys)
         row_of = {s: i for i, s in enumerate(shards)}
@@ -583,6 +730,36 @@ class MeshExecutor:
             self._refresh_overlays(ckey, token, frags, shards, keys,
                                    epochs, epoch)
         return out
+
+    def _group_sigs(self, frags, nk: int) -> list:
+        """Each shard's tuple of signatures over the ``nk`` keys, with
+        the compressed fragments of a key (of one row capacity) brought
+        to ONE signature: the largest container, payload, array and run
+        buckets among them (``_place_packed_block`` pads every member to
+        the group's buckets anyway).  Left apart, a key whose fragments
+        straddle a bucket's edge — ``dist_miles`` at 510–513 containers
+        a shard, or the odd fragment with a run container — splits every
+        slice into several shape groups of a few shards each, every one
+        a launch and, at every new stacked length, a compile."""
+        from ..ops import kernels
+        sigs = [[None if fr is None else self._frag_sig(fr) for fr in row]
+                for row in frags]
+        for i in range(nk):
+            largest: dict = {}      # row capacity -> the largest buckets
+            for row in sigs:
+                s = row[i]
+                if s is not None and s[0] == "z":
+                    top = largest.get(s[1])
+                    largest[s[1]] = s[2:6] if top is None else \
+                        tuple(map(max, top, s[2:6]))
+            for row in sigs:
+                s = row[i]
+                if s is not None and s[0] == "z" and \
+                        s[2:6] != largest[s[1]]:
+                    c, p, a, r = largest[s[1]]
+                    row[i] = ("z", s[1], c, p, a, r,
+                              kernels.backend_for(s[1]))
+        return [tuple(row) for row in sigs]
 
     def _store_entry(self, ckey, entry, fresh):
         """Store a stack-cache entry and the blocks it placed, and give
@@ -998,12 +1175,13 @@ class MeshExecutor:
 
     def _place_packed_block(self, frs, sig):
         """Compressed staging: pad each member fragment's packed
-        container stream to the group's pow2 buckets and place the five
-        stacked table/payload arrays mesh-sharded (ops/containers.py).
+        container stream to the group's shape buckets and place the seven
+        stacked table/payload/entry arrays mesh-sharded (ops/containers.py).
         Transfers move compressed bytes, so there is no warm-mirror
         stacking variant — re-shipping a packed stream is already far
         cheaper than a dense stack ever was."""
-        cb, pb = sig[2], sig[3]
+        from ..ops.containers import ARRAY_CLASSES, ARRAY_PAD
+        cb, pb, ab = sig[2], sig[3], sig[4]
         n = len(frs)
         bucket = self._bucket(n)
         keys = np.full((bucket, cb), -1, dtype=np.int32)
@@ -1011,6 +1189,9 @@ class MeshExecutor:
         counts = np.zeros((bucket, cb), dtype=np.int32)
         offsets = np.zeros((bucket, cb), dtype=np.int32)
         payload = np.zeros((bucket, pb), dtype=np.uint32)
+        a_idx = np.full((bucket, ARRAY_CLASSES, ab), ARRAY_PAD,
+                        dtype=np.int32)
+        a_val = np.zeros((bucket, ARRAY_CLASSES, ab), dtype=np.uint32)
         for i, fr in enumerate(frs):
             p = fr.packed_host()
             # a concurrent write may race the signature; clamping to the
@@ -1018,14 +1199,17 @@ class MeshExecutor:
             # (the stale token rebuilds the stack on the next query)
             c = min(p.keys.size, cb)
             pw = min(p.payload.size, pb)
+            aw = min(p.a_len, ab)
             keys[i, :c] = p.keys[:c]
             types[i, :c] = p.types[:c]
             counts[i, :c] = p.counts[:c]
             offsets[i, :c] = p.offsets[:c]
             payload[i, :pw] = p.payload[:pw]
+            a_idx[i, :, :aw] = p.a_idx[:, :aw]
+            a_val[i, :, :aw] = p.a_val[:, :aw]
         sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
-        return tuple(jax.device_put(a, sharding)
-                     for a in (keys, types, counts, offsets, payload))
+        return tuple(jax.device_put(a, sharding) for a in (
+            keys, types, counts, offsets, payload, a_idx, a_val))
 
     @staticmethod
     def _present(keys, placed, sig):
@@ -1064,71 +1248,123 @@ class MeshExecutor:
             dec.append(d)
         return res, dec
 
+    # slice plans kept (``_slice_plans``): a plan is a few KB, a program
+    # and a shard list a key
+    SLICE_PLANS_MAX = 256
+
     def shard_schedule(self, holder, index, key_lists, shards):
         """Residency-aware shard-group schedule for a dispatch that will
         stack ``key_lists`` (one key list per distinct stacked block) over
         ``shards``.
 
-        Fits-in-budget working sets (or an unlimited budget, or a
-        multi-process mesh, whose staging must stay deterministic across
-        processes) get ONE slice — the whole shard list, with cache keys
-        identical to the pre-streaming path.  Over-budget sets are carved
-        into contiguous slices of at most STREAM_SLICE_FRACTION of the
-        budget; slices already resident are ordered FIRST so a batch
-        drains all work against staged data before rotating the budget,
-        and iteration prefetches slice k+1 while slice k dispatches."""
+        While the node's dense set fits the device budget
+        (``DeviceBudget.dense_fits``: no limit, or every open fragment's
+        dense form inside it), and on a multi-process mesh, whose staging
+        must stay deterministic across processes, the answer is ONE slice
+        — the whole shard list, with cache keys identical to the
+        pre-streaming path — and no fragment is looked at.  A set that
+        does not fit is carved into contiguous slices of at most
+        STREAM_SLICE_FRACTION of the budget; slices already resident are
+        ordered FIRST so a batch drains all work against staged data
+        before rotating the budget, and iteration prefetches slice k+1
+        while slice k dispatches.  The cuts come from the fragments'
+        device forms, so they are kept per (index, keys, shards) while
+        the device epoch of those keys stands, as ``_placed_groups``
+        keeps a stack: a repeat launch walks nothing."""
         shards = list(shards)
-        # bytes are estimated over the union of the lists' keys: a
-        # (field, view) that two lists stack is one resident block
-        all_keys = list(dict.fromkeys(k for kl in key_lists for k in kl))
-        # the limit is one device's, and a stacked block lies over the
-        # mesh in equal shares (one shape a group, the bucket a multiple
-        # of the devices): the shards of a slice may hold the limit
-        # times the devices between them
         limit = self._budget.limit_bytes
-        if limit:
-            limit *= self.n_devices
         slices = [shards]
-        if limit and not self.multiprocess and \
-                len(shards) > self.n_devices:
-            per, dec = self._estimate_shard_bytes(all_keys, holder, index,
-                                                  shards)
-            ws = max(1, DECODE_WORKSPACE_BYTES)
-            if sum(per) > limit or sum(dec) > ws:
-                target = max(1, int(limit * self.STREAM_SLICE_FRACTION))
-                # contiguous cuts, deterministic for a given (shards,
-                # limit) so repeat queries hit the same slice cache keys;
-                # never below n_devices shards per slice — _bucket would
-                # pad a smaller slice back to a full mesh width of zero
-                # blocks, re-inflating the memory the cut tried to save.
-                # Two ceilings: resident bytes against the streaming
-                # target (rotating the budget) and decoded dense bytes
-                # against the per-launch workspace — a fully-resident
-                # compressed working set still slices by the latter, so
-                # one launch never materialises more dense tiles than
-                # the workspace allows (rotation is then free: every
-                # slice's compressed stack stays resident).
-                slices, cur, cur_b, cur_d = [], [], 0, 0
-                for s, b, d in zip(shards, per, dec):
-                    if (cur_b + b > target or cur_d + d > ws) and \
-                            len(cur) >= self.n_devices:
-                        slices.append(cur)
-                        cur, cur_b, cur_d = [], 0, 0
-                    cur.append(s)
-                    cur_b += b
-                    cur_d += d
-                if slices and len(cur) < self.n_devices:
-                    slices[-1].extend(cur)  # tail can't fill the mesh
-                elif cur:
-                    slices.append(cur)
-                if len(slices) > 1:
-                    # drain resident slices first (stable within each
-                    # class so rotation order stays deterministic)
-                    res = [all(self._is_resident(kl, holder, index, sl)
-                               for kl in key_lists) for sl in slices]
-                    slices = [sl for sl, r in zip(slices, res) if r] + \
-                        [sl for sl, r in zip(slices, res) if not r]
+        if limit and not self._budget.dense_fits() and \
+                not self.multiprocess and len(shards) > self.n_devices:
+            # bytes are estimated over the union of the lists' keys: a
+            # (field, view) that two lists stack is one resident block
+            all_keys = list(dict.fromkeys(
+                k for kl in key_lists for k in kl))
+            # the limit is one device's, and a stacked block lies over
+            # the mesh in equal shares (one shape a group, the bucket a
+            # multiple of the devices): the shards of a slice may hold
+            # the limit times the devices between them
+            slices = self._slice_plan(holder, index, all_keys, shards,
+                                      limit * self.n_devices)
+            if len(slices) > 1:
+                # drain resident slices first (stable within each
+                # class so rotation order stays deterministic)
+                res = [all(self._is_resident(kl, holder, index, sl)
+                           for kl in key_lists) for sl in slices]
+                slices = [sl for sl, r in zip(slices, res) if r] + \
+                    [sl for sl, r in zip(slices, res) if not r]
         return _ShardSchedule(self, holder, index, key_lists, slices)
+
+    def _slice_plan(self, holder, index, keys, shards, limit) -> list:
+        """The contiguous cuts of ``shards`` for a working set over
+        ``limit`` bytes, from the per-shard estimates; served from
+        ``_slice_plans`` while the device epoch read BEFORE the walk
+        that made them stands (the order ``_place_groups`` argues for)
+        and the limit and the workspace are what they were."""
+        ws = max(1, DECODE_WORKSPACE_BYTES)
+        epoch = holder.device_epoch(index, keys)
+        pkey = (index, tuple(keys), tuple(shards))
+        plan = self._slice_plans.get(pkey)
+        if plan is not None and plan[:3] == (epoch, limit, ws):
+            self.schedule_fast_hits += 1
+            return plan[3]
+        self.schedule_walks += 1
+        per, dec = self._estimate_shard_bytes(keys, holder, index, shards)
+        slices = [shards]
+        if sum(per) > limit or sum(dec) > ws:
+            target = max(1, int(limit * self.STREAM_SLICE_FRACTION))
+            # contiguous cuts, deterministic for a given (shards,
+            # limit) so repeat queries hit the same slice cache keys;
+            # never below n_devices shards per slice — _bucket would
+            # pad a smaller slice back to a full mesh width of zero
+            # blocks, re-inflating the memory the cut tried to save.
+            # Two ceilings: resident bytes against the streaming
+            # target (rotating the budget) and decoded dense bytes
+            # against the per-launch workspace — a fully-resident
+            # compressed working set still slices by the latter, so
+            # one launch never materialises more dense tiles than
+            # the workspace allows (rotation is then free: every
+            # slice's compressed stack stays resident).
+            slices, cur, cur_b, cur_d = [], [], 0, 0
+            for s, b, d in zip(shards, per, dec):
+                if (cur_b + b > target or cur_d + d > ws) and \
+                        len(cur) >= self.n_devices:
+                    slices.append(cur)
+                    cur, cur_b, cur_d = [], 0, 0
+                cur.append(s)
+                cur_b += b
+                cur_d += d
+            if slices and len(cur) < self.n_devices:
+                slices[-1].extend(cur)  # tail can't fill the mesh
+            elif cur:
+                slices.append(cur)
+            slices = self._even_cut(slices, per, dec, target, ws)
+        if len(self._slice_plans) >= self.SLICE_PLANS_MAX:
+            self._slice_plans.clear()
+        self._slice_plans[pkey] = (epoch, limit, ws, slices)
+        return slices
+
+    def _even_cut(self, slices, per, dec, target, ws) -> list:
+        """As many slices as the greedy cut made, of one length or two
+        that differ by a shard: they go to one shard bucket and run one
+        compiled program, where a short tail slice compiled its own for
+        every program and batch size (ROADMAP S6).  Kept only where
+        every even slice holds the two ceilings and fills the mesh;
+        otherwise the greedy cut stands."""
+        k = len(slices)
+        n = sum(len(sl) for sl in slices)
+        q, r = divmod(n, k)
+        if k < 2 or q < self.n_devices:
+            return slices
+        shards = [s for sl in slices for s in sl]
+        even, lo = [], 0
+        for i in range(k):
+            hi = lo + q + (i < r)
+            if sum(per[lo:hi]) > target or sum(dec[lo:hi]) > ws:
+                return slices
+            even.append(shards[lo:hi])
+            lo = hi
+        return even
 
     def _pin_stack(self, keys, index, shard_slice) -> list:
         """Pin the blocks of this (keys, shard slice) stack; returns
@@ -1217,7 +1453,8 @@ class MeshExecutor:
     def _build(self, key, node, layout):
         """The per-stage executable of ``node`` over one shape group:
         ``nodes.node_shard`` — the body the whole-query program traces —
-        vmapped over the device's shards under one of two combines, by
+        over the group's layout (``node_over_layout``), vmapped over the
+        device's shards under one of two combines, by
         the kind: summed over them and ``psum``ed to every device, or
         left per shard (gathered over the shard axis on a multi-process
         mesh, where no process can address them all).  Everything the
@@ -1227,30 +1464,11 @@ class MeshExecutor:
         n_flat = sum(n for _, n, _ in layout)
         per_shard_out = node.kind in PER_SHARD_KINDS
         gather = per_shard_out and self.multiprocess
-        fused = _fused_entry(layout, node.primary) \
-            if node.kind == "row_counts" else None
-
-        def per_shard(mat, *arrays):
-            if fused is not None and mat.shape[0] == 1:
-                # the headline fusion (ops/kernels.py): decode +
-                # filter-AND + per-row popcount in ONE Pallas kernel;
-                # the field fragment's dense words never leave the
-                # kernel's VMEM tile.  Other layout entries still decode
-                # normally for the filter plan (XLA drops the unused
-                # field decode).
-                from ..ops import kernels
-                i0, fs = fused
-                filt = None
-                if node.plan is not None:
-                    filt = eval_plan(node.plan,
-                                     _unpack_frags(layout, arrays), mat[0])
-                return kernels.fused_row_counts(
-                    *arrays[i0: i0 + 5], filt, rows=fs[1],
-                    words=SHARD_WORDS, a_bucket=fs[4],
-                    r_bucket=fs[5])[None]              # [1, rows]
-            return node_shard(node, mat, _unpack_frags(layout, arrays))
 
         def block_fn(mat, *arrays):
+            def per_shard(mat, *shard):
+                return node_over_layout(node, layout, mat, shard, arrays)
+
             outs = jax.vmap(per_shard, in_axes=(None,) + (0,) * n_flat)(
                 mat, *arrays)                          # [S_local, ...]
             if gather:
@@ -1265,7 +1483,7 @@ class MeshExecutor:
         return self._jit_shard_map(
             key, block_fn, (P(),) + (P(SHARD_AXIS),) * n_flat,
             P(SHARD_AXIS) if per_shard_out and not gather else P(),
-            check_vma=not gather, layout=layout)
+            check_vma=not gather, layout=layout, node=node)
 
     @staticmethod
     def merge_counts(parts) -> np.ndarray:
@@ -1299,13 +1517,18 @@ class _ShardSchedule:
     def max_slice_len(self) -> int:
         return max((len(s) for s in self.slices), default=0)
 
-    def _stage(self, shard_slice) -> list[tuple]:
-        """Stage every key list's stack for one slice and pin the
+    def _stage(self, shard_slice, pos: int) -> list[tuple]:
+        """Stage every key list's stack for slice ``pos`` and pin the
         entries; returns the pinned budget keys (for the iterator to
-        release after the slice's dispatch).  On a mid-stage failure
-        (device OOM, fragment closed concurrently) every pin taken so
-        far is released before re-raising — a leaked pin would shrink
-        the effective budget for the process lifetime."""
+        release after the slice's dispatch).  The launch ledger's and
+        the annotations' slice position is set here, in whichever
+        thread stages (the prefetch's has a context of its own): what
+        that thread places and launches from now on is slice ``pos``'s.
+        On a mid-stage failure (device OOM, fragment closed
+        concurrently) every pin taken so far is released before
+        re-raising — a leaked pin would shrink the effective budget for
+        the process lifetime."""
+        _devobs.set_slice(pos, len(self.slices))
         pinned = []
         try:
             for kl in self.key_lists:
@@ -1383,7 +1606,7 @@ class _ShardSchedule:
                         budget.note_prefetch(False)
                     fut = None
                 # cold slices stage here; prefetched ones hit the cache
-                pins.extend(self._stage(sl))
+                pins.extend(self._stage(sl, i))
                 if i + 1 < len(self.slices):
                     # the trace context crosses the uploader-pool
                     # boundary with the prefetch (orphan staging work
@@ -1391,10 +1614,7 @@ class _ShardSchedule:
                     fut = pool.submit(
                         GLOBAL_TRACER.task(self._stage,
                                            name="mesh.prefetch_slice"),
-                        self.slices[i + 1])
-                # launch-ledger slice position: dispatches between this
-                # yield and the next run against slice i
-                _devobs.set_slice(i, len(self.slices))
+                        self.slices[i + 1], i + 1)
                 yield sl
                 # the consumer dispatched against this slice between the
                 # yield and here — safe to let the budget rotate it out

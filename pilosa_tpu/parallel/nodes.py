@@ -96,13 +96,32 @@ def participates(node, sig_map) -> bool:
     return True
 
 
+# Params rows a pass unrolls at most where a plan takes rows of a
+# compressed fragment (and a fused count's filters: ops/kernels.py).
+UNROLL_ROWS_MAX = 8
+
+
+def plan_rows(plan, frags, mat):
+    """The plan's result for every params row of ``mat``: [B, 256, 128].
+    One ``vmap`` over the rows — but where the plan takes rows of a
+    compressed fragment (``mesh_exec.PackedRows``) the few rows are
+    unrolled: a row take decodes under a conditional on the launch's
+    whole block, which stays a conditional only while the row id is not
+    batched (under a batch axis both branches run, the scatter always)."""
+    if mat.shape[0] <= UNROLL_ROWS_MAX and any(
+            hasattr(f, "take_row") for f in frags.values()):
+        return jnp.stack([eval_plan(plan, frags, mat[b])
+                          for b in range(mat.shape[0])])
+    return jax.vmap(lambda p: eval_plan(plan, frags, p))(mat)
+
+
 def node_shard(node, mat, frags):
     """One reducer node's per-shard contribution, traced inside the
     vmapped per-shard pass of either launcher (decode has already
     produced dense [rows, 256, 128] fragments in ``frags``; a segment is
     one word tile, [256, 128]).  Counts accumulate in int32."""
     if node.kind in ("count", "segments"):
-        segs = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
+        segs = plan_rows(node.plan, frags, mat)
         if node.kind == "segments":
             return segs                                    # [B, 256, 128]
         return bitset.row_counts(segs)              # [B]
@@ -112,7 +131,7 @@ def node_shard(node, mat, frags):
             counts = bitset.row_counts(frag)
             return jnp.broadcast_to(counts,
                                     (mat.shape[0],) + counts.shape)
-        masks = jax.vmap(lambda p: eval_plan(node.plan, frags, p))(mat)
+        masks = plan_rows(node.plan, frags, mat)
         return masked_counts(frag, masks)           # [B, rows]
     if node.kind == "bsi_sum":
         if node.plan is None:
@@ -388,6 +407,18 @@ def device_bytes_limit() -> int | None:
     limits = [(d.memory_stats() or {}).get("bytes_limit")
               for d in jax.local_devices()]
     return min((b for b in limits if b), default=None)
+
+
+def device_budget_bytes() -> int | None:
+    """The device budget's limit where no ``device-budget-mb`` is given:
+    a device's ``bytes_limit`` less what one launch may take beside the
+    resident blocks — the batch-temp ceiling and its margin — so a budget
+    that holds leaves ``batch_temp_bound`` at its ceiling.  None where the
+    backend reports no memory (the CPU): the budget then only counts."""
+    limit = device_bytes_limit()
+    if limit is None:
+        return None
+    return max(0, limit - BATCH_TEMP_BYTES - BATCH_TEMP_MARGIN)
 
 
 def batch_temp_bound() -> int:

@@ -108,7 +108,8 @@ SEG_PACKED = 1   # ops/containers Packed stream (keys/types/counts/
 MAX_FRAME_BYTES = 256 << 20
 
 _SEG_HEAD = struct.Struct("<QBI")   # shard id, encoding, byte length
-_PACKED_HEAD = struct.Struct("<II")  # container count, payload words
+# container count, payload words, array entries
+_PACKED_HEAD = struct.Struct("<III")
 _VALCOUNT = struct.Struct("<qq")
 _U32 = struct.Struct("<I")
 
@@ -194,13 +195,17 @@ def encode_segment(seg) -> tuple[int, bytes]:
     if run_friendly or containers.estimate_packed_bytes(idx) \
             + _PACKED_HEAD.size < _RAW_SEG_BYTES:
         p = containers.pack_words(idx.astype(np.int64), words[idx])
+        live = p.a_idx != containers.ARRAY_PAD
         blob = b"".join((
-            _PACKED_HEAD.pack(p.keys.size, p.payload.size),
+            _PACKED_HEAD.pack(p.keys.size, p.payload.size,
+                              int(np.count_nonzero(live))),
             p.keys.astype("<i4", copy=False).tobytes(),
             p.types.astype("<i4", copy=False).tobytes(),
             p.counts.astype("<i4", copy=False).tobytes(),
             p.offsets.astype("<i4", copy=False).tobytes(),
             p.payload.astype("<u4", copy=False).tobytes(),
+            p.a_idx[live].astype("<i4", copy=False).tobytes(),
+            p.a_val[live].astype("<u4", copy=False).tobytes(),
         ))
         if len(blob) < _RAW_SEG_BYTES:
             return SEG_PACKED, blob
@@ -217,8 +222,8 @@ def decode_segment(enc: int, blob) -> np.ndarray:
         raise FrameError(f"unknown segment encoding {enc}")
     if len(blob) < _PACKED_HEAD.size:
         raise FrameError("packed segment shorter than its header")
-    c, pw = _PACKED_HEAD.unpack_from(blob, 0)
-    want = _PACKED_HEAD.size + 16 * c + 4 * pw
+    c, pw, na = _PACKED_HEAD.unpack_from(blob, 0)
+    want = _PACKED_HEAD.size + 16 * c + 4 * pw + 8 * na
     if len(blob) != want:
         raise FrameError(
             f"packed segment length {len(blob)} != expected {want}")
@@ -233,8 +238,13 @@ def decode_segment(enc: int, blob) -> np.ndarray:
     if c and (int(keys.min()) < 0
               or int(keys.max()) >= SHARD_WORDS // containers.CONTAINER_WORDS):
         raise FrameError("packed segment container key out of range")
+    off += 4 * pw
+    a_idx = np.frombuffer(blob, dtype="<i4", count=na, offset=off)
+    a_val = np.frombuffer(blob, dtype="<u4", count=na, offset=off + 4 * na)
+    if na and (int(a_idx.min()) < 0 or int(a_idx.max()) >= SHARD_WORDS):
+        raise FrameError("packed segment array entry out of range")
     p = containers.Packed(keys, types, counts, offsets, payload,
-                          a_max=0, r_max=0)
+                          a_idx[None], a_val[None], r_max=0)
     try:
         return containers.unpack_packed(p, 1, SHARD_WORDS)[0]
     except (IndexError, ValueError) as e:

@@ -64,7 +64,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..core import CONTAINER_WORDS, SHARD_WORDS
 from ..executor.plan import eval_plan
 from ..ops import bsi
 from ..utils import devobs as _devobs
@@ -74,7 +73,7 @@ from ..utils.faults import FAULTS
 from ..utils.tracing import layer_span
 from . import nodes
 from .mesh_exec import _DISPATCH_LOCK, _flatten_present, _unpack_frags, \
-    SHARD_AXIS
+    SHARD_AXIS, form_tag, launch_cost, node_over_layout, slice_tags
 
 
 class WholeQueryUnsupported(Exception):
@@ -180,13 +179,16 @@ class _InstrumentedWhole:
     detection), and every invocation lands in the launch ledger with
     the call site's actual-vs-padded shard and batch rows."""
 
-    __slots__ = ("fn", "sig", "detail", "out_index", "devices", "_temps")
+    __slots__ = ("fn", "sig", "detail", "out_index", "devices", "form",
+                 "_temps")
 
     def __init__(self, fn, key, out_index, devices: int):
         self.fn = fn
         self.devices = devices      # of the mesh the program runs over
         self.sig = _devobs.sig_of(key)
         self.detail = repr(key[1])[:120]
+        # ``dense`` | ``z:<backend>``, off the groups' signatures in the key
+        self.form = form_tag(s for _, sigs in key[2] for s in sigs)
         self.out_index = out_index
         # device-local stacked shards -> the compiler's temp bytes
         self._temps: dict = {}
@@ -235,7 +237,8 @@ class _InstrumentedWhole:
                         tickets=tickets, shards=m.get("shards", 0),
                         shards_padded=m.get("shards_padded", 0),
                         temp_bytes=m.get("temp_bytes", 0),
-                        devices=self.devices) as span:
+                        devices=self.devices, form=self.form,
+                        **slice_tags()) as span:
             t0 = _time.perf_counter()
             out = self.fn(mats, *flat)
             dt = _time.perf_counter() - t0
@@ -422,21 +425,14 @@ class WholeQueryRunner:
         if blocks is not None:
             mesh.temp_splits += 1
 
-        from ..ops import kernels as _kernels
-        decode_bytes = sum(
-            bucket * sum(s[1] * SHARD_WORDS * 4
-                         for _, n, s in g[3]
-                         if n > 1 and _kernels.sig_backend(s) != "pallas")
-            for bucket, g in zip(buckets, live))
-        kernel_launches = sum(
-            bucket * sum(1 for _, n, s in g[3]
-                         if n > 1 and _kernels.sig_backend(s) == "pallas")
-            for bucket, g in zip(buckets, live))
-        kernel_tiles = sum(
-            bucket * sum(s[1] * (SHARD_WORDS // CONTAINER_WORDS)
-                         for _, n, s in g[3]
-                         if n > 1 and _kernels.sig_backend(s) == "pallas")
-            for bucket, g in zip(buckets, live))
+        # what each node's pass over each group decodes and which kernel
+        # it runs: the per-stage launcher's own reckoning
+        costs = [tuple(bucket * c for c in launch_cost(
+                     g[3], program[ni], nodes.mat_rows(pad_mats[ni])))
+                 for gi, (bucket, g) in enumerate(zip(buckets, live))
+                 for ni in range(len(program)) if gi in sched[ni]]
+        decode_bytes, kernel_launches, kernel_tiles = (
+            sum(c[k] for c in costs) for k in range(3))
         launch_meta = {
             "shards": sum(len(g[0]) for g in live),
             "shards_padded": sum(buckets),
@@ -582,10 +578,10 @@ class WholeQueryRunner:
                     continue
 
                 def per_shard(*arrays, _layout=layout_g,
-                              _nis=node_ids):
-                    frags = _unpack_frags(_layout, arrays)
+                              _nis=node_ids, _arrs=arrs):
                     return tuple(
-                        nodes.node_shard(program[ni], mats[ni], frags)
+                        node_over_layout(program[ni], _layout, mats[ni],
+                                         arrays, _arrs)
                         for ni in _nis)
 
                 outs_g = _over_shards(
@@ -672,16 +668,14 @@ class WholeQueryRunner:
 
         traced.__name__ = program_name(program)
 
-        from ..ops import kernels as _kernels
-        # shard_map's replication checker has no rule for pallas_call;
-        # disable it only when a group actually decodes through the
-        # Pallas backend (mesh_exec._jit_shard_map does the same)
-        check = not any(
-            n > 1 and _kernels.sig_backend(s) == "pallas"
-            for layout_g, _ in groups_static for _, n, s in layout_g)
         fn = jax.jit(jax.shard_map(
             traced, mesh=self.mesh.mesh,
             in_specs=(P(),) + (P(SHARD_AXIS),) * n_flat_all
             + (P(),) * (2 * len(walk_at)),
-            out_specs=tuple(out_specs), check_vma=check))
+            out_specs=tuple(out_specs),
+            # the replication checker has no rule for pallas_call and
+            # trips over a conditional under vmap: off over compressed
+            # stacks, as ``mesh_exec._jit_shard_map`` has it
+            check_vma=not any(n > 1 for layout_g, _ in groups_static
+                              for _, n, _ in layout_g)))
         return _InstrumentedWhole(fn, key, out_index, self.mesh.n_devices)

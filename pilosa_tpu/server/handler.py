@@ -158,6 +158,10 @@ def build_debug_vars(api: API, server=None) -> dict:
             "executables": len(ex.mesh_exec._cache),
             "fastHits": ex.mesh_exec.stack_fast_hits,
             "walks": ex.mesh_exec.stack_walks,
+            # slice plans of an over-budget set: served by the device
+            # epoch / made by a walk over the fragments
+            "scheduleFastHits": ex.mesh_exec.schedule_fast_hits,
+            "scheduleWalks": ex.mesh_exec.schedule_walks,
             "blockBytes": ex.mesh_exec.stack_block_bytes(),
         }
         # the batch-temp bound (docs/batching.md): what one launch's
@@ -182,9 +186,9 @@ def build_debug_vars(api: API, server=None) -> dict:
     # p50/p99 — the knobs' feedback loop for tuning window/max
     if ex.batcher is not None:
         out["dispatchBatcher"] = ex.batcher.snapshot()
-    # whole-query pjit programs (docs/whole-query.md): requests
-    # served as one program vs fallbacks to the legacy per-stage
-    # path, with the last fallback's unsupported-node name
+    # whole-query pjit programs (docs/whole-query.md): requests that
+    # tried the program, those of them that fell back to the per-stage
+    # path, and the last fallback's unsupported-node name
     if ex.wholequery is not None:
         out["wholeQuery"] = {
             "enabled": ex.whole_query,

@@ -57,7 +57,11 @@ class Config:
     whole_query_fallback: str = "legacy"
     # HBM budget for device-resident fragment mirrors + stacked shard
     # blocks (storage/membudget.py DeviceBudget — the syswrap map-cap
-    # analog, syswrap/mmap.go:46).  0 = unlimited (accounting only).
+    # analog, syswrap/mmap.go:46).  0 (the default) = the device's own:
+    # its memory less what one launch may take (nodes.device_budget_bytes;
+    # accounting only where the backend reports no memory, as the CPU).
+    # While the node's dense set fits the limit, whichever it is,
+    # nothing is compressed, sliced or evicted (docs/memory-budget.md).
     device_budget_mb: int = 0
     # Host-side dense staging cache ceiling (docs/memory-budget.md):
     # expanded fragment blocks kept on host so re-uploads after HBM
@@ -557,12 +561,18 @@ class Server:
         self.stats = make_stats_client(self.config.metric_service,
                                        self.config.metric_host)
         # The budget is process-wide; the most recent Server's config wins
-        # (0 restores unlimited — a stale limit from an earlier instance in
-        # the same process must not outlive its config).
+        # (0 restores the device's own — a stale limit from an earlier
+        # instance in the same process must not outlive its config).  The
+        # batch-temp ceiling comes first: the device's own limit is what
+        # is left beside it.
+        from ..parallel import nodes as _nodes
+        _nodes.BATCH_TEMP_BYTES = max(self.config.batch_temp_mb, 1) << 20
         from ..storage.membudget import DEFAULT_BUDGET, HOST_STAGE_BUDGET
+        DEFAULT_BUDGET.limit_from_device = self.config.device_budget_mb <= 0
         DEFAULT_BUDGET.limit_bytes = (
             self.config.device_budget_mb * (1 << 20)
-            if self.config.device_budget_mb > 0 else None)
+            if self.config.device_budget_mb > 0
+            else _nodes.device_budget_bytes())
         HOST_STAGE_BUDGET.limit_bytes = (
             self.config.host_stage_mb * (1 << 20)
             if self.config.host_stage_mb > 0
@@ -594,10 +604,6 @@ class Server:
         # change rebuilds stacks/executables rather than retracing
         from ..ops import kernels as _kernels
         _kernels.CONTAINER_KERNELS = str(self.config.container_kernels)
-        # the batch-temp bound's ceiling (docs/batching.md);
-        # process-wide, most recent Server wins
-        from ..parallel import nodes as _nodes
-        _nodes.BATCH_TEMP_BYTES = max(self.config.batch_temp_mb, 1) << 20
         # streaming ingest (docs/ingest.md): the delta-overlay budget is
         # process-wide like the others (most recent Server wins)
         from ..storage import membudget as _membudget

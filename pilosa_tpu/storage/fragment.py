@@ -42,6 +42,7 @@ import itertools
 import json
 import os
 import struct
+import weakref
 
 import numpy as np
 
@@ -98,9 +99,10 @@ WAL_CRC = True
 QUARANTINE_ON_CORRUPTION = True
 
 # Compressed-resident device mirrors (docs/memory-budget.md "Compressed
-# residency"): under a configured device budget, fragments whose packed
-# container stream is small enough stay HBM-resident compressed and are
-# decoded to dense tiles on device at op time.  COMPRESSED_RESIDENT
+# residency"): once the node's dense set no longer fits the device budget
+# (DeviceBudget.dense_fits), fragments whose packed container stream is
+# small enough stay HBM-resident compressed and are decoded to dense
+# tiles on device at op time.  COMPRESSED_RESIDENT
 # disables the path wholesale; COMPRESS_MAX_DENSITY is the fallback
 # knob — a fragment compresses only when its estimated packed bytes are
 # at most this fraction of its dense footprint (dense corpora pack into
@@ -135,7 +137,7 @@ def device_knobs() -> tuple:
     Server config, bench legs and tests assign these module attributes
     directly, so the stack cache's fast check compares their values
     instead of trusting anyone to bump an epoch."""
-    return (DEFAULT_BUDGET.limit_bytes, COMPRESSED_RESIDENT,
+    return (DEFAULT_BUDGET.dense_fits(), COMPRESSED_RESIDENT,
             COMPRESS_MAX_DENSITY, _kernels.CONTAINER_KERNELS)
 
 
@@ -205,6 +207,14 @@ def _expand_words(idx: np.ndarray, val: np.ndarray):
     return rows[order], cols[order]
 
 
+def _forget_dense(budget, noted: list):
+    """Take a fragment's dense footprint out of its budget's demand
+    (close(), or collection of a fragment nobody closed), once: the
+    cell reads 0 afterwards."""
+    budget.note_dense(-noted[0])
+    noted[0] = 0
+
+
 class Fragment:
     """One (index, field, view, shard) bitmap."""
 
@@ -232,7 +242,14 @@ class Fragment:
         # sparse word store: sorted flat indices + non-zero word values
         self._idx = np.zeros(0, dtype=np.int64)
         self._val = np.zeros(0, dtype=np.uint32)
-        self._cap_rows = 0        # device-shape row capacity (pow2 growth)
+        # device-shape row capacity (pow2 growth), behind ``_cap_rows``:
+        # the bytes its dense form would hold are noted with the budget
+        # (DeviceBudget.dense_fits; the cell holds what is noted now)
+        # and leave it on close() or collection
+        self._cap = 0
+        self._dense_noted = [0]
+        weakref.finalize(self, _forget_dense, self.budget,
+                         self._dense_noted)
         self._mirrors = {}        # device -> cached jax.Array mirror
         # Data-generation stamp: unique across all fragments and bumped on
         # every mutation.  Derived caches (mesh stacked blocks) key their
@@ -618,6 +635,7 @@ class Fragment:
                         self._wal_file = None
             self._drop_mirrors()
             self._drop_stage()
+            _forget_dense(self.budget, self._dense_noted)
 
     def snapshot(self):
         """Rewrite the snapshot file (checksummed v4) and truncate the
@@ -651,6 +669,17 @@ class Fragment:
             self._op_n = 0
 
     # -- geometry ----------------------------------------------------------
+
+    @property
+    def _cap_rows(self) -> int:
+        return self._cap
+
+    @_cap_rows.setter
+    def _cap_rows(self, rows: int):
+        dense = rows * SHARD_WORDS * 4
+        self.budget.note_dense(dense - self._dense_noted[0])
+        self._dense_noted[0] = dense
+        self._cap = rows
 
     @property
     def n_rows(self) -> int:
@@ -1135,14 +1164,14 @@ class Fragment:
         not the transfer, dominates cold re-stages.  Keyed by the data
         generation (any mutation invalidates); HOST_STAGE_BUDGET bounds
         total cached host bytes LRU-wise (limit 0 disables caching).
-        With no device-budget limit nothing is ever evicted, so there is
-        no re-upload to accelerate — caching would only grow host RSS —
-        and the expansion stays transient like to_dense().
+        While the node's dense set fits the device budget nothing is
+        ever evicted, so there is no re-upload to accelerate — caching
+        would only grow host RSS — and the expansion stays transient
+        like to_dense().
 
         The returned array is SHARED — callers must treat it read-only
         (device uploads and stacked-block fills copy out of it)."""
-        if HOST_STAGE_BUDGET.limit_bytes == 0 or \
-                self.budget.limit_bytes is None:
+        if HOST_STAGE_BUDGET.limit_bytes == 0 or self.budget.dense_fits():
             return self.to_dense()
         with self._lock:
             st = self._stage
@@ -1215,13 +1244,13 @@ class Fragment:
 
     def device_form(self) -> str:
         """'compressed' | 'dense': which device-resident form this
-        fragment's data warrants.  Compressed only under a configured
-        device budget (with unlimited HBM the dense mirror is strictly
-        faster — no decode per launch — exactly as staged_dense only
-        caches under a limit) and only when the density heuristic says
-        the packed stream actually undercuts the dense footprint."""
+        fragment's data warrants.  Compressed only once the node's dense
+        set does not fit the device budget (while it fits, the dense
+        mirror pays no decode per launch — exactly as staged_dense only
+        caches then) and only when the density heuristic says the packed
+        stream actually undercuts the dense footprint."""
         from ..ops.containers import MAX_COMPRESSED_ROWS
-        if not COMPRESSED_RESIDENT or self.budget.limit_bytes is None:
+        if not COMPRESSED_RESIDENT or self.budget.dense_fits():
             return "dense"
         dense = self._cap_rows * SHARD_WORDS * 4
         if dense == 0 or self._cap_rows > MAX_COMPRESSED_ROWS:
@@ -1232,22 +1261,27 @@ class Fragment:
 
     def device_nbytes(self) -> int:
         """Bytes this fragment's device-resident form occupies — the
-        residency unit the budget and the shard-slice planner account
-        (compressed bytes for compressed-form fragments, the dense
-        tensor for the rest)."""
+        residency unit the shard-slice planner accounts (compressed
+        bytes for compressed-form fragments, the dense tensor for the
+        rest).  The compressed figure is the container census's bound
+        until the fragment has been packed and the pack's own bytes
+        from then on (``_compressed_est``): planning the slices of 954
+        shards must not pack them."""
         if self.device_form() == "compressed":
-            return self.packed_host().nbytes
+            return self._compressed_est()
         return self._cap_rows * SHARD_WORDS * 4
 
     def device_sig(self) -> tuple:
         """Stacked-group shape signature for the mesh executor: dense
         fragments keep the device tensor's shape (rows, 256, 128) — a
         row's words are a word tile, ops/bitset.py; compressed ones
-        carry ('z', rows, C, P, A, R, backend) with pow2-bucketed
-        container, payload, array-entry and run counts so one compiled
-        decode executable serves every fragment in a bucket.  The
+        carry ('z', rows, C, P, A, R, backend) with bucketed container
+        count, payload and class-stream lengths
+        (``containers.payload_bucket`` / ``stream_bucket``) and run
+        count, so one compiled executable serves every fragment in a
+        bucket.  The
         trailing element is the container-kernels backend selected for
-        this bucket (ops/kernels.py backend_for): the decode code
+        this fragment (ops/kernels.py backend_for): the count code
         compiled into the executable is part of its shape, so a knob
         flip mints new signatures — new plans, new stacks, fresh
         compiles — instead of replaying a jnp-compiled program through
@@ -1255,17 +1289,18 @@ class Fragment:
         if self.device_form() == "dense":
             return (self.n_rows,) + WORD_TILE
         from ..ops import kernels
-        from ..ops.containers import pow2_bucket
+        from ..ops.containers import payload_bucket, pow2_bucket, \
+            stream_bucket
         knob = kernels.CONTAINER_KERNELS
         with self._lock:
             s = self._psig
             if s is not None and s[0] == (self.device_gen, knob):
                 return s[1]
         p = self.packed_host()
-        rows, pb = self.n_rows, pow2_bucket(p.payload.size)
-        ab, rb = pow2_bucket(p.a_max), pow2_bucket(p.r_max)
+        rows, pb = self.n_rows, payload_bucket(p.payload.size)
+        ab, rb = stream_bucket(p.a_len), pow2_bucket(p.r_max)
         sig = ("z", rows, pow2_bucket(p.keys.size), pb, ab, rb,
-               kernels.backend_for(rows, pb, ab, rb))
+               kernels.backend_for(rows))
         with self._lock:
             self._psig = ((self.device_gen, knob), sig)
         return sig

@@ -27,8 +27,19 @@ them otherwise.  ``resident_bytes`` and ``upload_bytes`` stay sums over
 all devices.  With one device the two ledgers are one.
 
 One process-wide default budget keeps wiring simple (Server config
-``device_budget_mb`` / PILOSA_TPU_DEVICE_BUDGET_MB sets it); tests construct
-private instances.  ``HOST_STAGE_BUDGET`` is a second instance bounding the
+``device_budget_mb`` / PILOSA_TPU_DEVICE_BUDGET_MB sets it, and at its
+default the device's own memory does: ``limit_source``); tests construct
+private instances.
+
+A limit is always there on a device that reports its memory, so what a
+fragment and the slice planner switch on is not whether one is set but
+whether the node's dense set FITS it (``dense_fits``): every open
+fragment notes the bytes its dense form would hold (``note_dense``),
+and while their sum — with the shard bucket's padding, under an eighth —
+stays inside the limit times the devices a stacked block lies over
+(``spread``), nothing is compressed, staged on the host, sliced or
+evicted, exactly as with no limit at all (docs/memory-budget.md "The
+rule").  ``HOST_STAGE_BUDGET`` is a second instance bounding the
 HOST-side dense staging cache (storage/fragment.py staged_dense) with the
 same LRU machinery — there "upload bytes" counts staged host bytes.
 """
@@ -46,6 +57,17 @@ class DeviceBudget:
     def __init__(self, limit_bytes: int | None = None,
                  tenant_quota_bytes: int = 0):
         self.limit_bytes = limit_bytes  # None = unlimited (accounting only)
+        # a Server at ``device-budget-mb``'s default read the limit off
+        # the device's own memory (``limit_source``: "device"; a limit
+        # set by hand is "option", no limit "none")
+        self.limit_from_device = False
+        # bytes the dense forms of every open fragment would hold over
+        # all devices (Fragment notes its own, storage/fragment.py), and
+        # the devices a stacked block is spread over (the most recent
+        # MeshExecutor's mesh): what ``dense_fits`` holds against the
+        # limit
+        self.dense_demand = 0
+        self.spread = 1
         # Per-tenant residency cap (``tenant-cache-quota-mb``; 0 = off):
         # a tenant staging past it evicts ITS OWN unpinned-coldest
         # entries, and global pressure prefers over-quota tenants'
@@ -91,6 +113,28 @@ class DeviceBudget:
     def resident_bytes_max_device(self) -> int:
         """What the fullest device holds (module docstring)."""
         return self._held
+
+    @property
+    def limit_source(self) -> str:
+        if self.limit_bytes is None:
+            return "none"
+        return "device" if self.limit_from_device else "option"
+
+    def note_dense(self, delta: int):
+        """A fragment's dense footprint grew (or shrank, or left) by
+        ``delta`` bytes."""
+        with self._lock:
+            self.dense_demand += delta
+
+    def dense_fits(self) -> bool:
+        """Whether every open fragment's dense form fits the limit at
+        once, the shard bucket's padding (under an eighth,
+        ``MeshExecutor._bucket``) included: then no form but the dense
+        one, no host staging, no slicing and no eviction is ever needed.
+        Two int compares: read on every launch, walks nothing."""
+        limit = self.limit_bytes
+        return limit is None or \
+            self.dense_demand * 9 // 8 <= limit * self.spread
 
     def _pop_locked(self, key: tuple) -> list:
         """Pop ``key`` keeping the byte ledgers (total, per-device,
@@ -328,6 +372,11 @@ class DeviceBudget:
                 "denseBytes": self._total - self._compressed,
                 "peakBytes": self._peak,
                 "limitBytes": self.limit_bytes,
+                "limitSource": self.limit_source,
+                # what every open fragment would hold dense: while it
+                # fits the limit (``dense_fits``) nothing is compressed,
+                # sliced or evicted
+                "denseDemandBytes": self.dense_demand,
                 "entries": len(self._entries),
                 "evictions": self.evictions,
                 "evictedBytes": self.evicted_bytes,
@@ -342,7 +391,8 @@ class DeviceBudget:
             }
 
 
-# Process-wide default (accounting-only until a limit is configured).
+# Process-wide default (accounting-only until a Server sets a limit: the
+# option's, or the device's own at the option's default).
 DEFAULT_BUDGET = DeviceBudget()
 
 # Ingest delta-overlay budget (docs/ingest.md): accounts the host-side

@@ -113,6 +113,12 @@ class layer_span:
         self._t0 = time.perf_counter()
         return self
 
+    @property
+    def recording(self) -> bool:
+        """Whether a profiler takes this span's annotation: a tag that
+        costs something to make is made only then."""
+        return self._ann is not None
+
     def tag(self, **tags):
         """Tags known only once the work ran (did this call compile)."""
         if self._ann is not None:

@@ -8,9 +8,10 @@ shows here in seconds.  The executable cannot run; answers still come
 from interpret mode on the CPU and from chip_smoke.py on the chip.
 
 ``compile_for_v5e(fn, *avals)`` is the helper.  Run as a script it
-compiles every container kernel ``auto`` selects (ops/kernels.py), under
-the call sites' vmap, over the decode buckets given as rows:C:P:A:R
-arguments (default: chip_smoke.py's sparse corpus), and prints one JSON
+compiles the container kernel ``auto`` selects (ops/kernels.py), under
+the call site's vmap, over the buckets given as rows:C:P:A:R
+arguments (default: chip_smoke.py's sparse corpus and the benchmark's
+largest compressed field), and prints one JSON
 line.  Exit 0 all compiled · 1 a compile failed · 3 no topology here.
 """
 
@@ -32,9 +33,11 @@ import jax.numpy as jnp  # noqa: E402
 
 # (rows, C, P, A, R) decode buckets of chip_smoke.py's sparse corpus
 # (954 shards, seed 7) as the chip saw them
-SMOKE_BUCKETS = ((4, 64, 8192, 512, 0), (4, 64, 8192, 512, 64),
-                 (8, 128, 16384, 512, 0), (8, 128, 16384, 512, 32),
-                 (8, 128, 16384, 512, 64))
+SMOKE_BUCKETS = ((4, 64, 0, 512, 0), (4, 64, 0, 1024, 0),
+                 (8, 128, 0, 1024, 0), (8, 128, 128, 1024, 64))
+# ...and the largest field of the benchmark's taxi-1b-chip1
+# (total_amount_dollars, 53 stacked shards a launch)
+BENCH_BUCKETS = ((128, 2048, 589824, 35840, 0),)
 
 
 def v5e_topology():
@@ -48,28 +51,28 @@ def v5e_topology():
 
 
 def kernel_cases(bucket, stacked: int = 2) -> dict:
-    """{name: (fn, avals)} — every container kernel of ops/kernels.py
-    over one (rows, C, P, A, R) decode bucket, under the call sites'
-    vmap over ``stacked`` fragments."""
+    """{name: (fn, avals)} — the container kernel of ops/kernels.py over
+    one (rows, C, P, A, R) bucket, under the call site's vmap over
+    ``stacked`` fragments, without a filter and under 1 and 4."""
     from pilosa_tpu.core import WORD_TILE
     from pilosa_tpu.ops import kernels
     rows, C, P, A, R = bucket
-    kw = dict(rows=rows, a_bucket=A, r_bucket=R)
+    kw = dict(rows=rows, a_bucket=A, r_bucket=R, backend="pallas")
 
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct((stacked,) + shape, dtype)
 
-    packed = [aval((C,), jnp.int32)] * 4 + [aval((P,), jnp.uint32)]
-    return {
-        "decode_block": (
-            jax.vmap(lambda *a: kernels.decode_block(*a, **kw)), packed),
-        "fused_row_counts": (
-            jax.vmap(lambda *a: kernels.fused_row_counts(*a, None, **kw)),
-            packed),
-        "fused_row_counts+filter": (
+    packed = [aval((C,), jnp.int32)] * 4 + [
+        aval((P,), jnp.uint32), aval((8, A), jnp.int32),
+        aval((8, A), jnp.uint32)]
+    cases = {"fused_row_counts": (
+        jax.vmap(lambda *a: kernels.fused_row_counts(*a, None, **kw)),
+        packed)}
+    for b in (1, 4):
+        cases[f"fused_row_counts+filter{b}"] = (
             jax.vmap(lambda *a: kernels.fused_row_counts(*a, **kw)),
-            packed + [aval(WORD_TILE, jnp.uint32)]),
-    }
+            packed + [aval((b,) + WORD_TILE, jnp.uint32)])
+    return cases
 
 
 def compile_for_v5e(fn, *avals, topology=None):
@@ -86,7 +89,7 @@ def compile_for_v5e(fn, *avals, topology=None):
 def main(argv) -> int:
     from pilosa_tpu.ops import kernels
     buckets = [tuple(int(x) for x in a.split(":")) for a in argv] \
-        or list(SMOKE_BUCKETS)
+        or list(SMOKE_BUCKETS + BENCH_BUCKETS)
     try:
         topo = v5e_topology()
     except Exception as e:  # whatever libtpu raises without a topology
@@ -99,7 +102,7 @@ def main(argv) -> int:
         for name, (fn, avals) in kernel_cases(bucket).items():
             t0 = time.perf_counter()
             row = {"kernel": name, "bucket": list(bucket),
-                   "auto_selects": kernels.backend_for(rows, P, A, R)}
+                   "auto_selects": kernels.backend_for(rows)}
             try:
                 compile_for_v5e(fn, *avals, topology=topo)
                 row["compiled"] = True

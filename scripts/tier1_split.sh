@@ -47,6 +47,7 @@ tests/test_roaring_golden.py
 tests/test_ssb_sf30.py
 tests/test_stack_epoch.py
 tests/test_storage.py
+tests/test_taxi_1b_chip1.py
 tests/test_taxi_1b_mesh4.py
 tests/test_topn_prune.py
 tests/test_translate.py

@@ -32,3 +32,16 @@ def pytest_configure(config):
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+def over_budget_limit(holder) -> int:
+    """The largest device-budget limit (one device's) that the dense
+    forms of ``holder``'s own fragments do not fit — so its sparse
+    fragments go compressed-resident (``DeviceBudget.dense_fits``,
+    docs/memory-budget.md "The rule") — with as much room for what is
+    held as that allows.  Reckoned from the holder alone: what other
+    tests left alive in the process only adds to the budget's demand,
+    and what the collector takes away mid-test cannot make it fit."""
+    from pilosa_tpu.storage.membudget import DEFAULT_BUDGET
+    own = sum(fr.n_rows for *_, fr in holder.iter_fragments()) * (128 << 10)
+    return (own * 9 // 8 - 1) // DEFAULT_BUDGET.spread

@@ -164,10 +164,11 @@ def test_filterless_group_dispatches_single_chunk():
 # -- host staging cache -----------------------------------------------------
 
 def test_staged_dense_caches_until_mutation():
-    # a LIMITED device budget: with no limit nothing ever re-uploads,
-    # so staged_dense deliberately skips caching
+    # a device budget the dense set does not fit (4 rows, 512 KiB):
+    # while it fits nothing ever re-uploads, so staged_dense
+    # deliberately skips caching
     f = Fragment(None, "i", "f", "standard", 0,
-                 budget=DeviceBudget(limit_bytes=1 << 20))
+                 budget=DeviceBudget(limit_bytes=1 << 18))
     f.bulk_import(np.array([0, 1, 2]), np.array([5, 6, 7]))
     d1 = f.staged_dense()
     d2 = f.staged_dense()
@@ -192,7 +193,7 @@ def test_staged_dense_disabled_at_zero_limit():
     try:
         HOST_STAGE_BUDGET.limit_bytes = 0
         f = Fragment(None, "i", "f", "standard", 0,
-                     budget=DeviceBudget(limit_bytes=1 << 20))
+                     budget=DeviceBudget(limit_bytes=1 << 18))
         f.bulk_import(np.array([0]), np.array([1]))
         assert f.staged_dense() is not f.staged_dense()
         assert f._stage is None
@@ -304,10 +305,11 @@ def test_budgeted_run_matches_unbudgeted(wide, rng):
     try:
         DEFAULT_BUDGET.limit_bytes = None
         want = [_norm(r) for bt in batches for r in ex.execute("w", bt)]
-        # under the whole set's compressed bytes, 7.1 MB now that a
-        # (field, view) is resident once however many key lists read it:
-        # 4 MB over the mesh, and the limit is one device's
-        DEFAULT_BUDGET.limit_bytes = (4 << 20) // ex.mesh_exec.n_devices
+        # under the whole set's compressed bytes, 2.0 MB now that a
+        # (field, view) is resident once however many key lists read it
+        # and a key's compressed fragments are one shape group: 1 MB
+        # over the mesh, and the limit is one device's
+        DEFAULT_BUDGET.limit_bytes = (1 << 20) // ex.mesh_exec.n_devices
         DEFAULT_BUDGET.shrink_to_limit()
         ev0 = DEFAULT_BUDGET.evictions
         got = [_norm(r) for bt in batches for r in ex.execute("w", bt)]

@@ -11,6 +11,8 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import over_budget_limit
+
 from pilosa_tpu.core import CONTAINER_WORDS, SHARD_WIDTH, SHARD_WORDS, \
     WORD_TILE
 from pilosa_tpu.ops.bitset import from_tile
@@ -18,7 +20,8 @@ from pilosa_tpu.executor import Executor
 from pilosa_tpu.ops import containers
 from pilosa_tpu.ops.containers import (
     ARRAY_WORDS_MAX, RUN_MAX, TYPE_ARRAY, TYPE_BITMAP, TYPE_RUN,
-    pack_words, pad_packed, pow2_bucket, unpack_packed, upload_decode,
+    pack_words, pad_packed, pow2_bucket, stream_bucket, unpack_packed,
+    upload_decode,
 )
 from pilosa_tpu.storage import FieldOptions, Holder, fragment
 from pilosa_tpu.storage.fragment import Fragment
@@ -41,8 +44,10 @@ def _oracle(idx, val, rows):
 
 
 def _roundtrip(idx, val, rows):
-    """pack -> host unpack AND pack -> device decode, both against the
-    dense oracle."""
+    """pack -> host unpack, pack -> device decode AND pack -> every row
+    taken alone (and the row behind the last), all against the dense
+    oracle."""
+    import jax.numpy as jnp
     p = pack_words(idx, val)
     want = _oracle(idx, val, rows)
     np.testing.assert_array_equal(unpack_packed(p, rows), want)
@@ -50,6 +55,15 @@ def _roundtrip(idx, val, rows):
     got = np.asarray(upload_decode(p, rows))
     assert got.shape == (rows,) + WORD_TILE
     np.testing.assert_array_equal(from_tile(got), want)
+    arrs = [jnp.asarray(a) for a in pad_packed(p)]
+    take = jax.jit(lambda *a: containers.decode_row(
+        *a[:-1], a[-1], rows=rows, a_bucket=stream_bucket(p.a_len),
+        r_bucket=pow2_bucket(p.r_max),
+        has_array=containers.row_has_entries(a[5], a[-1])))
+    for r in range(rows + 1):
+        np.testing.assert_array_equal(
+            np.asarray(take(*arrs, jnp.int32(r))),
+            want[r] if r < rows else 0)
     return p
 
 
@@ -165,25 +179,115 @@ def test_decode_bucket_padding(rng):
     import jax.numpy as jnp
     padded = [jnp.asarray(a) for a in pad_packed(p)]
     assert padded[0].size == pow2_bucket(p.keys.size)
+    assert padded[5].shape == (8, stream_bucket(p.a_len))
     got = np.asarray(containers.decode_block(
-        *padded, rows=rows, a_bucket=pow2_bucket(p.a_max),
+        *padded, rows=rows, a_bucket=stream_bucket(p.a_len),
         r_bucket=pow2_bucket(p.r_max)))
     np.testing.assert_array_equal(got, _oracle(idx, val, rows))
+
+
+def test_class_streams_layout(rng):
+    """An array entry lies in the stream of its word's sublane in the
+    device's word tile, every stream ascends (padding included), and
+    the streams hold exactly the array containers' words."""
+    rows = 3
+    flat = np.zeros(rows * SHARD_WORDS, dtype=np.uint32)
+    flat[rng.choice(flat.size, 7000, replace=False)] = rng.integers(
+        1, 1 << 32, 7000, dtype=np.uint64).astype(np.uint32)
+    flat[SHARD_WORDS: SHARD_WORDS + 2 * CONTAINER_WORDS] |= 1   # bitmaps
+    idx, val = _store(flat)
+    p = pack_words(idx, val)
+    assert p.a_len % containers.ARRAY_LANES == 0
+    assert p.a_idx.shape == p.a_val.shape == (8, p.a_len)
+    live = p.a_idx != containers.ARRAY_PAD
+    for c in range(8):
+        assert ((p.a_idx[c][live[c]] >> 7) & 7 == c).all()
+        assert (np.diff(p.a_idx[c].astype(np.int64)) >= 0).all()
+    in_array = ~np.isin(idx // CONTAINER_WORDS,
+                        p.keys[p.types != TYPE_ARRAY])
+    assert int(live.sum()) == int(in_array.sum()) == p.array_words
+    np.testing.assert_array_equal(np.sort(p.a_idx[live]), idx[in_array])
+
+
+def test_scatter_targets_ascend(rng):
+    """The decoders' one scatter tells the compiler its indices ascend
+    and are unique (a TPU otherwise takes the updates one by one; a CPU
+    ignores the hint, so the claim is checked here): over whole streams,
+    and over a row's slices, those moved back at a stream's end too."""
+    import jax.numpy as jnp
+    rows = 5
+    flat = np.zeros(rows * SHARD_WORDS, dtype=np.uint32)
+    flat[rng.choice(flat.size, 9000, replace=False)] = 1
+    flat[4 * SHARD_WORDS + 100: 4 * SHARD_WORDS + 900: 3] = 1
+    p = pack_words(*_store(flat))
+    a_idx = jnp.asarray(pad_packed(p)[5])
+    n = a_idx.shape[1]
+
+    def ascends(idx, first, k):
+        t, span, plane = containers._stream_targets(idx, first, k,
+                                                    SHARD_WORDS)
+        t = np.asarray(t).astype(np.int64).reshape(-1)
+        assert t.min() >= 0 and t.max() < 8 * span
+        assert (np.diff(t) > 0).all()
+
+    ascends(a_idx, 0, rows)
+    w = 256
+    for r in range(rows + 1):
+        below = np.asarray(a_idx) < r * SHARD_WORDS
+        start = np.minimum(below.sum(axis=1), n - w)    # dynamic_slice's
+        ascends(jnp.stack([a_idx[c, s: s + w]
+                           for c, s in enumerate(start)]), r, 1)
+
+
+def test_decode_row_scatters_only_when_told(rng):
+    """``row_has_entries`` is true exactly for the rows that hold array
+    entries in some fragment of the block, and a row take told there
+    are none leaves them out: the bitmap containers alone."""
+    import jax.numpy as jnp
+    rows = 4
+    flat = np.zeros(rows * SHARD_WORDS, dtype=np.uint32)
+    flat[: SHARD_WORDS] = 3                            # row 0: bitmaps
+    at = SHARD_WORDS + rng.choice(SHARD_WORDS, 500, replace=False)
+    flat[at] = 9                                       # row 1: arrays
+    flat[3 * SHARD_WORDS: 3 * SHARD_WORDS + CONTAINER_WORDS] = 5
+    flat[3 * SHARD_WORDS + CONTAINER_WORDS + 17] = 1   # row 3: both
+    idx, val = _store(flat)
+    p = pack_words(idx, val)
+    arrs = [jnp.asarray(a) for a in pad_packed(p)]
+    block = jnp.stack([arrs[5], jnp.full_like(arrs[5],
+                                              containers.ARRAY_PAD)])
+    has = [bool(containers.row_has_entries(block, r)) for r in range(rows)]
+    assert has == [False, True, False, True]
+    want = _oracle(idx, val, rows)
+    kw = dict(rows=rows, a_bucket=stream_bucket(p.a_len), r_bucket=0)
+    for r in range(rows):
+        bare = np.asarray(containers.decode_row(
+            *arrs, r, has_array=jnp.bool_(False), **kw))
+        full = np.asarray(containers.decode_row(*arrs, r, **kw))
+        np.testing.assert_array_equal(full, want[r])
+        assert (bare == want[r]).all() == (not has[r])
 
 
 # -- density heuristic / fragment forms -------------------------------------
 
 def test_device_form_heuristic():
-    budget = DeviceBudget(limit_bytes=64 << 20)
+    # 8 rows, 1 MiB dense: a limit the dense form does not fit
+    budget = DeviceBudget(limit_bytes=1 << 19)
     f = Fragment(None, "i", "f", "standard", 0, budget=budget)
     f.bulk_import(np.arange(8), np.arange(8) * 1000)
+    assert budget.dense_demand == f._cap_rows * SHARD_WORDS * 4
     assert f.device_form() == "compressed"
-    assert f.device_nbytes() == f.packed_host().nbytes
+    assert f.packed_host().nbytes == f.device_nbytes()
     assert f.device_nbytes() < f._cap_rows * SHARD_WORDS * 4
-    # unlimited budget: dense mirror is strictly faster -> dense
+    # no limit, or one the dense set fits (padding's eighth included):
+    # the dense mirror pays no decode -> dense
     budget.limit_bytes = None
     assert f.device_form() == "dense"
     budget.limit_bytes = 64 << 20
+    assert f.device_form() == "dense"
+    budget.limit_bytes = budget.dense_demand
+    assert f.device_form() == "compressed"
+    budget.limit_bytes = 1 << 19
     # kill switch
     old = fragment.COMPRESSED_RESIDENT
     try:
@@ -197,7 +301,7 @@ def test_dense_data_stays_dense(rng):
     """A fragment dense enough that packing wins nothing must fall back
     to the dense form (all-bitmap streams are ~1x 'compression'): every
     cap row filled with random words — no zero words to drop, no runs."""
-    budget = DeviceBudget(limit_bytes=64 << 20)
+    budget = DeviceBudget(limit_bytes=1 << 16)  # no dense form fits it
     f = Fragment(None, "i", "f", "standard", 0, budget=budget)
     f.set_bit(0, 0)
     for row in range(f._cap_rows):
@@ -210,7 +314,7 @@ def test_dense_data_stays_dense(rng):
 def test_compressed_device_mirror_equals_dense():
     """Fragment.device()'s compressed upload path (ship packed, decode
     on device) produces the same mirror bytes as the dense upload."""
-    budget = DeviceBudget(limit_bytes=64 << 20)
+    budget = DeviceBudget(limit_bytes=1 << 19)  # under the 1 MiB dense form
     f = Fragment(None, "i", "f", "standard", 0, budget=budget)
     rng = np.random.default_rng(7)
     f.bulk_import(rng.integers(0, 6, 4000), rng.integers(0, SHARD_WIDTH, 4000))
@@ -273,8 +377,10 @@ def test_compressed_differential(corpus):
         DEFAULT_BUDGET.limit_bytes = None
         want = _run_corpus(ex, queries)
 
-        # compressed-resident, ample budget: everything stays resident
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        # compressed-resident: the largest budget the dense set does not
+        # fit, ample for
+        # the packed streams, so everything stays resident
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(corpus)
         DEFAULT_BUDGET.shrink_to_limit()
         assert _run_corpus(ex, queries) == want
         st = DEFAULT_BUDGET.stats()
@@ -309,7 +415,7 @@ def test_retrace_keeps_layout(corpus):
     old = DEFAULT_BUDGET.limit_bytes
     q = "Count(Intersect(Row(a=11), Row(a=2)))"
     try:
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(corpus)
         want = {}
         for size in (16, 2, 9, 16, 1):
             sl = list(range(size))
@@ -337,7 +443,7 @@ def test_compressed_stats_surface(corpus):
     ex = Executor(corpus, use_mesh=True)
     old = DEFAULT_BUDGET.limit_bytes
     try:
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(corpus)
         ex.execute("c", "Count(Union(Row(a=1), Row(a=11)))")
         st = corpus.container_stats()
         assert st["compressedFragments"] > 0

@@ -11,6 +11,8 @@ import urllib.request
 
 import pytest
 
+from conftest import over_budget_limit
+
 from pilosa_tpu.core import SHARD_WIDTH
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.server.server import Config, Server
@@ -96,7 +98,7 @@ def test_forced_retrace_fires_counter_and_event(corpus):  # noqa: F811
     before = devobs.COMPILES.totals()
     q = "Count(Intersect(Row(a=11), Row(a=2)))"
     try:
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(corpus)
         want = {}
         for size in (16, 2, 9, 16, 1):
             sl = list(range(size))

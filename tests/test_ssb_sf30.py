@@ -372,7 +372,9 @@ def test_the_cell_is_declared():
     assert [m["name"] for m in bench["per_layer"][17:19]] == [
         "padded_shard_share", "temp_split_share"]
     assert by_name["padded_shard_share"]["workloads"] == names
-    assert by_name["temp_split_share"]["workloads"] == [
+    # (and, appended by PR 37, the cell that launches under the bound
+    # at 71 % of HBM: tests/test_taxi_1b_chip1.py)
+    assert by_name["temp_split_share"]["workloads"][:2] == [
         "ssb.q1-flight", "ssb-q1-sf30.q1-flight"]
     # (the metrics past the twentieth are a later cell's own)
     assert all(cell["name"] in m["workloads"]

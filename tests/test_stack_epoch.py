@@ -20,6 +20,8 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import over_budget_limit
+
 from pilosa_tpu.core import SHARD_WIDTH
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.ingest.committer import GroupCommitter
@@ -263,9 +265,10 @@ def _field_recreated(h, idx, ref, ex):
 
 
 def _device_budget(h, idx, ref, ex):
-    # what server.py does for device-budget-mb: under a limit these
-    # sparse fragments turn compressed-resident, a new signature
-    DEFAULT_BUDGET.limit_bytes = 8 << 30
+    # what server.py does for device-budget-mb: under a limit their
+    # dense set does not fit (the largest such) these sparse fragments
+    # turn compressed-resident, a new signature
+    DEFAULT_BUDGET.limit_bytes = over_budget_limit(h)
 
 
 def _container_kernels(h, idx, ref, ex):
@@ -293,7 +296,9 @@ def test_token_input_moves_the_epoch(case, knobs):
     g_shards = N_SHARDS - 1 if case == "new-shard-fragment" else N_SHARDS
     h, idx, ref = build(g_shards=g_shards)
     if case == "container-kernels":
-        DEFAULT_BUDGET.limit_bytes = 8 << 30    # compressed signatures
+        # compressed signatures: the largest budget the dense set
+        # does not fit
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(h)
     ex = Executor(h, use_mesh=True)
     try:
         assert ex.execute("e", QUERY) == [ref.count()]

@@ -162,12 +162,14 @@ def test_the_configuration_is_the_source_at_its_scale():
 def test_the_cell_is_declared():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = bench["workloads"][-1]
+    cell = bench["workloads"][4]
     assert cell == {**cell, "name": CELL, "config": CONFIG, "traffic": MIX,
                     "chips": 4}
     assert len(cell["why"]) <= 200
-    assert [w["chips"] for w in bench["workloads"][:-1]] == [1, 1, 1, 1]
-    config = bench["configs"][-1]
+    # the one cell whose mechanism exists only across chips
+    assert [w["name"] for w in bench["workloads"] if w["chips"] != 1] == \
+        [CELL]
+    config = bench["configs"][3]
     assert config == {**config, "name": CONFIG, "reduced": ["fields"],
                       "file": f"benchmark/configs/{CONFIG}.json"}
     datagen = _bench()[0]
@@ -176,8 +178,10 @@ def test_the_cell_is_declared():
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index("kernels_roofline_per_chip")
     assert at == 20 and names[at + 1] == "collective_share_top10"
+    # of the metrics PR 35 found or added (the first 23), all but two
     listed = {n for n, m in by_name.items() if CELL in m["workloads"]}
-    assert set(by_name) - listed == {"kernels_roofline", "temp_split_share"}
+    assert set(names[:23]) - listed == {"kernels_roofline",
+                                        "temp_split_share"}
     for name in ("kernels_roofline_per_chip", "collective_share_top10"):
         assert by_name[name]["workloads"] == [CELL]
         assert by_name[name]["moves"] == "qps"

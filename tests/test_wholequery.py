@@ -25,6 +25,8 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import over_budget_limit
+
 from pilosa_tpu.core import SHARD_WIDTH
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor.executor import ExecutionError
@@ -133,8 +135,10 @@ def test_differential_three_legs(corpus):
         assert wq.wq_requests > 0
         want_sub = _run_corpus(legacy, SUBSET)
 
-        # compressed-resident: ample budget, packed stacks stay staged
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        # compressed-resident: the largest budget the dense set does not
+        # fit, ample for
+        # the packed stacks, which stay staged
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(corpus)
         DEFAULT_BUDGET.shrink_to_limit()
         assert _run_corpus(wq, SUBSET) == want_sub
         assert DEFAULT_BUDGET.stats()["compressedBytes"] > 0, \
@@ -280,7 +284,7 @@ def test_retrace_keeps_results(corpus):
     old = DEFAULT_BUDGET.limit_bytes
     q = "Count(Intersect(Row(a=11), Row(a=2)))"
     try:
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(corpus)
         want = {}
         for size in (20, 2, 9, 20, 1):
             got = ex.execute("w", q, shards=list(range(size)))[0]
@@ -543,19 +547,20 @@ def test_compressed_beside_dense_in_one_launch(rng, monkeypatch):
                                       shard).row(1)
         want += int(np.unpackbits(both.view(np.uint8)).sum())
     layouts, shapes = [], []
-    real = wq._unpack_frags
+    from pilosa_tpu.parallel import mesh_exec
+    real = mesh_exec._unpack_frags
 
-    def unpack(layout, arrays):
-        out = real(layout, arrays)
+    def unpack(layout, arrays, rows_of=(), stacked=None):
+        out = real(layout, arrays, rows_of, stacked)
         layouts.append([n for _, n, _ in layout])
         shapes.extend(f.shape for f in out.values())
         return out
 
-    monkeypatch.setattr(wq, "_unpack_frags", unpack)
+    monkeypatch.setattr(mesh_exec, "_unpack_frags", unpack)
     old = DEFAULT_BUDGET.limit_bytes
     ex = Executor(h, use_mesh=True, whole_query_fallback="error")
     try:
-        DEFAULT_BUDGET.limit_bytes = 256 << 20
+        DEFAULT_BUDGET.limit_bytes = over_budget_limit(h)
         DEFAULT_BUDGET.shrink_to_limit()
         forms = {h.fragment("m", f, "standard", 0).device_form()
                  for f in ("sparse", "dense")}
@@ -565,7 +570,7 @@ def test_compressed_beside_dense_in_one_launch(rng, monkeypatch):
         assert got == want
         # one launch decoded the packed entry (5 tables) beside the
         # dense one (1 array), and both came out tiled
-        assert layouts and all(sorted(l) == [1, 5] for l in layouts)
+        assert layouts and all(sorted(l) == [1, 7] for l in layouts)
         assert all(len(s) == 3 and s[1:] == WORD_TILE for s in shapes)
     finally:
         DEFAULT_BUDGET.limit_bytes = old
