@@ -21,7 +21,7 @@ CALL = re.compile(
 # layer spans name their timing in their first argument
 # (utils/tracing.py); the ones given no stats client feed no series
 SPAN = re.compile(
-    r'\blayer_span\(\s*"([^"]+)",\s*[\w.]*stats\b', re.S)
+    r'\b(?:layer|request)_span\(\s*"([^"]+)",\s*[\w.]*stats\b', re.S)
 HELPER = re.compile(r"\b_count\(")  # dotted-name prefix helpers
 NAME = re.compile(r'"([a-z0-9_]+(?:\.[a-z0-9_{}.]+)+)"')
 CATALOG = re.compile(r"<!-- metrics-catalog:begin -->(.*?)"

@@ -531,7 +531,7 @@ class Executor:
                         check_current, qprof) -> list[Any]:
         from ..utils import degraded
         from ..utils import tenant as qtenant
-        from ..utils.tracing import layer_span
+        from ..utils.tracing import request_span
         stats = self.stats
         # warm-start corpus (warmup/corpus.py) records by query TEXT —
         # the only replayable identity across restarts
@@ -607,8 +607,8 @@ class Executor:
                 if out is not None:
                     query = out  # parsed (tagged) AST — don't parse twice
         # query.plan off the prepared path: parse and translate (the
-        # lowering sits with its launch, inside query.dispatch)
-        with layer_span("query.plan", stats):
+        # lowering sits with its launch, in query.exec)
+        with request_span("query.plan", stats):
             if isinstance(query, str):
                 with stats.timer("query.parse"), qprof.stage("parse"):
                     query = parse(query)
@@ -628,8 +628,7 @@ class Executor:
         # Batched grouping reorders dispatch, which is only sound when no
         # call mutates state a later call could read — mixed write/read
         # queries run strictly sequentially like the reference.
-        with stats.timer("query.dispatch"), \
-                qprof.stage("dispatch") as dnode:
+        with qprof.stage("dispatch") as dnode:
             if dnode is not None:
                 # device-budget counters bracketing the dispatch: the
                 # deltas attribute upload/eviction traffic to THIS query
@@ -675,7 +674,7 @@ class Executor:
                     DEFAULT_BUDGET.upload_bytes - up0
                 dnode.tags["evictions"] = DEFAULT_BUDGET.evictions - ev0
         check_current("result fetch")
-        with layer_span("query.fetch", stats), qprof.stage("fetch"):
+        with request_span("query.fetch", stats), qprof.stage("fetch"):
             results = _resolve_pendings(results)
         if translate and self.translator.needs_translation(index_name):
             results = self.translator.translate_results(

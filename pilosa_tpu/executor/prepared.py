@@ -204,7 +204,7 @@ class PreparedEntry:
         Either way concurrent requests replaying the same template fuse
         into one device launch — the serving hot path the dynamic
         batching exists for."""
-        from ..utils.tracing import layer_span
+        from ..utils.tracing import request_span
         from .executor import _resolve_pendings, _run_batched_groups
 
         holder = ex.holder
@@ -218,14 +218,14 @@ class PreparedEntry:
                 ex._wq_run_batched(index, shards, groups, results)
                 ex.wq_requests += 1
                 ex.stats.count("wholequery.requests")
-                with layer_span("query.fetch", ex.stats):
+                with request_span("query.fetch", ex.stats):
                     return _resolve_pendings(results)
             except WholeQueryUnsupported as e:
                 ex._note_wq_fallback(index, e)
                 results = [None] * self.n_calls
         _run_batched_groups(ex.batcher, holder, index, shards, groups,
                             results)
-        with layer_span("query.fetch", ex.stats):
+        with request_span("query.fetch", ex.stats):
             return _resolve_pendings(results)
 
 
@@ -252,10 +252,10 @@ class PreparedCache:
         (True, results) on a hit; (False, parsed_query_or_None) on a miss
         — the parsed AST (literal-tagged, tags invisible to the classic
         path) is handed back so the caller never parses twice."""
-        from ..utils.tracing import layer_span
+        from ..utils.tracing import request_span
         # query.plan: the host's work before the launch — fingerprint,
         # lookup and guards (or the build, on a miss) and the params
-        with layer_span("query.plan", self.executor.stats):
+        with request_span("query.plan", self.executor.stats):
             entry, vals, parsed = self._lookup(index, query)
             if entry is None:
                 return False, parsed

@@ -58,7 +58,8 @@ from ..utils.deadline import DeadlineExceeded, activate, current
 from ..utils.faults import FAULTS
 from ..utils.locks import make_condition
 from ..utils.stats import BucketHistogram, NopStatsClient, ReservoirTimer
-from ..utils.tracing import GLOBAL_TRACER, layer_span
+from ..utils.hostthreads import HOST_THREADS
+from ..utils.tracing import GLOBAL_TRACER, layer_span, request_span
 from .fetch import HostView, SharedFetch
 from .mesh_exec import _DISPATCH_LOCK, field_rows
 from .nodes import BATCH_KINDS, PER_SHARD_KINDS, ROW_BYTES, \
@@ -197,7 +198,11 @@ class DispatchBatcher:
             self._ensure_thread()
             self._queue.append(t)
             self._cond.notify_all()
-        return t.future.result()
+        # query.wait: the submitting thread blocked from here until the
+        # dispatcher has launched the ticket and resolved its future —
+        # queue, window, launch and wake-up (docs/observability.md)
+        with request_span("query.wait", self.stats, kind=kind):
+            return t.future.result()
 
     @contextmanager
     def background(self):
@@ -316,6 +321,13 @@ class DispatchBatcher:
         # waits for this thread's bookkeeping, and a round's timings go
         # to the stats client together once it has ended.
         self._tid = threading.get_ident()
+        HOST_THREADS.register("dispatcher")
+        try:
+            self._rounds()
+        finally:
+            HOST_THREADS.retire()
+
+    def _rounds(self):
         rstats = self._round_stats
         while True:
             # only this thread takes tickets off the queue, so one seen

@@ -32,8 +32,9 @@ from ..utils import profile as qprof
 from ..utils import tenant as qtenant
 from ..utils.deadline import (DEADLINE_HEADER, DeadlineExceeded,
                               QueryContext, activate)
+from ..utils.hostthreads import HOST_THREADS
 from ..utils.tracing import (GLOBAL_TRACER, PROBE_HEADER, TRACE_HEADER,
-                             parse_trace_header)
+                             RequestTimings, parse_trace_header)
 from ..executor import RowResult, ValCount, RowIdentifiers
 from ..executor.results import GroupCount, Pair
 from .admission import AdmissionRejected, decorrelated_retry_after
@@ -297,6 +298,11 @@ def build_debug_vars(api: API, server=None) -> dict:
         else None
     if flightrec is not None:
         out["flightRecorder"] = flightrec.snapshot()
+    # each host thread's CPU time by role, read from the kernel here and
+    # nowhere else (docs/observability.md "Host threads")
+    threads = HOST_THREADS.snapshot()
+    if threads is not None:
+        out["hostThreads"] = threads
     return out
 
 
@@ -1020,6 +1026,18 @@ class _HandlerClass(BaseHTTPRequestHandler):
     slowlog = None
     profile_default: bool = False
 
+    # a connection's thread is a ``request`` thread of /debug/vars
+    # hostThreads from its set-up to its end (utils/hostthreads.py)
+    def setup(self):
+        HOST_THREADS.register("request")
+        super().setup()
+
+    def finish(self):
+        try:
+            super().finish()
+        finally:
+            HOST_THREADS.retire()
+
     # request helpers
     def json(self):
         if not self.body:
@@ -1102,6 +1120,9 @@ class _HandlerClass(BaseHTTPRequestHandler):
         want_explain = False
         trace_out = None
         fn_s = 0.0  # seconds inside the route's fn: http.query.self's rest
+        # the request's timings, handed over together as it ends; a
+        # query's own spans wait here too (utils/tracing.py)
+        rstats = RequestTimings()
         t_req0 = time.perf_counter()
         try:
             if fn is None:
@@ -1218,11 +1239,15 @@ class _HandlerClass(BaseHTTPRequestHandler):
                                 # searchable root-span tags:
                                 # /debug/traces?index=... filters on them
                                 span.set_tag("index", args["index"])
+                            token = rstats.install() \
+                                if gate == "query" else None
                             t_fn0 = time.perf_counter()
                             try:
                                 out = fn(self, args)
                             finally:
                                 fn_s = time.perf_counter() - t_fn0
+                                if token is not None:
+                                    rstats.uninstall(token)
                 finally:
                     if admitted:
                         adm.release()
@@ -1300,15 +1325,17 @@ class _HandlerClass(BaseHTTPRequestHandler):
             self._send(500, {"error": f"internal error: {e}"})
         finally:
             self._observe(gate, args, time.perf_counter() - t_req0,
-                          status, background, prof, erec, trace_out, fn_s)
+                          status, background, prof, erec, trace_out, fn_s,
+                          rstats)
 
     def _observe(self, gate, args, dur_s, status, background, prof,
-                 erec, trace_id, fn_s=0.0):
+                 erec, trace_id, fn_s, rstats):
         """Post-request accounting (docs/observability.md): latency
         histograms (with the trace id attached as the landing bucket's
         exemplar) + the slow-query log.  Background traffic (probes,
         status/metrics/debug) was tagged by the caller and is excluded
-        from both."""
+        from both.  Every timing of the request, its spans' included,
+        goes to the stats client in one call (``rstats.flush``)."""
         # status stamped post-finish onto the root span: the ring holds
         # Span objects and renders tags lazily, so /debug/traces search
         # by status sees it
@@ -1316,32 +1343,37 @@ class _HandlerClass(BaseHTTPRequestHandler):
         if sp is not None and trace_id is not None:
             sp.tags["status"] = status
         if background:
+            rstats.flush(self.stats)
             return
         # exemplars must RESOLVE at /debug/traces — only sampled traces
         # qualify (docs/observability.md "Trace exemplars")
         exemplar = trace_id if (sp is not None and sp.sampled
                                 and trace_id is not None) else None
-        if self.stats is not None:
-            self.stats.timing("http.request", dur_s, exemplar=exemplar)
-            if gate == "query":
-                self.stats.timing("http.query", dur_s, exemplar=exemplar)
-                # the front end's own share: admission, the tenant /
-                # deadline / profile / explain contexts, JSON and the
-                # socket write — http.query less the time inside the
-                # route's fn
-                self.stats.timing("http.query.self", dur_s - fn_s)
-                if status >= 500:
-                    # availability SLO numerator (utils/slo.py): 5xx
-                    # query responses, sheds and deadline aborts
-                    # included — the client saw a failure either way
-                    self.stats.count("http.query_5xx")
+        if gate == "query":
+            # the request thread's partition (docs/observability.md
+            # "Layer spans"): http.query.self, then inside the route's
+            # fn the spans query.plan / query.wait / query.fetch and
+            # query.exec, the rest of the fn
+            rstats.timing("query.exec", rstats.exec_seconds(fn_s))
+            # the front end's own share: admission, the tenant /
+            # deadline / profile / explain contexts, JSON and the
+            # socket write — http.query less the time inside the
+            # route's fn
+            rstats.timing("http.query.self", dur_s - fn_s)
+            rstats.timing("http.query", dur_s, exemplar=exemplar)
+            if status >= 500 and self.stats is not None:
+                # availability SLO numerator (utils/slo.py): 5xx
+                # query responses, sheds and deadline aborts
+                # included — the client saw a failure either way
+                self.stats.count("http.query_5xx")
+        rstats.timing("http.request", dur_s, exemplar=exemplar)
         # per-tenant accounting: latency/qps/error columns for the
         # /debug/vars "tenants" table and the fleet rollup
         tenant = getattr(self, "_tenant", None)
         if tenant is not None and gate == "query":
             qtenant.REGISTRY.note_request(tenant, dur_s, status)
-            if self.stats is not None:
-                self.stats.timing(f"tenant.{tenant}.query", dur_s)
+            rstats.timing(f"tenant.{tenant}.query", dur_s)
+        rstats.flush(self.stats)
         slog = self.slowlog
         if (gate == "query" and slog is not None and slog.enabled
                 and dur_s >= slog.threshold_s):

@@ -137,13 +137,15 @@ class StatsClient:
         with self._lock:
             self._timings[self._key(name)].observe(value_s, exemplar)
 
-    def timings(self, pairs: list[tuple[str, float]]):
+    def timings(self, pairs: list[tuple]):
         """Several timing observations under one acquisition of the lock
-        (the dispatch batcher hands over a round's layer spans at
-        once)."""
+        (the dispatch batcher hands over a round's layer spans at once,
+        a served request its own): ``(name, seconds)`` or ``(name,
+        seconds, exemplar)``."""
         with self._lock:
-            for name, value_s in pairs:
-                self._timings[self._key(name)].observe(value_s)
+            for name, value_s, *exemplar in pairs:
+                self._timings[self._key(name)].observe(
+                    value_s, exemplar[0] if exemplar else None)
 
     def histogram(self, name: str, value: float, rate: float = 1.0):
         self.timing(name, value, rate)
@@ -419,9 +421,9 @@ class StatsdClient(StatsClient):
         super().timing(name, value_s, rate, exemplar)
         self._send(f"{name}:{value_s * 1e3:.3f}|ms")
 
-    def timings(self, pairs: list[tuple[str, float]]):
+    def timings(self, pairs: list[tuple]):
         super().timings(pairs)
-        for name, value_s in pairs:
+        for name, value_s, *_ in pairs:
             self._send(f"{name}:{value_s * 1e3:.3f}|ms")
 
     def histogram(self, name: str, value: float, rate: float = 1.0):

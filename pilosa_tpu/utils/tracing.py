@@ -20,7 +20,9 @@ Layer spans (``layer_span``, docs/observability.md "Layer spans") are the
 second, cheaper primitive: one name that lands both on the profiler's
 clock (a ``jax.profiler.TraceAnnotation`` on the calling thread's host
 line of the same trace that holds the device ops) and in ``/debug/vars``
-``timings[name]``.  They never touch the span ring."""
+``timings[name]``.  They never touch the span ring.  A ``request_span``
+is a layer span of a served query's own thread, whose seconds wait in
+the request's ``RequestTimings`` until it ends."""
 
 from __future__ import annotations
 
@@ -130,6 +132,63 @@ class layer_span:
             self._ann.__exit__(*exc)
         if self.stats is not None:
             self.stats.timing(self.name, dt)
+
+
+class RequestTimings:
+    """A served request's timings, handed to the stats client in one
+    ``timings`` call when it ends, as the batcher's ``_RoundTimings``
+    does once a round: one acquisition of the stats lock a request.
+    The handler installs one around the route's function for a query,
+    where ``request_span`` finds it, and adds its own timings after
+    (docs/observability.md "Layer spans")."""
+
+    __slots__ = ("pending",)
+
+    def __init__(self):
+        self.pending: list[tuple] = []
+
+    def timing(self, name: str, seconds: float,
+               exemplar: str | None = None):
+        self.pending.append((name, seconds, exemplar))
+
+    def exec_seconds(self, fn_s: float) -> float:
+        """``query.exec``: the route function's ``fn_s`` less the
+        seconds of the request spans held so far — call it before the
+        handler adds its own timings.  The spans lie one after another
+        inside the function, so this is >= 0 but for rounding."""
+        return max(fn_s - sum(p[1] for p in self.pending), 0.0)
+
+    def flush(self, stats):
+        pending, self.pending = self.pending, []
+        if pending and stats is not None:
+            stats.timings(pending)
+
+    def install(self):
+        """Make this the sink of ``request_span`` in the current context;
+        returns the token ``uninstall`` takes."""
+        return _REQUEST.set(self)
+
+    @staticmethod
+    def uninstall(token):
+        _REQUEST.reset(token)
+
+
+_REQUEST: contextvars.ContextVar[RequestTimings | None] = \
+    contextvars.ContextVar("pilosa_tpu_request_timings", default=None)
+
+
+class request_span(layer_span):
+    """A layer span of a served query's own thread: one segment of the
+    partition ``http.query.self``, ``query.plan``, ``query.wait``,
+    ``query.fetch``, ``query.exec``.  Inside a query the handler serves,
+    its seconds go to that request's ``RequestTimings``; anywhere else
+    (a background thread, a bare executor) to ``stats``."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str, stats=None, **tags):
+        req = _REQUEST.get()
+        super().__init__(name, stats if req is None else req, **tags)
 
 
 class Span:

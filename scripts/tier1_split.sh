@@ -43,6 +43,7 @@ tests/test_native.py
 tests/test_observability.py
 tests/test_pql.py
 tests/test_prepared.py
+tests/test_request_partition.py
 tests/test_roaring_golden.py
 tests/test_ssb_sf30.py
 tests/test_stack_epoch.py

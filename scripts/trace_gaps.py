@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Put a profiler trace's device idle gaps down to what the dispatcher
-thread was doing.
+thread and the request threads were doing.
 
     python scripts/trace_gaps.py FILE.xplane.pb [FILE ...]
 
@@ -14,6 +14,17 @@ handler threads, the GIL), ``dispatch.window`` (the coalescing hold) and
 ``dispatch.place``, ``dispatch.enqueue`` or ``dispatch.scatter``.  This
 gives every gap's seconds to the innermost span(s) that cover it and
 prints idle seconds by span and the ten longest gaps.
+
+Beside it, how many request threads were where: a request thread's
+line (one that holds an ``http.query``) is inside ``query.wait``,
+``query.fetch``, ``query.plan``, a launch it makes itself
+(``dispatch.place`` / ``dispatch.enqueue``, where the batcher does not
+take the kind), elsewhere in ``http.query`` (its own front end and the
+rest of ``query.exec``) or outside any request.  A gap's thread-seconds
+in a state over its length is the mean number of request threads in
+that state while the device stood.  The dispatcher's spans are those of
+the line that holds its partition, so a request thread's own launch is
+never given to it.
 
 Two halves, like ``trace_reduce``: reading the file needs jax; the
 arithmetic (``flatten``, ``attribute``) takes plain tuples.
@@ -36,20 +47,40 @@ HOST_PLANE = "/host:CPU"
 # packing (np.concatenate, the pow-2 pad), the precheck, future wake-ups
 ROUND_REST = "dispatch.round (rest)"
 UNNAMED = "(no dispatcher span)"
+# the request thread's spans (docs/observability.md "Layer spans")
+REQUEST_SPANS = ("http.query", "query.plan", "query.wait", "query.fetch",
+                 "dispatch.place", "dispatch.enqueue")
+PARTITION = ("dispatch.idle", "dispatch.window", "dispatch.round")
+QUERY_REST = "http.query (rest)"    # http.query.self and query.exec
+OUTSIDE = "(outside http.query)"
 
 
-def host_spans(events: list) -> list:
-    """[(name, start_ns, end_ns)] of the dispatcher's layer spans among
-    ``trace_reduce.read_events`` tuples."""
-    return [(name, start, start + dur)
-            for plane, _line, name, start, dur in events
-            if plane == HOST_PLANE and name.startswith(SPAN_PREFIX)]
+def host_spans(lines: dict) -> list:
+    """[(name, start_ns, end_ns)] of the dispatcher's layer spans: the
+    ``dispatch.*`` events of the ``host_lines`` line(s) that hold its
+    partition."""
+    return [e for events in lines.values()
+            if any(name in PARTITION for name, _, _ in events)
+            for e in events if e[0].startswith(SPAN_PREFIX)]
 
 
-def flatten(spans: list) -> list:
+def request_lines(lines: dict) -> dict:
+    """{line: [(name, start_ns, end_ns)]} of the request spans on each
+    of ``host_lines``' lines that holds an ``http.query``."""
+    out = {}
+    for line, events in lines.items():
+        spans = [e for e in events if e[0] in REQUEST_SPANS]
+        if any(name == "http.query" for name, _, _ in spans):
+            out[line] = spans
+    return out
+
+
+def flatten(spans: list, rest: dict | None = None) -> list:
     """Disjoint, sorted [(name, start, end)] covering the same points as
     the nested ``spans``, each stretch under its innermost span's name;
-    the part of ``dispatch.round`` outside its children is ROUND_REST."""
+    the part of a span outside its children is named by ``rest``
+    (default: ``dispatch.round``'s is ROUND_REST)."""
+    rest = {"dispatch.round": ROUND_REST} if rest is None else rest
     out: list = []
     stack: list = []          # (name, end) of the open spans, outermost first
     cursor = None
@@ -58,8 +89,7 @@ def flatten(spans: list) -> list:
         nonlocal cursor
         if upto > cursor:
             name = stack[-1][0]
-            out.append((ROUND_REST if name == "dispatch.round" else name,
-                        cursor, upto))
+            out.append((rest.get(name, name), cursor, upto))
             cursor = upto
 
     for name, start, end in sorted(spans, key=lambda s: (s[1], -s[2])):
@@ -116,44 +146,76 @@ def attribute(gap_list: list, segments: list):
     return totals, per_gap
 
 
-def dispatch_lines(path: str) -> dict:
-    """{(host line index, line name): dispatch.* events on it}."""
+def threads_in(gap_list: list, lines: dict):
+    """Where the request threads were during each gap: ``lines`` maps a
+    thread's line to its ``flatten``-ed request spans.  Returns
+    ({state: thread-ns}, [{state: threads}] one a gap), a gap's
+    thread-ns in a state over its length being the mean number of
+    threads in it; OUTSIDE is a thread in no ``http.query``."""
+    totals: dict = {}
+    per_gap: list = [{} for _ in gap_list]
+    for segments in lines.values():
+        _, shares = attribute(gap_list, segments)
+        for acc, (_, gdur, sh) in zip(per_gap, shares):
+            for state, ns in sh.items():
+                state = OUTSIDE if state == UNNAMED else state
+                totals[state] = totals.get(state, 0.0) + ns
+                acc[state] = acc.get(state, 0.0) + ns / gdur
+    return totals, per_gap
+
+
+def host_lines(path: str) -> dict:
+    """{(host line index, line name): [(name, start_ns, end_ns)]}, one
+    entry a thread: Python threads share a line name, so only the index
+    tells their lines apart."""
     from jax.profiler import ProfileData
     out: dict = {}
     for plane in ProfileData.from_file(path).planes:
         if plane.name != HOST_PLANE:
             continue
         for i, line in enumerate(plane.lines):
-            n = sum(1 for e in line.events if e.name.startswith(SPAN_PREFIX))
-            if n:
-                out[(i, line.name)] = n
+            out[(i, line.name)] = [
+                (e.name, float(e.start_ns),
+                 float(e.start_ns) + float(e.duration_ns))
+                for e in line.events]
     return out
 
 
 def report(path: str) -> dict:
     events = trace_reduce.read_events(path)
-    spans = host_spans(events)
+    host = host_lines(path)
+    spans = host_spans(host)
     segments = flatten(spans)
     gap_list = [g for ops in trace_reduce.device_ops(events).values()
                 for g in trace_reduce.gaps(ops)]
     totals, per_gap = attribute(gap_list, segments)
+    lines = {line: flatten(spans, {"http.query": QUERY_REST})
+             for line, spans in request_lines(host).items()}
+    req_totals, req_per_gap = threads_in(gap_list, lines)
     idle_ns = sum(g[2] for g in gap_list)
     named = idle_ns - totals.get(UNNAMED, 0.0)
+    order = sorted(range(len(per_gap)), key=lambda i: -per_gap[i][1])[:10]
     return {
         "file": path,
-        "lines": dispatch_lines(path),
-        "partition_overlap_s": overlap_ns(
-            spans, ("dispatch.idle", "dispatch.window",
-                    "dispatch.round")) / 1e9,
+        "lines": {line: n for line, evs in host.items()
+                  if (n := sum(1 for e in evs
+                               if e[0].startswith(SPAN_PREFIX)))},
+        "partition_overlap_s": overlap_ns(spans, PARTITION) / 1e9,
         "idle_s": idle_ns / 1e9,
         "named_share": named / idle_ns if idle_ns else None,
         "by_span": sorted(((n, ns / 1e9) for n, ns in totals.items()),
                           key=lambda kv: -kv[1]),
-        "longest": [(n, d / 1e9, sorted(((s, ns / 1e9)
-                                         for s, ns in sh.items()),
-                                        key=lambda kv: -kv[1]))
-                    for n, d, sh in sorted(per_gap,
-                                           key=lambda g: -g[1])[:10]],
+        "request_threads": len(lines),
+        # mean request threads in each state over all the idle
+        "threads_by_state": sorted(
+            ((n, ns / idle_ns) for n, ns in req_totals.items()),
+            key=lambda kv: -kv[1]) if idle_ns else [],
+        "longest": [(per_gap[i][0], per_gap[i][1] / 1e9,
+                     sorted(((s, ns / 1e9)
+                             for s, ns in per_gap[i][2].items()),
+                            key=lambda kv: -kv[1]),
+                     sorted(req_per_gap[i].items(), key=lambda kv: -kv[1]))
+                    for i in order],
     }
 
 
@@ -176,10 +238,18 @@ def main(argv=None) -> int:
         print("|---|---|---|")
         for name, s in r["by_span"]:
             print(f"| `{name}` | {s:.4f} | {100 * s / r['idle_s']:.1f} % |")
+        print(f"{r['request_threads']} request threads while the device "
+              "stood, mean threads a state:")
+        print("| state | threads |")
+        print("|---|---|")
+        for name, n in r["threads_by_state"]:
+            print(f"| `{name}` | {n:.2f} |")
         print("ten longest gaps:")
-        for name, dur, shares in r["longest"]:
+        for name, dur, shares, threads in r["longest"]:
             under = ", ".join(f"{n} {1e3 * s:.2f} ms" for n, s in shares)
-            print(f"  {1e3 * dur:8.2f} ms  {name}  <- {under}")
+            where = ", ".join(f"{n} {k:.2f}" for n, k in threads)
+            print(f"  {1e3 * dur:8.2f} ms  {name}  <- {under}"
+                  + (f"; request threads: {where}" if where else ""))
     return 0
 
 

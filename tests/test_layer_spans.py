@@ -33,8 +33,8 @@ import trace_gaps  # noqa: E402
 QUERIES = ["Count(Row(f=1))", "Count(Intersect(Row(f=1), Row(f=2)))",
            "TopN(f, Row(f=1), n=2)", "Sum(Row(f=1), field=v)"]
 TIMINGS = ("http.query", "http.query.self", "query.plan", "query.fetch",
-           "dispatch.idle", "dispatch.window", "dispatch.round",
-           "dispatch.scatter", "dispatch.ticket_wait")
+           "query.wait", "query.exec", "dispatch.idle", "dispatch.window",
+           "dispatch.round", "dispatch.scatter", "dispatch.ticket_wait")
 N_SERIAL, N_THREADS, N_EACH = 6, 4, 5
 
 
@@ -70,11 +70,11 @@ def _burst(port, n_threads=N_THREADS, n_each=N_EACH):
         t.join()
 
 
-def _totals(srv) -> dict:
-    """Count and sum of every layer timing, the ledger's seconds and the
-    batcher's launch counts — read in process, at one instant, once the
-    handler threads have booked the requests already answered (a reply
-    is on the wire before ``_observe`` runs)."""
+def _settle(srv):
+    """Wait until the handler threads have booked the requests already
+    answered (a reply is on the wire before ``_observe`` hands the
+    request's timings over, its spans' included) and the dispatcher its
+    round."""
     stats = srv.api.stats
     b = srv.api.executor.batcher
     sent = stats.count_value("query")
@@ -84,6 +84,15 @@ def _totals(srv) -> dict:
                 and not b._round_stats.pending:
             break
         time.sleep(0.005)
+
+
+def _totals(srv) -> dict:
+    """Count and sum of every layer timing, the ledger's seconds and the
+    batcher's launch counts — read in process, at one instant, once
+    ``_settle``."""
+    stats = srv.api.stats
+    b = srv.api.executor.batcher
+    _settle(srv)
     out = {name: stats.timing_totals(name) for name in TIMINGS}
     out["ledger"] = devobs.LEDGER.aggregates()
     out["fused"] = b.fused_launches
@@ -147,8 +156,13 @@ def test_span_counts_what_its_boundary_implies(served, name):
     count, total = _delta(served, name)
     assert total > 0
     if name in ("http.query", "http.query.self", "query.plan",
-                "query.fetch"):
+                "query.fetch", "query.exec"):
         assert count == n
+    elif name == "query.wait":
+        # one a ticket, on the request thread that submitted it
+        assert count == n
+        assert count == served["after"]["tickets"] \
+            - served["before"]["tickets"]
     elif name == "dispatch.ticket_wait":
         # one ticket a request on the whole-query path, each counted
         # once where its launch begins
@@ -176,8 +190,10 @@ def test_sums_nest(served):
     assert 0 < parts <= busy <= wall
     assert 0 < _delta(served, "http.query.self")[1] \
         <= _delta(served, "http.query")[1]
-    # a ticket's wait ends before its request does
+    # a ticket's wait ends before its request does; the request
+    # thread's wait holds the dispatcher's for it
     assert _delta(served, "dispatch.ticket_wait")[1] \
+        <= _delta(served, "query.wait")[1] \
         <= _delta(served, "http.query")[1]
 
 
@@ -252,10 +268,19 @@ def test_profiler_trace_holds_the_spans(served, tmp_path):
         == sorted(int(e["tickets"]) for e in fused)
     assert any(j.startswith("PjitFunction(ptpu_wq_") for j in jits)
     # the arithmetic half reads the same spans
-    spans = trace_gaps.host_spans(
-        trace_gaps.trace_reduce.read_events(path))
-    assert trace_gaps.overlap_ns(
-        spans, ("dispatch.idle", "dispatch.window", "dispatch.round")) == 0
+    host = trace_gaps.host_lines(path)
+    spans = trace_gaps.host_spans(host)
+    assert {n for n, _, _ in spans} == names
+    assert trace_gaps.overlap_ns(spans, trace_gaps.PARTITION) == 0
+    # a request thread a line, though every Python thread's line has one
+    # name: no two of a line's requests overlap, and the burst's two
+    # threads are two lines
+    lines = trace_gaps.request_lines(host)
+    assert len(lines) >= 2
+    assert sum(1 for spans in lines.values() for name, _, _ in spans
+               if name == "http.query") == len(roots)
+    for spans in lines.values():
+        assert trace_gaps.overlap_ns(spans, ("http.query",)) == 0
 
 
 def test_scatter_holds_no_dispatch_lock(served, monkeypatch):
@@ -401,14 +426,19 @@ def test_timings_takes_a_rounds_observations_at_once(client):
 def test_a_prepared_miss_plans_twice_and_a_hit_once(served):
     """``query.plan`` is a plain block in ``prepared.attempt`` and another
     round parse and translate: a template's first request passes both."""
-    stats, port = served["srv"].api.stats, served["port"]
-    c0 = stats.timing_totals("query.plan")[0]
+    srv, port = served["srv"], served["port"]
+
+    def plans():
+        _settle(srv)
+        return srv.api.stats.timing_totals("query.plan")[0]
+
+    c0 = plans()
     _query(port, "Count(Union(Row(f=1), Row(f=2)))")    # built, then run
-    c1 = stats.timing_totals("query.plan")[0]
+    c1 = plans()
     _query(port, "Count(Union(Row(f=2), Row(f=1)))")    # replayed
-    c2 = stats.timing_totals("query.plan")[0]
+    c2 = plans()
     _query(port, "Set(9, f=1)")         # no template: attempt, then parse
-    c3 = stats.timing_totals("query.plan")[0]
+    c3 = plans()
     assert (c1 - c0, c2 - c1, c3 - c2) == (1, 1, 2)
 
 
@@ -456,9 +486,19 @@ def test_trace_gaps_flatten_and_attribute():
 
 
 def test_trace_gaps_reads_host_spans_from_event_tuples():
-    events = [
-        ("/host:CPU", "python", "dispatch.idle", 10.0, 5.0),
-        ("/host:CPU", "python", "np.asarray(jax.Array)", 11.0, 1.0),
-        ("/device:TPU:0", "XLA Ops", "dispatch.fake", 0.0, 1.0),
-    ]
-    assert trace_gaps.host_spans(events) == [("dispatch.idle", 10.0, 15.0)]
+    """The dispatcher's spans are the ``dispatch.*`` events of the line
+    that holds its partition: a request thread's own launch (a kind the
+    batcher does not take) stays on the request thread's line."""
+    lines = {
+        (3, "python"): [("dispatch.idle", 10.0, 15.0),
+                        ("np.asarray(jax.Array)", 11.0, 12.0),
+                        ("dispatch.round", 15.0, 20.0),
+                        ("dispatch.enqueue", 16.0, 19.0)],
+        (5, "python"): [("http.query", 0.0, 30.0),
+                        ("dispatch.enqueue", 12.0, 14.0)],
+    }
+    assert trace_gaps.host_spans(lines) == [
+        ("dispatch.idle", 10.0, 15.0), ("dispatch.round", 15.0, 20.0),
+        ("dispatch.enqueue", 16.0, 19.0)]
+    assert trace_gaps.request_lines(lines) == {
+        (5, "python"): lines[(5, "python")]}

@@ -176,9 +176,9 @@ def test_metrics_and_debug_vars(srv):
     call(srv, "POST", "/index/i/query", b"Count(Row(f=1))")
     snap = call(srv, "GET", "/debug/vars")
     assert snap["counts"]["query"] >= 1
-    # phase-level attribution (r3 verdict #10): parse/dispatch/fetch
-    # timings, budget + cache state
-    assert snap["timings"]["query.dispatch"]["count"] >= 1
+    # phase-level attribution (r3 verdict #10): exec/fetch timings,
+    # budget + cache state
+    assert snap["timings"]["query.exec"]["count"] >= 1
     assert snap["timings"]["query.fetch"]["count"] >= 1
     assert "residentBytes" in snap["deviceBudget"]
     assert snap["preparedCache"]["misses"] + \
