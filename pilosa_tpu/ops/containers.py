@@ -19,17 +19,20 @@ a container covers ``CONTAINER_WORDS`` = 2048 words = 2^16 bits):
 * **array** (type 0): ``count`` (word, value) entries, chosen for
   sparse containers (fewer than 1024 non-zero words, where 2 words an
   entry beat the bitmap's 2048).  The entries of ALL of a fragment's
-  array containers lie outside the payload, in two arrays of eight
-  *class streams*: ``a_idx[8, L]`` holds each entry's flat word index
-  (``row * SHARD_WORDS + word``) and ``a_val[8, L]`` its value, the
-  entry of word ``w`` in stream ``(w >> 7) & 7``, each stream ascending
-  and padded with ``ARRAY_PAD``.  A row of the device's word tile is
-  128 words and a vector register eight such rows, so an entry lies in
-  the SUBLANE of the register that the word it names has in the tile:
-  the TPU's gather along the lanes (``kernels.fused_row_counts``)
-  fetches a filter's words for a whole register of entries at once,
-  and a walk over the 32 registers of a shard's tile replaces one over
-  its 256 rows.  Decodes by one scatter.
+  array containers lie outside the payload, in two arrays of 32 *class
+  streams*: ``a_idx[32, L]`` holds each entry's flat word index
+  (``row * SHARD_WORDS + word``) and ``a_val[32, L]`` its value, the
+  entry of word ``w`` in stream ``q * 8 + c`` for its register group
+  ``q = (w >> 13) & 3`` and its sublane ``c = (w >> 7) & 7``, each
+  stream ascending and padded with ``ARRAY_PAD``.  A row of the
+  device's word tile is 128 words and a vector register eight such
+  rows, so an entry lies in the SUBLANE of the register that the word
+  it names has in the tile, and the eight streams of group ``q`` name
+  words of registers ``8q`` .. ``8q + 7`` alone: the TPU's gather
+  along the lanes (``kernels.fused_row_counts``) fetches a filter's
+  words for a whole register of entries at once, and a walk over the
+  8 registers of a group replaces one over a shard's 256 rows.
+  Decodes by one scatter.
 * **bitmap** (type 1): the container's 2048 words verbatim.  Chosen for
   dense containers; decodes by contiguous copy — compression-neutral by
   design, so dense corpora never regress.
@@ -90,12 +93,13 @@ RUN_SAVES_MIN = 8
 # compressed fragment's flat indices stay below 2^31 - 1), and above
 # every one, so a padded stream still ascends.
 ARRAY_PAD = np.int32((1 << 31) - 1)
-# Class streams of a fragment's array entries: one a sublane.
+# Class streams of a fragment's array entries: one a sublane of each
+# group of a filter tile's registers (eight a group).
 ARRAY_CLASSES = 8
-# ...each a whole number of registers' lanes, and of the blocks of up
-# to this many lanes one grid step of kernels.fused_row_counts takes.
+ARRAY_GROUPS = 4
+ARRAY_STREAMS = ARRAY_GROUPS * ARRAY_CLASSES
+# ...each a whole number of registers' lanes.
 ARRAY_LANES = 128
-ARRAY_BLOCK = 512
 
 # Dense fragments beyond this many rows never compress: the decode
 # scatter's flat int32 indices must stay below 2^31 — a fragment's words
@@ -114,6 +118,16 @@ def pow2_bucket(n: int) -> int:
     return 0 if n <= 0 else 1 << (int(n) - 1).bit_length()
 
 
+def _eighths_bucket(n: int, pow2_max: int) -> int:
+    """A power of two up to ``pow2_max``, and beyond a multiple of an
+    eighth of the power of two below ``n``: the padding stays under an
+    eighth."""
+    if n <= pow2_max:
+        return pow2_bucket(n)
+    step = (1 << ((int(n) - 1).bit_length() - 1)) // 8
+    return -(-int(n) // step) * step
+
+
 def payload_bucket(n: int) -> int:
     """The payload's shape bucket: a power of two up to 2^14 words, and
     beyond a multiple of an eighth of the power of two below ``n`` — the
@@ -123,18 +137,25 @@ def payload_bucket(n: int) -> int:
     fragments did not fit beside the rest, PERF.md PR 37), while an
     eighth's steps keep the padding under an eighth and every bucket a
     whole number of 2048-word blocks."""
-    if n <= 1 << 14:
-        return pow2_bucket(n)
-    step = (1 << ((int(n) - 1).bit_length() - 1)) // 8
-    return -(-int(n) // step) * step
+    return _eighths_bucket(n, 1 << 14)
 
 
 def stream_bucket(n: int) -> int:
-    """The class streams' shape bucket: 0 for none, else
-    ``payload_bucket``'s rule from one register's lanes up — a power of
-    two or, past 2^14, eighths of one: whole ``ARRAY_BLOCK``s from one
-    block on."""
-    return 0 if n <= 0 else max(ARRAY_LANES, payload_bucket(n))
+    """The class streams' shape bucket: 0 for none, else one register's
+    lanes up to a power of two of 2^11 lanes and, past it, eighths of
+    one (multiples of 256 lanes): ``payload_bucket``'s rule from a
+    quarter of its length, since 32 streams a quarter as long as eight
+    would otherwise round up to as much as 1.78 times the bytes."""
+    return 0 if n <= 0 else max(ARRAY_LANES, _eighths_bucket(n, 1 << 11))
+
+
+def stream_of(flat, groups: int = ARRAY_GROUPS):
+    """The class stream of each flat word index: ``q * 8 + c`` for the
+    word's register group ``q`` (of ``groups``) and sublane ``c`` in the
+    device's ``(256, 128)`` word tile."""
+    regs = SHARD_WORDS // (ARRAY_CLASSES * ARRAY_LANES)     # 32
+    group = ((flat >> 10) & (regs - 1)) // (regs // groups)
+    return group * ARRAY_CLASSES + ((flat >> 7) & (ARRAY_CLASSES - 1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,8 +167,8 @@ class Packed:
     counts: np.ndarray    # int32[C] entries (array) / words (bitmap) / runs
     offsets: np.ndarray   # int32[C] payload word offset (0: array)
     payload: np.ndarray   # uint32[P] bitmap blocks, then run pairs
-    a_idx: np.ndarray     # int32[8, L] array entries' flat word indices
-    a_val: np.ndarray     # uint32[8, L] ... and their values
+    a_idx: np.ndarray     # int32[32, L] array entries' flat word indices
+    a_val: np.ndarray     # uint32[32, L] ... and their values
     r_max: int            # largest run-container run count
 
     @property
@@ -157,7 +178,7 @@ class Packed:
 
     @property
     def a_len(self) -> int:
-        """Length of a class stream (the longest class, in whole
+        """Length of a class stream (the longest of the 32, in whole
         ``ARRAY_LANES``; 0: no array container)."""
         return int(self.a_idx.shape[1])
 
@@ -175,21 +196,23 @@ class Packed:
                 "run": int(np.count_nonzero(t == TYPE_RUN))}
 
 
-def class_streams(flat: np.ndarray, val: np.ndarray):
-    """(a_idx int32[8, L], a_val uint32[8, L]) of array entries given by
-    ascending flat word indices and their values: each entry in the
-    stream of its word's sublane, streams ascending, padded to a whole
-    number of ``ARRAY_LANES`` with ``ARRAY_PAD`` / 0."""
-    n = flat.size
+def class_streams(flat: np.ndarray, val: np.ndarray,
+                  groups: int = ARRAY_GROUPS):
+    """(a_idx int32[8 * groups, L], a_val uint32[8 * groups, L]) of array
+    entries given by ascending flat word indices and their values: each
+    entry in the stream of its word's register group and sublane
+    (``stream_of``), streams ascending, padded to a whole number of
+    ``ARRAY_LANES`` with ``ARRAY_PAD`` / 0."""
+    n, streams = flat.size, groups * ARRAY_CLASSES
     if n == 0:
-        return (np.zeros((ARRAY_CLASSES, 0), dtype=np.int32),
-                np.zeros((ARRAY_CLASSES, 0), dtype=np.uint32))
-    cls = ((flat >> 7) & (ARRAY_CLASSES - 1)).astype(np.intp)
+        return (np.zeros((streams, 0), dtype=np.int32),
+                np.zeros((streams, 0), dtype=np.uint32))
+    cls = stream_of(flat, groups).astype(np.intp)
     order = np.argsort(cls, kind="stable")      # ascending within a class
-    per = np.bincount(cls, minlength=ARRAY_CLASSES)
+    per = np.bincount(cls, minlength=streams)
     L = -(-int(per.max()) // ARRAY_LANES) * ARRAY_LANES
-    a_idx = np.full((ARRAY_CLASSES, L), ARRAY_PAD, dtype=np.int32)
-    a_val = np.zeros((ARRAY_CLASSES, L), dtype=np.uint32)
+    a_idx = np.full((streams, L), ARRAY_PAD, dtype=np.int32)
+    a_val = np.zeros((streams, L), dtype=np.uint32)
     c = cls[order]
     at = np.arange(n) - np.repeat(np.cumsum(per) - per, per)
     a_idx[c, at] = flat[order]
@@ -222,12 +245,11 @@ def estimate_packed_bytes(idx: np.ndarray) -> int:
     start = np.concatenate(([0], np.flatnonzero(cid[1:] != cid[:-1]) + 1))
     cnt = np.diff(start, append=idx.size)
     is_array = cnt <= ARRAY_WORDS_MAX
-    per = np.bincount(
-        (idx[np.repeat(is_array, cnt)] >> 7) & (ARRAY_CLASSES - 1),
-        minlength=ARRAY_CLASSES)
+    per = np.bincount(stream_of(idx[np.repeat(is_array, cnt)]),
+                      minlength=ARRAY_STREAMS)
     stream = -(-int(per.max()) // ARRAY_LANES) * ARRAY_LANES
     return 4 * (CONTAINER_WORDS * int(np.count_nonzero(~is_array))
-                + 2 * ARRAY_CLASSES * stream) + 16 * cnt.size
+                + 2 * ARRAY_STREAMS * stream) + 16 * cnt.size
 
 
 def pack_words(idx: np.ndarray, val: np.ndarray) -> Packed:
@@ -410,7 +432,7 @@ def decode_block(keys, types, counts, offsets, payload, a_idx, a_val, *,
     ``keys/types/counts/offsets``: int32[C], keys ascending (padded
     entries use key -1 / type -1 — they decode to nothing).
     ``payload``: uint32[P]; ``a_idx`` / ``a_val``: the array entries'
-    class streams, ``[8, a_bucket]``.  ``a_bucket``/``r_bucket``: static
+    class streams, ``[32, a_bucket]``.  ``a_bucket``/``r_bucket``: static
     per-bucket stream length and run-count maximum; 0 compiles that
     container form out entirely (a sparse-only corpus pays no run-mask
     code, a run-only corpus no scatter).
@@ -452,53 +474,53 @@ def decode_block(keys, types, counts, offsets, payload, a_idx, a_val, *,
 
 def _scatter_streams(a_idx, a_val, first, rows: int, words: int):
     """``uint32[rows, words]``: the entries of the class streams
-    ``a_idx`` / ``a_val`` ``[8, n]`` that lie in rows ``first`` ..
+    ``a_idx`` / ``a_val`` ``[streams, n]`` that lie in rows ``first`` ..
     ``first + rows`` (``first`` may be traced), each set at its word;
     all else zero.  ONE scatter whose indices ascend, are unique and in
     bounds, and are told to be: each stream fills a plane of its own —
-    the words of its sublane, in the order the stream has them — with
-    scratch words before it for the entries below ``first`` and behind
-    it for those past the last row and the padding, each entry its own;
-    the planes are then interleaved into word order, a copy of rows.
-    (Told to ascend with indices out of bounds to be dropped, the chip
-    lost updates, PERF.md PR 37: hence the scratch.)"""
+    the words of its register group and sublane, in the order the
+    stream has them — with scratch words before it for the entries below
+    ``first`` and behind it for those past the last row and the padding,
+    each entry its own; the planes are then interleaved into word order,
+    ``(rows, group, register, sublane, lane)``, a copy of rows.  (Told
+    to ascend with indices out of bounds to be dropped, the chip lost
+    updates, PERF.md §6: hence the scratch.)"""
     import jax.numpy as jnp
+    streams, n = a_idx.shape
     flat, span, plane = _stream_targets(a_idx, first, rows, words)
-    n = a_idx.shape[1]
-    planes = jnp.zeros(ARRAY_CLASSES * span, dtype=jnp.uint32).at[
+    planes = jnp.zeros(streams * span, dtype=jnp.uint32).at[
         flat.reshape(-1)].set(
         a_val.reshape(-1), mode="promise_in_bounds",
         indices_are_sorted=True, unique_indices=True).reshape(
-        ARRAY_CLASSES, span)[:, n: n + plane]
-    return planes.reshape(ARRAY_CLASSES, rows, plane // (rows * 128),
-                          128).transpose(1, 2, 0, 3).reshape(rows, words)
+        streams, span)[:, n: n + plane]
+    groups = streams // ARRAY_CLASSES
+    return planes.reshape(groups, ARRAY_CLASSES, rows,
+                          plane // (rows * ARRAY_LANES), ARRAY_LANES
+                          ).transpose(2, 0, 3, 1, 4).reshape(rows, words)
 
 
 def _stream_targets(a_idx, first, rows: int, words: int):
-    """(flat int32[8, n], span, plane): where ``_scatter_streams`` sets
-    each entry — stream ``c`` owns ``span`` = n + ``plane`` + n words
-    from ``c * span``: n scratch words, the plane, n scratch words — so
-    that, read stream after stream, the indices strictly ascend."""
+    """(flat int32[streams, n], span, plane): where ``_scatter_streams``
+    sets each entry — stream ``s`` owns ``span`` = n + ``plane`` + n
+    words from ``s * span``: n scratch words, the plane, n scratch words
+    — so that, read stream after stream, the indices strictly ascend."""
     import jax.numpy as jnp
-    n = a_idx.shape[1]
-    per = words // ARRAY_CLASSES            # a row's words in one class
+    streams, n = a_idx.shape
+    per = words // streams                  # a row's words in one stream
+    regs = per // ARRAY_LANES               # the registers of a group
     plane = rows * per
     lo = first * words
     at = jnp.arange(n, dtype=jnp.int32)[None, :]
     rel = a_idx - lo
-    # the word's place in its class's plane: row, register, lane
+    # the word's place in its stream's plane: row, register, lane
     w = rel % words
-    pos = (rel // words) * per + (w >> 10) * 128 + (w & 127)
+    pos = (rel // words) * per + ((w >> 10) & (regs - 1)) * ARRAY_LANES \
+        + (w & (ARRAY_LANES - 1))
     below, past = a_idx < lo, rel >= rows * words
     span = n + plane + n
     flat = jnp.where(below, at, jnp.where(past, n + plane + at, n + pos)) \
-        + jnp.arange(ARRAY_CLASSES, dtype=jnp.int32)[:, None] * span
+        + jnp.arange(streams, dtype=jnp.int32)[:, None] * span
     return flat, span, plane
-
-
-# A row has SHARD_WORDS / 8 words a class: no row has more entries in
-# one stream.
-_ROW_STREAM_MAX = SHARD_WORDS // ARRAY_CLASSES
 
 
 def row_has_entries(a_idx, rid, words: int = SHARD_WORDS):
@@ -539,7 +561,9 @@ def decode_row(keys, types, counts, offsets, payload, a_idx, a_val, rid,
                         tpr, r_bucket, out)
     out = out.reshape(words)
     if a_bucket:
-        w = min(a_bucket, _ROW_STREAM_MAX)
+        # a row has SHARD_WORDS / 32 words a stream: no row has more
+        # entries in one
+        w = min(a_bucket, words // a_idx.shape[0])
         lo = rid * words
 
         def scatter(out):
@@ -576,8 +600,8 @@ def pad_packed(p: Packed) -> tuple[np.ndarray, ...]:
     offsets[:c] = p.offsets
     payload = np.zeros(pb, dtype=np.uint32)
     payload[: p.payload.size] = p.payload
-    a_idx = np.full((ARRAY_CLASSES, ab), ARRAY_PAD, dtype=np.int32)
-    a_val = np.zeros((ARRAY_CLASSES, ab), dtype=np.uint32)
+    a_idx = np.full((ARRAY_STREAMS, ab), ARRAY_PAD, dtype=np.int32)
+    a_val = np.zeros((ARRAY_STREAMS, ab), dtype=np.uint32)
     a_idx[:, : p.a_len] = p.a_idx
     a_val[:, : p.a_len] = p.a_val
     return keys, types, counts, offsets, payload, a_idx, a_val

@@ -17,24 +17,27 @@ count each under the same filter, so they do not decode at all:
   fetching ``filter[word]`` for some hundred million entries a query is
   the one thing here XLA cannot do at speed (its gather fetches single
   words, about 5 ns each on a v5e).  The kernel ``container_row_counts``
-  does: the entries come in eight class streams, each entry in the
-  SUBLANE its word has in the filter's ``(256, 128)`` tile
-  (``containers.class_streams``), so for a block of entries a walk over
-  the tile's 32 vector registers takes each register ONCE, gathers along
-  its lanes by the entries' lane numbers (``take_along_axis``: the
-  TPU's own dynamic lane gather) and keeps the entries whose register it
-  is.  The streams ascend, so a block spans few rows; per-row sums go to
-  a ``(rows, 128)`` accumulator that stays in VMEM over the grid, and
-  XLA adds up its lanes.
+  does: the entries come in 32 class streams, each entry in the SUBLANE
+  its word has in the filter's ``(256, 128)`` tile and in the stream of
+  its register group, eight of the tile's 32 vector registers
+  (``containers.class_streams``), so for a block of one group's entries
+  a walk over the group's 8 registers takes each register ONCE, gathers
+  along its lanes by the entries' lane numbers (``take_along_axis``:
+  the TPU's own dynamic lane gather) and keeps the entries whose
+  register it is — 8 gathers a register of entries, where streams that
+  mixed all 32 registers took 32.  The streams ascend, so a chunk of
+  128 lanes spans few rows; per-row sums go to a ``(rows, 128)``
+  accumulator that stays in VMEM over the grid, and XLA adds up its
+  lanes.
 * Run containers (rare) build their tiles as the decode does.
 
 Under ``vmap`` (the call site maps over the stacked shard axis) jax's
 batching rule puts the shard axis on the kernel's grid: a launch is ONE
-pallas_call over (stacked shard, block of entries), pipelined across
-shards, whose steps widen where few filters leave the unrolled walk room
-(``step_lanes``).  Its per-block row spans are therefore an ordinary
-SMEM operand: a batched scalar prefetch would make it a ``while`` loop
-of one call a fragment (PERF.md PR 38).
+pallas_call over (stacked shard, register group, block of entries),
+pipelined across shards, whose steps widen where few filters leave the
+unrolled walk room (``step_lanes``).  Its per-chunk row spans are
+therefore an ordinary SMEM operand: a batched scalar prefetch would make
+it a ``while`` loop of one call a fragment (PERF.md §6).
 
 Backend selection rides the ``container-kernels`` knob
 (``CONTAINER_KERNELS``, set process-wide from the server config like
@@ -55,7 +58,7 @@ from __future__ import annotations
 import functools
 
 from ..core import CONTAINER_WORDS, SHARD_WORDS, WORD_TILE
-from .containers import ARRAY_BLOCK, ARRAY_CLASSES, TYPE_BITMAP, TYPE_RUN
+from .containers import ARRAY_CLASSES, ARRAY_GROUPS, TYPE_BITMAP, TYPE_RUN
 
 # Container kernel backend: "auto" | "pallas" | "jnp".
 # Process-wide, set from the server config (container-kernels) like
@@ -63,18 +66,25 @@ from .containers import ARRAY_BLOCK, ARRAY_CLASSES, TYPE_BITMAP, TYPE_RUN
 CONTAINER_KERNELS = "auto"
 
 TILE_LANES = WORD_TILE[1]               # 128
+# The registers of a filter tile one group's streams name.
+_GROUP_REGS = WORD_TILE[0] // ARRAY_CLASSES // ARRAY_GROUPS     # 8
+# Lanes one grid step takes at one or two params rows, at most, and
+# rows one iteration of its row loop adds up (PERF.md §6: the fastest
+# on a v5e of the widths and unrollings timed).
+STEP_LANES_MAX = 2304
+ROWS_UNROLL = 4
 
 # Params rows (padded) one fused count unrolls at most: each costs a
 # filter tile in VMEM and a gather a register; a launch of more takes
 # the decoding path.
 FUSED_PARAMS_MAX = 8       # = nodes.UNROLL_ROWS_MAX: the filters come unrolled
 # The kernel's accumulator, int32[params rows, rows, 128], stays in
-# VMEM over a shard's blocks; it and the filters' tiles (128 KiB a
-# params row) are double-buffered, a shard's pair loading while the one
-# before finishes, beside the blocks of entries: at this bound and 8
-# params rows 8 + 2 MiB + 0.25, under the 16 MiB of scoped VMEM the
-# v5e compiler grants by default (1024 rows under 8 compile for a
-# deviceless v5e: PERF.md PR 38).
+# VMEM over a shard's blocks; it and the block of the filters' tiles a
+# register group names (32 KiB a params row) are double-buffered, the
+# next loading while the one before finishes, beside the blocks of
+# entries: at this bound and 8 params rows 8 + 0.5 MiB + 0.25, under the
+# 16 MiB of scoped VMEM the v5e compiler grants by default (1024 rows
+# under 8 compile for a deviceless v5e: PERF.md §6).
 VMEM_BUDGET_BYTES = 4 << 20
 
 
@@ -135,77 +145,94 @@ def sig_backend(sig) -> str:
 
 def step_lanes(lanes: int, b: int) -> int:
     """Lanes of the class streams one grid step of ``container_row_counts``
-    takes, from the shapes alone: up to ``ARRAY_BLOCK`` times 4 // ``b``
-    (2048 at one params row, 1024 at two, 512 from four), so the
-    unrolled walk (lanes / 128 x ``b`` x 32 gathers) grows no larger than
-    at eight params rows and 512 lanes; the largest whole number of
-    registers' lanes that divides the stream, so no block is ragged and
-    no lane is read twice or past the end.  A stream bucket past 2^14 is
-    a multiple of 2048 and takes the cap (``containers.stream_bucket``)."""
+    takes, from the shapes alone: up to ``STEP_LANES_MAX`` at one or two
+    params rows and half of it from four, where a wider step's unrolled
+    walk (lanes / 128 x ``b`` x 8 gathers) spills; the largest whole
+    number of registers' lanes that divides the stream, so no block is
+    ragged and no lane is read twice or past the end."""
     chunks = lanes // TILE_LANES
-    cap = min(chunks, ARRAY_BLOCK * max(1, 4 // b) // TILE_LANES)
+    cap = min(chunks, STEP_LANES_MAX // (1 if b <= 2 else 2) // TILE_LANES)
     return TILE_LANES * max(d for d in range(1, cap + 1) if chunks % d == 0)
 
 
 def kernel_steps(lanes: int, b: int) -> int:
     """Grid steps ``container_row_counts`` takes over one fragment's
-    streams of ``lanes`` lanes under ``b`` filters."""
-    return lanes // step_lanes(lanes, b)
+    32 class streams of ``lanes`` lanes under ``b`` filters: a step a
+    block of each register group's eight."""
+    return ARRAY_GROUPS * (lanes // step_lanes(lanes, b))
+
+
+def kernel_gathers(lanes: int, b: int) -> int:
+    """Lane gathers ``container_row_counts`` makes over one fragment's
+    streams of ``lanes`` lanes under ``b`` filters — steps x 128-lane
+    chunks a step x the 8 registers of a group x ``b``: every chunk of a
+    group's block walks the group's registers, a gather a register a
+    filter."""
+    return ARRAY_GROUPS * (lanes // TILE_LANES) * _GROUP_REGS * b
 
 
 def _count_entries(filts, a_idx, a_val, rows: int, lanes: int | None = None):
     """int32[B, rows]: ``popcount(value & filter[word])`` of the array
-    entries in the class streams ``a_idx`` / ``a_val`` ``[8, L]`` summed
-    into their rows, under each of the filters' ``uint32[B, 256, 128]``
-    word tiles — the kernel ``container_row_counts``, a grid step a
-    block of ``step_lanes`` lanes of the streams (``lanes`` overrides
-    it: scripts/container_kernel_bench.py).
+    entries in the class streams ``a_idx`` / ``a_val`` ``[8 * G, L]``
+    summed into their rows, under each of the filters' ``uint32[B, 256,
+    128]`` word tiles — the kernel ``container_row_counts``, a grid step
+    a block of ``step_lanes`` lanes of one register group's eight
+    streams (``lanes`` overrides it, and G, the groups, comes from the
+    streams' shape: scripts/container_kernel_bench.py times both).
 
-    The walk over the tile's 32 registers is unrolled, every register
-    taken whether the block has entries in it or not: a loop from the
-    block's first register to its last read three times slower on the
-    chip, for a loop of traced bounds is scheduled a step at a time
-    (PERF.md PR 37: 10.4 ms a 53-shard launch of the 128-row field
-    against 3.1).  The rows a block spans are few and the loop over
-    them stays one; its bounds ride in through SMEM, reduced by XLA
+    A step reads the block of the filters' tiles that holds its group's
+    registers, and the walk over them is unrolled, every register taken
+    whether the block has entries in it or not: a loop from the block's
+    first register to its last read three times slower on the chip, for
+    a loop of traced bounds is scheduled a step at a time (PERF.md §6:
+    10.4 ms a 53-shard launch of the 128-row field against 3.1).
+    For the same reason the loop over the rows the block spans adds up
+    ``ROWS_UNROLL`` rows an iteration (the rows past the last add
+    nothing, into the accumulator's spare rows): a group's stream
+    crosses four times as many rows a block as a class's did, and a
+    loop of one row an iteration took as long as the gathers it saved
+    (PERF.md §6).  Its bounds ride in through SMEM, reduced by XLA
     beforehand, where a reduction to a scalar inside the kernel stalls
-    it.  They are an ordinary operand, not a scalar prefetch: under the
-    call site's ``vmap`` over the stacked shards jax then puts the
-    shard axis on the grid, ``(shard, block)``, one pipelined call a
-    launch, where a batched scalar prefetch is a ``while`` loop of one
-    call a fragment (PERF.md PR 38)."""
+    it, as an ordinary operand, not a scalar prefetch: under the call
+    site's ``vmap`` over the stacked shards jax then puts the shard axis
+    on the grid, ``(shard, group, block)``, one pipelined call a launch,
+    where a batched scalar prefetch is a ``while`` loop of one call a
+    fragment (PERF.md §6)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B = filts.shape[0]
-    total = rows * SHARD_WORDS
-    tl = lanes or step_lanes(a_idx.shape[1], B)
-    blocks = a_idx.shape[1] // tl
+    streams, L = a_idx.shape
+    groups = streams // ARRAY_CLASSES
+    regs = WORD_TILE[0] // ARRAY_CLASSES // groups  # registers a group
+    tl = lanes or step_lanes(L, B)
+    blocks = L // tl
     chunks = [slice(k * TILE_LANES, (k + 1) * TILE_LANES)
               for k in range(tl // TILE_LANES)]
-    regs = SHARD_WORDS // (ARRAY_CLASSES * TILE_LANES)      # 32
     # the rows each block's entries span: [first, last + 1), reduced
-    # along the lanes where they lie (a transpose that put a block's
-    # classes together first made it a reduction across the layout:
-    # 0.46 ms a 53-shard launch, PERF.md PR 38)
-    by_block = a_idx.reshape(ARRAY_CLASSES, blocks, tl) >> 15
+    # within the (8, 128) tiles where they lie (a transpose that put a
+    # block's classes together first made it a reduction across the
+    # layout: 0.46 ms a 53-shard launch, PERF.md §6); a block of
+    # padding alone spans [rows, 0), no row
+    by_block = a_idx.reshape(groups, ARRAY_CLASSES, blocks, tl) >> 15
     held = by_block < rows                  # padding is above every row
     spans = jnp.stack([
-        jnp.min(jnp.where(held, by_block, rows), axis=(0, 2)),
-        jnp.max(jnp.where(held, by_block, -1), axis=(0, 2)) + 1])
+        jnp.min(jnp.where(held, by_block, rows), axis=(1, 3)),
+        jnp.max(jnp.where(held, by_block, -1), axis=(1, 3)) + 1]
+    ).reshape(2, groups * blocks)
+    spare = ROWS_UNROLL - 1                 # rows an iteration runs past
 
     def kernel(span_ref, f_ref, idx_ref, val_ref, out_ref):
-        step = pl.program_id(0)
+        group, block = pl.program_id(0), pl.program_id(1)
+        step = group * blocks + block
 
         @pl.when(step == 0)
         def _first():
             out_ref[...] = jnp.zeros_like(out_ref)
 
         idx = idx_ref[...]
-        ok = idx < total
-        row = idx >> 15
         reg = (idx >> 10) & (regs - 1)
         lane = idx & (TILE_LANES - 1)
         got = [jnp.zeros((ARRAY_CLASSES, tl), dtype=jnp.uint32)] * B
@@ -217,36 +244,44 @@ def _count_entries(filts, a_idx, a_val, rows: int, lanes: int | None = None):
                     [jnp.take_along_axis(words, lane[:, c], axis=1)
                      for c in chunks], axis=1)
                 got[b] = jnp.where(mine, here, got[b])
+        # padding's value is 0: it adds nothing to any row
         val = val_ref[...]
-        bits = [jnp.where(ok, jax.lax.population_count(
-            val & g).astype(jnp.int32), 0) for g in got]
+        bits = [jax.lax.population_count(val & g).astype(jnp.int32)
+                for g in got]
+        row = idx >> 15
+        lo, hi = span_ref[0, step], span_ref[1, step]
 
-        def add(r, carry):
-            mine = row == r
-            for b in range(B):
-                x = jnp.where(mine, bits[b], 0)
-                part = x[:, chunks[0]]
-                for c in chunks[1:]:
-                    part = part + x[:, c]
-                out_ref[b, pl.ds(r, 1), :] += jnp.sum(
-                    part, axis=0, keepdims=True)
+        def add(i, carry):
+            for u in range(ROWS_UNROLL):
+                r = lo + i * ROWS_UNROLL + u
+                mine = row == r
+                for b in range(B):
+                    x = jnp.where(mine, bits[b], 0)
+                    part = x[:, chunks[0]]
+                    for c in chunks[1:]:
+                        part = part + x[:, c]
+                    out_ref[b, pl.ds(r, 1), :] += jnp.sum(
+                        part, axis=0, keepdims=True)
             return carry
 
-        jax.lax.fori_loop(span_ref[0, step], span_ref[1, step], add, 0)
+        jax.lax.fori_loop(0, (hi - lo + spare) // ROWS_UNROLL, add, 0)
 
     out = pl.pallas_call(
         kernel,
-        grid=(blocks,),
+        grid=(groups, blocks),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(filts.shape, lambda i: (0, 0, 0)),
-                  pl.BlockSpec((ARRAY_CLASSES, tl), lambda i: (0, i)),
-                  pl.BlockSpec((ARRAY_CLASSES, tl), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((B, rows, TILE_LANES), lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, rows, TILE_LANES), jnp.int32),
+                  pl.BlockSpec((B, regs * ARRAY_CLASSES, TILE_LANES),
+                               lambda g, i: (0, g, 0)),
+                  pl.BlockSpec((ARRAY_CLASSES, tl), lambda g, i: (g, i)),
+                  pl.BlockSpec((ARRAY_CLASSES, tl), lambda g, i: (g, i))],
+        out_specs=pl.BlockSpec((B, rows + spare, TILE_LANES),
+                               lambda g, i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, rows + spare, TILE_LANES),
+                                       jnp.int32),
         interpret=interpret_mode(),
         name="container_row_counts",
     )(spans, filts, a_idx, a_val)
-    return out.sum(axis=2)
+    return out[:, :rows].sum(axis=2)
 
 
 def fused_row_counts(keys, types, counts, offsets, payload, a_idx, a_val,

@@ -246,19 +246,20 @@ def launch_form(layout, node) -> str:
 
 
 def launch_cost(layout, node, b_pad: int, shards: int,
-                stacked: int) -> tuple[int, int, int, int]:
+                stacked: int) -> tuple[int, int, int, int, int]:
     """What one launch of ``b_pad`` params rows over ``shards`` stacked
     shards (``stacked`` with the bucket's padding) costs: (dense tile
     bytes decoded from compressed stacks, Pallas kernel launches,
-    container tiles those count, grid steps they take) — the launch
-    ledger's ``decodeBytesTotal`` / ``kernelLaunches`` / ``kernelTiles``
-    / ``kernelStepsTotal``.  A fragment decoded whole costs its rows, a
+    container tiles those count, grid steps they take, lane gathers
+    they make) — the launch ledger's ``decodeBytesTotal`` /
+    ``kernelLaunches`` / ``kernelTiles`` / ``kernelStepsTotal`` /
+    ``kernelGathersTotal``.  A fragment decoded whole costs its rows, a
     row take of a compressed fragment one row a params row, and a field
     counted in the packed stream decodes nothing: where the Pallas
     kernel counts it, that is ONE ``pallas_call`` over the launch's
     field's container tiles, whose grid is (stacked shard, block of
-    entries): ``kernels.kernel_steps`` a stacked shard, padding
-    included."""
+    entries): ``kernels.kernel_steps`` and ``kernels.kernel_gathers`` a
+    stacked shard, padding included."""
     from ..ops import kernels as _kernels
     tile = SHARD_WORDS * 4
     fused = _counts_in_place(layout, node, b_pad)
@@ -280,8 +281,9 @@ def launch_cost(layout, node, b_pad: int, shards: int,
         sig = fused[2]
         return (decode, 1,
                 shards * sig[1] * (SHARD_WORDS // CONTAINER_WORDS),
-                stacked * _kernels.kernel_steps(sig[4], b_pad))
-    return decode, 0, 0, 0
+                stacked * _kernels.kernel_steps(sig[4], b_pad),
+                stacked * _kernels.kernel_gathers(sig[4], b_pad))
+    return decode, 0, 0, 0, 0
 
 
 def node_over_layout(node, layout, mat, shard, stacked):
@@ -364,7 +366,7 @@ class _InstrumentedExec:
         if rows is None:
             rows = meta_rows if meta_rows is not None else b_pad
         tickets = ctx.get("tickets", 1)
-        decode, kernels_n, tiles, steps = launch_cost(
+        decode, kernels_n, tiles, steps, gathers = launch_cost(
             self.layout, self.node, b_pad, shards, shards_pad)
         reg = _devobs.COMPILES
         reg.begin_call()
@@ -394,7 +396,7 @@ class _InstrumentedExec:
             decode_bytes=decode,
             slice_pos=_devobs.current_slice(),
             kernel_launches=kernels_n, kernel_tiles=tiles,
-            kernel_steps=steps)
+            kernel_steps=steps, kernel_gathers=gathers)
         prof = qprof.current()
         if prof is not None:
             # rows/padding/decode tags feed the EXPLAIN launches section
@@ -1190,7 +1192,7 @@ class MeshExecutor:
         Transfers move compressed bytes, so there is no warm-mirror
         stacking variant — re-shipping a packed stream is already far
         cheaper than a dense stack ever was."""
-        from ..ops.containers import ARRAY_CLASSES, ARRAY_PAD
+        from ..ops.containers import ARRAY_PAD, ARRAY_STREAMS
         cb, pb, ab = sig[2], sig[3], sig[4]
         n = len(frs)
         bucket = self._bucket(n)
@@ -1199,9 +1201,9 @@ class MeshExecutor:
         counts = np.zeros((bucket, cb), dtype=np.int32)
         offsets = np.zeros((bucket, cb), dtype=np.int32)
         payload = np.zeros((bucket, pb), dtype=np.uint32)
-        a_idx = np.full((bucket, ARRAY_CLASSES, ab), ARRAY_PAD,
+        a_idx = np.full((bucket, ARRAY_STREAMS, ab), ARRAY_PAD,
                         dtype=np.int32)
-        a_val = np.zeros((bucket, ARRAY_CLASSES, ab), dtype=np.uint32)
+        a_val = np.zeros((bucket, ARRAY_STREAMS, ab), dtype=np.uint32)
         for i, fr in enumerate(frs):
             p = fr.packed_host()
             # a concurrent write may race the signature; clamping to the

@@ -263,7 +263,8 @@ class _InstrumentedWhole:
             slice_pos=_devobs.current_slice(),
             kernel_launches=m.get("kernel_launches", 0),
             kernel_tiles=m.get("kernel_tiles", 0),
-            kernel_steps=m.get("kernel_steps", 0))
+            kernel_steps=m.get("kernel_steps", 0),
+            kernel_gathers=m.get("kernel_gathers", 0))
         prof = qprof.current()
         if prof is not None:
             # rows/padding/decode tags feed the EXPLAIN launches section
@@ -432,8 +433,8 @@ class WholeQueryRunner:
                              nodes.mat_rows(pad_mats[ni]), bucket, bucket)
                  for gi, (bucket, g) in enumerate(zip(buckets, live))
                  for ni in range(len(program)) if gi in sched[ni]]
-        decode_bytes, kernel_launches, kernel_tiles, kernel_steps = (
-            sum(c[k] for c in costs) for k in range(4))
+        (decode_bytes, kernel_launches, kernel_tiles, kernel_steps,
+         kernel_gathers) = (sum(c[k] for c in costs) for k in range(5))
         launch_meta = {
             "shards": sum(len(g[0]) for g in live),
             "shards_padded": sum(buckets),
@@ -443,6 +444,7 @@ class WholeQueryRunner:
             "kernel_launches": kernel_launches,
             "kernel_tiles": kernel_tiles,
             "kernel_steps": kernel_steps,
+            "kernel_gathers": kernel_gathers,
             "temp_bytes": temp_bytes,
             "compiled": fresh,
         }

@@ -319,12 +319,15 @@ class LaunchLedger:
         # Pallas container-kernel accounting (ops/kernels.py): the
         # kernel's pallas_calls (one a launch that counts a field in
         # its packed streams), the container tiles they counted over,
-        # and the grid steps they took (stacked shard x block of
-        # entries) — decode bytes measured as tile traffic instead of
-        # an XLA temp watermark
+        # the grid steps they took (stacked shard x register group x
+        # block of entries) and the lane gathers those made (a chunk of
+        # 128 lanes x a register of its group x a params row) — decode
+        # bytes measured as tile traffic instead of an XLA temp
+        # watermark
         self.kernel_launches_total = 0
         self.kernel_tiles_total = 0
         self.kernel_steps_total = 0
+        self.kernel_gathers_total = 0
         # host seconds of the dispatch path's layers, summed over every
         # launch (docs/observability.md "Layer spans"): waiting for the
         # batcher, stacking and placing, handing programs to the runtime
@@ -354,7 +357,8 @@ class LaunchLedger:
                batch_rows_padded: int, queue_s: float, dispatch_s: float,
                decode_bytes: int, compiled: bool, tickets: int = 1,
                slice_pos: tuple | None = None, kernel_launches: int = 0,
-               kernel_tiles: int = 0, kernel_steps: int = 0):
+               kernel_tiles: int = 0, kernel_steps: int = 0,
+               kernel_gathers: int = 0):
         actual = max(shards, 0) * max(batch_rows, 1)
         total = max(shards_padded, shards) * max(batch_rows_padded,
                                                  batch_rows, 1)
@@ -375,6 +379,7 @@ class LaunchLedger:
             entry["kernelLaunches"] = kernel_launches
             entry["kernelTiles"] = kernel_tiles
             entry["kernelSteps"] = kernel_steps
+            entry["kernelGathers"] = kernel_gathers
         with self._lock:
             self._ring.append(entry)
             self.launches_total += 1
@@ -388,6 +393,7 @@ class LaunchLedger:
             self.kernel_launches_total += kernel_launches
             self.kernel_tiles_total += kernel_tiles
             self.kernel_steps_total += kernel_steps
+            self.kernel_gathers_total += kernel_gathers
             self.queue_seconds_total += queue_s
             self.dispatch_seconds_total += dispatch_s
         self.launch_hist.observe(dispatch_s)
@@ -433,6 +439,7 @@ class LaunchLedger:
                 "kernelLaunches": self.kernel_launches_total,
                 "kernelTiles": self.kernel_tiles_total,
                 "kernelStepsTotal": self.kernel_steps_total,
+                "kernelGathersTotal": self.kernel_gathers_total,
                 "queueSecondsTotal": self.queue_seconds_total,
                 "placeSecondsTotal": self.place_seconds_total,
                 "dispatchSecondsTotal": self.dispatch_seconds_total,

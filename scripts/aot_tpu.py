@@ -32,13 +32,13 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 # (rows, C, P, A, R) decode buckets of chip_smoke.py's sparse corpus
-# (954 shards, seed 7) as the chip saw them
-SMOKE_BUCKETS = ((4, 64, 0, 512, 0), (4, 64, 0, 1024, 0),
-                 (8, 128, 0, 1024, 0), (8, 128, 128, 1024, 64))
+# (seed 7), 32 class streams a fragment
+SMOKE_BUCKETS = ((4, 64, 0, 256, 0), (4, 64, 0, 512, 0),
+                 (8, 128, 0, 512, 0), (8, 128, 128, 512, 64))
 # ...and the two fields the benchmark's taxi-1b-chip1 counts in place,
 # as its generator packs them (total_amount_dollars, 53 stacked shards a
-# launch; dist_miles, up to 96): 2 048-lane steps divide both streams
-BENCH_BUCKETS = ((128, 2048, 589824, 36864, 0), (64, 1024, 294912, 8192, 0))
+# launch; dist_miles, up to 96), a group's streams 9 216 and 2 304 lanes
+BENCH_BUCKETS = ((128, 2048, 589824, 9216, 0), (64, 1024, 294912, 2304, 0))
 
 
 def v5e_topology():
@@ -58,6 +58,7 @@ def kernel_cases(bucket, stacked: int = 2) -> dict:
     step width (``kernels.step_lanes``)."""
     from pilosa_tpu.core import WORD_TILE
     from pilosa_tpu.ops import kernels
+    from pilosa_tpu.ops.containers import ARRAY_STREAMS
     rows, C, P, A, R = bucket
     kw = dict(rows=rows, a_bucket=A, r_bucket=R, backend="pallas")
 
@@ -65,8 +66,8 @@ def kernel_cases(bucket, stacked: int = 2) -> dict:
         return jax.ShapeDtypeStruct((stacked,) + shape, dtype)
 
     packed = [aval((C,), jnp.int32)] * 4 + [
-        aval((P,), jnp.uint32), aval((8, A), jnp.int32),
-        aval((8, A), jnp.uint32)]
+        aval((P,), jnp.uint32), aval((ARRAY_STREAMS, A), jnp.int32),
+        aval((ARRAY_STREAMS, A), jnp.uint32)]
     cases = {"fused_row_counts": (
         jax.vmap(lambda *a: kernels.fused_row_counts(*a, None, **kw)),
         packed)}

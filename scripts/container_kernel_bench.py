@@ -1,20 +1,21 @@
 #!/usr/bin/env python3
 """Time the container kernel alone: ms a launch of the fare field's
-array entries, 53 stacked shards, for each step width and params rows,
-as a loop of one call a fragment against one grid over the shards.
+array entries, 53 stacked shards, for each register-group count and
+step width, with the lane gathers a step makes beside it.
 
 The fare field is ``taxi-1b-chip1``'s ``total_amount_dollars`` (128
 rows), each shard made by the benchmark's own generator and packed as
-the server packs it; its streams are stacked at the launch's bucket
-(``containers.stream_bucket``: 36 864 lanes a class).  Each form is
-``kernels._count_entries`` — the kernel ``container_row_counts`` and
-the span reduction before it — under
-
-* ``grid``: ``jax.vmap`` over the stacked shards, as the call site
-  maps it: one ``pallas_call`` whose grid is (shard, block);
-* ``loop``: ``jax.lax.map`` over them, a ``while`` of one call a
-  fragment — what jax's batching rule made of the kernel while its
-  spans were a scalar prefetch (PERF.md PR 38).
+the server packs it.  Its array entries are laid out in ``8 * G`` class
+streams (``containers.class_streams``): G = 4, the layout the server
+keeps (a stream a register group of 8 registers and a sublane: 9 216
+lanes a stream at the launch's bucket, ``containers.stream_bucket``);
+G = 8 (groups of 4 registers: half the gathers, twice the rows a
+chunk); and G = 1, the eight class streams over all 32 registers that
+the layout replaced.  Each is counted by ``kernels._count_entries`` —
+the kernel ``container_row_counts`` and the span reduction before it —
+under ``jax.vmap`` over the stacked shards, as the call site maps it:
+one ``pallas_call`` whose grid is (shard, group, block).  A step of
+``lanes`` lanes makes lanes / 128 x 32 / G x params rows gathers.
 
 Every form's counts are checked against XLA's gather form
 (``backend="jnp"``).  One JSON line on stdout and in
@@ -42,41 +43,53 @@ import numpy as np  # noqa: E402
 FIELD = "total_amount_dollars"
 
 
-def fare_streams(shards: int, seed: int):
-    """(a_idx, a_val) ``[shards, 8, A]`` of the fare field's generated
-    shards, padded as ``MeshExecutor._stack_z`` pads them, and the
-    field's rows."""
+def fare_packs(shards: int, seed: int):
+    """The fare field's generated shards' array entries (ascending flat
+    indices, values) as the server packs them, and the field's rows."""
     import datagen
     import loader
-    from pilosa_tpu.ops.containers import (
-        ARRAY_CLASSES, ARRAY_PAD, pack_words, stream_bucket)
+    from pilosa_tpu.ops.containers import ARRAY_PAD, pack_words
     cfg = datagen.load_json("configs", "taxi-1b-chip1")
     field = next(f for f in cfg["fields"] if f["name"] == FIELD)
-    packs = []
+    entries = []
     for shard in range(shards):
         block = loader.field_block(
             field, datagen.shard_columns(cfg, seed, shard)[FIELD])
         flat = block.reshape(-1)
         nz = np.flatnonzero(flat)
-        packs.append(pack_words(nz.astype(np.int64), flat[nz]))
-    lanes = stream_bucket(max(p.a_len for p in packs))
-    a_idx = np.full((shards, ARRAY_CLASSES, lanes), ARRAY_PAD,
+        p = pack_words(nz.astype(np.int64), flat[nz])
+        live = p.a_idx != ARRAY_PAD
+        order = np.argsort(p.a_idx[live])
+        entries.append((p.a_idx[live][order], p.a_val[live][order]))
+    return entries, loader.field_rows(field)
+
+
+def stacked_streams(entries, groups: int):
+    """(a_idx, a_val) ``[shards, 8 * groups, L]``: each shard's entries in
+    ``8 * groups`` class streams, padded to the launch's bucket as
+    ``MeshExecutor._place_packed_block`` pads them."""
+    from pilosa_tpu.ops.containers import (
+        ARRAY_PAD, class_streams, stream_bucket)
+    streams = [class_streams(i, v, groups) for i, v in entries]
+    lanes = stream_bucket(max(a.shape[1] for a, _ in streams))
+    a_idx = np.full((len(streams), 8 * groups, lanes), ARRAY_PAD,
                     dtype=np.int32)
-    a_val = np.zeros((shards, ARRAY_CLASSES, lanes), dtype=np.uint32)
-    for i, p in enumerate(packs):
-        a_idx[i, :, : p.a_len] = p.a_idx
-        a_val[i, :, : p.a_len] = p.a_val
-    return a_idx, a_val, loader.field_rows(field), \
-        sum(p.a_len for p in packs) * ARRAY_CLASSES
+    a_val = np.zeros((len(streams), 8 * groups, lanes), dtype=np.uint32)
+    for k, (i, v) in enumerate(streams):
+        a_idx[k, :, : i.shape[1]] = i
+        a_val[k, :, : v.shape[1]] = v
+    return a_idx, a_val
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[1])
     ap.add_argument("--shards", type=int, default=53)
     ap.add_argument("--seed", type=int, default=3800000038)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--params-rows", default="1,2,4")
-    ap.add_argument("--lanes", default="512,1024,2048")
+    ap.add_argument("--groups", default="4,8,1")
+    ap.add_argument("--lanes", default="512,768,1024,1152,1536,2048,2304,"
+                                       "3072,4608")
     args = ap.parse_args(argv)
 
     import jax
@@ -85,61 +98,65 @@ def main(argv=None) -> int:
     from pilosa_tpu.ops import kernels
 
     t0 = time.perf_counter()
-    a_idx, a_val, rows, entries = fare_streams(args.shards, args.seed)
-    gen_s = time.perf_counter() - t0
-    idx, val = jnp.asarray(a_idx), jnp.asarray(a_val)
-    rng = np.random.default_rng(args.seed)
+    entries, rows = fare_packs(args.shards, args.seed)
     out = {"device": jax.devices()[0].device_kind, "shards": args.shards,
-           "rows": rows, "lanes_a_class": a_idx.shape[2],
-           "entries": int(entries), "interpreted": kernels.interpret_mode(),
-           "generate_s": round(gen_s, 2), "ms_a_launch": {}}
-    for b in (int(x) for x in args.params_rows.split(",")):
-        filts = jnp.asarray(rng.integers(
-            0, 1 << 32, (args.shards, b) + WORD_TILE,
-            dtype=np.uint64).astype(np.uint32))
-        # XLA's gather and scatter-add: the counts every form must give
-        flat = filts.reshape(args.shards, b, SHARD_WORDS)
+           "rows": rows, "entries": int(sum(i.size for i, _ in entries)),
+           "interpreted": kernels.interpret_mode(),
+           "generate_s": round(time.perf_counter() - t0, 2),
+           "lanes_a_stream": {}, "ms_a_launch": {}}
+    rng = np.random.default_rng(args.seed)
+    for g in (int(x) for x in args.groups.split(",")):
+        a_idx, a_val = stacked_streams(entries, g)
+        L = a_idx.shape[2]
+        out["lanes_a_stream"][f"g{g}"] = L
+        idx, val = jnp.asarray(a_idx), jnp.asarray(a_val)
+        for b in (int(x) for x in args.params_rows.split(",")):
+            filts = jnp.asarray(rng.integers(
+                0, 1 << 32, (args.shards, b) + WORD_TILE,
+                dtype=np.uint64).astype(np.uint32))
+            # XLA's gather and scatter-add: the counts every form must give
+            flat = filts.reshape(args.shards, b, SHARD_WORDS)
 
-        def xla(f, i, v, _b=b):
-            i, v = i.reshape(-1), v.reshape(-1)
-            ok = i < rows * SHARD_WORDS
-            bits = jnp.where(ok, jax.lax.population_count(
-                v & f[:, jnp.where(ok, i % SHARD_WORDS, 0)]), 0)
-            return jnp.zeros((_b, rows), jnp.int32).at[
-                :, jnp.where(ok, i // SHARD_WORDS, rows)].add(
-                    bits.astype(jnp.int32), mode="drop")
+            def xla(f, i, v, _b=b):
+                i, v = i.reshape(-1), v.reshape(-1)
+                ok = i < rows * SHARD_WORDS
+                bits = jnp.where(ok, jax.lax.population_count(
+                    v & f[:, jnp.where(ok, i % SHARD_WORDS, 0)]), 0)
+                return jnp.zeros((_b, rows), jnp.int32).at[
+                    :, jnp.where(ok, i // SHARD_WORDS, rows)].add(
+                        bits.astype(jnp.int32), mode="drop")
 
-        want = np.asarray(jax.jit(jax.vmap(xla))(flat, idx, val))
-        for lanes in (int(x) for x in args.lanes.split(",")):
-            # no body past the largest the server compiles (8 params
-            # rows at 512 lanes: kernels.step_lanes)
-            if a_idx.shape[2] % lanes or lanes * b > 8 * 512:
-                continue
-
-            def one(f, i, v, _lanes=lanes):
-                return kernels._count_entries(f, i, v, rows, lanes=_lanes)
-
-            forms = {
-                "grid": jax.jit(jax.vmap(one)),
-                "loop": jax.jit(lambda f, i, v, _one=one: jax.lax.map(
-                    lambda x: _one(*x), (f, i, v))),
-            }
-            for name, fn in forms.items():
+            want = np.asarray(jax.jit(jax.vmap(xla))(flat, idx, val))
+            for lanes in (int(x) for x in args.lanes.split(",")):
+                # no body past the largest the server compiles (8 params
+                # rows at 512 lanes: kernels.step_lanes), by its gathers
+                gathers = lanes // 128 * (32 // g) * b
+                if L % lanes or gathers > 8 * 4 * 8 * 4:
+                    continue
+                fn = jax.jit(jax.vmap(
+                    lambda f, i, v, _lanes=lanes: kernels._count_entries(
+                        f, i, v, rows, lanes=_lanes)))
+                name = f"g{g}.b{b}.{lanes}"
                 t0 = time.perf_counter()
-                got = np.asarray(fn(filts, idx, val))
+                try:
+                    got = np.asarray(fn(filts, idx, val))
+                except Exception as e:  # the compiler's refusal, reported
+                    out["ms_a_launch"][name] = {"error": str(e)[:300]}
+                    continue
                 compile_s = time.perf_counter() - t0
                 if not np.array_equal(got, want):
-                    print(json.dumps({"wrong": [name, b, lanes]}))
+                    print(json.dumps({"wrong": [g, b, lanes]}))
                     return 1
                 times = []
                 for _ in range(args.iters):
                     t0 = time.perf_counter()
                     fn(filts, idx, val).block_until_ready()
                     times.append((time.perf_counter() - t0) * 1e3)
-                out["ms_a_launch"][f"b{b}.{lanes}.{name}"] = {
+                out["ms_a_launch"][name] = {
                     "median": round(statistics.median(times), 4),
                     "min": round(min(times), 4),
-                    "steps": args.shards * a_idx.shape[2] // lanes,
+                    "steps": args.shards * g * L // lanes,
+                    "gathers_a_step": gathers,
                     "compile_s": round(compile_s, 2)}
     line = json.dumps(out)
     print(line)

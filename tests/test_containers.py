@@ -179,34 +179,80 @@ def test_decode_bucket_padding(rng):
     import jax.numpy as jnp
     padded = [jnp.asarray(a) for a in pad_packed(p)]
     assert padded[0].size == pow2_bucket(p.keys.size)
-    assert padded[5].shape == (8, stream_bucket(p.a_len))
+    assert padded[5].shape == (containers.ARRAY_STREAMS,
+                                stream_bucket(p.a_len))
     got = np.asarray(containers.decode_block(
         *padded, rows=rows, a_bucket=stream_bucket(p.a_len),
         r_bucket=pow2_bucket(p.r_max)))
     np.testing.assert_array_equal(got, _oracle(idx, val, rows))
 
 
-def test_class_streams_layout(rng):
-    """An array entry lies in the stream of its word's sublane in the
-    device's word tile, every stream ascends (padding included), and
-    the streams hold exactly the array containers' words."""
-    rows = 3
+# (rows, entries): streams a register's lanes long and several blocks
+# long; a fragment whose entries thin out across its rows (the fare
+# field's tail, a chunk of which spans many rows); one entry alone
+LAYOUT_CASES = [(3, 7000), (16, 60000), (40, 2500), (2, 1)]
+
+
+@pytest.mark.parametrize("groups", (4, 8))
+@pytest.mark.parametrize("rows,n", LAYOUT_CASES,
+                         ids=[f"r{r}-n{n}" for r, n in LAYOUT_CASES])
+def test_class_streams_layout(rng, rows, n, groups):
+    """An array entry lies in the stream ``q * 8 + c`` of its word's
+    register group ``q`` and sublane ``c`` in the device's word tile,
+    every stream ascends and is padded above, and the streams hold
+    exactly the array containers' words — as packed (four groups) and
+    in the eight groups the kernel bench times against them."""
     flat = np.zeros(rows * SHARD_WORDS, dtype=np.uint32)
-    flat[rng.choice(flat.size, 7000, replace=False)] = rng.integers(
-        1, 1 << 32, 7000, dtype=np.uint64).astype(np.uint32)
-    flat[SHARD_WORDS: SHARD_WORDS + 2 * CONTAINER_WORDS] |= 1   # bitmaps
+    # denser in the low rows, as the fare field is
+    w = 0.8 ** np.repeat(np.arange(rows), SHARD_WORDS)
+    at = rng.choice(flat.size, n, replace=False, p=w / w.sum())
+    flat[at] = rng.integers(1, 1 << 32, n, dtype=np.uint64).astype(
+        np.uint32)
+    flat[SHARD_WORDS: SHARD_WORDS + 2 * CONTAINER_WORDS] |= 1 \
+        if rows > 1 else 0                                      # bitmaps
     idx, val = _store(flat)
     p = pack_words(idx, val)
-    assert p.a_len % containers.ARRAY_LANES == 0
-    assert p.a_idx.shape == p.a_val.shape == (8, p.a_len)
-    live = p.a_idx != containers.ARRAY_PAD
-    for c in range(8):
-        assert ((p.a_idx[c][live[c]] >> 7) & 7 == c).all()
-        assert (np.diff(p.a_idx[c].astype(np.int64)) >= 0).all()
     in_array = ~np.isin(idx // CONTAINER_WORDS,
                         p.keys[p.types != TYPE_ARRAY])
+    a_idx, a_val = (p.a_idx, p.a_val) if groups == containers.ARRAY_GROUPS \
+        else containers.class_streams(idx[in_array], val[in_array], groups)
+    streams = 8 * groups
+    assert a_idx.shape == a_val.shape == (streams, a_idx.shape[1])
+    assert a_idx.shape[1] % containers.ARRAY_LANES == 0
+    live = a_idx != containers.ARRAY_PAD
+    regs = 32 // groups
+    for s_ in range(streams):
+        q, c = divmod(s_, 8)
+        mine = a_idx[s_][live[s_]]
+        assert ((mine >> 7) & 7 == c).all()
+        assert (((mine >> 10) & 31) // regs == q).all()
+        assert (np.diff(a_idx[s_].astype(np.int64)) >= 0).all()
+        # live entries first, the padding above them, its values 0
+        k = int(live[s_].sum())
+        assert live[s_][:k].all() and (a_val[s_][k:] == 0).all()
+    np.testing.assert_array_equal(containers.stream_of(idx, groups),
+                                  ((idx >> 7) & 7) + 8 * (
+                                      ((idx >> 10) & 31) // regs))
     assert int(live.sum()) == int(in_array.sum()) == p.array_words
-    np.testing.assert_array_equal(np.sort(p.a_idx[live]), idx[in_array])
+    order = np.argsort(a_idx[live])
+    np.testing.assert_array_equal(a_idx[live][order], idx[in_array])
+    np.testing.assert_array_equal(a_val[live][order], val[in_array])
+
+
+# stream lengths -> buckets: a power of two up to 2^11 lanes, then
+# eighths of the power of two below (the fare field's 9 216 a group)
+STREAM_BUCKETS = [(0, 0), (1, 128), (128, 128), (129, 256), (2048, 2048),
+                  (2049, 2304), (4097, 4608), (9216, 9216), (9217, 10240),
+                  (36865, 40960)]
+
+
+@pytest.mark.parametrize("n,bucket", STREAM_BUCKETS)
+def test_stream_bucket_rule(n, bucket):
+    """The grouped streams' bucket holds at most an eighth of padding
+    past 2^11 lanes and is a whole number of registers' lanes."""
+    assert stream_bucket(n) == bucket
+    if n > 1 << 11:
+        assert bucket % 256 == 0 and bucket - n < bucket / 8
 
 
 def test_scatter_targets_ascend(rng):
@@ -227,7 +273,7 @@ def test_scatter_targets_ascend(rng):
         t, span, plane = containers._stream_targets(idx, first, k,
                                                     SHARD_WORDS)
         t = np.asarray(t).astype(np.int64).reshape(-1)
-        assert t.min() >= 0 and t.max() < 8 * span
+        assert t.min() >= 0 and t.max() < containers.ARRAY_STREAMS * span
         assert (np.diff(t) > 0).all()
 
     ascends(a_idx, 0, rows)
@@ -266,6 +312,75 @@ def test_decode_row_scatters_only_when_told(rng):
         full = np.asarray(containers.decode_row(*arrs, r, **kw))
         np.testing.assert_array_equal(full, want[r])
         assert (bare == want[r]).all() == (not has[r])
+
+
+def _stacked(packs):
+    """The packed arrays of ``packs`` stacked as a launch stacks them:
+    every fragment padded to the largest buckets among them."""
+    import jax.numpy as jnp
+    cols = [pad_packed(p) for p in packs]
+    out = []
+    for k, fill in enumerate((-1, -1, 0, 0, 0, containers.ARRAY_PAD, 0)):
+        n = max(c[k].shape[-1] for c in cols)
+        a = np.full((len(cols),) + cols[0][k].shape[:-1] + (n,), fill,
+                    dtype=cols[0][k].dtype)
+        for i, c in enumerate(cols):
+            a[i, ..., : c[k].shape[-1]] = c[k]
+        out.append(jnp.asarray(a))
+    return out
+
+
+# (rows, shards): which row of which shard is array entries, which a
+# bitmap container, which both and which empty follows the shard
+STACK_CASES = [(4, 2), (6, 3), (3, 4)]
+
+
+@pytest.mark.parametrize("rows,shards", STACK_CASES,
+                         ids=[f"r{r}-s{s}" for r, s in STACK_CASES])
+def test_decode_row_block_across_shards(rng, rows, shards):
+    """``decode_row`` (under the call site's ``vmap``, told by
+    ``row_has_entries`` of the whole stacked block) and ``decode_block``
+    give the dense fragment back for every row of every shard, where one
+    row is array entries in some shards and bitmap containers in
+    others, and a shard's streams in some groups are all padding."""
+    import jax.numpy as jnp
+    fl, packs = [], []
+    for s in range(shards):
+        flat = np.zeros(rows * SHARD_WORDS, dtype=np.uint32)
+        for r in range(rows):
+            base = r * SHARD_WORDS
+            form = (r + s) % 4
+            if form == 0:          # array entries, register groups 0-1
+                at = base + rng.choice(SHARD_WORDS // 2, 300 + 40 * r,
+                                       replace=False)
+                flat[at] = rng.integers(1, 1 << 32, at.size,
+                                        dtype=np.uint64).astype(np.uint32)
+            elif form == 1:        # a bitmap container
+                flat[base + CONTAINER_WORDS * (r % 16): base
+                     + CONTAINER_WORDS * (r % 16 + 1)] = 7 + r
+            elif form == 2:        # both, the entries in group 3 alone
+                flat[base: base + CONTAINER_WORDS] = 3
+                at = base + 3 * SHARD_WORDS // 4 + rng.choice(
+                    SHARD_WORDS // 4, 90, replace=False)
+                flat[at] = 1 + s
+        fl.append(flat.reshape(rows, SHARD_WORDS))
+        idx, val = _store(flat)
+        packs.append(pack_words(idx, val))
+    arrs = _stacked(packs)
+    kw = dict(rows=rows, a_bucket=int(arrs[5].shape[-1]),
+              r_bucket=max(pow2_bucket(p.r_max) for p in packs))
+    take = jax.jit(jax.vmap(
+        lambda *a: containers.decode_row(
+            *a[:7], a[7], has_array=containers.row_has_entries(
+                arrs[5], a[7]), **kw),
+        in_axes=(0,) * 7 + (None,)))
+    want = np.stack(fl)
+    for r in range(rows + 1):
+        got = np.asarray(take(*arrs, jnp.int32(r)))
+        np.testing.assert_array_equal(
+            got, want[:, r] if r < rows else 0)
+    whole = jax.jit(jax.vmap(lambda *a: containers.decode_block(*a, **kw)))
+    np.testing.assert_array_equal(np.asarray(whole(*arrs)), want)
 
 
 # -- density heuristic / fragment forms -------------------------------------

@@ -237,47 +237,24 @@ def _stack_packs(packs, a_bucket):
     return [jnp.asarray(np.stack(col)) for col in zip(*cols)]
 
 
-# (stacked shards, params rows): each step width (2 048 lanes at one
-# params row, 1 024 at two, 512 at eight) over streams it divides and
-# over streams it does not, where the largest divisor steps
-GRID_CASES = [(s, b) for s in (1, 3, 5) for b in (1, 2, 8)]
-# class-stream lanes a case stacks, by its shard count: 4 096 takes every
-# cap whole; 2 560 (20 registers' lanes) takes 1 280 at one params row,
-# 640 at two; 1 536 takes 1 536, 768 and 512
-GRID_LANES = {1: 4096, 3: 2560, 5: 1536}
+# (stacked shards, params rows): each step width (2 304 lanes at one and
+# two params rows, 1 152 from four) over streams it divides and over
+# streams it does not, where the largest divisor steps
+GRID_CASES = [(s, b) for s in (1, 3, 5) for b in (1, 2, 4, 8)]
+# class-stream lanes a case stacks, by its shard count: 4 608 takes every
+# cap whole; 2 560 (20 registers' lanes) takes 1 280 at one and two
+# params rows, 640 from four; 1 536 takes 1 536 and 768
+GRID_LANES = {1: 4608, 3: 2560, 5: 1536}
 
 
-@pytest.mark.parametrize("shards,b", GRID_CASES,
-                         ids=[f"s{s}-b{b}" for s, b in GRID_CASES])
-def test_kernel_grid_over_shards(rng, shards, b):
-    """The count of a launch's stacked shards, the kernel's grid
-    (stacked shard, block of entries), against XLA's form of it and the
-    numpy golden: the step widths and the divisor they fall back to, a
-    shard whose stream is empty (all padding), and rows whose entries
-    run across block boundaries (4 rows over up to 8 blocks a shard).
-    Padding entries are never counted."""
+def _launch_golden(packs, rows, lanes, b, rng):
+    """The count of ``packs`` stacked at ``lanes`` lanes a stream under
+    ``b`` random filters a shard, by the kernel (interpreted) and by
+    XLA's form of it, against the numpy golden."""
     import jax
     import jax.numpy as jnp
-    rows, lanes = 4, GRID_LANES[shards]
-    packs = []
-    for s in range(shards):
-        # the first of several shards holds nothing; the others fill
-        # 30-80 % of the bucket, every row in array containers
-        n = 0 if s == 0 and shards > 1 else \
-            int(8 * lanes * (0.3 + 0.5 * s / shards))
-        flat = np.sort(rng.choice(rows * SHARD_WORDS, n, replace=False)) \
-            .astype(np.int64)
-        packs.append(pack_words(flat, rng.integers(
-            1, 1 << 32, n, dtype=np.uint64).astype(np.uint32)))
-        assert packs[-1].a_len <= lanes
-    assert packs[-1].type_histogram()["array"] > 0
-    tl = kernels.step_lanes(lanes, b)
-    assert lanes % tl == 0 and tl <= 2048 // min(b, 4)
-    assert kernels.kernel_steps(lanes, b) == lanes // tl
-    if shards == 3:
-        assert lanes % (2048 // min(b, 4)) != 0 or b == 8
     arrs = _stack_packs(packs, lanes)
-    filts = rng.integers(0, 1 << 32, (shards, b, 256, 128),
+    filts = rng.integers(0, 1 << 32, (len(packs), b, 256, 128),
                          dtype=np.uint64).astype(np.uint32)
     want = np.stack([
         np.stack([_popcounts(unpack_packed(p, rows) & f.reshape(1, -1))
@@ -287,6 +264,91 @@ def test_kernel_grid_over_shards(rng, shards, b):
             *a, rows=rows, a_bucket=lanes, r_bucket=0, backend=_b)))
         np.testing.assert_array_equal(
             np.asarray(fn(*arrs, jnp.asarray(filts))), want, backend)
+
+
+@pytest.mark.parametrize("shards,b", GRID_CASES,
+                         ids=[f"s{s}-b{b}" for s, b in GRID_CASES])
+def test_kernel_grid_over_shards(rng, shards, b):
+    """The count of a launch's stacked shards, the kernel's grid
+    (stacked shard, register group, block of entries), against XLA's
+    form of it and the numpy golden: the step widths and the divisor
+    they fall back to, a shard whose streams are empty (all padding),
+    and rows whose entries run across block boundaries (12 rows over up
+    to 4 blocks a stream), some blocks spanning a number of rows the
+    row loop's unrolling does not divide.  Padding entries are never counted."""
+    rows, lanes = 12, GRID_LANES[shards]
+    packs = []
+    for s in range(shards):
+        # the first of several shards holds nothing; the others fill
+        # 30-70 % of the bucket, every row in array containers
+        n = 0 if s == 0 and shards > 1 else \
+            int(containers.ARRAY_STREAMS * lanes * (0.3 + 0.5 * s / shards))
+        flat = np.sort(rng.choice(rows * SHARD_WORDS, n, replace=False)) \
+            .astype(np.int64)
+        packs.append(pack_words(flat, rng.integers(
+            1, 1 << 32, n, dtype=np.uint64).astype(np.uint32)))
+        assert packs[-1].a_len <= lanes
+    assert packs[-1].type_histogram()["array"] > 0
+    assert packs[-1].type_histogram()["bitmap"] == 0
+    tl = kernels.step_lanes(lanes, b)
+    cap = 2304 if b <= 2 else 1152
+    assert lanes % tl == 0 and tl <= cap
+    assert kernels.kernel_steps(lanes, b) == \
+        containers.ARRAY_GROUPS * lanes // tl
+    assert (tl == cap) == (shards == 1)
+    _launch_golden(packs, rows, lanes, b, rng)
+
+
+@pytest.mark.parametrize("shards", (1, 3))
+@pytest.mark.parametrize("b", (1, 2, 4, 8))
+def test_kernel_sparse_tail_and_empty_groups(rng, shards, b):
+    """Chunks that span many rows — the sparse tail of a field whose rows
+    thin out geometrically, as the fare field's do, where one 128-lane
+    chunk crosses dozens of rows — and register groups whose streams
+    are all padding (a shard's entries in the first half of each row's
+    words alone, or in the last quarter), counted exactly."""
+    rows = 48
+    packs = []
+    for s in range(shards):
+        w = 0.85 ** np.arange(rows)
+        per_row = np.maximum((3000 * w / w[0]).astype(int), 1)
+        parts = []
+        for r, k in enumerate(per_row):
+            lo, span = ((0, SHARD_WORDS // 2), (3 * SHARD_WORDS // 4,
+                                                SHARD_WORDS // 4),
+                        (0, SHARD_WORDS))[s % 3]
+            parts.append(r * SHARD_WORDS + lo + rng.choice(
+                span, min(k, span // 4), replace=False))
+        flat = np.sort(np.concatenate(parts)).astype(np.int64)
+        packs.append(pack_words(flat, rng.integers(
+            1, 1 << 32, flat.size, dtype=np.uint64).astype(np.uint32)))
+    assert all(p.type_histogram()["bitmap"] == 0 for p in packs)
+    first = packs[0].a_idx
+    # shard 0's groups 2 and 3 hold nothing: all padding
+    assert (first[16:] == containers.ARRAY_PAD).all()
+    lanes = stream_bucket(max(p.a_len for p in packs))
+    # the last live chunk of a stream spans many rows
+    live = first[0][first[0] != containers.ARRAY_PAD]
+    tail = live[-(live.size % 128 or 128):] >> 15
+    assert tail.max() - tail.min() > 8
+    _launch_golden(packs, rows, lanes, b, rng)
+
+
+@pytest.mark.parametrize("b", (1, 2, 4, 8))
+def test_kernel_gathers_a_quarter(b):
+    """The kernel makes a lane gather a 128-lane chunk, a register of its
+    group and a params row: 8 registers a chunk, where a walk over the
+    whole tile's 32 made four times as many for the same entries — at
+    the fare field's bucket, 9 216 lanes a grouped stream (36 864 a
+    class stream before)."""
+    lanes = 9216
+    tl = kernels.step_lanes(lanes, b)
+    steps = kernels.kernel_steps(lanes, b)
+    assert steps == containers.ARRAY_GROUPS * lanes // tl
+    assert kernels.kernel_gathers(lanes, b) == \
+        steps * (tl // 128) * 8 * b
+    assert kernels.kernel_gathers(lanes, b) * 4 == \
+        (4 * lanes // 128) * 32 * b
 
 
 @pytest.mark.parametrize("b", (1, 2, 8))
@@ -307,8 +369,9 @@ def test_vmapped_kernel_lowers_to_one_grid(monkeypatch, b):
         return jax.ShapeDtypeStruct((53,) + shape, dtype)
 
     avals = [aval((C,), jnp.int32)] * 4 + [
-        aval((P,), jnp.uint32), aval((8, A), jnp.int32),
-        aval((8, A), jnp.uint32), aval((b,) + WORD_TILE, jnp.uint32)]
+        aval((P,), jnp.uint32), aval((containers.ARRAY_STREAMS, A), jnp.int32),
+        aval((containers.ARRAY_STREAMS, A), jnp.uint32),
+        aval((b,) + WORD_TILE, jnp.uint32)]
     text = jax.jit(jax.vmap(lambda *a: kernels.fused_row_counts(
         *a, rows=rows, a_bucket=A, r_bucket=R, backend="pallas"))).trace(
             *avals).lower(lowering_platforms=("tpu",)).as_text()
@@ -589,6 +652,7 @@ def test_three_leg_differential(corpus, force_backend):
         DEFAULT_BUDGET.shrink_to_limit()
         kj = devobs.LEDGER.kernel_launches_total
         sj = devobs.LEDGER.kernel_steps_total
+        gj = devobs.LEDGER.kernel_gathers_total
         assert _run_corpus(ex, queries) == want
         assert _run_corpus(stage, queries) == want
         st = DEFAULT_BUDGET.stats()
@@ -598,6 +662,7 @@ def test_three_leg_differential(corpus, force_backend):
         assert devobs.LEDGER.kernel_launches_total == kj, \
             "jnp kill-switch leg launched container kernels"
         assert devobs.LEDGER.kernel_steps_total == sj
+        assert devobs.LEDGER.kernel_gathers_total == gj
 
         # leg 3 — compressed residency, the Pallas kernel (interpreted
         # on CPU): same bytes, plus kernel launches in the ledger
@@ -616,6 +681,13 @@ def test_three_leg_differential(corpus, force_backend):
                if e.get("kernelLaunches")]
         assert ran and all(e["kernelLaunches"] == 1 and e["kernelSteps"]
                            % e["shardsPadded"] == 0 for e in ran)
+        # a step's every 128-lane chunk gathers from its group's 8
+        # registers, once a params row: /debug/vars' kernelGathersTotal
+        assert all(e["kernelGathers"] % (8 * e["batchRowsPadded"]) == 0
+                   and e["kernelGathers"] >= 8 * e["kernelSteps"]
+                   for e in ran)
+        assert devobs.LEDGER.aggregates()["kernelGathersTotal"] == \
+            devobs.LEDGER.kernel_gathers_total > gj
 
         # flip back: the kill switch restores the jnp path in place
         force_backend("jnp")

@@ -228,18 +228,21 @@ def test_launch_form_and_cost():
     count = ReduceNode("count", plan, None, ())
 
     def layout(backend):
-        return ((amount, 7, ("z", 128, 2048, 1 << 19, 35840, 0, backend)),
+        return ((amount, 7, ("z", 128, 2048, 1 << 19, 9216, 0, backend)),
                 (year, 1, (8, 256, 128)),
-                (pcount, 7, ("z", 16, 256, 1 << 17, 3584, 0, backend)))
+                (pcount, 7, ("z", 16, 256, 1 << 17, 1024, 0, backend)))
 
     # 53 shards stacked at 56: the kernel is one launch a launch, over
     # the real shards' tiles, and its grid steps every stacked shard's
-    # blocks: 35 840 lanes in 20 steps of 1 792 at one params row (the
-    # largest divisor under 2 048), 70 of 512 at four
+    # four register groups' blocks: 9 216 lanes a stream in 4 steps of
+    # 2 304 a group at one params row, 8 of 1 152 at four; each step's
+    # 128-lane chunks gather from their group's 8 registers, a params
+    # row each
     for backend, form, one, four in (
-            ("pallas", "z:pallas", (1, 53 * 128 * 16, 56 * 20),
-             (1, 53 * 128 * 16, 56 * 70)),
-            ("jnp", "z:jnp", (0, 0, 0), (0, 0, 0))):
+            ("pallas", "z:pallas",
+             (1, 53 * 128 * 16, 56 * 16, 56 * 16 * 18 * 8),
+             (1, 53 * 128 * 16, 56 * 32, 56 * 32 * 9 * 8 * 4)),
+            ("jnp", "z:jnp", (0, 0, 0, 0), (0, 0, 0, 0))):
         lay = layout(backend)
         assert launch_form(lay, topn) == form
         assert launch_form(lay, count) == "z:jnp"
@@ -248,14 +251,16 @@ def test_launch_form_and_cost():
         # past what a fused count unrolls: the decoding path
         assert launch_cost(lay, topn, 2 * kernels.FUSED_PARAMS_MAX, 1,
                            1) == (
-            (128 + 2 * kernels.FUSED_PARAMS_MAX) * tile, 0, 0, 0)
+            (128 + 2 * kernels.FUSED_PARAMS_MAX) * tile, 0, 0, 0, 0)
         # a count reads its plan's rows alone, of the fields it reads
-        assert launch_cost(lay[1:], count, 2, 1, 1) == (2 * tile, 0, 0, 0)
+        assert launch_cost(lay[1:], count, 2, 1, 1) == (
+            2 * tile, 0, 0, 0, 0)
     assert launch_form(layout("pallas")[1:2], count) == "dense"
-    assert launch_cost(layout("pallas")[1:2], count, 1, 1, 1) == (0, 0, 0, 0)
+    assert launch_cost(layout("pallas")[1:2], count, 1, 1, 1) == \
+        (0, 0, 0, 0, 0)
     # no plan: nothing to take rows of, nothing decoded
     bare = ReduceNode("row_counts", None, amount, ())
-    assert launch_cost(layout("jnp")[:1], bare, 1, 1, 1) == (0, 0, 0, 0)
+    assert launch_cost(layout("jnp")[:1], bare, 1, 1, 1) == (0, 0, 0, 0, 0)
 
 
 def test_form_and_slice_tags():
